@@ -61,12 +61,12 @@
 #include <immintrin.h>
 #endif
 
-// io_uring multishot-receive backend: raw syscalls against the uapi
-// header (no liburing in the image).  Multishot recv + provided buffer
-// rings need kernel >= 6.0 at RUNTIME (probed; seccomp-blocked or old
-// kernels fall back to recvmmsg), and the uapi header in the image may
-// predate them — those constants/structs are ABI-frozen, so the missing
-// ones are self-defined below rather than compiled out.
+// io_uring multishot-receive backend: raw syscalls against the installed
+// uapi header (no liburing in the image).  Multishot recv + provided
+// buffer rings need kernel >= 6.0 at RUNTIME (probed; seccomp-blocked or
+// old kernels fall back to recvmmsg).  The header must carry them at
+// BUILD time (IORING_RECV_MULTISHOT, 6.0+): an older header compiles the
+// backend out and every reader takes recvmmsg.
 #if defined(__linux__) && __has_include(<linux/io_uring.h>)
 #include <linux/io_uring.h>
 #if __has_include(<linux/time_types.h>)
@@ -76,40 +76,9 @@
 #include <sys/syscall.h>
 #include <csignal>
 #if defined(IOSQE_BUFFER_SELECT) && defined(IORING_FEAT_EXT_ARG) && \
-    defined(IORING_ENTER_EXT_ARG) && defined(IORING_CQE_F_MORE)
+    defined(IORING_ENTER_EXT_ARG) && defined(IORING_CQE_F_MORE) && \
+    defined(IORING_RECV_MULTISHOT)
 #define VN_HAVE_IOURING 1
-// uapi additions newer than the image's header (values are kernel ABI)
-#ifndef IORING_RECV_MULTISHOT
-#define IORING_RECV_MULTISHOT (1U << 1)  // sqe->ioprio flag, 6.0+
-#endif
-#ifndef IORING_REGISTER_PBUF_RING
-#define IORING_REGISTER_PBUF_RING 22     // 5.19+
-#define IORING_UNREGISTER_PBUF_RING 23
-struct io_uring_buf {
-  __u64 addr;
-  __u32 len;
-  __u16 bid;
-  __u16 resv;
-};
-struct io_uring_buf_ring {
-  union {
-    struct {
-      __u64 resv1;
-      __u32 resv2;
-      __u16 resv3;
-      __u16 tail;
-    };
-    struct io_uring_buf bufs[0];
-  };
-};
-struct io_uring_buf_reg {
-  __u64 ring_addr;
-  __u32 ring_entries;
-  __u16 bgid;
-  __u16 flags;
-  __u64 resv[3];
-};
-#endif  // IORING_REGISTER_PBUF_RING
 #endif
 #endif
 
@@ -1284,7 +1253,11 @@ struct UringRx {
   size_t cq_len = 0;
   io_uring_sqe* sqes = nullptr;
   size_t sqes_len = 0;
-  io_uring_buf_ring* br = nullptr;
+  // The provided-buffer ring is addressed as the plain io_uring_buf array
+  // it is (tail overlaid on slot 0's resv): the uapi header's
+  // io_uring_buf_ring::bufs goes through __DECLARE_FLEX_ARRAY, whose empty
+  // struct has size 1 in C++ and shifts `bufs` 8 bytes off the ring.
+  io_uring_buf* br = nullptr;
   size_t br_len = 0;
   std::vector<char> pktmem;
   size_t bufsz = 0;
@@ -1325,14 +1298,14 @@ struct UringRx {
 
   // Return a consumed buffer to the kernel's provided-buffer ring.
   void recycle(unsigned bid) {
-    io_uring_buf* b = &br->bufs[br_tail & (nbufs - 1)];
+    io_uring_buf* b = &br[br_tail & (nbufs - 1)];
     b->addr = (__u64)(uintptr_t)buf_at(bid);
     b->len = (__u32)bufsz;
     b->bid = (__u16)bid;
     br_tail++;
   }
   void recycle_commit() {
-    __atomic_store_n(&br->tail, br_tail, __ATOMIC_RELEASE);
+    __atomic_store_n(&br[0].resv, br_tail, __ATOMIC_RELEASE);
   }
 
   // Push + submit one multishot recv SQE.  The kernel re-posts CQEs off
@@ -1409,8 +1382,8 @@ struct UringRx {
 
     pktmem.resize((size_t)nbufs * bufsz);
     br_len = (size_t)nbufs * sizeof(io_uring_buf);
-    br = (io_uring_buf_ring*)mmap(nullptr, br_len, PROT_READ | PROT_WRITE,
-                                  MAP_ANONYMOUS | MAP_PRIVATE, -1, 0);
+    br = (io_uring_buf*)mmap(nullptr, br_len, PROT_READ | PROT_WRITE,
+                             MAP_ANONYMOUS | MAP_PRIVATE, -1, 0);
     if (br == MAP_FAILED) return br = nullptr, false;
     io_uring_buf_reg reg{};
     reg.ring_addr = (__u64)(uintptr_t)br;

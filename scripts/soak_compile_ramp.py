@@ -28,11 +28,8 @@ from veneur_tpu.samplers.metric_key import (  # noqa: E402
 
 
 def main() -> int:
-    import jax
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.join(os.path.dirname(os.path.dirname(
-                          os.path.abspath(__file__))), ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    from veneur_tpu.util import compile_cache
+    compile_cache.enable(min_compile_secs=0.0)
 
     max_keys = int(sys.argv[1]) if len(sys.argv) > 1 else 1_000_000
     interval = float(sys.argv[2]) if len(sys.argv) > 2 else 10.0

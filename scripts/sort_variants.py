@@ -35,6 +35,7 @@ from jax.experimental.pallas import tpu as pltpu
 sys.path.insert(0, "/root/repo")
 
 from veneur_tpu.ops import sorted_eval as se
+from veneur_tpu.util import compile_cache
 
 _PAD = se._PAD_KEY
 
@@ -228,8 +229,7 @@ def main():
     modes = (sys.argv[5].split(",") if len(sys.argv) > 5
              else list(STAGES) + list(COMPACT_MODES))
 
-    jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    compile_cache.enable(min_compile_secs=0.0)
     print(f"device: {jax.devices()[0]} K={k} D={d} inner={inner} "
           f"pipeline={pipeline}", flush=True)
     rng = np.random.default_rng(0)

@@ -1,5 +1,6 @@
 """CLI entry-point tests (cmd/veneur, veneur-emit, veneur-prometheus)."""
 
+import os
 import socket
 import threading
 from http.server import BaseHTTPRequestHandler, HTTPServer
@@ -395,3 +396,44 @@ def test_emit_grpc_mode_statsd_and_ssf():
         assert span.metrics[0].value == 1.5
     finally:
         srv.shutdown()
+
+
+def _run_chip_smoke(*args):
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env["JAX_PLATFORMS"] = "cpu"
+    return subprocess.run(
+        [sys.executable, os.path.join(repo, "chip_smoke.py"), *args],
+        capture_output=True, text=True, timeout=600, cwd=repo, env=env)
+
+
+def test_chip_smoke_rehearsal_runs_the_whole_control_flow():
+    """`chip_smoke.py --rehearse`: the chip run's control flow on the CPU
+    at a tiny size — global in-process, 2 CLI locals, sender child, every
+    check — so a later PR cannot break the script unnoticed.  Its last
+    line says ok: false: a rehearsal can never pass as a chip run."""
+    import json
+
+    run = _run_chip_smoke("--rehearse")
+    assert run.returncode == 0, run.stdout[-3000:] + run.stderr[-3000:]
+    lines = [json.loads(ln) for ln in run.stdout.splitlines()]
+    assert {"rehearsal": True} in lines
+    checks = [ln for ln in lines if "check" in ln]
+    assert checks and all(c["ok"] for c in checks)
+    names = {c["check"] for c in checks}
+    assert {"global_native_engine", "local_boot", "dense_flush_shape",
+            "direct_interval", "percentiles_interval_0", "local_no_loss",
+            "global_no_loss", "no_compile_after_first_interval"} <= names
+    assert lines[-1] == {"ok": False, "device": {
+        "platform": "cpu", "kind": "cpu", "count": 1}}
+
+
+def test_chip_smoke_fails_without_an_accelerator():
+    """No TPU -> non-zero exit before any traffic, and no result line."""
+    run = _run_chip_smoke()
+    assert run.returncode != 0
+    assert '"ok"' not in run.stdout.splitlines()[-1]
+    assert "sender" not in run.stdout

@@ -144,8 +144,8 @@ def test_kernel_interpret_parity_classic():
 def test_kernel_interpret_parity_dma():
     import jax.numpy as jnp
     rng = np.random.default_rng(5)
-    u, d = 8192, 16
-    assert me._auto_nbuf(u, me._lane_tile(u)) > 1   # DMA path engaged
+    u, d = 8192, 128
+    assert me._auto_nbuf(u, me._lane_tile(u), d) > 1   # DMA path engaged
     dv, dw, ab, lab = _rand_dense(rng, u, d)
     twin = np.asarray(me._moments_sums_twin(
         jnp.asarray(dv), jnp.asarray(dw), jnp.asarray(ab),

@@ -784,3 +784,33 @@ def test_reader_backend_forced_recvmmsg():
     send.close()
     sock.close()
     eng.close()
+
+
+def test_server_refuses_to_start_without_buildable_engine(tmp_path,
+                                                          monkeypatch):
+    """`native_ingest: true` with an engine that cannot be built is a
+    BOOT ERROR carrying the compiler's words — never a warning and a
+    silent Python packet path.  `native_ingest: false` still boots."""
+    from veneur_tpu.core.server import Server
+
+    broken = tmp_path / "ingest_engine.cpp"
+    broken.write_text("this is not C++;\n")
+    monkeypatch.setattr(ingest_mod, "_SRC", str(broken))
+    monkeypatch.setattr(ingest_mod, "_SO",
+                        str(tmp_path / ".build" / "libvningest.so"))
+    monkeypatch.setattr(ingest_mod, "_lib", None)
+    cfg = dict(statsd_listen_addresses=["udp://127.0.0.1:0"],
+               interval=600.0, hostname="t")
+    srv = Server(config_mod.Config(native_ingest=True, **cfg))
+    try:
+        with pytest.raises(RuntimeError, match="build failed"):
+            srv.start()
+        assert srv.native is None and not srv.statsd_addrs
+    finally:
+        srv.shutdown()
+    srv = Server(config_mod.Config(native_ingest=False, **cfg))
+    try:
+        srv.start()
+        assert srv.native is None and srv.statsd_addrs
+    finally:
+        srv.shutdown()

@@ -24,9 +24,14 @@ from hypothesis import given, settings, strategies as st
 from veneur_tpu import ingest as ingest_mod
 from tests.test_native_ingest import native_parse, python_reference_parse
 
-pytestmark = pytest.mark.skipif(
-    ingest_mod.load_library() is None,
-    reason="native ingest engine unavailable")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _native_engine():
+    """Build/load inside a fixture: an engine that does not build is a
+    FAILED test here, not a collection error (and never a skip)."""
+    return ingest_mod.load_library()
+
 
 FUZZ_SETTINGS = settings(max_examples=250, deadline=None,
                          derandomize=True)

@@ -274,9 +274,14 @@ def test_lane_tile_v3_and_compact_predicates():
     assert se._lane_tile(32768, 128) == 512     # below cutoff
     assert se._lane_tile(131072, 256) == 512    # paired d=256: unchanged
     # DMA coarsening: engages at >= 16 steps, divides evenly, else off
-    assert se._auto_nbuf(131072, 512) == 4
-    assert se._auto_nbuf(4096, 512) == 1
-    assert se._auto_nbuf(16384, 1024) == 4
+    assert se._auto_nbuf(131072, 512, 256) == 4
+    assert se._auto_nbuf(4096, 512, 256) == 1
+    assert se._auto_nbuf(16384, 1024, 128) == 4
+    # ... and only where the [tile, d] row slices fill whole 128-lane
+    # rows: Mosaic refuses the DMA of a narrower minor dimension, so
+    # shallow depths keep the classic path at every key count
+    assert se._auto_nbuf(131072, 1024, 32) == 1
+    assert se._auto_nbuf(1048576, 1024, 64) == 1
     assert se.usable_compact(131072, 32, "tpu")
     assert se.usable_compact(131072, 64, "tpu")
     assert not se.usable_compact(131072, 128, "tpu")   # too deep

@@ -304,8 +304,9 @@ class Config:
     num_workers: int = 1
     num_readers: int = 1
     # native C++ data plane for UDP DogStatsD (recvmmsg readers + batch
-    # parser + columnar staging, native/ingest_engine.cpp); falls back to
-    # the Python path if the engine cannot be built
+    # parser + columnar staging, native/ingest_engine.cpp); a server
+    # whose engine cannot be built or loaded refuses to start — false
+    # asks for the Python packet path
     native_ingest: bool = True
     # native data-plane tuning (engine defaults when 0 / "auto"):
     #   ingest_reader_shards   SO_REUSEPORT sockets + native reader threads
@@ -350,14 +351,17 @@ class Config:
     # XLA compile-churn hardening: every new (keys, depth) pow2 bucket
     # compiles a fresh flush program (tens of seconds at high
     # cardinality).  The persistent cache makes recompiles across
-    # restarts near-free ("" disables); prewarm compiles the configured
+    # restarts near-free (on TPU backends; "" places it at
+    # <checkout>/.jax_cache, and JAX_COMPILATION_CACHE_DIR in the
+    # environment wins over any value here — util/compile_cache.py);
+    # prewarm compiles the configured
     # depth buckets for every pow2 key count up to the arena pre-size in
     # a background thread at boot, so a cardinality ramp never pays a
     # compile inside a flush interval.  Compile events surface as
     # flush.compile_events_total / flush.compile_seconds self-metrics,
     # and the flush watchdog is compile-aware (a first-bucket compile is
     # not a hang).
-    compilation_cache_dir: str = "~/.cache/veneur-tpu-xla"
+    compilation_cache_dir: str = ""
     prewarm_flush_shapes: bool = False
     prewarm_depths: list[int] = field(default_factory=lambda: [4, 32])
     # global-tier flushes >= chunks*8192 dense rows split into this many

@@ -168,23 +168,22 @@ class Server:
         # cluster join MUST precede any backend initialization (including
         # the default_backend() probe below)
         multihost.maybe_init_from_config(cfg)  # no-op without coordinator
-        if cfg.compilation_cache_dir:
-            # persistent XLA compile cache: recompiles of known flush
-            # buckets across process restarts become disk hits instead
-            # of multi-second (or, at 1M keys, minute-scale) compiles.
-            # TPU-backend only: XLA:CPU AOT cache entries are machine-
-            # feature-specific and can SIGILL when reloaded on a
-            # different host generation.
-            import jax as _jax
-            cache_dir = os.path.expanduser(cfg.compilation_cache_dir)
-            try:
-                if _jax.default_backend() == "tpu":
-                    _jax.config.update("jax_compilation_cache_dir",
-                                       cache_dir)
-                    _jax.config.update(
-                        "jax_persistent_cache_min_compile_time_secs", 0.5)
-            except Exception as e:
-                logger.warning("compilation cache unavailable: %s", e)
+        # persistent XLA compile cache: recompiles of known flush
+        # buckets across process restarts become disk hits instead of
+        # multi-second (or, at 1M keys, minute-scale) compiles.
+        # TPU-backend only: XLA:CPU AOT cache entries are machine-
+        # feature-specific and can SIGILL when reloaded on a different
+        # host generation.  util/compile_cache.py places the directory
+        # (JAX_COMPILATION_CACHE_DIR wins over the config).  No minimum
+        # compile time: a flush bucket that compiles in a fraction of a
+        # second still costs an uncovered in-flush compile on every boot.
+        import jax as _jax
+        logger.info("jax backend %s, %d device(s)",
+                    _jax.default_backend(), _jax.device_count())
+        if _jax.default_backend() == "tpu":
+            from veneur_tpu.util import compile_cache
+            compile_cache.enable(cfg.compilation_cache_dir,
+                                 min_compile_secs=0.0)
         if cfg.mesh_devices > 0:
             from veneur_tpu.parallel import mesh as mesh_mod
             self.mesh = mesh_mod.make_mesh(
@@ -527,29 +526,24 @@ class Server:
             for a in self.config.statsd_listen_addresses)
         if self.config.native_ingest and has_udp_statsd:
             # the C++ edge data plane (UDP readers + parser + staging);
-            # the Python chain stays as fallback and slow path.  Only
-            # built when a UDP listener exists to feed it — TCP/unix-only
-            # configs skip the engine (and its first-run g++ compile)
-            try:
-                from veneur_tpu.ingest import NativeIngest
-                self.native = NativeIngest(
-                    self.aggregator,
-                    max_packet=self.config.metric_max_length,
-                    implicit_tags=list(self.config.extend_tags),
-                    on_other=self.handle_metric_packet,
-                    simd=self.config.ingest_simd,
-                    backend=self.config.ingest_backend,
-                    batch=self.config.ingest_reader_batch,
-                    ring_slots=self.config.ingest_ring_slots)
-            # vnlint: disable=silent-loss (engine unavailability is a
-            #   FALLBACK, not a drop: native=None routes every packet
-            #   through the Python path, which has its own parse-error
-            #   accounting)
-            except Exception as e:
-                logger.warning(
-                    "native ingest engine unavailable (%s); "
-                    "using the Python packet path", e)
-                self.native = None
+            # the Python chain stays as the slow path for what the
+            # engine hands back (events, service checks).  Only built
+            # when a UDP listener exists to feed it — TCP/unix-only
+            # configs skip the engine (and its first-run g++ compile).
+            # An engine that cannot be built or loaded is a BOOT ERROR:
+            # a server asked for the native data plane must not carry
+            # on through the Python packet path at a fraction of the
+            # rate.  `native_ingest: false` is how to ask for that path.
+            from veneur_tpu.ingest import NativeIngest
+            self.native = NativeIngest(
+                self.aggregator,
+                max_packet=self.config.metric_max_length,
+                implicit_tags=list(self.config.extend_tags),
+                on_other=self.handle_metric_packet,
+                simd=self.config.ingest_simd,
+                backend=self.config.ingest_backend,
+                batch=self.config.ingest_reader_batch,
+                ring_slots=self.config.ingest_ring_slots)
         for sspec, sink in self.metric_sinks:
             sink.start(None)
         for sink in self.span_sinks:

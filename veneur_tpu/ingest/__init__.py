@@ -68,7 +68,11 @@ def _compile() -> None:
         # introduced by a change fails the suite, not just stderr
         cmd.append("-Werror")
     cmd += ["-o", tmp, _SRC]
-    subprocess.run(cmd, check=True, capture_output=True)
+    build = subprocess.run(cmd, capture_output=True, text=True)
+    if build.returncode != 0:
+        raise RuntimeError(
+            f"native ingest engine build failed ({' '.join(cmd)}):\n"
+            f"{build.stderr[-4000:]}")
     os.replace(tmp, _SO)
 
 

@@ -98,12 +98,17 @@ def _pass_tile(stage, cnt, off, cap: int, levels: int, sortfn):
         buf = sortfn(jnp.concatenate([stage_l, carry], axis=0))
         occ = cnt[lvl:lvl + 1, :] + carry_n
         if lvl < levels - 1:
+            # `do` gates only the [1, T] section size: m == 0 where no
+            # compaction runs, which already empties `surv` and widens
+            # `retain` to every occupied slot.  Broadcasting the bool
+            # row itself over the buffer has no Mosaic lowering (i8 ->
+            # i1 truncation).
             do = occ > cap
             sec = occ - keep
             m = jnp.where(do, sec - (sec & 1), 0)
             o = off[lvl:lvl + 1, :]
-            surv = do & (idx < m) & ((idx & 1) == o)
-            retain = jnp.where(do, (idx >= m) & (idx < occ), idx < occ)
+            surv = (idx < m) & ((idx & 1) == o)
+            retain = (idx >= m) & (idx < occ)
             carry = sortfn(jnp.where(surv, buf, pad))[:s2, :]
             carry_n = m // 2
             out_rows.append(sortfn(jnp.where(retain, buf, pad))[:cap, :])
@@ -114,8 +119,7 @@ def _pass_tile(stage, cnt, off, cap: int, levels: int, sortfn):
                 m = jnp.where(do, top - (top & 1), 0)
                 o = off[levels + r:levels + r + 1, :]
                 surv = (idx < m) & ((idx & 1) == o)
-                keepm = jnp.where(
-                    do, surv | ((idx >= m) & (idx < top)), idx < top)
+                keepm = surv | ((idx >= m) & (idx < top))
                 buf = sortfn(jnp.where(keepm, buf, pad))
                 top = top - m // 2
             out_rows.append(buf[:cap, :])

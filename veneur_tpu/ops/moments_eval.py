@@ -183,7 +183,7 @@ def _moments_sums_pallas(dv, dw, ab, lab, k: int, uniform: bool,
                          interpret: bool = False):
     u, d = dv.shape
     tile = _lane_tile(u)
-    nbuf = _auto_nbuf(u, tile)
+    nbuf = _auto_nbuf(u, tile, d)
     out_rows = 2 * (k + 1)
     dv = dv.astype(jnp.float32)
     if uniform:
@@ -200,9 +200,9 @@ def _moments_sums_pallas(dv, dw, ab, lab, k: int, uniform: bool,
                               nbuf=nbuf, k=k, uniform=uniform),
             grid=(u // (tile * nbuf),),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
                 (pl.BlockSpec((1, tile * nbuf), lambda i: (0, i))
-                 if uniform else pl.BlockSpec(memory_space=pltpu.ANY)),
+                 if uniform else pl.BlockSpec(memory_space=pl.ANY)),
                 pl.BlockSpec((2, tile * nbuf), lambda i: (0, i)),
                 pl.BlockSpec((2, tile * nbuf), lambda i: (0, i)),
             ],

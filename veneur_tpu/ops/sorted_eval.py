@@ -31,14 +31,15 @@ operands cross HBM exactly once.
 v3 — the HBM-roofline rework (ROADMAP #2: 0.444 -> >=0.6 at the 100k
 shape).  Three coordinated changes, all output-preserving:
 
-  * **compact sort keys.**  bf16-staged tiles sort NATIVELY at 16-bit
-    width: the compare-exchange network runs on bf16 vregs (half the
-    in-VMEM traffic per stage, half the HBM-facing read) and the keys
-    widen to f32 only after the last stage.  Exact by construction —
-    bf16 -> f32 widening is monotone and injective, so sorting before or
-    after widening commutes (this is the narrow-key/value-reconstruct
-    legality argument: the quantile tail is reconstruction-exact as
-    long as the sort ORDER is preserved).  The general weighted network
+  * **compact sort keys.**  bf16-staged tiles cross HBM at 16-bit
+    width (half the HBM-facing read).  The key-only network widens
+    them to f32 in VMEM before its first stage — Mosaic has no
+    sublane rotate for 16-bit data on the v5e — which is exact:
+    bf16 -> f32 widening is monotone and injective, so sorting before
+    or after widening commutes (this is the narrow-key/value-
+    reconstruct legality argument: the quantile tail is
+    reconstruction-exact as long as the sort ORDER is preserved).
+    The general weighted network
     additionally gets a packed formulation (`compact=True`): one int32
     word per point carrying the monotone-mapped 16-bit key in the high
     half and the depth index in the low half, sorted as a SINGLE array
@@ -132,12 +133,19 @@ def _lane_tile(u: int, d: int, wide: bool = False) -> int:
     return min(cap, u)
 
 
-def _auto_nbuf(u: int, tile: int) -> int:
+def _auto_nbuf(u: int, tile: int, d: int) -> int:
     """Sub-tiles per coarse grid step for the DMA pipeline: the largest
     of (4, 2) that divides the classic step count once that count is
-    >= _DMA_MIN_STEPS, else 1 (classic auto-pipelined path)."""
+    >= _DMA_MIN_STEPS, else 1 (classic auto-pipelined path).
+
+    Depths that do not fill whole 128-lane rows always take the classic
+    path: the pipeline copies `[tile, d]` row slices of the HBM-resident
+    operand, and Mosaic refuses a DMA whose minor dimension is not
+    aligned to the (8, 128) tiling ("Slice shape along dimension 1 must
+    be aligned to tiling (128)").  The classic BlockSpec path pads such
+    blocks itself and compiles at every depth."""
     steps = u // tile
-    if steps >= _DMA_MIN_STEPS:
+    if d % 128 == 0 and steps >= _DMA_MIN_STEPS:
         for nbuf in (_DMA_NBUF, 2):
             if steps % nbuf == 0:
                 return nbuf
@@ -403,21 +411,24 @@ def _tile_uniform(m_block, w_block, mm, qs):
     then never enters the sort network — sorted positions ARE the
     cumulative weights (cum_i = i+1, cmid_i = i+0.5, total = n_real) —
     so a stage is 6 passes instead of 11 and the prefix-sum disappears.
-    The key network runs at the BLOCK dtype: bf16-staged tiles sort on
-    16-bit vregs (half the traffic per stage) and widen after.
+    bf16-staged tiles cross HBM at 16-bit width and widen to f32 in
+    VMEM BEFORE the key network: Mosaic has no sublane rotate for
+    16-bit data on the v5e ("Rotate with non-32-bit data"), and
+    bf16 -> f32 is monotone and injective, so the sorted order and
+    every output bit are those of a 16-bit sort.
     Numerically identical outputs to the general network on w in {0, 1}
     inputs (enforced in interpret mode by tests/test_ops.py; the
     compiled Mosaic path is exercised natively by the bench and the
     verify flow — CI runs on CPU and cannot lower Mosaic)."""
-    m = m_block.T                 # [D, T] — keeps the staged dtype
+    m = m_block.T.astype(jnp.float32)                           # [D, T]
     w = w_block.T
     d, t = m.shape
     idx = jax.lax.broadcasted_iota(jnp.int32, (d, t), 0)
     occ0 = w > 0
-    key = jnp.where(occ0, m, jnp.asarray(_PAD_KEY, m.dtype))
+    key = jnp.where(occ0, m, _PAD_KEY)
     n_real = jnp.sum(occ0.astype(jnp.int32), axis=0,
                      keepdims=True)                             # [1, T]
-    key = _sort_keys(key, idx).astype(jnp.float32)
+    key = _sort_keys(key, idx)
     occ_sorted = idx < n_real     # real points sort before +inf padding
     m_clean = jnp.where(occ_sorted, key, 0.0)
     # summed AFTER the sort, like the general kernel, so the two
@@ -441,15 +452,14 @@ def _tile_uniform_depth(m_block, dep, qs):
     range (the clip is a provable no-op), and the exact f64 totals
     live in host accumulators (`DigestArena.d_weight`/`d_sum`).  The
     flush's readback is therefore the quantile columns alone.  Like
-    _tile_uniform, the sort runs at the staged dtype (bf16 tiles sort
-    on 16-bit vregs)."""
-    m = m_block.T                 # [D, T] — keeps the staged dtype
+    _tile_uniform, bf16 tiles widen to f32 in VMEM before the sort."""
+    m = m_block.T.astype(jnp.float32)                           # [D, T]
     d, t = m.shape
     idx = jax.lax.broadcasted_iota(jnp.int32, (d, t), 0)
     occ0 = idx < dep
-    key = jnp.where(occ0, m, jnp.asarray(_PAD_KEY, m.dtype))
+    key = jnp.where(occ0, m, _PAD_KEY)
     n_real = dep
-    key = _sort_keys(key, idx).astype(jnp.float32)
+    key = _sort_keys(key, idx)
     occ_sorted = idx < n_real     # real points sort before +inf padding
     m_clean = jnp.where(occ_sorted, key, 0.0)
     total = n_real.astype(jnp.float32)
@@ -594,10 +604,9 @@ def uniform_eval(mean: jax.Array, depths: jax.Array,
     columns for w = (col < depths[row]), at half the HBM traffic and a
     P-column readback (totals/sums come from the host accumulators).
 
-    bf16 inputs stay bf16 through the WHOLE path: the HBM read and the
-    sort network run at 16-bit width (compact sort keys), and the keys
-    widen to f32 only after the last compare-exchange — bit-identical
-    to widening first, since bf16 -> f32 is monotone.  `tile`/`nbuf`
+    bf16 inputs cross HBM as bf16 and widen to f32 in VMEM before the
+    sort — bit-identical to a 16-bit sort, since bf16 -> f32 is
+    monotone.  `tile`/`nbuf`
     override the lane-tile width and DMA sub-tile count (tests sweep
     them; production uses the defaults)."""
     u, d = mean.shape
@@ -605,7 +614,7 @@ def uniform_eval(mean: jax.Array, depths: jax.Array,
     if tile is None:
         tile = _lane_tile(u, d, wide=True)
     if nbuf is None:
-        nbuf = _auto_nbuf(u, tile)
+        nbuf = _auto_nbuf(u, tile, d)
     if u % (tile * nbuf):
         raise ValueError(
             f"uniform_eval: key count {u} is not a whole number of "
@@ -621,7 +630,7 @@ def uniform_eval(mean: jax.Array, depths: jax.Array,
                               nbuf=nbuf),
             grid=(u // (tile * nbuf),),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec((1, tile * nbuf), lambda i: (0, i)),
                 pl.BlockSpec((1, n_pct), lambda i: (0, 0)),
             ],
@@ -679,7 +688,7 @@ def weighted_eval(mean: jax.Array, weight: jax.Array,
     if tile is None:
         tile = _lane_tile(u, d)
     if nbuf is None:
-        nbuf = _auto_nbuf(u, tile)
+        nbuf = _auto_nbuf(u, tile, d)
     if u % (tile * nbuf):
         raise ValueError(
             f"weighted_eval: key count {u} is not a whole number of "
@@ -688,9 +697,9 @@ def weighted_eval(mean: jax.Array, weight: jax.Array,
     minmax = jnp.stack([d_min, d_max], axis=0).astype(jnp.float32)
     qs = percentiles.reshape(1, n_pct).astype(jnp.float32)
     # bf16-staged values cross HBM at their wire width for EVERY
-    # network: the compact and key-only tiles sort 16-bit keys
-    # natively, and the paired network widens in-register
-    # (_tile_general) — an XLA-side astype would materialize an f32
+    # network: the compact tile packs 16-bit keys and the others
+    # widen in VMEM (_tile_general, _tile_uniform) — an XLA-side
+    # astype would materialize an f32
     # copy in HBM, tripling the value-matrix traffic
     if mean.dtype != jnp.bfloat16:
         mean = mean.astype(jnp.float32)
@@ -701,8 +710,8 @@ def weighted_eval(mean: jax.Array, weight: jax.Array,
                               uniform=uniform, compact=compact),
             grid=(u // (tile * nbuf),),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec((2, tile * nbuf), lambda i: (0, i)),
                 pl.BlockSpec((1, n_pct), lambda i: (0, 0)),
             ],

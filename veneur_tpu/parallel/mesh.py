@@ -28,19 +28,11 @@ REPLICA_AXIS = "replica"
 
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """jax.shard_map across JAX releases: new JAX exposes it at the top
-    level (with `check_vma`); older releases only ship
-    jax.experimental.shard_map.shard_map (with `check_rep`).  Both
-    checks are disabled — the flush body's collectives are hand-placed
-    and the replication checker rejects the axis-size-1 specialization
-    it cannot see through."""
-    top = getattr(jax, "shard_map", None)
-    if top is not None:
-        return top(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=False)
-    from jax.experimental.shard_map import shard_map as legacy
-    return legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+    """jax.shard_map with the replication check disabled — the flush
+    body's collectives are hand-placed and the checker rejects the
+    axis-size-1 specialization it cannot see through."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(n_devices: int | None = None,

@@ -78,12 +78,7 @@ def init_multihost(coordinator_address: str,
     platforms = (jax.config.jax_platforms
                  or os.environ.get("JAX_PLATFORMS", ""))
     if "cpu" in str(platforms).lower():
-        try:
-            jax.config.update(
-                "jax_cpu_collectives_implementation", "gloo")
-        except Exception as e:  # older jaxlib without the option
-            logger.warning("could not select gloo CPU collectives: %s",
-                           e)
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
     kwargs = {"coordinator_address": coordinator_address}
     if num_processes is not None and num_processes >= 0:

@@ -226,7 +226,7 @@ def digest_eval(dv: jax.Array, dw: jax.Array, d_min: jax.Array,
             and se.usable(u, d, backend)):
         if uniform:
             # the key-only network beats the compact one (~1.8x: no
-            # payload, no prefix-sum) and sorts bf16 keys natively —
+            # payload, no prefix-sum) and reads bf16 values at wire width —
             # checked FIRST so bf16 uniform intervals never pay the
             # packed network's permutation-apply
             return se.weighted_eval(dv, dw, d_min, d_max, percentiles,
@@ -499,6 +499,25 @@ def make_serving_flush(mesh: Optional[Mesh]):
     return meshed
 
 
+def collective_group_sizes(hlo: str, op: str) -> list[int]:
+    """Group sizes of every `op` collective (e.g. "all-to-all") in an
+    optimized HLO text, both replica_groups syntaxes — what the dryrun,
+    the chip smoke and the chip-compile test check the depth
+    repartition against (it must stay inside one replica group)."""
+    import re
+
+    sizes = []
+    # brace-list form: replica_groups={{0,4},{1,5},...}
+    for g in re.findall(op + r"[^\n]*replica_groups=\{(.*?)\}\}", hlo):
+        sizes += [len(grp.strip("{}").split(","))
+                  for grp in g.split("},{")]
+    # iota v2 form: replica_groups=[num_groups,group_size]<=[..]
+    for _ng, gs in re.findall(
+            op + r"[^\n]*replica_groups=\[(\d+),(\d+)\]<=", hlo):
+        sizes.append(int(gs))
+    return sizes
+
+
 @functools.partial(jax.jit, static_argnames=("compression", "cap"))
 def digest_export(dense_v: jax.Array, dense_w: jax.Array,
                   rows: jax.Array, compression: float, cap: int
@@ -697,7 +716,7 @@ def resident_donation_ok() -> bool:
 
 
 # One-shot measured staged-vs-resident probe state (ROADMAP #2
-# remainder: marginal links — tunnel-attached chips — pick the faster
+# remainder: hosts with a marginal host-device link pick the faster
 # assembly path empirically, not by backend name).  Module-level dict
 # rather than an lru_cache so /debug/vars can INSPECT the decision
 # without forcing a measurement (http_api.link_probe_stats).

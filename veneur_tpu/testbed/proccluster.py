@@ -50,6 +50,7 @@ from dataclasses import dataclass, field
 import yaml
 
 from veneur_tpu.testbed.cluster import pack_datagrams
+from veneur_tpu.util import compile_cache
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -73,6 +74,26 @@ REAP_TIMEOUT_S = 10.0
 STATS_JOIN_TIMEOUT_S = 5.0
 EMIT_WAIT_S = 30.0
 INGEST_WAIT_S = 30.0
+
+
+def child_env(n_local_devices: int = 0) -> dict:
+    """Environment of a spawned tier process: pinned to the CPU backend
+    (one process owns a chip — the parent's; a child that initialised a
+    TPU backend would fail or hang), the parent's XLA_FLAGS dropped, the
+    checkout importable, the compile cache shared."""
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
+    env["JAX_PLATFORMS"] = "cpu"
+    env["GRPC_VERBOSITY"] = "ERROR"
+    env["PYTHONPATH"] = (_REPO_ROOT + os.pathsep
+                         + env.get("PYTHONPATH", ""))
+    # persistent XLA cache: later boots (revivals!) replay flush
+    # compiles from disk instead of paying them inside the arm
+    compile_cache.child_env_dir(env)
+    if n_local_devices > 0:
+        env["XLA_FLAGS"] = ("--xla_force_host_platform_device_"
+                            f"count={n_local_devices}")
+    return env
 
 
 @dataclass
@@ -332,20 +353,7 @@ class ProcCluster:
     #    in terminate_node or harvest_node on all paths) -------------------
 
     def _child_env(self, n_local_devices: int = 0) -> dict:
-        env = {k: v for k, v in os.environ.items()
-               if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
-        env["JAX_PLATFORMS"] = "cpu"
-        env["GRPC_VERBOSITY"] = "ERROR"
-        env["PYTHONPATH"] = (_REPO_ROOT + os.pathsep
-                             + env.get("PYTHONPATH", ""))
-        # persistent XLA cache: later boots (revivals!) replay flush
-        # compiles from disk instead of paying them inside the arm
-        env.setdefault("JAX_COMPILATION_CACHE_DIR",
-                       os.path.join(_REPO_ROOT, ".jax_cache"))
-        if n_local_devices > 0:
-            env["XLA_FLAGS"] = ("--xla_force_host_platform_device_"
-                                f"count={n_local_devices}")
-        return env
+        return child_env(n_local_devices)
 
     def spawn_node(self, name: str, role: str, cfg: dict,
                    module: str, n_local_devices: int = 0) -> ProcNode:
