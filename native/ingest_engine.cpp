@@ -607,6 +607,11 @@ struct ThreadBuf {
   alignas(64) std::atomic<uint32_t> owner{OWN_FREE};
   std::atomic<int> backend{VN_BACKEND_NONE};
   StageCounters stages;
+  // overflow accounting (vn_ring_stats): publishes that found the ring
+  // full (no line is lost: the batch stays in `cur`), and the ring's peak
+  // occupancy in slots since the last read
+  std::atomic<uint64_t> ring_full{0};
+  std::atomic<uint32_t> ring_peak{0};
 
   explicit ThreadBuf(size_t ring_slots) : ring(ring_slots) {}
 };
@@ -814,7 +819,16 @@ static inline void producer_release(ThreadBuf* tb) {
 // frees slots or steals it — the producer never blocks on the drainer.
 static inline void publish(ThreadBuf* tb) {
   if (tb->cur.packets == 0) return;
-  tb->ring.try_push(tb->cur);
+  uint32_t occ;
+  if (tb->ring.try_push(tb->cur)) {
+    occ = (uint32_t)(tb->ring.tail.load(std::memory_order_relaxed) -
+                     tb->ring.head.load(std::memory_order_relaxed));
+  } else {
+    tb->ring_full.fetch_add(1, std::memory_order_relaxed);
+    occ = (uint32_t)tb->ring.slots.size();
+  }
+  if (occ > tb->ring_peak.load(std::memory_order_relaxed))
+    tb->ring_peak.store(occ, std::memory_order_relaxed);
 }
 
 struct ThreadScratch {
@@ -1934,6 +1948,26 @@ void vn_stage_drain(void* ep, unsigned long long* out3) {
       e->rep_drain_ns,
       (unsigned long long)(
           (double)e->drain_ticks.load(std::memory_order_relaxed) * r));
+}
+
+// Ring overflow accounting, over every thread: {publishes that found a
+// full ring (monotonic), peak occupancy in slots of any ring since the
+// last call (read and reset), slots per ring}.
+void vn_ring_stats(void* ep, unsigned long long* out3) {
+  auto* e = (Engine*)ep;
+  unsigned long long full = 0, peak = 0;
+  {
+    std::lock_guard<std::mutex> l(e->bufs_mu);
+    for (auto& tb : e->bufs) {
+      full += tb->ring_full.load(std::memory_order_relaxed);
+      unsigned long long p =
+          tb->ring_peak.exchange(0, std::memory_order_relaxed);
+      if (p > peak) peak = p;
+    }
+  }
+  out3[0] = full;
+  out3[1] = peak;
+  out3[2] = (unsigned long long)e->opt_ring_slots;
 }
 
 unsigned long long vn_intern_count(void* ep) {

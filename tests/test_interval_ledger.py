@@ -1,0 +1,371 @@
+"""The interval ledger (core/aggregator.py): work done for an interval by
+threads other than the flush thread — import RPCs, the native drain's fold
+— is accumulated under the aggregator lock, swapped out at the snapshot
+exactly as `imported` is, and lands on the flush timeline row of the flush
+that closes the interval; the snapshot's own parts and the egress lane's
+are spans on the flush's trace; which queue overflowed is a counter."""
+
+import socket
+import threading
+import time
+
+import pytest
+
+from veneur_tpu import config as config_mod
+from veneur_tpu import http_api
+from veneur_tpu import ingest as ingest_mod
+from veneur_tpu.core.aggregator import (LEDGER_SEGMENT_KEYS,
+                                        MetricAggregator)
+from veneur_tpu.core.server import Server
+from veneur_tpu.protocol import forward_pb2, metric_pb2
+from veneur_tpu.sinks import simple as simple_sinks
+from veneur_tpu.trace import assembly
+
+ROW_FIELDS = ("snapshot_lock_wait_ms", "snapshot_sync_ms",
+              "snapshot_staged_ms", "snapshot_columns_ms", "import_rpcs",
+              "import_lock_wait_ms", "import_scan_ms", "import_held_ms",
+              "fold_calls", "fold_lines", "fold_lock_wait_ms", "fold_ms")
+
+
+def _wait(cond, timeout_s=10.0):
+    deadline = time.time() + timeout_s
+    while time.time() < deadline:
+        if cond():
+            return True
+        time.sleep(0.01)
+    return False
+
+
+def _payload(i: int, n: int = 20) -> bytes:
+    return forward_pb2.MetricList(metrics=[
+        metric_pb2.Metric(name=f"led.c{j}", type=metric_pb2.Counter,
+                          tags=[f"rpc:{i % 3}"],
+                          counter=metric_pb2.CounterValue(value=1))
+        for j in range(n)]).SerializeToString()
+
+
+def _segments_sum(rows: list, key: str):
+    return sum(r[key] for r in rows)
+
+
+@pytest.mark.parametrize("native_scan", [True, False])
+def test_ledger_swap_is_exact_under_concurrent_import_and_drain(native_scan):
+    """Sums over intervals == sums over RPCs and drain calls: nothing is
+    counted twice or lost across the cut, whichever side of a snapshot an
+    RPC or a fold lands on."""
+    agg = MetricAggregator(percentiles=[0.5])
+    if not native_scan:
+        agg._native_import = False      # the protobuf batch path
+    nat = ingest_mod.NativeIngest(agg)
+    tid = nat.engine.new_thread()
+    stop = threading.Event()
+    segs: list = []
+    per_rpc: list = []          # (scan, lock wait, held) ns per RPC
+    folds = {"calls": 0, "lines": 0}
+    lock = threading.Lock()
+
+    def importer(k: int) -> None:
+        for i in range(40):
+            ok, failed = agg.import_payload(_payload(k * 100 + i))
+            assert (ok, failed) == (20, 0)
+            timing = agg.take_import_timing()
+            assert timing is not None and agg.take_import_timing() is None
+            with lock:
+                per_rpc.append(timing)
+
+    def drainer() -> None:
+        i = 0
+        while not stop.is_set():
+            for _ in range(3):
+                nat.engine.ingest(tid, b"led.t:%d|ms\nled.n:1|c" % i)
+                i += 1
+            batch = nat.drain_into()
+            if not batch.empty:
+                folds["calls"] += 1
+                folds["lines"] += batch.processed
+
+    def flusher() -> None:
+        while not stop.is_set():
+            agg.flush(is_local=False)
+            segs.append(dict(agg.last_flush_segments))
+
+    threads = [threading.Thread(target=importer, args=(k,))
+               for k in range(4)]
+    side = [threading.Thread(target=drainer),
+            threading.Thread(target=flusher)]
+    for t in threads + side:
+        t.start()
+    for t in threads:
+        t.join()
+    stop.set()
+    for t in side:
+        t.join()
+    agg.flush(is_local=False)           # closes whatever is still open
+    segs.append(dict(agg.last_flush_segments))
+    nat.close()
+
+    assert len(segs) >= 2
+    assert _segments_sum(segs, "import_rpcs") == len(per_rpc) == 160
+    assert _segments_sum(segs, "fold_calls") == folds["calls"] > 0
+    assert _segments_sum(segs, "fold_lines") == folds["lines"] > 0
+    for i, key in enumerate(("import_scan_s", "import_lock_wait_s",
+                             "import_held_s")):
+        want = sum(t[i] for t in per_rpc) / 1e9
+        assert _segments_sum(segs, key) == pytest.approx(want, rel=1e-9)
+    assert _segments_sum(segs, "fold_s") > 0
+    # the open ledger is empty again: the last flush took everything
+    assert not any(agg._ledger.values())
+
+
+def test_snapshot_lock_wait_reads_the_hold_and_parts_sum_to_snapshot():
+    agg = MetricAggregator(percentiles=[0.5])
+    for i in range(50):
+        agg.import_pb_batch(forward_pb2.MetricList.FromString(
+            _payload(i)).metrics)
+    held = threading.Event()
+
+    def hold() -> None:
+        with agg.lock:
+            held.set()
+            time.sleep(0.05)
+
+    t = threading.Thread(target=hold)
+    t.start()
+    assert held.wait(5.0)
+    t_flush = time.perf_counter()
+    agg.flush(is_local=False)
+    t.join()
+    seg = agg.last_flush_segments
+    # the holder sleeps 50 ms from `held`; the flush began right after it
+    assert 0.04 <= seg["snapshot_lock_wait_s"] \
+        <= time.perf_counter() - t_flush
+    parts = sum(seg[f"snapshot_{p}_s"]
+                for p in ("lock_wait", "sync", "staged", "columns"))
+    # snapshot_s keeps its meaning: the parts plus the deferred
+    # unique-timeseries estimate and the lock's release
+    assert parts <= seg["snapshot_s"] + 1e-9
+    assert seg["snapshot_s"] - parts < 0.005
+    assert min(seg[f"snapshot_{p}_s"]
+               for p in ("lock_wait", "sync", "staged", "columns")) >= 0.0
+    assert {k for k in seg if k.startswith(("snapshot_", "import_",
+                                            "fold_"))} \
+        == LEDGER_SEGMENT_KEYS | {"snapshot_s"}
+
+
+@pytest.fixture
+def server():
+    servers = []
+
+    def boot(**kw):
+        sink = simple_sinks.ChannelMetricSink()
+        cfg = config_mod.Config(
+            statsd_listen_addresses=["udp://127.0.0.1:0"], interval=10.0,
+            percentiles=[0.5], hostname="ledger-test", **kw)
+        srv = Server(cfg, extra_metric_sinks=[sink])
+        servers.append(srv)
+        return srv, sink
+
+    yield boot
+    for srv in servers:
+        srv.shutdown()
+
+
+def _send_and_drain(srv, lines: int = 40) -> None:
+    _, addr = srv.statsd_addrs[0]
+    tx = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
+    tx.sendto(b"\n".join(b"led.t%d:%d|ms" % (i, i) for i in range(lines)),
+              addr)
+    tx.close()
+    assert _wait(lambda: (srv._drain_native() or True)
+                 and srv.native.engine.totals()[0] >= lines)
+
+
+def _flush_and_spans(srv) -> list:
+    """One flush, its lanes settled and its root span (which rides the
+    span pipeline) in the ring."""
+    srv.flush()
+    assert srv.egress.settle(timeout_s=10.0)
+    assert _wait(lambda: any(s["name"] == "flush"
+                             for s in srv.flight_recorder.snapshot()))
+    return srv.flight_recorder.snapshot()
+
+
+def test_lane_spans_continue_the_flush_trace_under_the_sink_span(server):
+    srv, sink = server()
+    srv.start()
+    _send_and_drain(srv)
+    spans = _flush_and_spans(srv)
+    root = [s for s in spans if s["name"] == "flush"][0]
+    sink_span = [s for s in spans if s["name"] == "flush.sink.channel"][0]
+    assert sink_span["trace_id"] == root["trace_id"]
+    assert sink_span["parent_id"] == root["span_id"]
+    lane = {s["name"]: s for s in spans
+            if s["name"].startswith("flush.seg.lane.")}
+    assert set(lane) == {"flush.seg.lane.wait", "flush.seg.lane.filter",
+                         "flush.seg.lane.sink"}
+    for s in lane.values():
+        assert s["trace_id"] == root["trace_id"]
+        assert s["parent_id"] == sink_span["span_id"]
+        assert s["tags"]["sink"] == "channel"
+        # real timestamps, inside the sink span
+        assert s["start_ns"] >= sink_span["start_ns"]
+    # durations are rounded to the microsecond, each
+    assert sum(s["duration_ms"] for s in lane.values()) \
+        <= sink_span["duration_ms"] + 0.004
+    # only metric lanes carry them: one of each per trace with one sink
+    assert len([s for s in spans
+                if s["name"].startswith("flush.seg.lane.")]) == 3
+
+
+def test_critical_path_table_is_unchanged_by_the_new_grandchildren(server):
+    srv, _sink = server()
+    srv.start()
+    _send_and_drain(srv)
+    spans = _flush_and_spans(srv)
+    root = [s for s in spans if s["name"] == "flush"][0]
+    trace = [s for s in spans if s["trace_id"] == root["trace_id"]]
+    new = [s for s in trace
+           if s["name"].startswith(("flush.seg.snapshot.",
+                                    "flush.seg.lane."))]
+    assert len(new) == 7
+    assert all(s["parent_id"] != root["span_id"] for s in new)
+    with_new = assembly.interval_row(root, trace)
+    without = assembly.interval_row(
+        root, [s for s in trace if s not in new])
+    assert with_new["sum_segments_ms"] == without["sum_segments_ms"]
+    assert with_new["segments_ms"] == without["segments_ms"]
+    assert with_new["critical_path_ms"] == without["critical_path_ms"]
+    assert not assembly.find_orphans(trace)
+    # the snapshot's parts are laid inside their parent
+    snap = [s for s in trace if s["name"] == "flush.seg.snapshot"][0]
+    parts = [s for s in trace
+             if s["name"].startswith("flush.seg.snapshot.")]
+    assert {s["parent_id"] for s in parts} == {snap["span_id"]}
+    assert sum(s["duration_ms"] for s in parts) \
+        <= snap["duration_ms"] + 0.004
+
+
+def test_tracing_off_records_no_span_and_keeps_the_row_fields(server):
+    srv, _sink = server(trace_flush_enabled=False)
+    srv.start()
+    _send_and_drain(srv)
+    spans = _flush_and_spans(srv)
+    assert not [s for s in spans if s["name"].startswith(
+        ("flush.seg.", "flush.sink."))]
+    row = srv.flush_timeline.snapshot()[-1]
+    for field in ROW_FIELDS:
+        assert field in row, field
+    assert row["fold_calls"] >= 1 and row["fold_lines"] == 40
+    assert row["import_rpcs"] == 0
+    for field in ("udp_rcvbuf_drops", "ring_full_stalls",
+                  "ring_peak_share"):
+        assert field in row, field
+
+
+def test_ledger_keys_become_no_self_metric(server):
+    """The row and the spans are the ledger's outlet: a dozen new timers
+    per flush would add rows to the digest arena the flush measures."""
+    from tests.test_self_telemetry import FakeStatsd
+
+    srv, _sink = server()
+    srv.statsd = FakeStatsd()
+    srv.start()
+    _send_and_drain(srv)
+    srv.flush()
+    names = {c[1] for c in srv.statsd.calls}
+    assert "flush.segment.snapshot_ms" in names         # as before
+    for key in LEDGER_SEGMENT_KEYS:
+        stem = key[:-2] if key.endswith("_s") else key
+        assert f"flush.segment.{stem}_ms" not in names, key
+        assert f"flush.{key}" not in names, key
+
+
+def test_overflow_counters_on_the_row_and_in_debug_vars(server):
+    srv, _sink = server()
+    srv.start()
+    _send_and_drain(srv)
+    srv.flush()
+    row = srv.flush_timeline.snapshot()[-1]
+    assert row["udp_rcvbuf_drops"] == 0 and row["ring_full_stalls"] == 0
+    assert 0.0 < row["ring_peak_share"] <= 1.0
+    dv = http_api.debug_vars(srv)["ingest_overflow"]
+    assert dv["ring_peak_share"] == row["ring_peak_share"]
+    assert dv["udp_rcvbuf_drops_total"] == 0
+    assert dv["ring_full_stalls_total"] == 0
+    # a second, idle interval: the peak is per interval, read and reset
+    srv.flush()
+    assert srv.flush_timeline.snapshot()[-1]["ring_peak_share"] == 0.0
+
+
+def test_ring_stats_count_full_rings_and_lose_nothing():
+    """A 2-slot ring never drained: every publish past the second finds
+    it full (the batch stays with the producer; nothing is dropped), and
+    the peak reads the whole ring until it is read."""
+    eng = ingest_mod.IngestEngine(4096, batch=1, ring_slots=2)
+    tid = eng.new_thread()
+    assert eng.ring_stats() == (0, 0, 2)
+    for i in range(10):
+        eng.ingest(tid, b"ring:%d|c" % i)
+    full, peak, slots = eng.ring_stats()
+    assert (full, peak, slots) == (8, 2, 2)
+    batch = eng.drain()
+    assert batch.processed == 10                # ... and no line lost
+    full, peak, _ = eng.ring_stats()
+    assert (full, peak) == (8, 0)               # monotonic; peak was reset
+    eng.close()
+
+
+def test_udp_rcvbuf_drops_are_read_from_the_socket(server):
+    """The kernel's drop count of a UDP socket whose reader is not
+    reading (the Python data plane's socket, never started)."""
+    srv, _sink = server(read_buffer_size_bytes=4096)
+    srv.start()
+    # a second datagram socket nobody reads, registered like a listener
+    rx = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
+    rx.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, 4096)
+    rx.bind(("127.0.0.1", 0))
+    srv._listeners.append(rx)
+    tx = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
+    for _ in range(200):
+        tx.sendto(b"x" * 1000, rx.getsockname())
+    tx.close()
+    srv.flush()
+    row = srv.flush_timeline.snapshot()[-1]
+    assert row["udp_rcvbuf_drops"] > 0
+    srv.flush()
+    assert srv.flush_timeline.snapshot()[-1]["udp_rcvbuf_drops"] == 0
+    assert http_api.debug_vars(srv)["ingest_overflow"][
+        "udp_rcvbuf_drops_total"] == row["udp_rcvbuf_drops"]
+
+
+def test_global_import_span_carries_the_three_durations():
+    """The import span is recorded only for a sender that sent a trace
+    context; when it is, its tags say where the RPC's time went."""
+    glob = Server(config_mod.Config(grpc_address="127.0.0.1:0",
+                                    interval=10.0, percentiles=[0.5],
+                                    hostname="g0"))
+    glob.start()
+    loc = Server(config_mod.Config(
+        statsd_listen_addresses=["udp://127.0.0.1:0"],
+        forward_address=f"127.0.0.1:{glob.grpc_import.port}",
+        interval=10.0, percentiles=[0.5], hostname="l0"))
+    loc.start()
+    try:
+        _send_and_drain(loc, lines=4)
+        loc.flush()
+        assert _wait(lambda: any(
+            r["name"] == "global.import"
+            for r in glob.flight_recorder.snapshot()))
+        imp = [r for r in glob.flight_recorder.snapshot()
+               if r["name"] == "global.import"][0]
+        for tag in ("scan_ms", "lock_wait_ms", "held_ms"):
+            assert float(imp["tags"][tag]) >= 0.0
+        assert float(imp["tags"]["held_ms"]) > 0.0
+        glob.flush()
+        row = glob.flush_timeline.snapshot()[-1]
+        assert row["import_rpcs"] >= 1
+        assert row["import_held_ms"] > 0.0
+        assert row["imported"] >= 4
+    finally:
+        loc.shutdown()
+        glob.shutdown()

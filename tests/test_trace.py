@@ -246,6 +246,7 @@ def test_flush_trace_recorded_and_timeline_linked(traced_server):
     rec = srv.flight_recorder
     assert _wait(lambda: any(r["name"] == "flush"
                              for r in rec.snapshot()))
+    srv.egress.settle(timeout_s=5.0)    # the lanes' spans are in too
     spans = rec.snapshot()
     roots = [r for r in spans if r["name"] == "flush"]
     assert len(roots) == 1
@@ -255,9 +256,18 @@ def test_flush_trace_recorded_and_timeline_linked(traced_server):
     assert root["tags"]["interval"] == "1"
     segs = [r for r in spans if r["name"].startswith("flush.seg.")]
     assert segs, spans
-    assert all(s["parent_id"] == root["span_id"] for s in segs)
+    # segment children hang off the root; their parts (the snapshot's,
+    # the egress lane's under flush.sink.<name>) one level below it
+    by_id = {r["span_id"]: r for r in spans}
+    direct = [s for s in segs if s["parent_id"] == root["span_id"]]
+    for s in segs:
+        if s not in direct:
+            parent = by_id[s["parent_id"]]
+            assert parent["parent_id"] == root["span_id"], s
+            assert s["name"].startswith(
+                ("flush.seg.snapshot.", "flush.seg.lane.")), s
     assert {"snapshot", "emit", "fanout"} <= {
-        s["name"].split(".")[-1] for s in segs}
+        s["name"].split(".")[-1] for s in direct}
     # the timeline row cross-links to the exact trace/span
     row = srv.flush_timeline.snapshot()[-1]
     assert row["trace_id"] == f"{root['trace_id']:x}"

@@ -84,6 +84,30 @@ class PendingFlush:
                                        self._seg)
 
 
+# The interval ledger: work done FOR the open interval by threads other
+# than the flush thread (the import RPCs, the native drain's fold), kept
+# in nanoseconds and counts.  Every update happens under the aggregator
+# lock, and _snapshot_and_reset swaps the dict out under the same lock —
+# exactly as `imported` is — so each RPC and each fold is accounted once,
+# to the flush that closes its interval.  flush_dispatch writes the
+# swapped-out ledger and the snapshot's own parts into
+# last_flush_segments; LEDGER_SEGMENT_KEYS names those keys, whose outlet
+# is the flush timeline row (and, traced, flush.seg.snapshot.* spans),
+# not a self-metric: core/server.py keeps them out of its
+# flush.segment.* loop.
+_LEDGER_FIELDS = ("import_rpcs", "import_lock_wait_ns", "import_scan_ns",
+                  "import_held_ns", "fold_calls", "fold_lines",
+                  "fold_lock_wait_ns", "fold_ns")
+LEDGER_SEGMENT_KEYS = frozenset(
+    ["snapshot_lock_wait_s", "snapshot_sync_s", "snapshot_staged_s",
+     "snapshot_columns_s"]
+    + [f[:-3] + "_s" if f.endswith("_ns") else f for f in _LEDGER_FIELDS])
+
+
+def _new_ledger() -> dict:
+    return dict.fromkeys(_LEDGER_FIELDS, 0)
+
+
 # Ceiling for the logical [rows, depth, ccap] intermediate of one
 # digest_export chunk (elements); see _emit_digests' forwarding branch.
 _EXPORT_ELEM_BUDGET = 1 << 26
@@ -315,6 +339,10 @@ class MetricAggregator:
                 seed=cube_seed)
         self.processed = 0
         self.imported = 0
+        self._ledger = _new_ledger()    # the interval ledger (see above)
+        # the calling thread's last import RPC as (scan, lock wait, held)
+        # nanoseconds, for the global.import span's tags
+        self._import_tls = threading.local()
         # V1 import identity->row cache; cleared at every snapshot so a
         # later end_interval GC can never recycle a cached row
         self._import_row_cache: dict = {}
@@ -606,7 +634,8 @@ class MetricAggregator:
         "histogram": (2, 4),    # metric_pb2.Histogram / Timer
     }
 
-    def import_pb_batch(self, pbs) -> tuple[int, int]:
+    def import_pb_batch(self, pbs, t_call: Optional[int] = None
+                        ) -> tuple[int, int]:
         """Batched V1 import: ONE lock for the whole MetricList, direct
         protobuf field access, an identity->row cache (cleared every
         flush, BEFORE end_interval's GC can recycle rows), and
@@ -617,9 +646,13 @@ class MetricAggregator:
         metrics whose `type` field contradicts their value oneof are
         rejected (see _ONEOF_LEGAL_TYPES — the legacy convert.from_pb
         path instead trusted `type` and mis-filed the payload).
-        Returns (imported, failed)."""
+        Returns (imported, failed).  `t_call` (perf_counter_ns) is when
+        the RPC's import began, for the interval ledger: import_payload
+        hands its own over so the protobuf parse counts as scan time."""
         from veneur_tpu.protocol import metric_pb2
 
+        if t_call is None:
+            t_call = time.perf_counter_ns()
         ok = failed = 0
         counters, gauges, sets, digests = (
             self.counters, self.gauges, self.sets, self.digests)
@@ -629,7 +662,9 @@ class MetricAggregator:
         c_vals: list = []
         g_rows: list = []
         g_vals: list = []
+        t_wait = time.perf_counter_ns()
         with self.lock:
+            t_held = time.perf_counter_ns()
             for pb in pbs:
                 try:
                     which = pb.WhichOneof("value")
@@ -689,7 +724,38 @@ class MetricAggregator:
             if g_rows:
                 gauges.merge_batch(np.asarray(g_rows, np.int64),
                                    np.asarray(g_vals, np.float64))
+            self._ledger_import(t_call, t_wait, t_held)
         return ok, failed
+
+    def _ledger_import(self, t_call: int, t_wait: int, t_held: int) -> None:
+        """Account one import RPC to the open interval (perf_counter_ns
+        marks: the RPC's import began, it started waiting for the lock,
+        it held the lock).  Call under self.lock, last."""
+        held = time.perf_counter_ns() - t_held
+        led = self._ledger
+        led["import_rpcs"] += 1
+        led["import_scan_ns"] += t_wait - t_call
+        led["import_lock_wait_ns"] += t_held - t_wait
+        led["import_held_ns"] += held
+        self._import_tls.timing = (t_wait - t_call, t_held - t_wait, held)
+
+    def take_import_timing(self) -> Optional[tuple]:
+        """(scan, lock wait, held) nanoseconds of the batch import this
+        thread just made, once; None when it made none since the last
+        take (a per-metric stream import is not timed)."""
+        timing = getattr(self._import_tls, "timing", None)
+        self._import_tls.timing = None
+        return timing
+
+    def ledger_fold(self, lines: int, t_wait: int, t_held: int) -> None:
+        """Account one fold of a native drain into the arenas (ingest.
+        NativeIngest._drain_apply) to the open interval.  Call under
+        self.lock, last."""
+        led = self._ledger
+        led["fold_calls"] += 1
+        led["fold_lines"] += lines
+        led["fold_lock_wait_ns"] += t_held - t_wait
+        led["fold_ns"] += time.perf_counter_ns() - t_held
 
     def _import_slow_pb(self, pb, which: str) -> None:
         """Set/histogram import body (sketch merges; call under
@@ -746,6 +812,7 @@ class MetricAggregator:
         their byte ranges (they carry sketches python merges anyway).
         Falls back to import_pb_batch when the native engine is
         unavailable or rejects the payload."""
+        t_call = time.perf_counter_ns()
         scan = None
         # the native wire scan never materializes tags, which the
         # per-tenant budget classifies on — with the guard armed on
@@ -764,7 +831,8 @@ class MetricAggregator:
         if scan is None:
             from veneur_tpu.protocol import forward_pb2
             return self.import_pb_batch(
-                forward_pb2.MetricList.FromString(payload).metrics)
+                forward_pb2.MetricList.FromString(payload).metrics,
+                t_call)
         n = scan["n"]
         if n == 0:
             return 0, 0
@@ -783,7 +851,9 @@ class MetricAggregator:
         g_rows: list = []
         g_vals: list = []
         ok = failed = 0
+        t_wait = time.perf_counter_ns()
         with self.lock:
+            t_held = time.perf_counter_ns()
             for i in range(n):
                 w = wl[i]
                 if w == 1 or w == 2:
@@ -848,6 +918,7 @@ class MetricAggregator:
             if g_rows:
                 gauges.merge_batch(np.asarray(g_rows, np.int64),
                                    np.asarray(g_vals, np.float64))
+            self._ledger_import(t_call, t_wait, t_held)
         return ok, failed
 
     def sync_staged(self, min_samples: int = 0) -> bool:
@@ -1012,6 +1083,7 @@ class MetricAggregator:
         seg = self.last_flush_segments = {}
         t0 = time.perf_counter()
         with self.lock:
+            t_held = time.perf_counter()
             # vnlint: disable=blocking-propagation (the snapshot must
             #   be lock-coherent; its only flagged chain stages a
             #   host-built lanes buffer via serving.put — asarray of
@@ -1019,6 +1091,7 @@ class MetricAggregator:
             #   reduction is deferred below, outside the lock)
             snap = self._snapshot_and_reset()
             res.processed, res.imported = snap.pop("counts")
+            t_cut = time.perf_counter()
         # deferred from the locked snapshot: the unique-ts estimate is
         # a pure reduction over the swapped-out registers, so it runs
         # without the ingest lock held
@@ -1026,6 +1099,22 @@ class MetricAggregator:
         if uts_raw is not None:
             snap["uts_host"] = hll_mod.estimate_np(uts_raw)
         seg["snapshot_s"] = time.perf_counter() - t0
+        # snapshot_s in parts: the wait for the lock (the drain's fold
+        # and the import hold it), then under the lock the arenas'
+        # sync()s, the take_staged() consolidations, and the rest (the
+        # per-family column copies and the reset); what remains of
+        # snapshot_s is the deferred estimate above
+        sync_s, staged_s = snap.pop("part_seconds")
+        seg["snapshot_lock_wait_s"] = t_held - t0
+        seg["snapshot_sync_s"] = sync_s
+        seg["snapshot_staged_s"] = staged_s
+        seg["snapshot_columns_s"] = t_cut - t_held - sync_s - staged_s
+        # the interval ledger, swapped out at the cut
+        for name, v in snap.pop("ledger").items():
+            if name.endswith("_ns"):
+                seg[name[:-3] + "_s"] = v / 1e9
+            else:
+                seg[name] = v
         # per-family touched-key counts ride the segment dict so the
         # flush timeline (and the flush.* self-metric gauges) can relate
         # segment times to interval size
@@ -1846,13 +1935,17 @@ class MetricAggregator:
         d, s, c, g, st = (self.digests, self.sets, self.counters,
                           self.gauges, self.status)
         self._import_row_cache.clear()
+        t_sync = time.perf_counter()
         d.sync()
         self.moments.sync()
         self.compactors.sync()
         s.sync()
-        snap = {"counts": (self.processed, self.imported)}
+        sync_s = time.perf_counter() - t_sync
+        snap = {"counts": (self.processed, self.imported),
+                "ledger": self._ledger}
         self.processed = 0
         self.imported = 0
+        self._ledger = _new_ledger()
         snap["have_uts"] = self.unique_ts is not None
         if self.unique_ts is not None:
             uts = self.unique_ts.regs
@@ -1951,7 +2044,9 @@ class MetricAggregator:
         # its result (the tail's (row, pos) coordinates come from the
         # same consolidated arrays)
         d_uniform = d.staged_uniform
+        t_staged = time.perf_counter()
         d_staged = d.take_staged()
+        staged_s = time.perf_counter() - t_staged
         snap["digests"] = {
             "rows": drows,
             "names": d.name_col[drows],
@@ -1983,7 +2078,9 @@ class MetricAggregator:
         m = self.moments
         mrows = m.touched_rows()
         m_uniform = m.staged_uniform
+        t_staged = time.perf_counter()
         m_staged = m.take_staged()
+        staged_s += time.perf_counter() - t_staged
         snap["moments"] = {
             "rows": mrows,
             "names": m.name_col[mrows],
@@ -2012,7 +2109,9 @@ class MetricAggregator:
 
         cp = self.compactors
         prows = cp.touched_rows()
+        t_staged = time.perf_counter()
         cp_staged = cp.take_staged()
+        staged_s += time.perf_counter() - t_staged
         snap["compactors"] = {
             "rows": prows,
             "names": cp.name_col[prows],
@@ -2040,6 +2139,10 @@ class MetricAggregator:
             "d_weight": cp.d_weight[prows].copy(),
             "d_sum": cp.d_sum[prows].copy(),
         }
+
+        # what the syncs and the take_staged consolidations took, for
+        # flush_dispatch's split of snapshot_s
+        snap["part_seconds"] = (sync_s, staged_s)
 
         # key-dictionary fingerprints for the multi-controller lockstep
         # gather — snapshotted HERE, under the lock and before the GC in

@@ -23,13 +23,15 @@ import logging
 import os
 import socket
 import ssl
+import struct
 import threading
 import time
 from typing import Callable, Optional
 
 from veneur_tpu import config as config_mod
 from veneur_tpu import sinks as sink_mod
-from veneur_tpu.core.aggregator import MetricAggregator
+from veneur_tpu.core.aggregator import (LEDGER_SEGMENT_KEYS,
+                                        MetricAggregator)
 from veneur_tpu.profiling.timeline import FlushTimeline
 from veneur_tpu.samplers import parser as parser_mod
 from veneur_tpu.samplers import samplers as sm
@@ -421,6 +423,11 @@ class Server:
         self._proto_lock = threading.Lock()
         # last-reported native parse-error/too-long totals (flush deltas)
         self._native_err_reported = (0, 0)
+        # which queue overflowed (read once per flush, _ingest_overflow):
+        # the last interval's reading for /debug/vars, and the monotonic
+        # totals its deltas are taken from
+        self.ingest_overflow: dict = {}
+        self._overflow_totals = (0, 0)
         # host-path loss counters (the no-silent-loss ledger for lines
         # the PYTHON paths discard — the native plane keeps its own):
         # unparseable statsd lines, unparseable SSF datagrams, span-sink
@@ -1406,12 +1413,20 @@ class Server:
         covering the import, and remember the trace ids so the next
         flush's root span can tag the intervals it settles."""
         from veneur_tpu.trace import recorder as trace_rec
+        tags = {"metrics": str(n_metrics), "transport": transport,
+                "host": self.config.hostname}
+        # a batch import (this handler thread's, just made) says where
+        # its time went: outside the aggregator lock, waiting for it,
+        # holding it
+        timing = self.aggregator.take_import_timing()
+        if timing is not None and transport == "v1":
+            for name, ns in zip(("scan_ms", "lock_wait_ms", "held_ms"),
+                                timing):
+                tags[name] = f"{ns / 1e6:.3f}"
         for tid, sid in ctxs:
             span = trace_rec.continue_span(
                 "global.import", tid, sid, client=self.trace_client,
-                tags={"metrics": str(n_metrics), "transport": transport,
-                      "host": self.config.hostname},
-                start_ns=start_ns)
+                tags=dict(tags), start_ns=start_ns)
             span.finish()
         if ctxs:
             with self._imported_traces_lock:
@@ -1470,7 +1485,33 @@ class Server:
             child.client = None          # ring fast path below
             child.finish()
             self.flight_recorder.record_span(child)
+            if seg_name == "snapshot":
+                self._emit_snapshot_part_spans(child, segs)
             off += dur_ns
+
+    # the parts of snapshot_s the aggregator measures (flush_dispatch):
+    # the wait for the aggregator lock, then under it the arenas' syncs,
+    # the take_staged consolidations and the column copies + reset
+    _SNAPSHOT_PARTS = ("lock_wait", "sync", "staged", "columns")
+
+    def _emit_snapshot_part_spans(self, span, segs: dict) -> None:
+        """Grandchildren under flush.seg.snapshot, laid end to end from
+        its start (durations are measured, positions are not: take_staged
+        runs between column copies).  trace/assembly sums only the root's
+        direct children, so these leave the critical-path table alone."""
+        off = span.start_ns
+        for part in self._SNAPSHOT_PARTS:
+            v = segs.get(f"snapshot_{part}_s")
+            if v is None:
+                continue
+            child = span.child(f"flush.seg.snapshot.{part}")
+            try:
+                child.start_ns = off
+                child.end_ns = off = off + int(float(v) * 1e9)
+                child.client = None
+            finally:
+                child.finish()
+            self.flight_recorder.record_span(child)
 
     def _emit_chunk_spans(self, span, t0_ns: int, chunks) -> None:
         """Per-chunk grandchildren under flush.seg.device: one span per
@@ -1584,6 +1625,10 @@ class Server:
                 self.aggregator.last_flush_segments.items()):
             if not isinstance(v, (int, float)):
                 continue   # structured values (per-chunk stats list)
+            if seg_name in LEDGER_SEGMENT_KEYS:
+                # the interval ledger's outlet is the timeline row (and
+                # flush.seg.snapshot.* spans), not a dozen new series
+                continue
             if seg_name.endswith("_s"):
                 statsd.timing(f"flush.segment.{seg_name[:-2]}_ms",
                               v * 1e3)
@@ -1685,7 +1730,45 @@ class Server:
             metrics_emitted=len(res.metrics),
             forward_metrics=len(res.forward),
             trace_id=f"{span.trace_id:x}",
-            span_id=f"{span.span_id:x}")
+            span_id=f"{span.span_id:x}",
+            **self._ingest_overflow())
+
+    # getsockopt(SOL_SOCKET, SO_MEMINFO) -> 9 u32; the last is the
+    # socket's drop count (sk_drops: the `drops` column of /proc/net/udp)
+    _SO_MEMINFO = 55
+
+    def _ingest_overflow(self) -> dict:
+        """Which queue overflowed, once per flush, as timeline-row fields
+        (and /debug/vars -> ingest_overflow): datagrams the kernel dropped
+        at the UDP sockets' receive buffers since the last flush, publishes
+        of the native readers that found their ring full (no line is lost
+        there: the batch stays with the reader), and the rings' peak
+        occupancy as a share of their capacity.  Empty without the native
+        UDP plane."""
+        if self.native is None:
+            return {}
+        ring = self.native.ring_stats()
+        if ring is None:
+            return {}   # engine torn down under us
+        full, peak, slots = ring
+        drops = 0
+        for sock in self._listeners:
+            if (sock.type != socket.SOCK_DGRAM
+                    or sock.family == socket.AF_UNIX):
+                continue
+            try:
+                drops += struct.unpack("9I", sock.getsockopt(
+                    socket.SOL_SOCKET, self._SO_MEMINFO, 36))[8]
+            except (OSError, struct.error):
+                pass    # closed mid-shutdown, or a kernel without it
+        drops0, full0 = self._overflow_totals
+        self._overflow_totals = (drops, full)
+        out = {"udp_rcvbuf_drops": max(0, drops - drops0),
+               "ring_full_stalls": max(0, full - full0),
+               "ring_peak_share": round(peak / slots, 4) if slots else 0.0}
+        self.ingest_overflow = dict(
+            out, udp_rcvbuf_drops_total=drops, ring_full_stalls_total=full)
+        return out
 
     def _flush_interval_accounting(self, statsd) -> None:
         """Host-side per-interval self-metric accounting that does not

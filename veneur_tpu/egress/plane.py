@@ -118,7 +118,7 @@ class EgressJob:
     """One sink's share of one flush interval."""
 
     __slots__ = ("metrics", "events", "statsd", "interval",
-                 "trace_id", "parent_span_id", "traced")
+                 "trace_id", "parent_span_id", "traced", "enqueued_ns")
 
     def __init__(self, metrics, events, statsd, interval: int,
                  trace_id: int = 0, parent_span_id: int = 0,
@@ -130,6 +130,9 @@ class EgressJob:
         self.trace_id = trace_id
         self.parent_span_id = parent_span_id
         self.traced = traced
+        # wall clock of the handoff onto a lane's queue (SinkLane.submit):
+        # where the job's flush.sink.<name> span and its lane.wait start
+        self.enqueued_ns = 0
 
 
 def _safe_dirname(name: str) -> str:
@@ -180,6 +183,7 @@ class SinkLane:
         cannot keep up drops whole intervals VISIBLY instead of
         wedging the flush ticker."""
         self.plane.job_opened()
+        job.enqueued_ns = time.time_ns()
         try:
             self.queue.put_nowait(job)
         except queue_mod.Full:
@@ -260,11 +264,18 @@ class SinkLane:
             self.busy_since = t0
         span = None
         if job.traced and job.trace_id:
+            # the span covers the sink's whole share of the interval,
+            # from the flush's handoff: its first child is the wait on
+            # this lane's queue
+            claimed_ns = time.time_ns()
             span = trace_rec.continue_span(
                 f"flush.sink.{self.name}", job.trace_id,
                 job.parent_span_id,
                 tags={"sink": self.name, "kind": self.kind,
-                      "interval": str(job.interval)})
+                      "interval": str(job.interval)},
+                start_ns=job.enqueued_ns or claimed_ns)
+            if self.kind == "metric":
+                self._lane_span(span, "wait", span.start_ns, claimed_ns)
         try:
             if self.kind == "metric":
                 self._deliver_metric(job, statsd, span)
@@ -286,10 +297,27 @@ class SinkLane:
                 span.finish()
                 self.plane.record_span(span)
 
+    def _lane_span(self, span, part: str, start_ns: int,
+                   end_ns: int) -> None:
+        """One measured part of a traced metric job (flush.seg.lane.wait
+        / .filter / .sink) as a child of its flush.sink.<name> span, on
+        the interval's own trace.  With several metric sinks the names
+        repeat per trace; the `sink` tag tells them apart."""
+        child = span.child(f"flush.seg.lane.{part}",
+                           tags={"sink": self.name})
+        child.start_ns = start_ns
+        child.end_ns = end_ns
+        child.client = None      # ring fast path, like the flush segments
+        child.finish()
+        self.plane.record_span(child)
+
     def _deliver_metric(self, job: EgressJob, statsd, span) -> None:
+        t_filter = time.time_ns()
         filtered, counts = sink_mod.filter_metrics_for_sink(
             self.spec, self.plane.routing_enabled, job.metrics,
             excluded_tags=self.plane.excluded_tags_for(self.name))
+        if span is not None:
+            self._lane_span(span, "filter", t_filter, time.time_ns())
         start = time.perf_counter()
         try:
             # status counts are emitted whether or not delivery lands
@@ -306,7 +334,13 @@ class SinkLane:
                              tags=self.sink_tags)
                 logger.error("sink %s flush_other_samples failed: %s",
                              self.name, e)
-            self._attempt_flush(filtered, job, statsd, span)
+            t_sink = time.time_ns()
+            try:
+                self._attempt_flush(filtered, job, statsd, span)
+            finally:
+                if span is not None:
+                    # the sink.flush call, all attempts and backoffs
+                    self._lane_span(span, "sink", t_sink, time.time_ns())
         finally:
             statsd.timing("sink.metric_flush_total_duration_ms",
                           (time.perf_counter() - start) * 1e3,

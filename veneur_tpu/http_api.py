@@ -196,6 +196,12 @@ def debug_vars(server) -> dict:
             # thread + totals — the live view the ceiling
             # harness (scripts/ingest_ceiling.py) tabulates
             stats["ingest_stages"] = st
+        # which queue overflowed, as of the last flush: kernel drops at
+        # the UDP sockets' receive buffers, reader publishes that found
+        # their ring full, peak ring occupancy (also on the flush
+        # timeline's rows, per interval)
+        stats["ingest_overflow"] = dict(
+            getattr(server, "ingest_overflow", None) or {})
     timeline = getattr(server, "flush_timeline", None)
     if timeline is not None:
         stats["flush_timeline_recorded"] = \

@@ -24,6 +24,7 @@ import os
 import struct
 import subprocess
 import threading
+import time
 from dataclasses import dataclass
 from typing import Callable, Optional
 
@@ -140,6 +141,8 @@ def load_library():
             ctypes.c_void_p, ctypes.POINTER(ctypes.c_ulonglong),
             ctypes.c_longlong]
         lib.vn_stage_drain.argtypes = [
+            ctypes.c_void_p, ctypes.POINTER(ctypes.c_ulonglong)]
+        lib.vn_ring_stats.argtypes = [
             ctypes.c_void_p, ctypes.POINTER(ctypes.c_ulonglong)]
         lib.vn_metro64.restype = ctypes.c_ulonglong
         lib.vn_metro64.argtypes = [ctypes.c_char_p, ctypes.c_long]
@@ -550,6 +553,15 @@ class IngestEngine:
     def intern_count(self) -> int:
         return int(self.lib.vn_intern_count(self.handle))
 
+    def ring_stats(self) -> tuple[int, int, int]:
+        """(publishes that found a reader's ring full — monotonic, and no
+        line is lost: the batch stays with the reader until a drain frees
+        a slot; peak ring occupancy in slots since the LAST call — read
+        and reset; slots per ring)."""
+        out = (ctypes.c_ulonglong * 3)()
+        self.lib.vn_ring_stats(self.handle, out)
+        return int(out[0]), int(out[1]), int(out[2])
+
     def stage_stats(self) -> dict:
         """Per-stage data-plane accounting (profiling subsystem).
 
@@ -802,7 +814,9 @@ class NativeIngest:
             self.too_long += batch.too_long
         if not batch.empty:
             agg = self.agg
+            t_wait = time.perf_counter_ns()
             with agg.lock:
+                t_held = time.perf_counter_ns()
                 for nk in batch.new_keys:
                     self._register(nk)
                 agg.processed += batch.processed
@@ -834,6 +848,9 @@ class NativeIngest:
                 if len(batch.s_ids):
                     rows = self._rows_for(agg.sets, batch.s_ids)
                     agg.sets.stage_hash_batch(rows, batch.s_hashes)
+                # interval ledger: this fold belongs to the interval the
+                # next snapshot closes (last statement under the lock)
+                agg.ledger_fold(batch.processed, t_wait, t_held)
         return batch
 
     def _apply_cube_rollups(self, agg, cubes, batch) -> None:
@@ -889,6 +906,15 @@ class NativeIngest:
             if self.engine._closed:
                 return None
             return self.engine.stage_stats()
+
+    def ring_stats(self) -> Optional[tuple]:
+        """The engine's ring overflow accounting (IngestEngine.ring_stats;
+        the peak is read and reset), under the drain lock so a flush
+        racing teardown reads None instead of freed memory."""
+        with self._drain_lock:
+            if self.engine._closed:
+                return None
+            return self.engine.ring_stats()
 
     def stop(self) -> None:
         self.engine.stop()
