@@ -28,6 +28,7 @@ from veneur_tpu.ops import sorted_eval as se
 from veneur_tpu.parallel import serving
 from veneur_tpu.parallel.mesh import REPLICA_AXIS, SHARD_AXIS
 from veneur_tpu.sketches import compactor as cs
+from veneur_tpu.sketches import hll as hll_mod
 from veneur_tpu.sketches import moments as ms
 
 N_PCT = 3
@@ -215,6 +216,23 @@ def test_serving_flush_program_compiles_for_v5e(variant, one_chip,
         lowered = flush_fn.lower_donated(
             s((u, d)), s((u, d)), s((2, u)), s((N_PCT,)), uniform=False)
     assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("rows", [1024, 65536],
+                         ids=["node1.fanout", "sets50k"])
+def test_set_estimate_compiles_for_v5e(rows, one_chip):
+    """`hll.estimate` over a row bucket of p = 14 u8 registers, the
+    program `MetricAggregator._dispatch_sets` launches: the 1,000 set
+    keys of `node1.fanout`, and 50k rows (1 GiB of registers).  One
+    fusion straight off the u8 operand — no f32 copy of the registers
+    in HBM (the chip measured it ahead of `ops/hll_estimate.py`'s
+    Pallas form, which is not on the served path)."""
+    compiled = hll_mod.estimate.lower(
+        _struct(one_chip, (rows, 1 << 14), jnp.uint8)).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes == rows << 14
+    assert mem.temp_size_in_bytes < (rows << 14) // 8
 
 
 def test_meshed_flush_program_compiles_for_v5e_2x2(topo, as_tpu):

@@ -55,8 +55,9 @@ def test_prewarm_makes_ramp_compile_free():
     # the depth-vector uniform flush and the general weighted flush
     # for the digest family, plus the moments and compactor read-offs
     # (wire payloads route into their arenas on any tier, so every
-    # family's programs prewarm too)
-    assert warmed == 20
+    # family's programs prewarm too), and the set estimate at the set
+    # arena's capacity
+    assert warmed == 21
     base = agg.compile_events
     for n in (128, 200, 400, 900, 1024):    # ramp within the buckets
         _stage(agg, n)

@@ -102,6 +102,11 @@ LEDGER_SEGMENT_KEYS = frozenset(
     ["snapshot_lock_wait_s", "snapshot_sync_s", "snapshot_staged_s",
      "snapshot_columns_s"]
     + [f[:-3] + "_s" if f.endswith("_ns") else f for f in _LEDGER_FIELDS])
+# every key of last_flush_segments that core/server.py keeps out of that
+# loop: the ledger's, and what the set estimate's device program
+# (_dispatch_sets) says of itself
+ROW_ONLY_SEGMENT_KEYS = LEDGER_SEGMENT_KEYS | {
+    "set_rows_device", "set_upload_bytes", "set_device_s"}
 
 
 def _new_ledger() -> dict:
@@ -115,6 +120,14 @@ _EXPORT_ELEM_BUDGET = 1 << 26
 # A flush smaller than chunks * this many dense rows is not worth
 # splitting for upload/evaluate overlap (dispatch overhead dominates).
 _CHUNK_MIN_ROWS = 8192
+
+# Fewer touched set rows than this are estimated by numpy at dispatch
+# (0.3 ms a row on a v5e's host, outside the lock) instead of on the
+# chip: a launch costs the host more than that below a few rows, and
+# the one row a server's own telemetry touches in a flush out of a
+# hundred (ssf.names_unique, sampled at 1 %) must not compile a
+# [1, m] program inside that flush.  8 = the rows of one f32 tile.
+_SET_DEVICE_MIN_ROWS = 8
 
 
 class MetricAggregator:
@@ -351,8 +364,9 @@ class MetricAggregator:
         self.unique_ts = hll_mod.HLLSketch() if count_unique_timeseries else None
         self.is_local = is_local
         # ONE device program evaluates the flush (parallel/serving.py):
-        # mesh-less it is the digest sorted-eval alone (sets/counters/
-        # unique-ts resolve on host); meshed it is the shard_map'd
+        # mesh-less it is the digest sorted-eval, beside it the set
+        # estimate over the snapshot's register copy (_dispatch_sets;
+        # counters/unique-ts resolve on host); meshed it is the shard_map'd
         # full-family program (all_gather over sample depth, set pmax,
         # counter psum, unique-ts union).
         self.flush_fn = serving.make_serving_flush(mesh)
@@ -1102,8 +1116,9 @@ class MetricAggregator:
         # snapshot_s in parts: the wait for the lock (the drain's fold
         # and the import hold it), then under the lock the arenas'
         # sync()s, the take_staged() consolidations, and the rest (the
-        # per-family column copies and the reset); what remains of
-        # snapshot_s is the deferred estimate above
+        # per-family column copies, the set registers' copy and the
+        # reset); what remains of snapshot_s is the deferred estimate
+        # above
         sync_s, staged_s = snap.pop("part_seconds")
         seg["snapshot_lock_wait_s"] = t_held - t0
         seg["snapshot_sync_s"] = sync_s
@@ -1123,15 +1138,19 @@ class MetricAggregator:
         seg["keys_compactor"] = len(snap["compactors"]["rows"])
         seg["keys_counter"] = len(snap["counters"]["rows"])
         seg["keys_set"] = len(snap["sets"]["rows"])
+        # rows whose estimate the chip computes this flush
+        # (_dispatch_sets); 0 on every flush that launches no set program
+        seg["set_rows_device"] = 0
         # the window-ring cut timestamp is taken HERE (the cut), but
         # the slot is published at emit time — see _emit_pending
         snap["query_cut_ts"] = time.time()
 
         # ONE device program call evaluates the flush on the snapshot
         # OUTSIDE the lock, so ingest continues (flusher.go:26-122 +
-        # worker.go:402-459 as one program).  Mesh-less, sets/counters/
-        # unique-ts resolve on host and the program only runs when digest
-        # rows were touched; an idle interval skips the dispatch entirely.
+        # worker.go:402-459 as one program).  Mesh-less, counters/
+        # unique-ts resolve on host, and the digest and set programs run
+        # only when their rows were touched; an idle interval skips the
+        # dispatch entirely.
         # Multi-controller meshes may NEVER take the idle skip: the
         # lockstep agreement gather inside _dispatch_flush is a
         # collective, and a controller that skipped it while a peer
@@ -1289,12 +1308,23 @@ class MetricAggregator:
         flush interval (the compiles land in the persistent cache, making
         later boots near-free).  Meant for a background thread at boot;
         `stop` aborts between buckets.  Returns programs compiled
-        (2 per bucket: the uniform and general sort networks).
+        (per bucket: the uniform and general sort networks, the moments
+        pair and the compactor read-off; plus the set estimate, once).
         Mesh-less only: meshed program shapes include per-family state
         and are pre-sized by configuration instead."""
         if self.mesh is not None:
             return 0
         n = 0
+        if self.sets.host_regs is not None:
+            # the set estimate (_dispatch_sets) at the one row bucket
+            # known at boot: the set arena's capacity
+            # (set_arena_initial_capacity), which a deployment that
+            # touches over half its set rows an interval lands in
+            s_regs = jax.ShapeDtypeStruct(
+                (self.sets.capacity, self.sets.m), np.uint8)
+            with self._CompileGuard(self, ("set_estimate", s_regs.shape)):
+                hll_mod.estimate.lower(s_regs).compile()
+            n += 1
         u = 1 << (max(min_keys, 2) - 1).bit_length()
         max_keys = arena_mod._pow2(max_keys)   # arena rounds up too
         buckets = []
@@ -1397,8 +1427,9 @@ class MetricAggregator:
         wait happens here.  Returns the pending-launch state that
         _fetch_flush consumes at emit time.
 
-        Mesh-less: one digest program call per upload chunk (dense
-        upload -> [K, P+2] readback); sets/counters/unique-ts were
+        Mesh-less: the set estimate over the snapshot's register copy
+        (_dispatch_sets), then one digest program call per upload chunk
+        (dense upload -> [K, P+2] readback); counters/unique-ts were
         already resolved on host at snapshot.  Meshed: the full-family
         shard_map'd program as ONE packed launch over pre-sharded staged
         buffers.  On a non-forwarding (global) tier every per-flush
@@ -1409,6 +1440,10 @@ class MetricAggregator:
         nd = len(dpart["rows"])
         seg = self.last_flush_segments
         pend: dict = {"nd": nd, "meshed": self.mesh is not None}
+        if "host_regs" in snap["sets"]:
+            # first, so the register upload rides the transfer engine
+            # under the digest build/layout below
+            pend["sets"] = self._dispatch_sets(snap["sets"])
         # the moments family launches its own program — a dense
         # segmented-sum merge + batched maxent solve, a different
         # compute class from the digest sort network — so it dispatches
@@ -1423,8 +1458,7 @@ class MetricAggregator:
                 # resident set registers (flush_resident_arenas):
                 # dispatch ONE device gather of the touched rows'
                 # lane-union registers; the fetch reads the exact u8
-                # rows back and estimates HOST-side, so the results are
-                # bit-identical to the host-register path
+                # rows back and estimates HOST-side
                 ps = self._padded_rows(spart["rows"])
                 pend["set_rows_dev"] = serving.set_gather_rows(
                     spart["lanes"], jnp.asarray(ps))
@@ -1707,6 +1741,32 @@ class MetricAggregator:
                 dense_dev=None if donate else (dvd, dwd))
             return pend
 
+    def _dispatch_sets(self, spart: dict) -> dict:
+        """Upload the snapshot's register copy and LAUNCH the set
+        estimate on it (mesh-less host registers, outside the lock):
+        [rows bucket, m] u8 -> [rows bucket] f32 LogLog-Beta estimates,
+        the program the meshed flush runs inside flush_body.  The
+        readback starts here and is waited for in _fetch_flush.  A
+        handful of rows (_SET_DEVICE_MIN_ROWS) is estimated here, by
+        the numpy twin, and launches nothing."""
+        seg = self.last_flush_segments
+        regs = spart["host_regs"]
+        n = len(spart["rows"])
+        if n < _SET_DEVICE_MIN_ROWS:
+            return {"ests": hll_mod.estimate_np_rows(regs[:n])}
+        t0 = time.perf_counter()
+        regs_dev = serving.put(regs, None)
+        t1 = time.perf_counter()
+        with self._CompileGuard(self, ("set_estimate", regs.shape)):
+            ests = hll_mod.estimate(regs_dev)
+        ests.copy_to_host_async()
+        seg["set_rows_device"] = n
+        seg["set_upload_bytes"] = regs.nbytes
+        seg["upload_bytes"] = seg.get("upload_bytes", 0) + regs.nbytes
+        return {"ests": ests, "t0": t0,
+                "stats": {"rows": n, "upload_s": t1 - t0,
+                          "dispatch_s": time.perf_counter() - t1}}
+
     def _dispatch_moments(self, snap: dict) -> Optional[dict]:
         """Build, stage and LAUNCH the moments-family program on the
         snapshot (outside the lock): compact dense build of the staged
@@ -1839,9 +1899,9 @@ class MetricAggregator:
         if not pend["meshed"]:
             if "set_rows_dev" in pend:
                 # resident set registers: exact u8 readback of the
-                # touched rows, estimated HOST-side — bit-identical to
-                # the host-register path, and the registers double as
-                # the forwarding marshal source (host["set_regs"])
+                # touched rows, estimated HOST-side; the registers
+                # double as the forwarding marshal source
+                # (host["set_regs"])
                 srows = snap["sets"]["rows"]
                 t0 = time.perf_counter()
                 regs = serving.fetch(
@@ -1853,9 +1913,24 @@ class MetricAggregator:
                     hll_mod.estimate_np_rows(regs) if len(regs)
                     else np.zeros(0, np.float64))
                 host["set_regs"] = regs
-            elif "estimates" in snap["sets"]:
-                host["set_ests"] = snap["sets"]["estimates"]
+            sp = pend.get("sets", {})
+            set_t0 = sp.get("t0")       # None: nothing was launched
+            set_wait = 0.0
+            if set_t0 is not None:
+                t0 = time.perf_counter()
+                ests = serving.fetch(sp["ests"])
+                set_wait = time.perf_counter() - t0
+                sp["stats"]["wait_s"] = seg["set_device_s"] = set_wait
+                seg["device_sets"] = sp["stats"]
+                seg["readback_bytes"] = (seg.get("readback_bytes", 0)
+                                         + ests.nbytes)
+                # padding rows of the bucket stop here
+                host["set_ests"] = ests[:len(snap["sets"]["rows"])]
+            elif sp:
+                host["set_ests"] = sp["ests"]
             if nd == 0:
+                if set_t0 is not None:
+                    seg["device_s"] = set_wait
                 return host
             t0 = time.perf_counter()
             cs = pend.get("chunk_stats")
@@ -1875,13 +1950,16 @@ class MetricAggregator:
                 # segments, the causal proof of the pipeline — lands in
                 # device_window_s and is what the flight recorder lays
                 # as the flush.seg.device span
-                seg["device_window_s"] = (time.perf_counter()
-                                          - pend["t_dispatch0"])
+                seg["device_window_s"] = (
+                    time.perf_counter()
+                    - (pend["t_dispatch0"] if set_t0 is None else set_t0))
             else:
                 fetched = serving.fetch(tuple(pend["outs"]))
             ev = (fetched[0] if pend["n_chunks"] == 1
                   else np.concatenate(fetched))
-            seg["device_s"] = time.perf_counter() - t0
+            # the flush's blocking wait on the device: the digest
+            # outputs and, before them, the set estimates
+            seg["device_s"] = time.perf_counter() - t0 + set_wait
             seg["readback_bytes"] = (seg.get("readback_bytes", 0)
                                      + ev.nbytes)
             host["dense_dev"] = pend["first_dev"]
@@ -2021,11 +2099,11 @@ class MetricAggregator:
             "legacy_ests": s.legacy_estimates(srows),
         }
         if s.host_regs is not None:
-            # host registers: estimates now, register copies only if rows
-            # will forward (Set.Metric marshal needs them post-reset)
-            snap["sets"]["estimates"] = s.host_estimates(srows)
-            if len(srows) and (snap["sets"]["scopes"]
-                               == int(MetricScope.MIXED)).any():
+            # host registers: under the lock, copy and nothing else.
+            # The estimate runs from the copy at dispatch, on the chip
+            # (_dispatch_sets); a forwarding tier marshals its MIXED
+            # rows from the same copy (post-reset)
+            if len(srows):
                 snap["sets"]["host_regs"] = s.host_regs_copy(srows)
         elif self.mesh is not None or len(srows):
             # device lanes — meshed, or unmeshed-resident

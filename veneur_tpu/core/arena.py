@@ -639,8 +639,8 @@ class SetArena(_ArenaBase):
         # sharding.  Inserts then stream to HBM during the interval and
         # the flush reads back only the touched rows' registers
         # (serving.set_gather_rows); estimates still compute HOST-side
-        # on the exact u8 readback, so they are bit-identical to the
-        # host-register path.
+        # on the exact u8 readback (hll.estimate_np_rows: within one
+        # count of the host-register path's device estimate).
         self.resident = bool(resident) and mesh is None
         # Rolling-upgrade migration lane (hll_legacy_migration): legacy
         # 'VH' imports carry blake2b-hashed members which do NOT union
@@ -834,16 +834,20 @@ class SetArena(_ArenaBase):
         del ref  # kept for call-site symmetry; holds are counted
         self._snapshot_inflight = max(0, self._snapshot_inflight - 1)
 
-    def host_estimates(self, rows: np.ndarray) -> np.ndarray:
-        """Mesh-less only: batched LogLog-Beta estimates of the given
-        rows' host registers (sync first)."""
-        self.sync()
-        return hll_mod.estimate_np_rows(self.host_regs[rows])
-
     def host_regs_copy(self, rows: np.ndarray) -> np.ndarray:
-        """Mesh-less only: snapshot of the given rows' registers for
-        forwarding marshal (call under the aggregator lock)."""
-        return self.host_regs[rows].copy()
+        """Mesh-less only: the given rows' registers, gathered into a
+        zero-padded power-of-two row bucket (call under the aggregator
+        lock, sync first).  ONE copy serves the flush: the bucket is
+        the shape the device estimate compiles for (all-zero padding
+        rows estimate to 0 and are sliced off at the fetch), and its
+        first len(rows) rows are the forwarding marshal source."""
+        n = len(rows)
+        out = np.empty((_pow2(n), self.m), np.uint8)
+        # mode="clip": rows are arena row ids, and the default "raise"
+        # would gather through a second buffer
+        np.take(self.host_regs, rows, axis=0, out=out[:n], mode="clip")
+        out[n:] = 0
+        return out
 
     def reset_rows(self, rows: np.ndarray) -> None:
         self.sync()

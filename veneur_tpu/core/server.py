@@ -30,7 +30,7 @@ from typing import Callable, Optional
 
 from veneur_tpu import config as config_mod
 from veneur_tpu import sinks as sink_mod
-from veneur_tpu.core.aggregator import (LEDGER_SEGMENT_KEYS,
+from veneur_tpu.core.aggregator import (ROW_ONLY_SEGMENT_KEYS,
                                         MetricAggregator)
 from veneur_tpu.profiling.timeline import FlushTimeline
 from veneur_tpu.samplers import parser as parser_mod
@@ -1475,8 +1475,7 @@ class Server:
                     child.client = None
                     child.finish()
                     self.flight_recorder.record_span(child)
-                    self._emit_chunk_spans(child, child.start_ns,
-                                           segs.get("device_chunks"))
+                    self._emit_device_part_spans(child, segs)
                     off += dur_ns
                     continue
             child = span.child(f"flush.seg.{seg_name}")
@@ -1487,6 +1486,8 @@ class Server:
             self.flight_recorder.record_span(child)
             if seg_name == "snapshot":
                 self._emit_snapshot_part_spans(child, segs)
+            elif seg_name == "device":
+                self._emit_device_part_spans(child, segs)
             off += dur_ns
 
     # the parts of snapshot_s the aggregator measures (flush_dispatch):
@@ -1513,28 +1514,31 @@ class Server:
                 child.finish()
             self.flight_recorder.record_span(child)
 
-    def _emit_chunk_spans(self, span, t0_ns: int, chunks) -> None:
-        """Per-chunk grandchildren under flush.seg.device: one span per
-        pipelined upload chunk laid from its measured upload/dispatch/
-        drain/wait durations, so a traced interval shows chunk i+1's
+    def _emit_device_part_spans(self, span, segs: dict) -> None:
+        """Grandchildren under flush.seg.device, laid end to end from
+        its start out of their measured upload/dispatch/drain/wait
+        durations: `sets` (the set estimate's register upload, launch
+        and wait, aggregator._dispatch_sets), then one span per
+        pipelined upload chunk, so a traced interval shows chunk i+1's
         upload riding on top of chunk i's device window."""
-        if not chunks:
-            return
-        off = 0
-        for i, c in enumerate(chunks):
+        parts = []
+        if segs.get("device_sets"):
+            parts.append(("sets", segs["device_sets"]))
+        parts += [(f"chunk{i}", c)
+                  for i, c in enumerate(segs.get("device_chunks") or ())]
+        off = span.start_ns
+        for name, c in parts:
             dur = (c.get("upload_s", 0.0) + c.get("dispatch_s", 0.0)
                    + c.get("drain_s", 0.0) + c.get("wait_s", 0.0))
-            dur_ns = int(float(dur) * 1e9)
-            child = span.child(f"flush.seg.device.chunk{i}")
+            child = span.child(f"flush.seg.device.{name}")
             try:
-                child.start_ns = t0_ns + off
-                child.end_ns = child.start_ns + dur_ns
+                child.start_ns = off
+                child.end_ns = off = off + int(float(dur) * 1e9)
                 child.tags = {"rows": str(c.get("rows", 0))}
                 child.client = None
             finally:
                 child.finish()
             self.flight_recorder.record_span(child)
-            off += dur_ns
 
     def _flush_locked(self) -> None:
         from veneur_tpu import failpoints
@@ -1625,7 +1629,7 @@ class Server:
                 self.aggregator.last_flush_segments.items()):
             if not isinstance(v, (int, float)):
                 continue   # structured values (per-chunk stats list)
-            if seg_name in LEDGER_SEGMENT_KEYS:
+            if seg_name in ROW_ONLY_SEGMENT_KEYS:
                 # the interval ledger's outlet is the timeline row (and
                 # flush.seg.snapshot.* spans), not a dozen new series
                 continue

@@ -24,8 +24,10 @@ pre-reduction (`partial_digests`), both of which return slim arrays.
 
 Sets (HLL registers) and counters keep device-resident lane state only
 when a mesh is configured (the registers then pmax over 'replica' and the
-counter hi/lo planes psum); without a mesh both families resolve on host
-(see core/arena.py) and the program evaluates digests only.
+counter hi/lo planes psum); without a mesh both families keep their state on
+host (see core/arena.py), the program evaluates digests only, and a flush
+uploads a copy of the touched set rows' registers for `sketches/hll.estimate`
+(core/aggregator.py `_dispatch_sets`).
 
 Counters ride as two float32 planes (hi, lo) with value = hi * 2^24 + lo:
 each plane is integer-exact below 2^24, so the psum'd total is exact below

@@ -218,9 +218,12 @@ def estimate(regs: jax.Array) -> jax.Array:
 
 
 def estimate_np_rows(regs: np.ndarray) -> np.ndarray:
-    """Batched numpy twin of `estimate` for `[S, m]` register rows —
-    used by the mesh-less SetArena where a device round-trip per flush
-    would cost more than the math (parity-tested against the XLA path)."""
+    """Batched numpy twin of `estimate` for `[S, m]` register rows
+    (parity-tested against the XLA path): the reference of the served
+    estimate's tests, and what the few-row host lanes use (unique
+    timeseries, the legacy migration lane, the resident path's
+    readback).  323 ms per 1,000 rows at p = 14 on the v5e's host,
+    against 45 us on the chip: not for the flush's set rows."""
     if regs.shape[0] == 0:
         return np.zeros(0, np.float32)
     r = regs.astype(np.float32)
