@@ -259,3 +259,44 @@ def test_meshed_flush_program_compiles_for_v5e_2x2(topo, as_tpu):
     assert "tpu_custom_call" in hlo
     sizes = serving.collective_group_sizes(hlo, "all-to-all")
     assert sizes and set(sizes) == {2}, sizes
+
+
+def test_mesh4_cell_programs_compile_for_v5e_2x2(topo, as_tpu):
+    """What `benchmark`'s `mesh4.steady` launches (configuration
+    `global-mesh4`: `arena_initial_capacity` 131,072, so 8,192 set rows
+    and 131,072 counter rows a lane), at its own shapes: the meshed
+    uniform program on `[131072, 32]`, its three collectives under the
+    scopes the device trace names them by, and the set-lane kernels
+    `SetArena.prewarm_lanes` runs."""
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2),
+                (SHARD_AXIS, REPLICA_AXIS))
+    u, d, k2, s_rows, m = 131072, 32, 131072, 8192, 1 << 14
+
+    def s(shape, spec, dt=jnp.float32):
+        return _struct(NamedSharding(mesh, spec), shape, dt)
+
+    lanes = P(REPLICA_AXIS, SHARD_AXIS, None)
+    regs = s((2, s_rows, m), lanes, jnp.uint8)
+    inputs = serving.FlushInputs(
+        dense_v=s((u, d), P(SHARD_AXIS, REPLICA_AXIS)),
+        dense_w=s((u, d), P(SHARD_AXIS, REPLICA_AXIS)),
+        minmax=s((2, u), P(None, SHARD_AXIS)),
+        hll_regs=regs, counter_planes=s((2, k2, 2), lanes),
+        uts_regs=s((2, m), P(REPLICA_AXIS, None), jnp.uint8))
+    compiled = serving.make_serving_flush(mesh).lower(
+        inputs, s((N_PCT + 1,), P(None)), uniform=True).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    for scope, opcode in (("flush.a2a", "all-to-all("),
+                          ("flush.psum", "all-reduce("),
+                          ("flush.pmax", "all-reduce(")):
+        assert any(opcode in ln and f"/{scope}/" in ln
+                   for ln in hlo.splitlines()), scope
+    assert set(serving.collective_group_sizes(hlo, "all-to-all")) == {2}
+    # a chip's share of the lanes is [1, 4096, 16384] u8 = 64 MiB
+    assert compiled.memory_analysis().argument_size_in_bytes < 80 << 20
+    one = jax.ShapeDtypeStruct((1,), jnp.int32)
+    rank = jax.ShapeDtypeStruct((1,), jnp.uint8)
+    serving.set_reset_rows.lower(regs, one).compile()
+    serving.set_lane_scatter.lower(regs, one, one, rank, 0).compile()
+    serving.set_lane_scatter_copy.lower(regs, one, one, rank, 1).compile()

@@ -568,6 +568,10 @@ def test_range_form_serves_bins_over_the_ring():
     # the first-ever cut's slot is zero-width (no prior cut anchors
     # its window start), so warm the ring before the measured flush
     agg.flush(is_local=False)
+    # ...and give the measured slot a width: two back-to-back cuts can
+    # lie under the planner's overlap slack (1e-4 of the step = 0.5 ms),
+    # and a slot that narrow covers no bin (it failed by the clock)
+    _time.sleep(0.01)
     _ingest_histo(agg, "h", [1.0, 2.0, 3.0, 4.0])
     agg.flush(is_local=False)
     since = _time.time() - 5.0

@@ -357,8 +357,16 @@ class Config:
     # prewarm compiles the configured
     # depth buckets for every pow2 key count up to the arena pre-size in
     # a background thread at boot, so a cardinality ramp never pays a
-    # compile inside a flush interval.  Compile events surface as
+    # compile inside a flush interval.  With a device mesh (mesh_devices
+    # > 0) one compile costs what that whole sweep does, and the tier is
+    # a global sized by its configuration: prewarm then compiles the
+    # meshed flush program at the bucket of arena_initial_capacity (at
+    # prewarm_depths, first), the set-lane kernels, and the bucket an
+    # interval of the server's own telemetry lands in — nothing between
+    # — and does so before the server opens a listener, not beside it.
+    # Compile events surface as
     # flush.compile_events_total / flush.compile_seconds self-metrics,
+    # /debug/vars carries prewarm_programs / prewarm_seconds,
     # and the flush watchdog is compile-aware (a first-bucket compile is
     # not a hang).
     compilation_cache_dir: str = ""

@@ -23,6 +23,7 @@ like the reference's swap-maps-under-mutex (`worker.go:462-481`).
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from dataclasses import dataclass, field
@@ -103,10 +104,13 @@ LEDGER_SEGMENT_KEYS = frozenset(
      "snapshot_columns_s"]
     + [f[:-3] + "_s" if f.endswith("_ns") else f for f in _LEDGER_FIELDS])
 # every key of last_flush_segments that core/server.py keeps out of that
-# loop: the ledger's, and what the set estimate's device program
-# (_dispatch_sets) says of itself
+# loop: the ledger's, and what the device programs (_dispatch_sets,
+# _launch_meshed) say of themselves
 ROW_ONLY_SEGMENT_KEYS = LEDGER_SEGMENT_KEYS | {
-    "set_rows_device", "set_upload_bytes", "set_device_s"}
+    "set_rows_device", "set_upload_bytes", "set_device_s",
+    # the meshed launch's own (_launch_meshed): the dense shape it ran
+    # and the bytes its collectives move per device
+    "device_rows", "device_depth", "collective_bytes"}
 
 
 def _new_ledger() -> dict:
@@ -390,6 +394,10 @@ class MetricAggregator:
         self.compile_events = 0
         self.compile_seconds_total = 0.0
         self.compile_in_progress = threading.Event()
+        # lane-kernel launches (set scatter / merge / reset on device
+        # lanes) are compile events like the flush program's
+        self.sets.compile_guard = functools.partial(self._CompileGuard,
+                                                    self)
         self._uts_m = self.unique_ts.m if self.unique_ts is not None \
             else 1 << hll_mod.DEFAULT_PRECISION
         self._pct_arr = jnp.asarray([0.5] + list(self.percentiles),
@@ -1310,10 +1318,14 @@ class MetricAggregator:
         `stop` aborts between buckets.  Returns programs compiled
         (per bucket: the uniform and general sort networks, the moments
         pair and the compactor read-off; plus the set estimate, once).
-        Mesh-less only: meshed program shapes include per-family state
-        and are pre-sized by configuration instead."""
+
+        On a mesh (`_prewarm_meshed`) it is the shard_map'd program at
+        the bucket of `max_keys` and at the bucket an interval of the
+        server's own telemetry lands in, and the set-lane kernels: a
+        meshed tier is a global sized by its configuration, and each of
+        its compiles takes what a whole mesh-less sweep does."""
         if self.mesh is not None:
-            return 0
+            return self._prewarm_meshed(depths, max_keys, stop)
         n = 0
         if self.sets.host_regs is not None:
             # the set estimate (_dispatch_sets) at the one row bucket
@@ -1420,6 +1432,89 @@ class MetricAggregator:
                         self._pct_arr).compile()
                 n += 1
         return n
+
+    def _uts_lanes(self, uts: Optional[np.ndarray]):
+        """[R, m] unique-timeseries register lanes on the mesh, this
+        process's tally (if any) in lane 0; the program pmaxes over both
+        mesh axes (across processes this is the DCN union of per-host
+        tallies)."""
+        from veneur_tpu.parallel.mesh import REPLICA_AXIS
+        lanes = np.zeros((self.mesh.shape[REPLICA_AXIS], self._uts_m),
+                         np.uint8)
+        if uts is not None:
+            lanes[0] = uts
+        return serving.put(lanes, jax.sharding.NamedSharding(
+            self.mesh, jax.sharding.PartitionSpec(REPLICA_AXIS, None)))
+
+    def _prewarm_meshed(self, depths, max_keys: int,
+                        stop: Optional[threading.Event]) -> int:
+        """Compile what a meshed global launches in steady state, by
+        launching each program once on zeros through the flush's own
+        launch (_launch_meshed), so that the executable a data flush
+        looks up is the one compiled here — shapes, shardings, donation
+        and all.  In this order:
+
+          1. the flush program at the bucket `max_keys` touched rows land
+             in (arena_initial_capacity: the deployment's key count) at
+             each of `depths`, in the uniform form and the general one
+             where the chip tells them apart;
+          2. the set-lane kernels (SetArena.prewarm_lanes);
+          3. the flush program at the smallest bucket, which an interval
+             that brought nothing but the server's own telemetry lands in
+             (the first intervals after boot, a fleet gone quiet).
+
+        Nothing in between: a global receives its fleet's whole key set
+        from its first interval on, a meshed compile is tens of seconds
+        cold, and the pow2 sweep the mesh-less branch makes (11 buckets
+        from 128 to 131,072, two forms each) would still be compiling
+        minutes after the first forward arrived.  A bucket not compiled
+        here compiles in its first flush, under the same guard.  The
+        zeros run beside live flushes: the set lanes they read are
+        pinned like a flush's snapshot, every other buffer is their own.
+        Returns the programs this call compiled; 0 once all are."""
+        d = self.digests
+        donate = not self.is_local
+
+        def launch(u_pad: int, d_pad: int, uniform: bool) -> None:
+            shapes = ((u_pad, d_pad), (u_pad, d_pad), (2, u_pad),
+                      self.sets.lanes_regs.shape,
+                      self.counters.values.shape + (2,),
+                      (d.n_replicas, self._uts_m))
+            with self._compile_lock:
+                if (shapes, uniform, donate) in self._compiled_shapes:
+                    return
+            dv = np.zeros((u_pad, d_pad), d.eval_dtype)
+            with self.lock:
+                lanes = self.sets.snapshot_lanes()
+            try:
+                _in, flat, _regs, _don = self._launch_meshed(
+                    dv, dv.copy(), np.zeros((2, u_pad), d.eval_dtype),
+                    lanes, self.counters.planes_from(
+                        np.zeros(shapes[4][:2])),
+                    self._uts_lanes(None), uniform, self.is_local, {})
+                flat.block_until_ready()
+            finally:
+                self.sets.unpin_lanes(lanes)
+
+        with self._compile_lock:
+            before = set(self._compiled_shapes)
+        buckets = [(max_keys, dpt) for dpt in depths] + [(1, 1)]
+        for i, (rows, depth) in enumerate(buckets):
+            if stop is not None and stop.is_set():
+                break
+            if i == len(buckets) - 1:
+                self.sets.prewarm_lanes()
+            u_pad = d.n_shards * d.dense_block_per_shard(rows)
+            d_pad = d.dense_depth(depth)
+            # the uniform (key-only) network is a program of its own
+            # only where the Pallas kernel takes the per-device shape
+            if serving.pallas_eval_applies(
+                    u_pad // d.n_shards // d.n_replicas, d_pad,
+                    d.eval_dtype):
+                launch(u_pad, d_pad, True)
+            launch(u_pad, d_pad, False)
+        with self._compile_lock:
+            return len(self._compiled_shapes - before)
 
     def _dispatch_flush(self, snap: dict, is_local: bool) -> dict:
         """Build, stage and LAUNCH the per-flush device program on the
@@ -1690,39 +1785,12 @@ class MetricAggregator:
             seg["upload_bytes"] = (seg.get("upload_bytes", 0)
                                    + dv.nbytes + dw.nbytes
                                    + minmax.nbytes)
-            # pre-sharded staging: each device's blocks are placed
-            # directly (no process-wide re-layout on program entry)
+            inputs, flat_dev, set_regs_out, donate = self._launch_meshed(
+                dv, dw, minmax, snap["sets"]["lanes"],
+                snap["counter_planes"](), snap["uts_regs"], g_uniform,
+                is_local, seg)
+            dvd, dwd = inputs.dense_v, inputs.dense_w
             t0 = time.perf_counter()
-            dvd, dwd, mmd = self.digests.put_dense_sharded(dv, dw, minmax)
-            inputs = serving.FlushInputs(
-                dense_v=dvd, dense_w=dwd, minmax=mmd,
-                hll_regs=snap["sets"]["lanes"],
-                counter_planes=snap["counter_planes"](),
-                uts_regs=snap["uts_regs"])
-            seg["layout_s"] = time.perf_counter() - t0
-            from veneur_tpu.parallel.mesh import REPLICA_AXIS, SHARD_AXIS
-            # per-device eval shape decides whether the Pallas network
-            # choice is a distinct program (see pallas_eval_applies):
-            # after the all_to_all repartition each device evaluates
-            # K/(S*R) rows at the full staged depth
-            n_dev_rows = (inputs.dense_v.shape[0]
-                          // self.mesh.shape[SHARD_AXIS]
-                          // self.mesh.shape[REPLICA_AXIS])
-            g_uniform = (g_uniform and serving.pallas_eval_applies(
-                n_dev_rows, inputs.dense_v.shape[1],
-                inputs.dense_v.dtype))
-            # a forwarding tier re-reads the dense matrices for digest
-            # export; only a global tier donates its staged buffers
-            donate = not is_local
-            shapes = tuple(x.shape for x in inputs)
-            t0 = time.perf_counter()
-            with self._CompileGuard(self, (shapes, g_uniform, donate)):
-                # ONE flat f32 buffer + the u8 set registers — the
-                # packed launch shape (serving.pack_outputs): dispatch
-                # cost scales with output-handle count
-                flat_dev, set_regs_out = self.flush_fn(
-                    inputs, self._pct_arr, uniform=g_uniform,
-                    donate=donate)
             set_regs_dev = None
             ps = None
             if (g_ns and is_local
@@ -1731,7 +1799,7 @@ class MetricAggregator:
                 ps = self._padded_rows(srows)
                 set_regs_dev = serving.set_regs_pack(
                     set_regs_out, jnp.asarray(ps))
-            seg["dispatch_s"] = time.perf_counter() - t0
+            seg["dispatch_s"] += time.perf_counter() - t0
             pend.update(
                 flat_dev=flat_dev, set_regs_dev=set_regs_dev, ps=ps,
                 k_rows=inputs.dense_v.shape[0],
@@ -1740,6 +1808,51 @@ class MetricAggregator:
                 crows=crows, srows=srows,
                 dense_dev=None if donate else (dvd, dwd))
             return pend
+
+    def _launch_meshed(self, dv, dw, minmax, lanes, planes, uts_regs,
+                       uniform: bool, is_local: bool, seg: dict):
+        """Place one flush's host-built dense matrices on the mesh and
+        LAUNCH the shard_map'd program on them and the lane state — the
+        one way the meshed program is ever called, by _dispatch_flush on
+        an interval's snapshot and by prewarm on zeros, so the two
+        cannot drift apart in shape, sharding or donation.  Returns
+        (inputs, flat f32 output, merged set registers, donated)."""
+        from veneur_tpu.parallel.mesh import REPLICA_AXIS, SHARD_AXIS
+
+        # pre-sharded staging: each device's blocks are placed
+        # directly (no process-wide re-layout on program entry)
+        t0 = time.perf_counter()
+        dvd, dwd, mmd = self.digests.put_dense_sharded(dv, dw, minmax)
+        inputs = serving.FlushInputs(
+            dense_v=dvd, dense_w=dwd, minmax=mmd, hll_regs=lanes,
+            counter_planes=planes, uts_regs=uts_regs)
+        seg["layout_s"] = time.perf_counter() - t0
+        # per-device eval shape decides whether the Pallas network
+        # choice is a distinct program (see pallas_eval_applies):
+        # after the all_to_all repartition each device evaluates
+        # K/(S*R) rows at the full staged depth
+        n_dev_rows = (dvd.shape[0] // self.mesh.shape[SHARD_AXIS]
+                      // self.mesh.shape[REPLICA_AXIS])
+        uniform = bool(uniform and serving.pallas_eval_applies(
+            n_dev_rows, dvd.shape[1], dvd.dtype))
+        # a forwarding tier re-reads the dense matrices for digest
+        # export; only a global tier donates its staged buffers
+        donate = not is_local
+        shapes = tuple(x.shape for x in inputs)
+        # what ran where, on the flush timeline row: the dense shape
+        # launched (padding included) and what its collectives move
+        seg["device_rows"], seg["device_depth"] = dvd.shape
+        seg["collective_bytes"] = serving.collective_bytes(
+            self.mesh, shapes)
+        t0 = time.perf_counter()
+        with self._CompileGuard(self, (shapes, uniform, donate)):
+            # ONE flat f32 buffer + the u8 set registers — the
+            # packed launch shape (serving.pack_outputs): dispatch
+            # cost scales with output-handle count
+            flat_dev, set_regs_out = self.flush_fn(
+                inputs, self._pct_arr, uniform=uniform, donate=donate)
+        seg["dispatch_s"] = time.perf_counter() - t0
+        return inputs, flat_dev, set_regs_out, donate
 
     def _dispatch_sets(self, spart: dict) -> dict:
         """Upload the snapshot's register copy and LAUNCH the set
@@ -2041,19 +2154,8 @@ class MetricAggregator:
             snap["uts_raw"] = uts
             snap["uts_regs"] = None
         else:
-            # [R, m] register lanes, this process's tally in lane 0; the
-            # program pmaxes over both mesh axes (across processes this is
-            # the DCN union of per-host tallies)
             snap["uts_host"] = None
-            from veneur_tpu.parallel.mesh import REPLICA_AXIS
-            r = self.mesh.shape[REPLICA_AXIS]
-            lanes = np.zeros((r, self._uts_m), np.uint8)
-            if uts is not None:
-                lanes[0] = uts
-            snap["uts_regs"] = serving.put(
-                lanes, jax.sharding.NamedSharding(
-                    self.mesh, jax.sharding.PartitionSpec(
-                        REPLICA_AXIS, None)))
+            snap["uts_regs"] = self._uts_lanes(uts)
 
         for name, ar in (("gauges", g), ("status", st)):
             rows = ar.touched_rows()

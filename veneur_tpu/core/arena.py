@@ -28,6 +28,7 @@ cardinality churn cannot grow the arena unboundedly.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 from dataclasses import dataclass, field
 from typing import Optional
@@ -670,6 +671,10 @@ class SetArena(_ArenaBase):
         # handed to XLA as scratch.
         self._snapshot_inflight = 0
         self._seq = 0
+        # key -> context manager around each lane-kernel launch; the
+        # aggregator installs its _CompileGuard here so that a lane
+        # program's first launch is a compile event like any other
+        self.compile_guard = None
         # staging: raw hashes per batch (vectorized split at sync)
         self._stage_rows: list[int] = []
         self._stage_hashes: list[int] = []
@@ -785,12 +790,8 @@ class SetArena(_ArenaBase):
             pk[:n] = rank
             lane = self._seq % self.n_lanes
             self._seq += 1
-            scatter = (serving.set_lane_scatter
-                       if self._lane_donate_ok()
-                       else serving.set_lane_scatter_copy)
-            self.lanes_regs = scatter(
-                self.lanes_regs, jnp.asarray(pr), jnp.asarray(pi),
-                jnp.asarray(pk), lane)
+            self.lanes_regs = self._lane_scatter(
+                self.lanes_regs, pr, pi, pk, lane, self._lane_donate_ok())
         if self._merge_rows:
             items = sorted(self._merge_rows.items())
             self._merge_rows = {}
@@ -803,11 +804,64 @@ class SetArena(_ArenaBase):
                 mat[i] = regs
             lane = self._seq % self.n_lanes
             self._seq += 1
-            merge = (serving.set_lane_merge_rows
-                     if self._lane_donate_ok()
+            donate = self._lane_donate_ok()
+            merge = (serving.set_lane_merge_rows if donate
                      else serving.set_lane_merge_rows_copy)
-            self.lanes_regs = merge(
-                self.lanes_regs, jnp.asarray(pr), jnp.asarray(mat), lane)
+            with self._guard(("set_lane_merge", self.lanes_regs.shape,
+                              padded, lane, donate)):
+                self.lanes_regs = merge(
+                    self.lanes_regs, jnp.asarray(pr), jnp.asarray(mat),
+                    lane)
+
+    def _guard(self, key):
+        """The owner's compile guard around a lane-kernel launch (the
+        aggregator's _CompileGuard: a first launch of `key` counts as a
+        compile event); nothing for a bare arena."""
+        if self.compile_guard is None:
+            return contextlib.nullcontext()
+        return self.compile_guard(key)
+
+    def _lane_scatter(self, lanes, pr, pi, pk, lane: int, donate: bool):
+        """Launch the scatter-max of padded (row, register, rank)
+        triples into `lane` of `lanes` — sync()'s launch, and the one
+        prewarm_lanes compiles."""
+        scatter = (serving.set_lane_scatter if donate
+                   else serving.set_lane_scatter_copy)
+        with self._guard(("set_lane_scatter", lanes.shape, len(pr), lane,
+                          donate)):
+            return scatter(lanes, jnp.asarray(pr), jnp.asarray(pi),
+                           jnp.asarray(pk), lane)
+
+    def _lane_reset(self, lanes, idx):
+        """Launch the row reset (a fresh buffer, rows `idx` zeroed)."""
+        with self._guard(("set_lane_reset", lanes.shape, len(idx))):
+            return serving.set_reset_rows(lanes, jnp.asarray(idx))
+
+    def prewarm_lanes(self) -> None:
+        """Compile the lane programs whose shapes are known at boot, by
+        running each once on a scratch all-zero lane buffer of the live
+        one's shape and sharding (never the live registers: a donating
+        kernel would have to run under the aggregator lock): the
+        per-flush row reset at the one-row index an untouched interval
+        and a single touched row both use, and the one-sample scatter —
+        the server's own 1 %-sampled `ssf.names_unique` SET — into every
+        lane, in the donating form and the copying one a pinned
+        snapshot forces.  Imported register rows (set_lane_merge_rows)
+        come in the fleet's row counts, which no configuration names:
+        their first launch compiles, counted by the guard."""
+        if self.lanes_regs is None:
+            return
+        lanes = serving.put(
+            np.zeros(self.lanes_regs.shape, np.uint8), self._lane_shd)
+        lanes = self._lane_reset(lanes, self._reset_index(np.zeros(0)))
+        one = np.zeros(1, np.int32)
+        rank = np.zeros(1, np.uint8)
+        forms = (True, False) if serving.lane_donation_ok() else (False,)
+        for lane in range(self.n_lanes):
+            for donate in forms:
+                lanes = self._lane_scatter(lanes, one, one, rank, lane,
+                                           donate)
+        lanes.block_until_ready()
 
     def _lane_donate_ok(self) -> bool:
         """In-place (donating) lane updates are legal only when no
@@ -869,8 +923,8 @@ class SetArena(_ArenaBase):
         # runs even for empty rows while a snapshot is pinned: the
         # kernel swaps in a fresh buffer so the flush snapshot never
         # aliases the live (donatable) one
-        self.lanes_regs = serving.set_reset_rows(
-            self.lanes_regs, jnp.asarray(self._reset_index(rows)))
+        self.lanes_regs = self._lane_reset(self.lanes_regs,
+                                           self._reset_index(rows))
 
     def _checkpoint_arrays(self) -> dict:
         # call after sync(): staging and imported-row unions are folded
@@ -1452,8 +1506,7 @@ class DigestArena(_ArenaBase):
         counts = (np.bincount(rows, minlength=self.capacity)[touched]
                   if len(rows) and nd else np.zeros(nd, np.int64))
         depth = max(int(counts.max()) if len(counts) else 1, 1)
-        d_pad = max(2, self.n_replicas * _pow2(
-            -(-depth // self.n_replicas)))
+        d_pad = self.dense_depth(depth)
         vdt = (self.stage_dtype if (uniform or self.compact_general)
                else self.eval_dtype)
         chunks = list(part["chunks"])
@@ -1540,6 +1593,11 @@ class DigestArena(_ArenaBase):
                 -(-per_shard // self.n_replicas))
         return per_shard
 
+    def dense_depth(self, depth: int) -> int:
+        """Padded depth of the dense build for a deepest row of `depth`
+        staged points: a power of two to each replica's slice."""
+        return max(2, self.n_replicas * _pow2(-(-depth // self.n_replicas)))
+
     def build_dense(self, staged, touched: np.ndarray,
                     d_min_t: np.ndarray, d_max_t: np.ndarray,
                     u_floor: int = 0, d_floor: int = 0,
@@ -1600,8 +1658,7 @@ class DigestArena(_ArenaBase):
         if native_fill is not None:
             counts = np.bincount(rid, minlength=nd)
             depth = max(int(counts.max()) if len(rows) else 1, d_floor, 1)
-            d_pad = max(2, self.n_replicas * _pow2(
-                -(-depth // self.n_replicas)))
+            d_pad = self.dense_depth(depth)
             rows64 = np.ascontiguousarray(rows, np.int64)
             vals64 = np.ascontiguousarray(vals, np.float64)
             dv = np.zeros((u_pad, d_pad), np.float32)
@@ -1632,8 +1689,7 @@ class DigestArena(_ArenaBase):
         first = np.searchsorted(r, np.arange(nd))
         pos = np.arange(len(r)) - first[r]
         depth = max(int(pos.max()) + 1 if len(r) else 1, d_floor)
-        d_pad = max(2, self.n_replicas * _pow2(
-            -(-depth // self.n_replicas)))
+        d_pad = self.dense_depth(depth)
         if uniform:
             # bf16 staging narrows the VALUE matrix only; weights (0/1,
             # implicit here) and exported centroid weights stay exact

@@ -367,6 +367,9 @@ class Server:
         # profiling subsystem: per-flush structured records, served at
         # /debug/flush_timeline (veneur_tpu/profiling/timeline.py)
         self.flush_timeline = FlushTimeline(cfg.profiling_timeline_capacity)
+        # what the boot-time prewarm (_prewarm) compiled and how long it
+        # took: /debug/vars -> prewarm_programs, prewarm_seconds
+        self.prewarm_stats = {"programs": 0, "seconds": 0.0}
         # tags_exclude rules: "key" (every sink) or "key|sink1|sink2"
         # (those sinks only) — setSinkExcludedTags, server.go:660,1456-1463
         self._tags_exclude_global: set[str] = set()
@@ -528,6 +531,17 @@ class Server:
         # rebuild (the arenas must be fresh for restore_state)
         if self.config.checkpoint_dir:
             self._maybe_restore_checkpoint()
+        if self.config.prewarm_flush_shapes and self.mesh is not None:
+            # a meshed global compiles its programs BEFORE it opens a
+            # listener or ticks.  One meshed compile is ~20 s cold; a
+            # global that took forwards meanwhile flushed late, the late
+            # flush held two intervals (twice the depth: a new bucket,
+            # another 20 s), ticks cut 14 s imports in half (partial key
+            # sets: more buckets), and it took 19 intervals to come
+            # back (PERF.md section 6, PR 28: 15 compiles, 282 s).  A
+            # sender that cannot connect yet retries or spools; ~45 s
+            # from a cold compile cache, ~1.5 s from a warm one.
+            self._prewarm()
         has_udp_statsd = any(
             parse_listen_addr(a)[0] == "udp"
             for a in self.config.statsd_listen_addresses)
@@ -644,18 +658,9 @@ class Server:
                                  daemon=True, name="checkpoint-loop")
             t.start()
             self._threads.append(t)
-        if self.config.prewarm_flush_shapes:
-            # boot-time background compile of the configured flush
-            # buckets (compile-churn hardening; persists via the
-            # compilation cache, so later boots replay from disk)
-            cap = self.config.arena_initial_capacity or 8192
-            # prewarm rounds up to the arena's pow2 capacity internally,
-            # so the top bucket a ramp can reach is always covered
-            t = threading.Thread(
-                target=lambda: self.aggregator.prewarm(
-                    list(self.config.prewarm_depths), cap,
-                    stop=self._shutdown),
-                daemon=True, name="flush-prewarm")
+        if self.config.prewarm_flush_shapes and self.mesh is None:
+            t = threading.Thread(target=self._prewarm, daemon=True,
+                                 name="flush-prewarm")
             t.start()
             self._threads.append(t)
         # self-metrics statsd client + runtime diagnostics loop
@@ -1540,6 +1545,38 @@ class Server:
                 child.finish()
             self.flight_recorder.record_span(child)
 
+    def _prewarm(self) -> None:
+        """Boot-time compile of the configured flush buckets
+        (compile-churn hardening; persists via the compilation cache,
+        so later boots replay from disk): mesh-less, in a background
+        thread, every pow2 key bucket up to the arena pre-size at
+        `prewarm_depths`; on a mesh, before start() opens a listener,
+        the pre-size's own bucket first, then the set-lane kernels and
+        the bucket of the server's own telemetry
+        (MetricAggregator.prewarm).  What it did is a `server.prewarm`
+        span in the flight recorder and `prewarm_programs` /
+        `prewarm_seconds` in /debug/vars."""
+        from veneur_tpu import trace as trace_mod
+        cap = self.config.arena_initial_capacity or 8192
+        span = trace_mod.Span("server.prewarm",
+                              service=self.config.hostname)
+        t0 = time.perf_counter()
+        try:
+            # prewarm rounds up to the arena's pow2 capacity internally,
+            # so the top bucket a ramp can reach is always covered
+            self.prewarm_stats["programs"] += self.aggregator.prewarm(
+                list(self.config.prewarm_depths), cap,
+                stop=self._shutdown)
+        except Exception:
+            span.error = True
+            logger.exception("flush prewarm failed; first flushes of "
+                             "each shape will compile in place")
+        finally:
+            self.prewarm_stats["seconds"] += time.perf_counter() - t0
+            span.tags = {"programs": str(self.prewarm_stats["programs"])}
+            span.finish()
+            self.flight_recorder.record_span(span)
+
     def _flush_locked(self) -> None:
         from veneur_tpu import failpoints
         from veneur_tpu import scopedstatsd
@@ -1730,6 +1767,9 @@ class Server:
             total_s=time.perf_counter() - flush_start,
             segments=self.aggregator.last_flush_segments,
             devices=serving_mod.mesh_device_count(self.mesh),
+            # "2x2" = shard x replica; left out mesh-less
+            mesh_shape=(None if self.mesh is None else "x".join(
+                str(n) for n in self.mesh.devices.shape)),
             processed=res.processed, imported=res.imported,
             metrics_emitted=len(res.metrics),
             forward_metrics=len(res.forward),

@@ -202,6 +202,12 @@ def debug_vars(server) -> dict:
         # timeline's rows, per interval)
         stats["ingest_overflow"] = dict(
             getattr(server, "ingest_overflow", None) or {})
+    prewarm = getattr(server, "prewarm_stats", None)
+    if prewarm is not None and server.config.prewarm_flush_shapes:
+        # the boot-time compile of the configured flush shapes: programs
+        # compiled so far and their wall seconds
+        stats["prewarm_programs"] = prewarm["programs"]
+        stats["prewarm_seconds"] = round(prewarm["seconds"], 3)
     timeline = getattr(server, "flush_timeline", None)
     if timeline is not None:
         stats["flush_timeline_recorded"] = \

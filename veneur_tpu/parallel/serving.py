@@ -310,9 +310,12 @@ def flush_body(inputs: FlushInputs, percentiles: jax.Array,
         # every collective is a cross-device rendezvous, and the flush's
         # wall-clock overhead scales with rendezvous count, not bytes —
         # the stack copy is plain HBM traffic the combiner pays anyway.
-        both = jax.lax.all_to_all(jnp.stack([dv, dw]), axis,
-                                  split_axis=1, concat_axis=2,
-                                  tiled=True)
+        # (the scopes name the three collectives in the device trace:
+        # flush.a2a, flush.psum, flush.pmax)
+        with jax.named_scope("flush.a2a"):
+            both = jax.lax.all_to_all(jnp.stack([dv, dw]), axis,
+                                      split_axis=1, concat_axis=2,
+                                      tiled=True)
         dv, dw = both[0], both[1]
         # this replica's key sub-block of the (replica-replicated) minmax
         j = jax.lax.axis_index(axis)
@@ -328,19 +331,48 @@ def flush_body(inputs: FlushInputs, percentiles: jax.Array,
     if axis is not None:
         # one psum for both counter planes, one u8 pmax for both
         # register families (same rendezvous-count argument as above)
-        planes = jax.lax.psum(planes, axis)
+        with jax.named_scope("flush.psum"):
+            planes = jax.lax.psum(planes, axis)
         n_set = set_regs.size
-        regs = jax.lax.pmax(
-            jnp.concatenate([set_regs.ravel(), uts]), axis)
+        with jax.named_scope("flush.pmax"):
+            regs = jax.lax.pmax(
+                jnp.concatenate([set_regs.ravel(), uts]), axis)
         set_regs = regs[:n_set].reshape(set_regs.shape)
         uts = regs[n_set:]
     chi, clo = planes[..., 0], planes[..., 1]
     if shard_axis is not None:
-        uts = jax.lax.pmax(uts, shard_axis)
+        with jax.named_scope("flush.pmax"):
+            uts = jax.lax.pmax(uts, shard_axis)
     return FlushOutputs(
         digest_eval=ev, counter_hi=chi, counter_lo=clo,
         set_regs=set_regs, set_estimates=hll_mod.estimate(set_regs),
         unique_ts=hll_mod.estimate(uts[None, :])[0])
+
+
+def collective_bytes(mesh: Mesh, shapes) -> int:
+    """Bytes ONE device sends over the mesh per flush, reckoned from the
+    global shapes of the FlushInputs dispatched (`shapes`: one shape per
+    field, in field order) — arithmetic, not a measurement; the flush
+    timeline row carries it as `collective_bytes`.  flush_body's
+    collectives, S = shards, R = replicas:
+
+      all_to_all over 'replica': the stacked [2, K/S, D/R] f32 block,
+        of which a device keeps 1/R and sends (R-1)/R;
+      psum over 'replica': the [K2/S, 2] f32 counter planes, as a ring
+        all-reduce sends them, 2(R-1)/R times;
+      pmax over 'replica': the [S_rows/S, m] u8 set registers and the
+        [m_u] unique-timeseries registers in one buffer, 2(R-1)/R times;
+      pmax over 'shard': the [m_u] u8 registers again, 2(S-1)/S times
+        (the one collective left when R == 1).
+    """
+    dense, _dw, _mm, hll, planes, uts = shapes
+    S = int(mesh.shape[SHARD_AXIS])
+    R = int(mesh.shape[REPLICA_AXIS])
+    a2a = 2 * (dense[0] // S) * (dense[1] // R) * 4 * (R - 1) // R
+    psum = (planes[1] // S) * planes[2] * 4 * 2 * (R - 1) // R
+    pmax = ((hll[1] // S) * hll[2] + uts[1]) * 2 * (R - 1) // R
+    pmax_shard = uts[1] * 2 * (S - 1) // S
+    return a2a + psum + pmax + pmax_shard
 
 
 def pack_outputs(out: FlushOutputs) -> jax.Array:

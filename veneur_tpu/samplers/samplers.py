@@ -11,8 +11,11 @@ types both sides exchange.
 from __future__ import annotations
 
 import enum
+import gc
 from dataclasses import dataclass
 from typing import Optional
+
+import numpy as np
 
 # Metric type constants (samplers/samplers.go:50-60).
 COUNTER = "counter"
@@ -173,18 +176,28 @@ class MetricSegment:
             tags=self.tags[r], type=self.type,
             sinks=self.sinks[i] if self.sinks is not None else None)
 
+    def materialize(self) -> list[InterMetric]:
+        """Every record of the segment at once: the value column leaves
+        numpy in one `tolist`, names and (for a sparse column) rows are
+        taken column-wise, and the records are built positionally — a
+        third of the per-record cost of taking them one `metric(i)` at a
+        time."""
+        vals = np.asarray(self.values, np.float64).tolist()
+        bases, tags, suffix = self.bases, self.tags, self.suffix
+        if self.sel is not None:
+            rows = np.asarray(self.sel).tolist()
+            bases = [bases[r] for r in rows]
+            tags = [tags[r] for r in rows]
+        names = [b + suffix for b in bases] if suffix else bases
+        ts, typ = self.timestamp, self.type
+        if self.sinks is None:
+            return [InterMetric(n, ts, v, t, typ)
+                    for n, v, t in zip(names, vals, tags)]
+        return [InterMetric(n, ts, v, t, typ, "", "", s)
+                for n, v, t, s in zip(names, vals, tags, self.sinks)]
+
     def __iter__(self):
-        bases, tags, suffix, values = (self.bases, self.tags, self.suffix,
-                                       self.values)
-        ts, typ, sinks = self.timestamp, self.type, self.sinks
-        rows = (range(len(values)) if self.sel is None
-                else map(int, self.sel))
-        for i, r in enumerate(rows):
-            base = bases[r]
-            yield InterMetric(
-                name=base + suffix if suffix else base, timestamp=ts,
-                value=float(values[i]), tags=tags[r], type=typ,
-                sinks=sinks[i] if sinks is not None else None)
+        return iter(self.materialize())
 
 
 class MetricBatch:
@@ -253,7 +266,24 @@ class MetricBatch:
         return NotImplemented
 
     def materialize(self) -> list[InterMetric]:
-        return list(self)
+        """Every record at once, for a consumer that keeps them all.  The
+        cyclic collector is paused meanwhile: a batch is hundreds of
+        thousands of records that are all alive when the list is done,
+        so the collections their allocation sets off (one young pass per
+        700 records, and one or two passes over the whole server heap per
+        batch) can free nothing, and the full passes land in one flush
+        and not in the next."""
+        paused = gc.isenabled()
+        gc.disable()
+        try:
+            out: list[InterMetric] = []
+            for seg in self.segments:
+                out.extend(seg.materialize())
+            out.extend(self.loose)
+        finally:
+            if paused:
+                gc.enable()
+        return out
 
     def apply_routing(self, rules, match_fn) -> None:
         """Compute per-metric sink allowlists (flusher.go:97-113) across

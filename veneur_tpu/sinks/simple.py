@@ -17,7 +17,7 @@ import queue
 from typing import Optional
 
 from veneur_tpu import sinks as sink_mod
-from veneur_tpu.samplers.samplers import InterMetric
+from veneur_tpu.samplers.samplers import InterMetric, MetricBatch
 
 logger = logging.getLogger("veneur_tpu.sinks")
 
@@ -109,7 +109,11 @@ class ChannelMetricSink(sink_mod.BaseMetricSink):
         self.other_samples: list = []
 
     def flush(self, metrics):
-        self.queue.put(list(metrics))
+        # a columnar batch builds its records in bulk (collector
+        # paused); a list is copied
+        self.queue.put(metrics.materialize()
+                       if isinstance(metrics, MetricBatch)
+                       else list(metrics))
         return sink_mod.MetricFlushResult(flushed=len(metrics))
 
     def flush_other_samples(self, samples):
