@@ -2319,9 +2319,14 @@ void vn_route_free(void* handle) { delete (RouteResult*)handle; }
 // vectorized merge per family — the per-metric python attribute reads
 // (tuple(pb.tags) alone is ~2 us) were the fleet-rate inbound ceiling.
 // Identity = metro64 of (name \0 type \x1F tag \x1E tag ...) under two
-// seeds (128 bits: collisions are ~1e-20 at 1M identities); set and
-// histogram records are handed back as byte ranges for the python slow
-// path (they carry sketches that python merges anyway).
+// seeds (128 bits: collisions are ~1e-20 at 1M identities); every
+// record's byte range is handed back too (sets, the sketch-family
+// markers and a key's first sighting still parse in python).  A
+// histogram record's t-digest is decoded here as well: its centroids'
+// (mean, weight) doubles land in two flat columns in wire order, with a
+// per-record range and the digest's scalars, so the importer stages a
+// whole payload's centroids as arrays instead of walking them one
+// protobuf object at a time under the aggregator lock.
 // ---------------------------------------------------------------------------
 
 namespace {
@@ -2330,11 +2335,123 @@ struct ImportScan {
   std::vector<uint64_t> h_lo, h_hi;
   std::vector<uint8_t> which;   // 0 none/unknown, 1 counter, 2 gauge,
                                 // 3 set, 4 histogram
-  std::vector<uint8_t> mtype;   // metricpb Type enum
-  std::vector<uint8_t> scope;   // metricpb Scope enum
+  std::vector<uint8_t> mtype;   // metricpb Type enum (> 255 reads 255)
+  std::vector<uint8_t> scope;   // metricpb Scope enum (> 255 reads 255)
   std::vector<double> value;    // counter/gauge payload
   std::vector<long long> rec_off, rec_len;  // Metric submessage range
+  // HistogramValue.t_digest (tdigest.MergingDigestData), per record;
+  // all zero for the other kinds
+  std::vector<double> cent_mean, cent_weight;  // flat, wire order
+  std::vector<long long> cent_off, cent_n;     // the record's range
+  std::vector<double> dmin, dmax, drsum, compression;
 };
+
+// Skip one field's payload by wire type.  False = truncated, a group
+// (wire types 3/4) or an undefined wire type: the scan then fails and
+// the payload takes the protobuf path.
+inline bool skip_wire(const uint8_t*& p, const uint8_t* end, int wt) {
+  uint64_t tmp;
+  switch (wt) {
+    case 0: return read_varint(p, end, tmp);
+    case 1: if (end - p < 8) return false; p += 8; return true;
+    case 2: if (!read_varint(p, end, tmp) ||
+                (uint64_t)(end - p) < tmp) return false;
+            p += tmp; return true;
+    case 5: if (end - p < 4) return false; p += 4; return true;
+    default: return false;
+  }
+}
+
+// Open a length-delimited field at p: [sub, sub_end) is its payload
+// and p moves past it.
+inline bool open_sub(const uint8_t*& p, const uint8_t* end,
+                     const uint8_t*& sub, const uint8_t*& sub_end) {
+  uint64_t sl;
+  if (!read_varint(p, end, sl) || (uint64_t)(end - p) < sl) return false;
+  sub = p;
+  sub_end = p + sl;
+  p = sub_end;
+  return true;
+}
+
+inline bool read_double(const uint8_t*& p, const uint8_t* end,
+                        double& out) {
+  if (end - p < 8) return false;
+  memcpy(&out, p, 8);
+  p += 8;
+  return true;
+}
+
+// One record's digest as the wire scan accumulates it.  Protobuf's
+// parse semantics are kept: a scalar that appears twice keeps its
+// last value, a t_digest (or HistogramValue) that appears twice is
+// parsed onto the same message, so centroids append.
+struct DigestAcc {
+  double dmin = 0, dmax = 0, drsum = 0, compression = 0;
+};
+
+// tdigest.Centroid {mean = 1, weight = 2, samples = 3 (skipped)}
+inline bool scan_centroid(const uint8_t* s, const uint8_t* end,
+                          double& mean, double& weight) {
+  while (s < end) {
+    uint64_t t;
+    if (!read_varint(s, end, t) || (t >> 3) == 0) return false;
+    int f = (int)(t >> 3), wt = (int)(t & 7);
+    if (f == 1 && wt == 1) {
+      if (!read_double(s, end, mean)) return false;
+    } else if (f == 2 && wt == 1) {
+      if (!read_double(s, end, weight)) return false;
+    } else if (!skip_wire(s, end, wt)) {
+      return false;
+    }
+  }
+  return true;
+}
+
+// tdigest.MergingDigestData {main_centroids = 1, compression = 2,
+// min = 3, max = 4, reciprocalSum = 5}
+inline bool scan_digest(const uint8_t* s, const uint8_t* end,
+                        ImportScan* res, DigestAcc& acc) {
+  while (s < end) {
+    uint64_t t;
+    if (!read_varint(s, end, t) || (t >> 3) == 0) return false;
+    int f = (int)(t >> 3), wt = (int)(t & 7);
+    if (f == 1 && wt == 2) {
+      const uint8_t *c, *cend;
+      double mean = 0, weight = 0;
+      if (!open_sub(s, end, c, cend) ||
+          !scan_centroid(c, cend, mean, weight)) return false;
+      res->cent_mean.push_back(mean);
+      res->cent_weight.push_back(weight);
+    } else if (f >= 2 && f <= 5 && wt == 1) {
+      double v;
+      if (!read_double(s, end, v)) return false;
+      (f == 2 ? acc.compression : f == 3 ? acc.dmin
+       : f == 4 ? acc.dmax : acc.drsum) = v;
+    } else if (!skip_wire(s, end, wt)) {
+      return false;
+    }
+  }
+  return true;
+}
+
+// metricpb.HistogramValue {t_digest = 1}
+inline bool scan_histogram(const uint8_t* s, const uint8_t* end,
+                           ImportScan* res, DigestAcc& acc) {
+  while (s < end) {
+    uint64_t t;
+    if (!read_varint(s, end, t) || (t >> 3) == 0) return false;
+    int wt = (int)(t & 7);
+    if ((t >> 3) == 1 && wt == 2) {
+      const uint8_t *d, *dend;
+      if (!open_sub(s, end, d, dend) ||
+          !scan_digest(d, dend, res, acc)) return false;
+    } else if (!skip_wire(s, end, wt)) {
+      return false;
+    }
+  }
+  return true;
+}
 
 }  // namespace
 
@@ -2351,31 +2468,24 @@ void* vn_import_scan(const uint8_t* data, long long len) {
     if (!read_varint(p, end, tag)) { delete res; return nullptr; }
     int field = (int)(tag >> 3), wt = (int)(tag & 7);
     if (field != 1 || wt != 2) {
-      uint64_t tmp;
-      switch (wt) {
-        case 0: if (!read_varint(p, end, tmp)) { delete res; return nullptr; } break;
-        case 1: if (end - p < 8) { delete res; return nullptr; } p += 8; break;
-        case 2: if (!read_varint(p, end, tmp) ||
-                    (uint64_t)(end - p) < tmp) { delete res; return nullptr; }
-                p += tmp; break;
-        case 5: if (end - p < 4) { delete res; return nullptr; } p += 4; break;
-        default: delete res; return nullptr;
-      }
+      if (!skip_wire(p, end, wt)) { delete res; return nullptr; }
       continue;
     }
-    uint64_t mlen;
-    if (!read_varint(p, end, mlen) || (uint64_t)(end - p) < mlen) {
-      delete res; return nullptr;
-    }
-    const uint8_t* m = p;
-    const uint8_t* mend = p + mlen;
-    p = mend;
+    const uint8_t *m, *mend;
+    if (!open_sub(p, end, m, mend)) { delete res; return nullptr; }
 
     const uint8_t* name = nullptr;
     uint64_t name_len = 0;
     uint64_t type_val = 0, scope_val = 0;
     uint8_t which = 0;
     double value = 0.0;
+    DigestAcc dig;
+    const size_t cent_start = res->cent_mean.size();
+    auto drop_digest = [&] {
+      res->cent_mean.resize(cent_start);
+      res->cent_weight.resize(cent_start);
+      dig = DigestAcc();
+    };
     std::vector<std::pair<const uint8_t*, uint64_t>> tags;
     const uint8_t* q = m;
     bool ok = true;
@@ -2383,26 +2493,19 @@ void* vn_import_scan(const uint8_t* data, long long len) {
       uint64_t mtag;
       if (!read_varint(q, mend, mtag)) { ok = false; break; }
       int mf = (int)(mtag >> 3), mwt = (int)(mtag & 7);
+      const uint8_t *s, *send_;
       if (mf == 1 && mwt == 2) {
-        if (!read_varint(q, mend, name_len) ||
-            (uint64_t)(mend - q) < name_len) { ok = false; break; }
-        name = q; q += name_len;
+        if (!open_sub(q, mend, s, send_)) { ok = false; break; }
+        name = s; name_len = (uint64_t)(send_ - s);
       } else if (mf == 2 && mwt == 2) {
-        uint64_t tl;
-        if (!read_varint(q, mend, tl) ||
-            (uint64_t)(mend - q) < tl) { ok = false; break; }
-        tags.emplace_back(q, tl); q += tl;
+        if (!open_sub(q, mend, s, send_)) { ok = false; break; }
+        tags.emplace_back(s, (uint64_t)(send_ - s));
       } else if (mf == 3 && mwt == 0) {
         if (!read_varint(q, mend, type_val)) { ok = false; break; }
       } else if (mf == 9 && mwt == 0) {
         if (!read_varint(q, mend, scope_val)) { ok = false; break; }
       } else if (mf == 5 && mwt == 2) {          // CounterValue
-        uint64_t sl;
-        if (!read_varint(q, mend, sl) ||
-            (uint64_t)(mend - q) < sl) { ok = false; break; }
-        const uint8_t* s = q;
-        const uint8_t* send_ = q + sl;
-        q = send_;
+        if (!open_sub(q, mend, s, send_)) { ok = false; break; }
         which = 1;
         while (s < send_) {
           uint64_t st;
@@ -2414,50 +2517,37 @@ void* vn_import_scan(const uint8_t* data, long long len) {
           } else { ok = false; break; }
         }
       } else if (mf == 6 && mwt == 2) {          // GaugeValue
-        uint64_t sl;
-        if (!read_varint(q, mend, sl) ||
-            (uint64_t)(mend - q) < sl) { ok = false; break; }
-        const uint8_t* s = q;
-        const uint8_t* send_ = q + sl;
-        q = send_;
+        if (!open_sub(q, mend, s, send_)) { ok = false; break; }
         which = 2;
         while (s < send_) {
           uint64_t st;
           if (!read_varint(s, send_, st)) { ok = false; break; }
           if ((st >> 3) == 1 && (st & 7) == 1) {  // double value
-            if (send_ - s < 8) { ok = false; break; }
-            memcpy(&value, s, 8); s += 8;
+            if (!read_double(s, send_, value)) { ok = false; break; }
           } else { ok = false; break; }
         }
       } else if (mf == 7 && mwt == 2) {          // HistogramValue
-        uint64_t sl;
-        if (!read_varint(q, mend, sl) ||
-            (uint64_t)(mend - q) < sl) { ok = false; break; }
-        q += sl; which = 4;
+        if (!open_sub(q, mend, s, send_)) { ok = false; break; }
+        // the oneof switches to histogram: a fresh message (one that
+        // is histogram already is parsed onto, as protobuf merges)
+        if (which != 4) drop_digest();
+        which = 4;
+        ok = scan_histogram(s, send_, res, dig);
       } else if (mf == 8 && mwt == 2) {          // SetValue
-        uint64_t sl;
-        if (!read_varint(q, mend, sl) ||
-            (uint64_t)(mend - q) < sl) { ok = false; break; }
-        q += sl; which = 3;
+        if (!open_sub(q, mend, s, send_)) { ok = false; break; }
+        which = 3;
       } else {
-        uint64_t tmp;
-        switch (mwt) {
-          case 0: if (!read_varint(q, mend, tmp)) ok = false; break;
-          case 1: if (mend - q < 8) { ok = false; } else q += 8; break;
-          case 2:
-            if (!read_varint(q, mend, tmp) ||
-                (uint64_t)(mend - q) < tmp) {
-              ok = false;
-            } else {
-              q += tmp;
-            }
-            break;
-          case 5: if (mend - q < 4) { ok = false; } else q += 4; break;
-          default: ok = false;
-        }
+        ok = skip_wire(q, mend, mwt);
       }
     }
     if (!ok) { delete res; return nullptr; }
+    // protobuf keeps an enum's low 32 bits; a value past one byte
+    // must not alias a legal one in the columns python checks type
+    // and scope from
+    type_val = (uint32_t)type_val > 255 ? 255 : (uint32_t)type_val;
+    scope_val = (uint32_t)scope_val > 255 ? 255 : (uint32_t)scope_val;
+    // the oneof ended on another member: the digest is not there
+    if (which != 4) drop_digest();
     key.clear();
     if (name) key.insert(key.end(), name, name + name_len);
     key.push_back(0);
@@ -2473,7 +2563,13 @@ void* vn_import_scan(const uint8_t* data, long long len) {
     res->scope.push_back((uint8_t)scope_val);
     res->value.push_back(value);
     res->rec_off.push_back((long long)(m - data));
-    res->rec_len.push_back((long long)mlen);
+    res->rec_len.push_back((long long)(mend - m));
+    res->cent_off.push_back((long long)cent_start);
+    res->cent_n.push_back((long long)(res->cent_mean.size() - cent_start));
+    res->dmin.push_back(dig.dmin);
+    res->dmax.push_back(dig.dmax);
+    res->drsum.push_back(dig.drsum);
+    res->compression.push_back(dig.compression);
   }
   return res;
 }
@@ -2493,6 +2589,26 @@ void vn_import_scan_arrays(void* handle, const uint64_t** h_lo,
   *which = r->which.data(); *mtype = r->mtype.data();
   *scope = r->scope.data(); *value = r->value.data();
   *rec_off = r->rec_off.data(); *rec_len = r->rec_len.data();
+}
+
+// The decoded digests: two flat centroid columns of *n_cent doubles,
+// and per record (vn_import_scan_n of them) the record's range in
+// those columns and its digest's scalars.
+void vn_import_scan_digests(void* handle, long long* n_cent,
+                            const double** cent_mean,
+                            const double** cent_weight,
+                            const long long** cent_off,
+                            const long long** cent_n,
+                            const double** dmin, const double** dmax,
+                            const double** drsum,
+                            const double** compression) {
+  auto* r = (ImportScan*)handle;
+  *n_cent = (long long)r->cent_mean.size();
+  *cent_mean = r->cent_mean.data();
+  *cent_weight = r->cent_weight.data();
+  *cent_off = r->cent_off.data(); *cent_n = r->cent_n.data();
+  *dmin = r->dmin.data(); *dmax = r->dmax.data();
+  *drsum = r->drsum.data(); *compression = r->compression.data();
 }
 
 void vn_import_scan_free(void* handle) { delete (ImportScan*)handle; }

@@ -19,7 +19,11 @@
 // Phase 2 (wire fuzz) hand-encodes a forwardrpc.MetricList, routes
 // and import-scans it intact, truncated at every stride, bit-flipped,
 // and with degenerate ring/chunk arguments — corrupt wire bytes must
-// yield a null fallback, never an out-of-bounds read.  Phase 3 feeds
+// yield a null fallback, never an out-of-bounds read; then a list of
+// forwarded t-digests (centroids with samples and unknown fields)
+// through vn_import_scan's digest descent: decoded values checked
+// intact, then truncated at every cut and mutated at every byte, each
+// surviving scan's columns read back whole.  Phase 3 feeds
 // vn_fill_dense adversarial COO rows (negative ids, ids past the
 // arena capacity, per-row overflow past the dense depth) and checks
 // the drop accounting and depth clamps hold.  Phase 4 (SPSC stress)
@@ -68,6 +72,14 @@ void vn_route_dest(void* handle, int d, const uint8_t** ptr,
 void vn_route_free(void* handle);
 void* vn_import_scan(const uint8_t* data, long long len);
 long long vn_import_scan_n(void* handle);
+void vn_import_scan_digests(void* handle, long long* n_cent,
+                            const double** cent_mean,
+                            const double** cent_weight,
+                            const long long** cent_off,
+                            const long long** cent_n,
+                            const double** dmin, const double** dmax,
+                            const double** drsum,
+                            const double** compression);
 void vn_import_scan_free(void* handle);
 long long vn_fill_dense(const long long* rows, const double* vals,
                         const double* wts, long long n,
@@ -118,6 +130,151 @@ std::vector<uint8_t> make_metric_list(int n) {
     ml.insert(ml.end(), m.begin(), m.end());
   }
   return ml;
+}
+
+void put_double(std::vector<uint8_t>& v, uint8_t tag, double x) {
+  v.push_back(tag);
+  uint8_t b[8];
+  memcpy(b, &x, 8);
+  v.insert(v.end(), b, b + 8);
+}
+
+void put_sub(std::vector<uint8_t>& v, uint8_t tag,
+             const std::vector<uint8_t>& body) {
+  v.push_back(tag);
+  put_varint(v, body.size());
+  v.insert(v.end(), body.begin(), body.end());
+}
+
+// Hand-encoded histogram records: name (1), tag (2), type (3),
+// HistogramValue (7) { t_digest (1) { kCents x main_centroids (1)
+// { mean (1), weight (2), packed samples (3), an unknown varint (15) },
+// compression (2), min (3), max (4), reciprocalSum (5), an unknown
+// length-delimited field (9) } }, scope (9) — every level of the
+// descent vn_import_scan makes into a forwarded t-digest.
+const int kDigests = 16, kCents = 6;
+
+std::vector<uint8_t> make_digest_list() {
+  std::vector<uint8_t> ml;
+  char buf[48];
+  for (int i = 0; i < kDigests; i++) {
+    std::vector<uint8_t> td;
+    for (int c = 0; c < kCents; c++) {
+      std::vector<uint8_t> cent;
+      put_double(cent, 0x09, 1.0 + i + 0.25 * c);
+      put_double(cent, 0x11, 1.0 + (c % 3));
+      std::vector<uint8_t> samples(16, 0);
+      put_sub(cent, 0x1A, samples);
+      cent.push_back(0x78);
+      put_varint(cent, 300);
+      put_sub(td, 0x0A, cent);
+    }
+    put_double(td, 0x11, 100.0);
+    put_double(td, 0x19, 1.0 + i);
+    put_double(td, 0x21, 3.0 + i);
+    put_double(td, 0x29, 0.5);
+    put_sub(td, 0x4A, std::vector<uint8_t>{1, 2, 3});
+    std::vector<uint8_t> hv;
+    put_sub(hv, 0x0A, td);
+    std::vector<uint8_t> m;
+    int nl = snprintf(buf, sizeof buf, "svc.wire.digest.%d", i % 5);
+    m.push_back(0x0A);
+    put_varint(m, (uint64_t)nl);
+    m.insert(m.end(), buf, buf + nl);
+    int tl = snprintf(buf, sizeof buf, "shard:%d", i % 3);
+    m.push_back(0x12);
+    put_varint(m, (uint64_t)tl);
+    m.insert(m.end(), buf, buf + tl);
+    m.push_back(0x18);
+    put_varint(m, (uint64_t)(i % 2 ? 4 : 2));   // Timer / Histogram
+    put_sub(m, 0x3A, hv);
+    m.push_back(0x48);
+    put_varint(m, (uint64_t)(i % 3));           // scope
+    put_sub(ml, 0x0A, m);
+  }
+  return ml;
+}
+
+// The digest descent of vn_import_scan: the intact list decodes to the
+// values encoded above, and every truncation and every single-byte
+// mutation parses or falls back without reading out of bounds.
+int digest_wire_fuzz() {
+  std::vector<uint8_t> ml = make_digest_list();
+  void* s = vn_import_scan(ml.data(), (long long)ml.size());
+  if (s == nullptr || vn_import_scan_n(s) != kDigests) {
+    fprintf(stderr, "digest fuzz: intact list failed to scan\n");
+    if (s) vn_import_scan_free(s);
+    return 1;
+  }
+  long long n_cent = 0;
+  const double *mean, *weight, *dmin, *dmax, *drsum, *comp;
+  const long long *off, *cnt;
+  vn_import_scan_digests(s, &n_cent, &mean, &weight, &off, &cnt, &dmin,
+                         &dmax, &drsum, &comp);
+  int rc = 0;
+  if (n_cent != (long long)kDigests * kCents) rc = 1;
+  for (int i = 0; i < kDigests && rc == 0; i++) {
+    if (off[i] != (long long)i * kCents || cnt[i] != kCents ||
+        comp[i] != 100.0 || dmin[i] != 1.0 + i || dmax[i] != 3.0 + i ||
+        drsum[i] != 0.5)
+      rc = 1;
+    for (int c = 0; c < kCents && rc == 0; c++)
+      if (mean[off[i] + c] != 1.0 + i + 0.25 * c ||
+          weight[off[i] + c] != 1.0 + (c % 3))
+        rc = 1;
+  }
+  vn_import_scan_free(s);
+  if (rc) {
+    fprintf(stderr, "digest fuzz: intact list decoded wrongly\n");
+    return 1;
+  }
+  // a scan that survives is read back whole, so a range the scan got
+  // wrong is an out-of-bounds read here and not only in python
+  auto read_back = [](void* h) {
+    long long nc = 0;
+    const double *a, *b, *c, *d, *e, *f;
+    const long long *o, *k;
+    vn_import_scan_digests(h, &nc, &a, &b, &o, &k, &c, &d, &e, &f);
+    long long n = vn_import_scan_n(h);
+    double sum = 0;
+    for (long long i = 0; i < n; i++) {
+      if (o[i] < 0 || k[i] < 0 || o[i] + k[i] > nc) return false;
+      for (long long j = o[i]; j < o[i] + k[i]; j++) sum += a[j] + b[j];
+      sum += c[i] + d[i] + e[i] + f[i];
+    }
+    volatile double sink = sum;   // the reads must happen
+    (void)sink;
+    return true;
+  };
+  for (size_t cut = 0; cut <= ml.size(); cut++) {
+    void* ss = vn_import_scan(ml.data(), (long long)cut);
+    if (ss) {
+      bool fine = read_back(ss);
+      vn_import_scan_free(ss);
+      if (!fine) {
+        fprintf(stderr, "digest fuzz: bad ranges at cut %zu\n", cut);
+        return 1;
+      }
+    }
+  }
+  std::vector<uint8_t> mut(ml);
+  const uint8_t flips[] = {0xFF, 0x80, 0x01, 0x7F};
+  for (size_t i = 0; i < mut.size(); i++) {
+    for (uint8_t flip : flips) {
+      mut[i] ^= flip;
+      void* ss = vn_import_scan(mut.data(), (long long)mut.size());
+      if (ss) {
+        bool fine = read_back(ss);
+        vn_import_scan_free(ss);
+        if (!fine) {
+          fprintf(stderr, "digest fuzz: bad ranges, byte %zu\n", i);
+          return 1;
+        }
+      }
+      mut[i] ^= flip;
+    }
+  }
+  return 0;
 }
 
 int wire_fuzz() {
@@ -189,7 +346,7 @@ int wire_fuzz() {
   }
   vn_metro64((const char*)ml.data(), (long)ml.size());
   vn_metro64("", 0);
-  return 0;
+  return digest_wire_fuzz();
 }
 
 int fill_dense_fuzz() {

@@ -507,3 +507,76 @@ def test_nonuniform_counts_sums_keep_host_f64_precision():
     assert by["adv.h.sum"].value == big * 1.0 + 2.0 + 3.5
     # the same totals in f32 would have rounded
     assert float(np.float32(big + 2.0)) != big + 2.0
+
+
+@pytest.mark.parametrize("arena_cls", ["DigestArena", "MomentsArena",
+                                       "CompactorArena"])
+@pytest.mark.parametrize("local_between", [False, True])
+def test_merge_digest_batch_is_merge_digest_in_a_loop(arena_cls,
+                                                      local_between):
+    """One staging call for a payload's digests leaves what a
+    merge_digest call per digest leaves — points in the same order,
+    the exact scalars, the imported points NOT local (every l_*
+    accumulator and _sync_extra see them so) — also with a chunk of
+    local samples staged in between, and in the arenas that inherit
+    the staging."""
+    from veneur_tpu.core import arena as arena_mod
+
+    rng = np.random.default_rng(11)
+    rows = np.array([3, 7, 3, 0, 9, 7], np.int64)     # rows repeat
+    counts = np.array([4, 0, 2, 5, 1, 3], np.int64)   # an empty digest
+    means = rng.gamma(2.0, 10.0, int(counts.sum()))
+    weights = rng.integers(1, 5, len(means)).astype(np.float64)
+    dmin = rng.uniform(0.0, 1.0, len(rows))
+    dmax = rng.uniform(90.0, 99.0, len(rows))
+    drsum = rng.uniform(0.0, 2.0, len(rows))
+    dmin[2] = np.nan              # off the wire; must not stick
+    local = (np.array([0, 3, 3], np.int64), np.array([5.0, 6.0, 7.0]),
+             np.array([1.0, 1.0, 2.0]))
+
+    def build(batch: bool):
+        a = getattr(arena_mod, arena_cls)(capacity=16)
+        extra = []
+        a._sync_extra = (lambda r, v, w, loc, orig=a._sync_extra:
+                         (extra.append(loc.copy()), orig(r, v, w, loc)))
+        for half in (slice(0, 3), slice(3, 6)):
+            lo = int(counts[:half.start].sum())
+            hi = lo + int(counts[half].sum())
+            if batch:
+                a.merge_digest_batch(rows[half], counts[half],
+                                     means[lo:hi], weights[lo:hi],
+                                     dmin[half], dmax[half], drsum[half])
+            else:
+                off = lo
+                for i in range(half.start, half.stop):
+                    n = int(counts[i])
+                    a.merge_digest(int(rows[i]), means[off:off + n],
+                                   weights[off:off + n], float(dmin[i]),
+                                   float(dmax[i]), float(drsum[i]))
+                    off += n
+            if local_between and half.start == 0:
+                a.sample_batch(*local)
+                a.sync()
+        # what the sync in between has not consolidated yet
+        assert a.staged_count() == int(
+            counts[3 if local_between else 0:].sum())
+        a.sync()
+        uniform = a.staged_uniform
+        out = {"staged": a.take_staged(), "uniform": uniform,
+               "local_flags": np.concatenate(extra)}
+        for name in a._CKPT_SCALARS:
+            out[name] = getattr(a, name).copy()
+        return out
+
+    got, want = build(True), build(False)
+    assert got["uniform"] is want["uniform"] is False
+    for name, value in want.items():
+        if name == "staged":
+            for g, w in zip(got[name], value):
+                assert g.dtype == w.dtype and np.array_equal(g, w)
+        elif name != "uniform":
+            assert np.array_equal(got[name], value, equal_nan=True), name
+    n_local = 3 if local_between else 0
+    assert int(got["local_flags"].sum()) == n_local
+    assert got["l_weight"].sum() == (4.0 if local_between else 0.0)
+    assert not np.isnan(got["d_min"]).any()

@@ -20,6 +20,10 @@ from veneur_tpu.protocol import forward_pb2, metric_pb2, tdigest_pb2
 from veneur_tpu.samplers import samplers as sm
 from veneur_tpu.samplers.metric_key import MetricScope
 from veneur_tpu.sinks import simple as simple_sinks
+# protobuf wire encoders the cortex sink has: one length-delimited
+# field, one varint field
+from veneur_tpu.sinks.cortex import _tag_field as _ld
+from veneur_tpu.sinks.cortex import _varint_field
 
 
 def boot_global(**kw):
@@ -568,3 +572,340 @@ def test_import_row_cache_survives_flush_and_gc_cycles():
     agg.import_payload(pay)
     by = flush_values()
     assert by["a"] == 20.0 and by["b"] == 24.0
+
+
+# ---------------------------------------------------------------------------
+# columnar digest import: import_payload (the native scan decodes every
+# plain t-digest and the payload stages as arrays) against
+# import_pb_batch (protobuf objects, merge_digest per record), which is
+# the plain reference
+# ---------------------------------------------------------------------------
+
+def _wire_list(records) -> bytes:
+    """A MetricList from Metric messages or raw Metric bytes."""
+    return b"".join(
+        _ld(1, r if isinstance(r, bytes) else r.SerializeToString())
+        for r in records)
+
+
+def _wire_records(payload: bytes) -> list:
+    """The Metric submessages of a MetricList, as bytes."""
+    out, p = [], 0
+    while p < len(payload):
+        assert payload[p] == 0x0A
+        p += 1
+        n = shift = 0
+        while True:
+            b = payload[p]
+            p += 1
+            n |= (b & 0x7F) << shift
+            shift += 7
+            if not b & 0x80:
+                break
+        out.append(payload[p:p + n])
+        p += n
+    return out
+
+
+def _td(means, weights=None, compression=100.0, **scalars):
+    weights = [1.0] * len(means) if weights is None else weights
+    return tdigest_pb2.MergingDigestData(
+        main_centroids=[tdigest_pb2.Centroid(mean=float(m), weight=float(w))
+                        for m, w in zip(means, weights)],
+        compression=compression, **scalars)
+
+
+def _histo(name, td, type=metric_pb2.Histogram, scope=metric_pb2.Mixed,
+           tags=("svc:x", "az:b")):
+    return metric_pb2.Metric(
+        name=name, type=type, scope=scope, tags=list(tags),
+        histogram=metric_pb2.HistogramValue(t_digest=td))
+
+
+def _seeded_digests(seed, n_keys=12, weighted=True, cents=8):
+    rng = np.random.default_rng(seed)
+    out = []
+    for k in range(n_keys):
+        means = np.sort(rng.gamma(2.0, 10.0, cents))
+        weights = (rng.integers(1, 9, cents).astype(float) if weighted
+                   else np.ones(cents))
+        out.append(_histo(
+            f"lat.{k}", _td(means, weights, min=float(means[0]) - 0.5,
+                            max=float(means[-1]) + 0.5,
+                            reciprocalSum=float((weights / means).sum())),
+            tags=[f"k:{k % 5}", "env:prod"]))
+    return out
+
+
+def _case_singletons():
+    return [_wire_list(_seeded_digests(1, weighted=False))]
+
+
+def _case_weighted():
+    # a NaN min off the wire must not stick, as in merge_digest
+    extra = _histo("lat.0", _td([3.0, 4.0], [2.0, 2.0], min=float("nan"),
+                                max=9.0, reciprocalSum=1.25),
+                   tags=["k:0", "env:prod"])
+    return [_wire_list(_seeded_digests(2) + [extra])]
+
+
+def _case_zero_centroids():
+    return [_wire_list([
+        _histo("empty", _td([], min=0.0, max=0.0)),
+        _histo("some", _td([1.0, 2.0], [3.0, 1.0], min=0.5, max=2.5,
+                           reciprocalSum=3.5)),
+        # no t_digest at all, and no HistogramValue body
+        metric_pb2.Metric(name="bare", type=metric_pb2.Timer,
+                          histogram=metric_pb2.HistogramValue()),
+        _histo("empty", _td([], min=-1.0, max=4.0, reciprocalSum=2.0))])]
+
+
+def _case_histogram_and_timer():
+    td = _td([1.0, 5.0, 9.0], [2.0, 1.0, 1.0], min=1.0, max=9.0,
+             reciprocalSum=2.3)
+    return [_wire_list([_histo("dur", td), _histo("dur", td,
+                                                  type=metric_pb2.Timer),
+                        _histo("dur", td, type=metric_pb2.Timer),
+                        _histo("dur", td)])]
+
+
+def _case_global_and_mixed_scope():
+    td = _td([2.0, 4.0], [1.0, 1.0], min=2.0, max=4.0, reciprocalSum=0.75)
+    return [_wire_list([_histo("sc", td, scope=metric_pb2.Global),
+                        _histo("sc", td, scope=metric_pb2.Mixed),
+                        _histo("sc", td, scope=metric_pb2.Global),
+                        # an enum value the schema does not name reads
+                        # as mixed scope, as in _import_slow_pb
+                        _histo("sc", td, scope=7)])]
+
+
+def _case_refused_records():
+    td = _td([2.0, 4.0], [1.0, 3.0], min=2.0, max=4.0, reciprocalSum=1.25)
+    good = _histo("rf", td)
+    bad = [_histo("rf", td, scope=metric_pb2.Local),
+           _histo("rf", td, type=metric_pb2.Counter),
+           _histo("rf", td, type=metric_pb2.Set),
+           _histo("rf", td, type=300),        # past one byte
+           _histo("rf", td, scope=257)]       # past one byte: mixed
+    # refused before the key is cached, and after
+    return [_wire_list(bad[:3] + [good] + bad + [good])]
+
+
+def _case_family_markers():
+    from veneur_tpu.sketches import compactor as cs
+    from veneur_tpu.sketches import moments as mo
+
+    rng = np.random.default_rng(5)
+    ms = mo.MomentsSketch()
+    ms.add_batch(rng.gamma(2.0, 10.0, 500))
+    ck = cs.CompactorSketch()
+    ck.add_batch(rng.gamma(2.0, 10.0, 500))
+    recs = _seeded_digests(3, n_keys=4)
+    for name, fm in (("mk.m", sm.ForwardMetric(
+            name="mk.m", tags=["a:b"], kind="histogram",
+            scope=int(MetricScope.MIXED), moments=ms.vec.tolist())),
+            ("mk.c", sm.ForwardMetric(
+                name="mk.c", tags=["a:b"], kind="timer",
+                scope=int(MetricScope.MIXED),
+                compactor=ck.to_vector().tolist()))):
+        recs.insert(len(recs) // 2, convert.to_pb(fm))
+    # a marker's vector of the wrong length fails its record alone
+    recs.insert(1, _histo("mk.bad", _td([1.0, 2.0], compression=-8.0)))
+    return [_wire_list(recs + _seeded_digests(3, n_keys=4))]
+
+
+def _case_samples_and_unknown_fields():
+    unknown = _varint_field(15, 7)
+    cents = [tdigest_pb2.Centroid(mean=1.5, weight=2.0,
+                                  samples=[1.0, 2.0]).SerializeToString()
+             + unknown,
+             # samples unpacked, then mean and weight
+             bytes([3 << 3 | 1]) + np.float64(9.0).tobytes()
+             + tdigest_pb2.Centroid(mean=4.5,
+                                    weight=1.0).SerializeToString(),
+             # mean under the wrong wire type is an unknown field:
+             # the centroid keeps mean 0
+             _varint_field(1, 3)
+             + tdigest_pb2.Centroid(weight=5.0).SerializeToString(),
+             # a scalar twice: the last one stands
+             tdigest_pb2.Centroid(mean=7.0).SerializeToString()
+             + tdigest_pb2.Centroid(mean=8.0,
+                                    weight=1.0).SerializeToString()]
+    scalars = tdigest_pb2.MergingDigestData(
+        compression=100.0, min=1.0, max=8.0,
+        reciprocalSum=0.9).SerializeToString()
+    digest = (_ld(1, cents[0]) + _ld(1, cents[1]) + scalars + unknown
+              + _ld(1, cents[2]) + _ld(9, b"opaque") + _ld(1, cents[3]))
+    head = metric_pb2.Metric(name="wire", tags=["a:b"],
+                             type=metric_pb2.Histogram).SerializeToString()
+    one = head + _ld(7, _ld(1, digest) + unknown) + _ld(12, b"later")
+    # the digest split over two t_digest fields, and over two
+    # HistogramValue fields: protobuf parses them onto one message
+    half_a = _ld(1, cents[0]) + tdigest_pb2.MergingDigestData(
+        min=-5.0, max=1.0).SerializeToString()
+    half_b = _ld(1, cents[1]) + scalars
+    two = head + _ld(7, _ld(1, half_a) + _ld(1, half_b))
+    three = head + _ld(7, _ld(1, half_a)) + _ld(7, _ld(1, half_b))
+    return [_wire_list([one, two, three])]
+
+
+def _case_oneof_switches():
+    td = _td([1.0, 3.0], [2.0, 2.0], min=1.0, max=3.0, reciprocalSum=2.6)
+    hv = _ld(7, metric_pb2.HistogramValue(t_digest=td).SerializeToString())
+    cv = _ld(5, metric_pb2.CounterValue(value=4).SerializeToString())
+    h = metric_pb2.Metric(name="sw", type=metric_pb2.Histogram
+                          ).SerializeToString()
+    c = metric_pb2.Metric(name="sw").SerializeToString()  # type Counter
+    return [_wire_list([
+        h + cv + hv,          # ends a histogram: the counter is gone
+        c + hv + cv,          # ends a counter: no digest is staged
+        h + hv + cv,          # type Histogram carrying a counter: refused
+        h + hv + cv + hv,     # histogram, cleared, histogram afresh
+        _histo("sw", td)])]
+
+
+def _case_interleaved_families():
+    from veneur_tpu.sketches import hll as hll_mod
+
+    sk = hll_mod.HLLSketch()
+    for i in range(50):
+        sk.insert(b"u%d" % i)
+    digests = _seeded_digests(4, n_keys=6)
+    recs = []
+    for i, d in enumerate(digests + digests):
+        recs.append(metric_pb2.Metric(
+            name=f"c{i % 3}", type=metric_pb2.Counter, tags=["t:1"],
+            counter=metric_pb2.CounterValue(value=i + 1)))
+        recs.append(d)
+        recs.append(metric_pb2.Metric(
+            name=f"g{i % 2}", type=metric_pb2.Gauge,
+            gauge=metric_pb2.GaugeValue(value=i / 4)))
+        if i % 4 == 0:
+            recs.append(metric_pb2.Metric(
+                name="users", type=metric_pb2.Set,
+                set=metric_pb2.SetValue(hyper_log_log=sk.marshal())))
+    recs.append(metric_pb2.Metric(name="nil", type=metric_pb2.Gauge))
+    return [_wire_list(recs)]
+
+
+def _case_twice_in_one_interval():
+    pay = _wire_list(_seeded_digests(6))
+    return [pay, pay]
+
+
+def _case_across_a_flush():
+    pay = _wire_list(_seeded_digests(7))
+    other = _wire_list(_seeded_digests(8, n_keys=5))
+    return [pay, "flush", other, pay, "flush", pay]
+
+
+def _case_invalid_utf8_first_sighting():
+    td = _td([1.0, 2.0], [1.0, 4.0], min=1.0, max=2.0, reciprocalSum=3.0)
+    bad = _histo("u8.BAD", td).SerializeToString().replace(
+        b"u8.BAD", b"u8.\xff\xfe\xfd")
+    return [_wire_list([_histo("u8.a", td), bad, _histo("u8.b", td),
+                        bad, _histo("u8.a", td)])]
+
+
+# case -> (steps, plain digests that were a key's first sighting in
+# their interval, plain digests whose row came from the cache)
+_COLUMNAR_CASES = {
+    "singleton_centroids": (_case_singletons, 12, 0),
+    "weighted_centroids": (_case_weighted, 12, 1),
+    "zero_centroids": (_case_zero_centroids, 3, 1),
+    "histogram_and_timer": (_case_histogram_and_timer, 2, 2),
+    "global_and_mixed_scope": (_case_global_and_mixed_scope, 3, 1),
+    "local_scope_and_type_mismatch": (_case_refused_records, 2, 1),
+    "moments_and_compactor_markers": (_case_family_markers, 4, 4),
+    "samples_and_unknown_fields": (_case_samples_and_unknown_fields, 1, 2),
+    "oneof_switches": (_case_oneof_switches, 2, 1),
+    "counters_gauges_sets_interleaved": (_case_interleaved_families, 6, 6),
+    "same_payload_twice": (_case_twice_in_one_interval, 12, 12),
+    "across_a_flush": (_case_across_a_flush, 12 + 5 + 7 + 12, 5),
+    "invalid_utf8_first_sighting": (_case_invalid_utf8_first_sighting,
+                                    2, 1),
+}
+
+
+def _arena_state(arena) -> dict:
+    """What an import left in a digest-family arena: the consolidated
+    COO in a stable order by row, the exact scalars, the uniform flag."""
+    arena.sync()
+    uniform = arena.staged_uniform
+    rows, vals, wts = arena.take_staged()
+    order = np.argsort(rows, kind="stable")
+    out = {"rows": rows[order], "vals": vals[order], "wts": wts[order],
+           "uniform": uniform, "touched": arena.touched.copy()}
+    for name in ("d_min", "d_max", "d_rsum", "d_weight", "d_sum",
+                 "l_weight"):
+        out[name] = getattr(arena, name).copy()
+    return out
+
+
+@pytest.mark.parametrize("case", sorted(_COLUMNAR_CASES))
+def test_columnar_digest_import_matches_pb_path(case):
+    """The same seeded payloads through import_payload and through
+    import_pb_batch leave identical state: consolidated COO, scalars,
+    uniform flag, (ok, failed), imported — and identical flushes."""
+    import veneur_tpu.ingest as ingest_mod
+    from veneur_tpu.core.aggregator import MetricAggregator
+
+    ingest_mod.load_library()   # loud if the engine can't build
+    build, want_misses, want_hits = _COLUMNAR_CASES[case]
+
+    def reference_import(agg, payload):
+        pbs = []
+        for rec in _wire_records(payload):
+            try:
+                pbs.append(metric_pb2.Metric.FromString(rec))
+            except Exception:
+                pbs.append(metric_pb2.Metric())    # fails as a nil value
+        return agg.import_pb_batch(pbs)
+
+    runs = []
+    for native in (True, False):
+        agg = MetricAggregator(percentiles=[0.5, 0.9])
+        seen = {"counts": [], "flushes": [], "hits": 0, "misses": 0}
+
+        def bank_ledger():
+            seen["hits"] += agg._ledger["import_digest_hits"]
+            seen["misses"] += agg._ledger["import_digest_misses"]
+
+        for step in build():
+            if step == "flush":
+                bank_ledger()
+                res = agg.flush(is_local=False)
+                seen["flushes"].append((res.imported, sorted(
+                    (m.name, tuple(m.tags), m.value)
+                    for m in res.metrics)))
+                assert not agg._import_row_cache
+            elif native:
+                seen["counts"].append(agg.import_payload(step))
+            else:
+                seen["counts"].append(reference_import(agg, step))
+        bank_ledger()
+        seen["imported"] = agg.imported
+        seen["arenas"] = {fam: _arena_state(getattr(agg, fam))
+                          for fam in ("digests", "moments", "compactors")}
+        runs.append(seen)
+
+    got, ref = runs
+    assert got["counts"] == ref["counts"]
+    assert got["imported"] == ref["imported"]
+    assert got["flushes"] == ref["flushes"]
+    for fam, state in got["arenas"].items():
+        for name, value in state.items():
+            want = ref["arenas"][fam][name]
+            if isinstance(value, np.ndarray):
+                assert value.dtype == want.dtype, (fam, name)
+                assert np.array_equal(
+                    value, want,
+                    equal_nan=value.dtype.kind == "f"), (fam, name)
+            else:
+                assert value == want, (fam, name)
+    # the reference never takes the columnar path; the scan path's
+    # counter says how often it engaged, and with what
+    assert (ref["misses"], ref["hits"]) == (0, 0)
+    assert (got["misses"], got["hits"]) == (want_misses, want_hits)
+    staged = sum(len(s["rows"]) for s in got["arenas"].values())
+    assert staged > 0

@@ -15,16 +15,19 @@ from veneur_tpu import config as config_mod
 from veneur_tpu import http_api
 from veneur_tpu import ingest as ingest_mod
 from veneur_tpu.core.aggregator import (LEDGER_SEGMENT_KEYS,
+                                        ROW_ONLY_SEGMENT_KEYS,
                                         MetricAggregator)
 from veneur_tpu.core.server import Server
-from veneur_tpu.protocol import forward_pb2, metric_pb2
+from veneur_tpu.forward.client import ForwardClient
+from veneur_tpu.protocol import forward_pb2, metric_pb2, tdigest_pb2
 from veneur_tpu.sinks import simple as simple_sinks
 from veneur_tpu.trace import assembly
 
 ROW_FIELDS = ("snapshot_lock_wait_ms", "snapshot_sync_ms",
               "snapshot_staged_ms", "snapshot_columns_ms", "import_rpcs",
               "import_lock_wait_ms", "import_scan_ms", "import_held_ms",
-              "fold_calls", "fold_lines", "fold_lock_wait_ms", "fold_ms")
+              "fold_calls", "fold_lines", "fold_lock_wait_ms", "fold_ms",
+              "import_digest_hits", "import_digest_misses")
 
 
 def _wait(cond, timeout_s=10.0):
@@ -36,12 +39,25 @@ def _wait(cond, timeout_s=10.0):
     return False
 
 
-def _payload(i: int, n: int = 20) -> bytes:
+def _digest_pb(name: str, tags=(), compression: float = 100.0):
+    return metric_pb2.Metric(
+        name=name, type=metric_pb2.Timer, tags=list(tags),
+        histogram=metric_pb2.HistogramValue(
+            t_digest=tdigest_pb2.MergingDigestData(
+                main_centroids=[tdigest_pb2.Centroid(mean=m, weight=2.0)
+                                for m in (1.0, 2.0, 4.0)],
+                compression=compression, min=1.0, max=4.0,
+                reciprocalSum=3.5)))
+
+
+def _payload(i: int, n: int = 20, digests: int = 0) -> bytes:
     return forward_pb2.MetricList(metrics=[
         metric_pb2.Metric(name=f"led.c{j}", type=metric_pb2.Counter,
                           tags=[f"rpc:{i % 3}"],
                           counter=metric_pb2.CounterValue(value=1))
-        for j in range(n)]).SerializeToString()
+        for j in range(n)] + [
+        _digest_pb(f"led.d{j}", [f"rpc:{i % 3}"])
+        for j in range(digests)]).SerializeToString()
 
 
 def _segments_sum(rows: list, key: str):
@@ -66,8 +82,9 @@ def test_ledger_swap_is_exact_under_concurrent_import_and_drain(native_scan):
 
     def importer(k: int) -> None:
         for i in range(40):
-            ok, failed = agg.import_payload(_payload(k * 100 + i))
-            assert (ok, failed) == (20, 0)
+            ok, failed = agg.import_payload(
+                _payload(k * 100 + i, digests=5))
+            assert (ok, failed) == (25, 0)
             timing = agg.take_import_timing()
             assert timing is not None and agg.take_import_timing() is None
             with lock:
@@ -113,6 +130,13 @@ def test_ledger_swap_is_exact_under_concurrent_import_and_drain(native_scan):
         want = sum(t[i] for t in per_rpc) / 1e9
         assert _segments_sum(segs, key) == pytest.approx(want, rel=1e-9)
     assert _segments_sum(segs, "fold_s") > 0
+    # every plain digest the wire scan staged is a hit or a miss of the
+    # interval it landed in; the protobuf path counts none
+    assert (_segments_sum(segs, "import_digest_hits")
+            + _segments_sum(segs, "import_digest_misses")
+            == (160 * 5 if native_scan else 0))
+    assert (_segments_sum(segs, "import_digest_misses") >= 3 * 5) \
+        == native_scan
     # the open ledger is empty again: the last flush took everything
     assert not any(agg._ledger.values())
 
@@ -257,6 +281,8 @@ def test_tracing_off_records_no_span_and_keeps_the_row_fields(server):
         assert field in row, field
     assert row["fold_calls"] >= 1 and row["fold_lines"] == 40
     assert row["import_rpcs"] == 0
+    # no import ran: no digest was staged from a scan
+    assert row["import_digest_hits"] == row["import_digest_misses"] == 0
     for field in ("udp_rcvbuf_drops", "ring_full_stalls",
                   "ring_peak_share"):
         assert field in row, field
@@ -368,4 +394,43 @@ def test_global_import_span_carries_the_three_durations():
         assert row["imported"] >= 4
     finally:
         loc.shutdown()
+        glob.shutdown()
+
+
+def test_digest_hits_and_misses_add_up_to_the_plain_digests_imported():
+    """The row of a flush that closed an importing interval says how
+    often the columnar path engaged: per interval, first sightings and
+    cache hits are disjoint and add up to the plain digests imported;
+    a marker record (python merges it) and counters are in neither."""
+    assert {"import_digest_hits", "import_digest_misses"} \
+        <= LEDGER_SEGMENT_KEYS <= ROW_ONLY_SEGMENT_KEYS
+    glob = Server(config_mod.Config(grpc_address="127.0.0.1:0",
+                                    interval=10.0, percentiles=[0.5],
+                                    hostname="g0"))
+    glob.start()
+    client = ForwardClient(f"127.0.0.1:{glob.grpc_import.port}",
+                           timeout_s=10.0, source="ledger-test")
+    pbs = ([_digest_pb(f"led.h{j}", ["a:b"]) for j in range(30)]
+           + [metric_pb2.Metric(name="led.c", type=metric_pb2.Counter,
+                                counter=metric_pb2.CounterValue(value=1))
+              for _ in range(5)]
+           # a moments-family marker of the wrong length: refused by
+           # merge_moments, and never a plain digest
+           + [_digest_pb("led.m", compression=-8.0)])
+    try:
+        for epoch, sends in ((1, 3), (2, 1)):
+            for k in range(sends):
+                client.send_pbs(pbs, epoch=10 * epoch + k)
+            glob.flush()
+            row = glob.flush_timeline.snapshot()[-1]
+            assert row["import_rpcs"] == sends
+            assert row["import_digest_misses"] == 30
+            assert row["import_digest_hits"] == 30 * (sends - 1)
+            assert row["imported"] == 35 * sends
+        glob.flush()        # an interval nobody forwarded in
+        row = glob.flush_timeline.snapshot()[-1]
+        assert row["import_rpcs"] == 0
+        assert row["import_digest_hits"] == row["import_digest_misses"] == 0
+    finally:
+        client.close()
         glob.shutdown()

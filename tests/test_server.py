@@ -234,6 +234,43 @@ def test_ticker_flushes(fixture_server):
     srv.shutdown()
 
 
+def test_ticker_drops_the_ticks_a_slow_flush_missed(fixture_server):
+    """A flush that outlasts several intervals is followed by ONE flush
+    at once (the tick the reference's ticker holds) and then by flushes
+    on the grid again, not by a burst replaying every missed tick."""
+    import threading
+    srv, _sink = fixture_server(interval=0.2)
+    interval = srv.config.interval
+    starts = []
+    slow = threading.Event()
+
+    def flush():
+        starts.append(time.time())
+        if len(starts) == 2:
+            time.sleep(4.5 * interval)      # misses four ticks
+            slow.set()
+
+    srv.flush = flush
+    t = threading.Thread(target=srv.serve, daemon=True)
+    t.start()
+    assert slow.wait(10 + 8 * interval)
+    deadline = time.time() + 10 + 8 * interval
+    while len(starts) < 6 and time.time() < deadline:
+        time.sleep(interval / 10)
+    srv.stop_serving()
+    t.join(5)
+    assert len(starts) >= 6
+    gaps = [b - a for a, b in zip(starts, starts[1:])]
+    # the slow flush, then the one held tick at once, then the grid
+    assert gaps[1] >= 4.5 * interval
+    assert gaps[2] < interval
+    for gap in gaps[3:5]:
+        assert 0.5 * interval < gap < 1.5 * interval, gaps
+    # ... and the grid is the old one: whole intervals from the start
+    phase = (starts[4] - starts[0]) / interval
+    assert abs(phase - round(phase)) < 0.35, (phase, gaps)
+
+
 def test_watchdog_fires():
     cfg = make_config(flush_watchdog_missed_flushes=2, interval=0.05)
     srv = Server(cfg)

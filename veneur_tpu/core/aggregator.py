@@ -98,7 +98,13 @@ class PendingFlush:
 # flush.segment.* loop.
 _LEDGER_FIELDS = ("import_rpcs", "import_lock_wait_ns", "import_scan_ns",
                   "import_held_ns", "fold_calls", "fold_lines",
-                  "fold_lock_wait_ns", "fold_ns")
+                  "fold_lock_wait_ns", "fold_ns",
+                  # plain t-digests import_payload staged as arrays: the
+                  # row came from the identity cache (no protobuf parse),
+                  # or the record was its key's first sighting in the
+                  # interval (parsed, resolved, cached).  Records that
+                  # keep _import_slow_pb are in neither.
+                  "import_digest_hits", "import_digest_misses")
 LEDGER_SEGMENT_KEYS = frozenset(
     ["snapshot_lock_wait_s", "snapshot_sync_s", "snapshot_staged_s",
      "snapshot_columns_s"]
@@ -779,6 +785,19 @@ class MetricAggregator:
         led["fold_lock_wait_ns"] += t_held - t_wait
         led["fold_ns"] += time.perf_counter_ns() - t_held
 
+    def _import_histo_identity(self, pb):
+        """(key, class, tags) a forwarded histogram / timer record
+        merges under, after the cardinality guard."""
+        from veneur_tpu.protocol import metric_pb2
+
+        tags = list(pb.tags)
+        kind = (sm.TYPE_TIMER if pb.type == metric_pb2.Timer
+                else sm.TYPE_HISTOGRAM)
+        cls = (MetricScope.GLOBAL_ONLY if pb.scope == metric_pb2.Global
+               else MetricScope.MIXED)
+        return self._card_resolve(
+            MetricKey(pb.name, kind, ",".join(sorted(tags))), cls, tags)
+
     def _import_slow_pb(self, pb, which: str) -> None:
         """Set/histogram import body (sketch merges; call under
         self.lock) — shared by the batch and native-scan paths."""
@@ -789,21 +808,15 @@ class MetricAggregator:
         if pb.type not in self._ONEOF_LEGAL_TYPES[which]:
             raise ValueError(
                 f"type/value mismatch: type={pb.type} carrying {which}")
-        tags = list(pb.tags)
-        joined = ",".join(sorted(tags))
         if which == "set":
+            tags = list(pb.tags)
             key, cls, tags = self._card_resolve(
-                MetricKey(pb.name, sm.TYPE_SET, joined),
+                MetricKey(pb.name, sm.TYPE_SET, ",".join(sorted(tags))),
                 MetricScope.MIXED, tags)
             row = self.sets.row_for(key, cls, tags)
             self.sets.merge(row, pb.set.hyper_log_log)
             return
-        kind = (sm.TYPE_TIMER if pb.type == metric_pb2.Timer
-                else sm.TYPE_HISTOGRAM)
-        cls = (MetricScope.GLOBAL_ONLY if pb.scope == metric_pb2.Global
-               else MetricScope.MIXED)
-        key, cls, tags = self._card_resolve(
-            MetricKey(pb.name, kind, joined), cls, tags)
+        key, cls, tags = self._import_histo_identity(pb)
         dig = pb.histogram.t_digest
         if dig.compression <= -1024:
             # compactor-family wire marker (forward/convert.py): the
@@ -828,12 +841,16 @@ class MetricAggregator:
 
     def import_payload(self, payload: bytes) -> tuple[int, int]:
         """V1 import from the RAW MetricList bytes: the native scanner
-        (ingest.import_scan) extracts identity hashes + values in C++,
-        so python does one dict lookup per metric and one vectorized
-        merge per family.  Set/histogram records parse individually via
-        their byte ranges (they carry sketches python merges anyway).
-        Falls back to import_pb_batch when the native engine is
-        unavailable or rejects the payload."""
+        (ingest.import_scan) extracts identity hashes, values and every
+        plain t-digest's centroids in C++, so python does one dict
+        lookup per metric, one vectorized merge per family and ONE
+        staging call for the payload's digests
+        (DigestArena.merge_digest_batch).  What the wire says decides
+        per record: sets, the moments / compactor markers (compression
+        < 0) and a key's first sighting in the interval parse
+        individually via their byte ranges.  Falls back to
+        import_pb_batch when the native engine is unavailable or
+        rejects the payload."""
         t_call = time.perf_counter_ns()
         scan = None
         # the native wire scan never materializes tags, which the
@@ -861,24 +878,61 @@ class MetricAggregator:
         from veneur_tpu.protocol import metric_pb2
         h_lo = scan["h_lo"].tolist()
         h_hi = scan["h_hi"].tolist()
-        wl = scan["which"].tolist()
-        mtypes = scan["mtype"].tolist()
+        mtype, scope = scan["mtype"], scan["scope"]
+        # a histogram record's route, from the scan's columns and
+        # before the row cache can short-circuit the checks: 6 = refused
+        # (a type its oneof may not carry, or local scope), 5 = a
+        # sketch-family marker (python merges it), 4 = a plain digest
+        route = scan["which"].copy()
+        histo = route == 4
+        route[histo & (scan["compression"] < 0)] = 5
+        route[histo & (((mtype != metric_pb2.Histogram)
+                        & (mtype != metric_pb2.Timer))
+                       | (scope == metric_pb2.Local))] = 6
+        wl = route.tolist()
+        mtypes = mtype.tolist()
+        scopes = scope.tolist()
         vals = scan["value"].tolist()
         offs = scan["rec_off"].tolist()
         lens = scan["rec_len"].tolist()
         cache = self._import_row_cache
-        counters, gauges = self.counters, self.gauges
+        counters, gauges, digests = self.counters, self.gauges, self.digests
         c_rows: list = []
         c_vals: list = []
         g_rows: list = []
         g_vals: list = []
+        d_recs: list = []       # plain digests staged: record index, row
+        d_rows: list = []
+        misses = 0
         ok = failed = 0
         t_wait = time.perf_counter_ns()
         with self.lock:
             t_held = time.perf_counter_ns()
             for i in range(n):
                 w = wl[i]
-                if w == 1 or w == 2:
+                if w == 4:
+                    ck = (h_lo[i], h_hi[i], 4, scopes[i])
+                    row = cache.get(ck)
+                    if row is None:
+                        # first sighting this interval: the record is
+                        # parsed for its name and tags (and one bad
+                        # record, e.g. invalid UTF-8 the wire scanner
+                        # can't see, must not abort the payload);
+                        # row_for marks the row touched
+                        try:
+                            pb = metric_pb2.Metric.FromString(
+                                payload[offs[i]:offs[i] + lens[i]])
+                            row = digests.row_for(
+                                *self._import_histo_identity(pb))
+                        except Exception:
+                            failed += 1
+                            continue
+                        cache[ck] = row
+                        misses += 1
+                    d_recs.append(i)
+                    d_rows.append(row)
+                    ok += 1
+                elif w == 1 or w == 2:
                     # type/value-oneof agreement (same contract as
                     # import_pb_batch): the wire scan already carries
                     # the type field, so mismatches reject without a
@@ -919,7 +973,7 @@ class MetricAggregator:
                         g_rows.append(row)
                         g_vals.append(vals[i])
                     ok += 1
-                elif w == 3 or w == 4:
+                elif w == 3 or w == 5:
                     try:
                         pb = metric_pb2.Metric.FromString(
                             payload[offs[i]:offs[i] + lens[i]])
@@ -940,8 +994,35 @@ class MetricAggregator:
             if g_rows:
                 gauges.merge_batch(np.asarray(g_rows, np.int64),
                                    np.asarray(g_vals, np.float64))
+            if d_recs:
+                # vnlint: disable=blocking-propagation (the flagged
+                #   asarray converts host lists — record indexes and
+                #   arena rows — never a device array)
+                self._stage_scanned_digests(scan, d_recs, d_rows)
+                self._ledger["import_digest_hits"] += len(d_recs) - misses
+                self._ledger["import_digest_misses"] += misses
             self._ledger_import(t_call, t_wait, t_held)
         return ok, failed
+
+    def _stage_scanned_digests(self, scan: dict, recs: list,
+                               rows: list) -> None:
+        """Stage the plain digests of one scanned payload (record
+        indexes `recs`, ascending, into arena rows `rows`) as one
+        columnar chunk.  Call under self.lock."""
+        recs = np.asarray(recs, np.int64)
+        counts = scan["cent_n"][recs]
+        means, weights = scan["cent_mean"], scan["cent_weight"]
+        if int(counts.sum()) != len(means):
+            # the flat columns also hold what was not staged (markers'
+            # vectors, refused or failed records): take the staged
+            # records' ranges, in wire order
+            keep = np.zeros(scan["n"], bool)
+            keep[recs] = True
+            keep = np.repeat(keep, scan["cent_n"])
+            means, weights = means[keep], weights[keep]
+        self.digests.merge_digest_batch(
+            np.asarray(rows, np.int64), counts, means, weights,
+            scan["dmin"][recs], scan["dmax"][recs], scan["drsum"][recs])
 
     def sync_staged(self, min_samples: int = 0) -> bool:
         """Push staged samples into device state NOW if the backlog is

@@ -1091,9 +1091,11 @@ class DigestArena(_ArenaBase):
         self._vals: list[float] = []
         self._wts: list[float] = []
         self._local: list[bool] = []
-        # array-chunk staging from the native ingest engine (always local
-        # samples; imports go through merge_digest)
-        self._chunks: list[tuple[np.ndarray, np.ndarray, np.ndarray]] = []
+        # array-chunk staging: (rows, vals, wts, local) — local samples
+        # from the native ingest engine (sample_batch) and a payload's
+        # forwarded centroids (merge_digest_batch; local False)
+        self._chunks: list[
+            tuple[np.ndarray, np.ndarray, np.ndarray, bool]] = []
         # consolidated interval accumulator: scalar-applied (rows, vals,
         # wts) parts + per-row staged depth
         self._acc: list[tuple[np.ndarray, np.ndarray, np.ndarray]] = []
@@ -1190,10 +1192,32 @@ class DigestArena(_ArenaBase):
         ingest drain path)."""
         if not self._staged_nonuniform and not np.all(wts == 1.0):
             self._staged_nonuniform = True
-        self._chunks.append((rows, vals, wts))
+        self._chunks.append((rows, vals, wts, True))
+
+    def merge_digest_batch(self, rows: np.ndarray, counts: np.ndarray,
+                           means: np.ndarray, weights: np.ndarray,
+                           dmin: np.ndarray, dmax: np.ndarray,
+                           drsum: np.ndarray) -> None:
+        """merge_digest for a whole payload in one call: digest i folds
+        into rows[i] with its counts[i] centroids, which lie one digest
+        after the other in the flat `means` / `weights` (float64, the
+        wire's values), and its wire scalars dmin[i] / dmax[i] /
+        drsum[i].  The centroids stage as ONE columnar chunk, not
+        local; the scalars merge in digest order, so a row that occurs
+        twice reads what two merge_digest calls leave (fmin / fmax: as
+        Python's min / max there, a NaN from the wire does not
+        stick)."""
+        if len(means):
+            if not self._staged_nonuniform and not np.all(weights == 1.0):
+                self._staged_nonuniform = True
+            self._chunks.append((np.repeat(rows, counts), means, weights,
+                                 False))
+        np.fmin.at(self.d_min, rows, dmin)
+        np.fmax.at(self.d_max, rows, dmax)
+        np.add.at(self.d_rsum, rows, drsum)
 
     def staged_count(self) -> int:
-        return len(self._rows) + sum(len(r) for r, _, _ in self._chunks)
+        return len(self._rows) + sum(len(c[0]) for c in self._chunks)
 
     # -- consolidation / hot-key pre-reduction ----------------------------
 
@@ -1212,11 +1236,11 @@ class DigestArena(_ArenaBase):
                           np.asarray(self._wts, np.float64),
                           np.asarray(self._local, bool)))
             self._rows, self._vals, self._wts, self._local = [], [], [], []
-        for r, v, w in self._chunks:
+        for r, v, w, is_local in self._chunks:
             parts.append((r.astype(np.int64, copy=False),
                           v.astype(np.float64, copy=False),
                           w.astype(np.float64, copy=False),
-                          np.ones(len(r), bool)))
+                          np.full(len(r), is_local, bool)))
         self._chunks = []
         if len(parts) == 1:
             rows, vals, wts, local = parts[0]

@@ -2044,6 +2044,15 @@ class Server:
             if self._shutdown.wait(timeout):
                 break
             next_tick += interval
+            late = time.time() - next_tick
+            if late > 0:
+                # the last flush outlasted its interval (a cold XLA
+                # compile takes several).  The reference's ticker holds
+                # ONE tick for a slow receiver and drops the rest
+                # (time.Ticker): this flush is that one, and the next
+                # comes on the grid — not every missed tick replayed
+                # back to back, a burst of empty flushes off the grid
+                next_tick += interval * -(-late // interval)
             try:
                 self.flush()
             except Exception as e:

@@ -177,6 +177,9 @@ def load_library():
         lib.vn_import_scan_n.argtypes = [ctypes.c_void_p]
         lib.vn_import_scan_arrays.argtypes = [
             ctypes.c_void_p] + [ctypes.POINTER(ctypes.c_void_p)] * 8
+        lib.vn_import_scan_digests.argtypes = [
+            ctypes.c_void_p, ctypes.POINTER(ctypes.c_longlong)
+        ] + [ctypes.POINTER(ctypes.c_void_p)] * 8
         lib.vn_import_scan_free.argtypes = [ctypes.c_void_p]
         _lib = lib
         return lib
@@ -187,7 +190,11 @@ def import_scan(payload: bytes):
     returns dict of numpy arrays {h_lo, h_hi (u64 identity hashes),
     which (u8: 1 counter, 2 gauge, 3 set, 4 histogram), mtype, scope
     (u8), value (f64), rec_off, rec_len (i64 Metric submessage
-    ranges)} — copies, safe after free — or None if the payload failed
+    ranges)} and, decoded from each histogram record's t-digest,
+    {cent_mean, cent_weight (f64, every record's centroids in wire
+    order), cent_off, cent_n (i64, the record's range in those two),
+    dmin, dmax, drsum, compression (f64 per record; zeros for the other
+    kinds)} — copies, safe after free — or None if the payload failed
     the wire scan (caller falls back to protobuf parsing)."""
     import numpy as np
 
@@ -197,15 +204,22 @@ def import_scan(payload: bytes):
         return None
     try:
         n = lib.vn_import_scan_n(handle)
-        ptrs = [ctypes.c_void_p() for _ in range(8)]
-        lib.vn_import_scan_arrays(handle, *map(ctypes.byref, ptrs))
         if n == 0:
             return {"n": 0}
+        ptrs = [ctypes.c_void_p() for _ in range(8)]
+        lib.vn_import_scan_arrays(handle, *map(ctypes.byref, ptrs))
+        n_cent = ctypes.c_longlong()
+        dptrs = [ctypes.c_void_p() for _ in range(8)]
+        lib.vn_import_scan_digests(handle, ctypes.byref(n_cent),
+                                   *map(ctypes.byref, dptrs))
 
         def arr(ptr, dtype, count=n):
+            if count == 0:      # an empty vector's data() may be null
+                return np.zeros(0, dtype)
             size = np.dtype(dtype).itemsize * count
             return np.frombuffer(
-                ctypes.string_at(ptr.value, size), dtype).copy()
+                (ctypes.c_char * size).from_address(ptr.value),
+                dtype).copy()
 
         return {
             "n": int(n),
@@ -217,6 +231,14 @@ def import_scan(payload: bytes):
             "value": arr(ptrs[5], np.float64),
             "rec_off": arr(ptrs[6], np.int64),
             "rec_len": arr(ptrs[7], np.int64),
+            "cent_mean": arr(dptrs[0], np.float64, n_cent.value),
+            "cent_weight": arr(dptrs[1], np.float64, n_cent.value),
+            "cent_off": arr(dptrs[2], np.int64),
+            "cent_n": arr(dptrs[3], np.int64),
+            "dmin": arr(dptrs[4], np.float64),
+            "dmax": arr(dptrs[5], np.float64),
+            "drsum": arr(dptrs[6], np.float64),
+            "compression": arr(dptrs[7], np.float64),
         }
     finally:
         lib.vn_import_scan_free(handle)
