@@ -225,8 +225,8 @@ def test_set_estimate_compiles_for_v5e(rows, one_chip):
     program `MetricAggregator._dispatch_sets` launches: the 1,000 set
     keys of `node1.fanout`, and 50k rows (1 GiB of registers).  One
     fusion straight off the u8 operand — no f32 copy of the registers
-    in HBM (the chip measured it ahead of `ops/hll_estimate.py`'s
-    Pallas form, which is not on the served path)."""
+    in HBM (the chip read it at 45.3 us against a Pallas form's 55.5,
+    PR 27; the Pallas form left the tree with PR 30)."""
     compiled = hll_mod.estimate.lower(
         _struct(one_chip, (rows, 1 << 14), jnp.uint8)).compile()
     assert "tpu_custom_call" not in compiled.as_text()

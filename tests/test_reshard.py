@@ -130,6 +130,42 @@ def test_set_members_two_phase_record_and_failpoint():
             srv.shutdown()
 
 
+def test_two_callers_offered_one_membership_publish_one_reshard():
+    """The discovery poll and an operator's direct call can both be
+    offered the same new membership.  Each takes its diff before the
+    reshard window; the one admitted second finds the joiner already
+    on the ring and must not publish an empty record over the real one
+    (what `ring-scale-up` read as `added == []` on a loaded host)."""
+    import threading
+    g1, _ = boot_global()
+    g2, _ = boot_global()
+    a1 = f"127.0.0.1:{g1.grpc_import.port}"
+    a2 = f"127.0.0.1:{g2.grpc_import.port}"
+    d = Destinations(reshard_sample_keys=512)
+    try:
+        d.set_members([a1])
+        # hold the first caller inside its window while the second
+        # takes its diff (joiner not connected yet) and queues behind it
+        failpoints.configure("destinations.reshard", "delay", delay_s=0.4)
+        first = threading.Thread(target=d.set_members, args=([a1, a2],))
+        first.start()
+        time.sleep(0.1)
+        d.set_members([a1, a2])
+        first.join(timeout=10.0)
+        assert not first.is_alive()
+        rs = d.reshard_stats()
+        assert rs["epochs"] == 2
+        assert rs["last"]["added"] == [a2] and rs["last"]["committed"]
+        # the window is not left open: a real change still reshards
+        failpoints.disarm("destinations.reshard")
+        d.set_members([a1])
+        assert d.reshard_stats()["last"]["removed"] == [a2]
+    finally:
+        d.clear()
+        for srv in (g1, g2):
+            srv.shutdown()
+
+
 def test_reshard_drop_failpoint_aborts_but_commits_record():
     """A fault injected at the top of the reshard window aborts the
     membership change; the window still commits (no wedged serial lock,

@@ -405,10 +405,9 @@ def test_worker_drain_fences_queued_cuts(monkeypatch):
     seen = []
     monkeypatch.setattr(
         tl, "_compact_one",
-        lambda dp, mp, cp, ts, ma, ca: (time.sleep(0.02),
-                                        seen.append(ts)))
+        lambda cut, ts: (time.sleep(0.02), seen.append(ts)))
     for i in range(4):
-        tl.compact_cut(None, None, None, T0 + i, None, None)
+        tl.compact_cut(None, T0 + i)
     assert tl.drain(timeout=10.0)
     assert seen == [T0, T0 + 1, T0 + 2, T0 + 3]   # FIFO
     assert tl.stats()["pending_cuts"] == 0
@@ -424,17 +423,16 @@ def test_worker_close_without_drain_discards_queue(monkeypatch):
     done = []
     monkeypatch.setattr(
         tl, "_compact_one",
-        lambda dp, mp, cp, ts, ma, ca: (gate.wait(5.0),
-                                        done.append(ts)))
+        lambda cut, ts: (gate.wait(5.0), done.append(ts)))
     for i in range(3):
-        tl.compact_cut(None, None, None, T0 + i, None, None)
+        tl.compact_cut(None, T0 + i)
     tl.close(drain=False)
     gate.set()
     tl._worker.join(timeout=5.0)
     assert len(done) <= 1          # at most the in-flight cut
     assert tl.stats()["pending_cuts"] == 0
     # enqueue after close is a no-op
-    tl.compact_cut(None, None, None, T0 + 9, None, None)
+    tl.compact_cut(None, T0 + 9)
     assert tl.stats()["pending_cuts"] == 0
 
 
@@ -443,7 +441,7 @@ def test_worker_errors_are_counted_not_fatal(monkeypatch):
     monkeypatch.setattr(
         tl, "_compact_one",
         lambda *a: (_ for _ in ()).throw(RuntimeError("boom")))
-    tl.compact_cut(None, None, None, T0, None, None)
+    tl.compact_cut(None, T0)
     assert tl.drain(timeout=10.0)
     assert tl.compact_errors == 1
     assert tl.stats()["compact_errors"] == 1

@@ -587,8 +587,29 @@ class ConcurrencyIndex:
         cands = self.methods_by_name.get(name, [])
         if len(cands) == 1:
             return [cands[0]]
+        if cands and self._one_family(cands, name):
+            return list(cands)
         self.unresolved_calls += 1
         return []
+
+    def _one_family(self, cands: list, name: str) -> bool:
+        """Every definition of `name` is a method of ONE class
+        hierarchy, rooted at a class that defines it too: a call on an
+        untyped receiver (`for ar in arenas: ar.sync()`) can only be
+        dynamic dispatch inside that family, so it reaches every
+        override (DigestArena.sync and SetArena.sync through
+        _ArenaBase.sync)."""
+        def root(ci: ClassInfo) -> ClassInfo:
+            for base in ci.bases:
+                bc = self._class_by_name(base.rsplit(".", 1)[-1],
+                                         ci.module_stem)
+                if bc is not None and self.resolve_method(
+                        bc, name) is not None:
+                    return root(bc)
+            return ci
+        roots = {root(c.cls).qname if c.cls is not None else None
+                 for c in cands}
+        return len(roots) == 1 and None not in roots
 
     # -- lock identity -----------------------------------------------------
 

@@ -141,6 +141,27 @@ _SET_DEVICE_MIN_ROWS = 8
 
 
 class MetricAggregator:
+    # Every arena, once, by attribute (which is also its section of the
+    # crash checkpoint).  What a family is called elsewhere rides its
+    # arena class: `family` (key fingerprints, keys_* segments) and, for
+    # the histogram families, `ring` and `wire_field`.  A flush cuts
+    # them in this order (the scalar families, then the 16 KiB-a-row
+    # set registers, then the histogram columns).
+    _FAMILIES = ("gauges", "status", "counters", "sets",
+                 "digests", "moments", "compactors")
+    _HISTO_FAMILIES = _FAMILIES[4:]
+    # the families whose touched rows make a flush dispatch (a gauge or
+    # a status check alone emits from the snapshot)
+    _DISPATCHED = _FAMILIES[2:]
+    # the families that forward wire VECTORS and evaluate in a program
+    # of their own: (attribute, flush-segment prefix, fetched-quantiles
+    # key)
+    _VECTOR_FAMILIES = (("moments", "m", "m_qs"),
+                        ("compactors", "c", "comp_qs"))
+
+    def _arenas(self, names=_FAMILIES) -> list:
+        return [(name, getattr(self, name)) for name in names]
+
     def __init__(self,
                  percentiles: Optional[list[float]] = None,
                  aggregates: sm.HistogramAggregates = sm.HistogramAggregates(),
@@ -386,7 +407,7 @@ class MetricAggregator:
         # in-progress first-bucket compile as progress, not a hang
         # per-flush measured segments (snapshot/build/dispatch/device/
         # emit seconds + upload/readback bytes): the e2e decomposition
-        # the bench and self-metrics report
+        # the benchmark and self-metrics report
         self.last_flush_segments: dict = {}
         # rounded DOWN to a power of two: dense row counts are pow2, so
         # only pow2 chunk counts tile them exactly (a 3-way split would
@@ -419,12 +440,8 @@ class MetricAggregator:
         if query_window_slots > 0:
             from veneur_tpu.query.rings import WindowRing
             self.query_rings = {
-                "tdigest": WindowRing(query_window_slots,
-                                      query_slot_seconds),
-                "moments": WindowRing(query_window_slots,
-                                      query_slot_seconds),
-                "compactor": WindowRing(query_window_slots,
-                                        query_slot_seconds)}
+                ar.ring: WindowRing(query_window_slots, query_slot_seconds)
+                for _, ar in self._arenas(self._HISTO_FAMILIES)}
         # multi-resolution retention (veneur_tpu/retention/): the same
         # flush-cut snapshot parts the window ring holds also compact
         # UPWARD into coarser in-memory tiers (minute/hour/day rings of
@@ -1037,23 +1054,15 @@ class MetricAggregator:
                 # outgrows the dense cap); batch enough samples per tick
                 # to amortize the fixed numpy overheads
                 min_samples = 4096
-            if (self.digests.staged_count()
-                    + self.moments.staged_count()
-                    + self.compactors.staged_count()
-                    + self.sets.staged_count() < min_samples):
+            arenas = self._arenas()
+            if sum(ar.staged_count() for _, ar in arenas) < min_samples:
                 return False
-            # vnlint: disable=blocking-propagation (arena sync IS the
-            #   locked work by design — it consolidates host-side COO
-            #   staging; the asarray chains convert host lists, never
-            #   device arrays)
-            self.digests.sync()
-            # vnlint: disable=blocking-propagation (same as above:
-            #   host staging consolidation, no device wait)
-            self.moments.sync()
-            # vnlint: disable=blocking-propagation (same as above)
-            self.compactors.sync()
-            # vnlint: disable=blocking-propagation (same as above)
-            self.sets.sync()
+            for _, ar in arenas:
+                # vnlint: disable=blocking-propagation (arena sync IS
+                #   the locked work by design — it consolidates
+                #   host-side COO staging; the asarray chains convert
+                #   host lists, never device arrays)
+                ar.sync()
             if self.flush_resident:
                 # resident arenas: mirror the freshly-consolidated
                 # prefix to the device NOW, inside the interval — this
@@ -1066,9 +1075,6 @@ class MetricAggregator:
 
     # -- crash checkpoint (core/checkpoint.py) -----------------------------
 
-    _FAMILIES = ("digests", "moments", "compactors", "sets",
-                 "counters", "gauges", "status")
-
     def checkpoint_state(self) -> tuple[dict, dict]:
         """One coherent cut of every arena (plus unique-ts registers and
         the cardinality quota ledger), taken under the aggregator lock
@@ -1076,16 +1082,12 @@ class MetricAggregator:
         checkpoint.  Returns (JSON-able meta, numpy arrays); the disk
         format is core/checkpoint.py's concern."""
         with self.lock:
-            # vnlint: disable=blocking-propagation (arena sync is
-            #   host-side COO consolidation — asarray of host lists,
-            #   no device wait; same rationale as sync_staged)
-            self.digests.sync()
-            # vnlint: disable=blocking-propagation (same as above)
-            self.moments.sync()
-            # vnlint: disable=blocking-propagation (same as above)
-            self.compactors.sync()
-            # vnlint: disable=blocking-propagation (same as above)
-            self.sets.sync()
+            arenas = self._arenas()
+            for _, ar in arenas:
+                # vnlint: disable=blocking-propagation (arena sync is
+                #   host-side COO consolidation — asarray of host lists,
+                #   no device wait; same rationale as sync_staged)
+                ar.sync()
             meta: dict = {"processed": self.processed,
                           "imported": self.imported,
                           "families": {}}
@@ -1093,8 +1095,7 @@ class MetricAggregator:
             # LOCK-HELD: C-speed captures only; the per-key Python
             # rendering runs after release so ingest is never queued
             # behind O(keys) row formatting
-            caps = {name: getattr(self, name).checkpoint_capture()
-                    for name in self._FAMILIES}
+            caps = {name: ar.checkpoint_capture() for name, ar in arenas}
             if self.unique_ts is not None:
                 arrays["unique_ts/regs"] = self.unique_ts.regs.copy()
             if self.cardinality is not None:
@@ -1222,11 +1223,10 @@ class MetricAggregator:
         # per-family touched-key counts ride the segment dict so the
         # flush timeline (and the flush.* self-metric gauges) can relate
         # segment times to interval size
-        seg["keys_digest"] = len(snap["digests"]["rows"])
-        seg["keys_moments"] = len(snap["moments"]["rows"])
-        seg["keys_compactor"] = len(snap["compactors"]["rows"])
-        seg["keys_counter"] = len(snap["counters"]["rows"])
-        seg["keys_set"] = len(snap["sets"]["rows"])
+        touched = 0
+        for name, ar in self._arenas(self._DISPATCHED):
+            seg["keys_" + ar.family] = n = len(snap[name]["rows"])
+            touched += n
         # rows whose estimate the chip computes this flush
         # (_dispatch_sets); 0 on every flush that launches no set program
         seg["set_rows_device"] = 0
@@ -1247,12 +1247,7 @@ class MetricAggregator:
         # later flush off by one — the gather itself decides (all-idle
         # => zero-shape program).
         multi_mesh = self.mesh is not None and jax.process_count() > 1
-        idle = (not multi_mesh
-                and len(snap["digests"]["rows"]) == 0
-                and len(snap["moments"]["rows"]) == 0
-                and len(snap["compactors"]["rows"]) == 0
-                and len(snap["sets"]["rows"]) == 0
-                and len(snap["counters"]["rows"]) == 0
+        idle = (not multi_mesh and touched == 0
                 and (not snap["have_uts"]
                      or snap["uts_host"] is not None))
         try:
@@ -1297,8 +1292,10 @@ class MetricAggregator:
         self._emit_status(res, snap, now)
         self._emit_sets(res, snap, host, is_local, now)
         self._emit_digests(res, snap, host, is_local, now)
-        self._emit_moments(res, snap, host, is_local, now)
-        self._emit_compactors(res, snap, host, is_local, now)
+        for name, _, qs_key in self._VECTOR_FAMILIES:
+            if len(snap[name]["rows"]):
+                self._emit_vectors(res, snap[name], host[qs_key],
+                                   getattr(self, name), is_local, now)
         if "m_resid" in host and len(host["m_resid"]):
             # solver-convergence observability (sketch.* self-metrics)
             self.last_moments_resid = float(
@@ -1313,25 +1310,22 @@ class MetricAggregator:
         # rather than dispatch so the first query's lazy slot
         # finalization (name-hash build + staged-COO sort) lands in
         # the inter-flush gap instead of overlapping the in-flight
-        # flush.  Two O(1) deque appends; empty intervals rotate too,
+        # flush.  O(1) deque appends; empty intervals rotate too,
         # so the staleness contract (answers cover data up to the last
         # completed cut) holds through idle periods.
         if self.query_rings is not None:
             cut_ts = snap["query_cut_ts"]
-            self.query_rings["tdigest"].rotate(snap["digests"], cut_ts)
-            self.query_rings["moments"].rotate(snap["moments"], cut_ts)
-            self.query_rings["compactor"].rotate(snap["compactors"],
-                                                 cut_ts)
+            cut = {ar.ring: (snap[name], ar)
+                   for name, ar in self._arenas(self._HISTO_FAMILIES)}
+            for ring, (part, _) in cut.items():
+                self.query_rings[ring].rotate(part, cut_ts)
             # the retention timeline compacts the SAME immutable cut
             # upward into its coarser tiers (summarized per-key state,
             # not part references — the part's lifetime stays bound to
             # the ring).  Runs at emit, off the ingest lock, like the
             # rotation it rides.
             if self.retention is not None:
-                self.retention.compact_cut(
-                    snap["digests"], snap["moments"],
-                    snap["compactors"], cut_ts,
-                    self.moments, self.compactors)
+                self.retention.compact_cut(cut, cut_ts)
         return res
 
     @staticmethod
@@ -1644,115 +1638,82 @@ class MetricAggregator:
             uniform = dpart["uniform"]
             donate = not is_local
             rpart = dpart.pop("resident", None)
+            n_chunks = 1
+            t0 = time.perf_counter()
             if rpart is not None and not rpart["dirty"]:
                 # resident delta path: the dense matrices assemble ON
                 # DEVICE from the interval's streamed chunks plus the
                 # tail (arena.assemble_resident) — the critical-path
                 # upload is the dense-id map + tail; everything else
                 # already crossed the link during the interval
-                # (amortized_bytes vs upload_bytes is the bench's
-                # upload_amortized_pct)
-                t0 = time.perf_counter()
-                dvd, dwd, mmd, critical = \
-                    self.digests.assemble_resident(
-                        rpart, dpart["staged"], dpart["rows"],
-                        dpart["d_min"], dpart["d_max"], uniform,
-                        donate)
-                seg["build_s"] = time.perf_counter() - t0
-                seg["layout_s"] = 0.0
+                *dev, critical = self.digests.assemble_resident(
+                    rpart, dpart["staged"], dpart["rows"],
+                    dpart["d_min"], dpart["d_max"], uniform, donate)
                 seg["resident"] = 1.0
                 seg["amortized_bytes"] = (
                     seg.get("amortized_bytes", 0)
                     + rpart["streamed_bytes"])
-                seg["upload_bytes"] = (seg.get("upload_bytes", 0)
-                                       + critical)
-                t0 = time.perf_counter()
-                shape = (int(dvd.shape[0]), int(dvd.shape[1]))
-                if uniform:
-                    fn = (self.flush_fn.depth_variant_donated
-                          if donate else self.flush_fn.depth_variant)
-                    with self._CompileGuard(
-                            self, (shape, True, donate)):
-                        outs = [fn(dvd, dwd, self._pct_arr)]
-                else:
-                    with self._CompileGuard(
-                            self, (shape, False, donate)):
-                        outs = [self.flush_fn(dvd, dwd, mmd,
-                                              self._pct_arr,
-                                              uniform=False,
-                                              donate=donate)]
-                seg["dispatch_s"] = time.perf_counter() - t0
-                pend.update(outs=outs, n_chunks=1, uniform=uniform,
-                            first_dev=None if donate else (dvd, dwd))
-                return pend
-            t0 = time.perf_counter()
-            dv, dw, minmax = self.digests.build_dense(
-                dpart["staged"], dpart["rows"],
-                dpart["d_min"], dpart["d_max"], uniform=uniform)
-            # uniform intervals: dw is the [U] int16 depth vector, not
-            # the [U, D] weight matrix, and minmax stays host-side —
-            # roughly half the build and the uploaded bytes
+                n_rows = int(dev[0].shape[0])
+
+                def operands(sl):
+                    return dev
+            else:
+                dv, dw, minmax = self.digests.build_dense(
+                    dpart["staged"], dpart["rows"],
+                    dpart["d_min"], dpart["d_max"], uniform=uniform)
+                # uniform intervals: dw is the [U] int16 depth vector,
+                # not the [U, D] weight matrix, and minmax stays
+                # host-side — roughly half the build and the uploaded
+                # bytes
+                critical = (dv.nbytes + dw.nbytes
+                            + (0 if uniform else minmax.nbytes))
+                n_rows = dv.shape[0]
+                # Upload/evaluate/readback overlap (the _dma_pipeline
+                # double buffer lifted to the host<->HBM boundary): a
+                # big GLOBAL-tier flush splits into row chunks — chunk
+                # i+1's upload rides the transfer engine while chunk
+                # i's program runs and chunk i-1's readback drains
+                # (copy_to_host_async below), with at most _delta_nbuf
+                # chunks in flight before the host blocks.  Forwarding
+                # tiers keep one piece (the digest export gathers from
+                # the whole dense matrix).
+                if not is_local:
+                    if (self._delta_chunk
+                            and n_rows >= 2 * self._delta_chunk):
+                        # explicit rows-per-chunk override
+                        # (flush_delta_chunk_keys); pow2 over pow2 rows
+                        # always tiles exactly
+                        n_chunks = n_rows // self._delta_chunk
+                    elif (self._upload_chunks > 1 and n_rows
+                            >= self._upload_chunks * _CHUNK_MIN_ROWS):
+                        n_chunks = self._upload_chunks
+
+                def operands(sl):
+                    if uniform:
+                        return (*self.digests.put_dense_uniform(
+                            dv[sl], dw[sl]), None)
+                    return self.digests.put_dense(dv[sl], dw[sl],
+                                                  minmax[:, sl])
             seg["build_s"] = time.perf_counter() - t0
-            seg["upload_bytes"] = (
-                seg.get("upload_bytes", 0) + dv.nbytes + dw.nbytes
-                + (0 if uniform else minmax.nbytes))
-            # Upload/evaluate/readback overlap (the _dma_pipeline
-            # double buffer lifted to the host<->HBM boundary): a big
-            # GLOBAL-tier flush splits into row chunks — chunk i+1's
-            # upload rides the transfer engine while chunk i's program
-            # runs and chunk i-1's readback drains (copy_to_host_async
-            # below), with at most _delta_nbuf chunks in flight before
-            # the host blocks.  Forwarding tiers keep one piece (the
-            # digest export gathers from the whole dense matrix).
-            n_chunks = 1
-            if not is_local:
-                if (self._delta_chunk
-                        and dv.shape[0] >= 2 * self._delta_chunk):
-                    # explicit rows-per-chunk override
-                    # (flush_delta_chunk_keys); pow2 over pow2 rows
-                    # always tiles exactly
-                    n_chunks = dv.shape[0] // self._delta_chunk
-                elif (self._upload_chunks > 1 and dv.shape[0]
-                        >= self._upload_chunks * _CHUNK_MIN_ROWS):
-                    n_chunks = self._upload_chunks
-            rows_per = dv.shape[0] // n_chunks
+            seg["upload_bytes"] = seg.get("upload_bytes", 0) + critical
+            rows_per = n_rows // n_chunks
             layout_s = dispatch_s = 0.0
             outs = []
             chunk_stats = [] if n_chunks > 1 else None
             first_dev = None
             t_dispatch0 = None
             for c in range(n_chunks):
-                sl = slice(c * rows_per, (c + 1) * rows_per)
                 t0 = time.perf_counter()
-                if uniform:
-                    dvd, depd = self.digests.put_dense_uniform(
-                        dv[sl], dw[sl])
-                    up_s = time.perf_counter() - t0
-                    layout_s += up_s
-                    t0 = time.perf_counter()
-                    if first_dev is None:
-                        first_dev = (dvd, depd)
-                    fn = (self.flush_fn.depth_variant_donated if donate
-                          else self.flush_fn.depth_variant)
-                    with self._CompileGuard(
-                            self, (dv[sl].shape, True, donate)):
-                        outs.append(fn(dvd, depd, self._pct_arr))
-                else:
-                    dvd, dwd, mmd = self.digests.put_dense(
-                        dv[sl], dw[sl], minmax[:, sl])
-                    up_s = time.perf_counter() - t0
-                    layout_s += up_s
-                    t0 = time.perf_counter()
-                    if first_dev is None:
-                        first_dev = (dvd, dwd)
-                    with self._CompileGuard(
-                            self, (dv[sl].shape, False, donate)):
-                        outs.append(self.flush_fn(dvd, dwd, mmd,
-                                                  self._pct_arr,
-                                                  uniform=False,
-                                                  donate=donate))
-                if t_dispatch0 is None:
+                dvd, dwd, mmd = operands(
+                    slice(c * rows_per, (c + 1) * rows_per))
+                up_s = time.perf_counter() - t0
+                layout_s += up_s
+                t0 = time.perf_counter()
+                if first_dev is None:
+                    first_dev = (dvd, dwd)
                     t_dispatch0 = t0
+                outs.append(self._launch_digests(dvd, dwd, mmd, uniform,
+                                                 donate))
                 d_s = time.perf_counter() - t0
                 dispatch_s += d_s
                 if chunk_stats is not None:
@@ -1868,7 +1829,7 @@ class MetricAggregator:
                                    + minmax.nbytes)
             inputs, flat_dev, set_regs_out, donate = self._launch_meshed(
                 dv, dw, minmax, snap["sets"]["lanes"],
-                snap["counter_planes"](), snap["uts_regs"], g_uniform,
+                snap["counters"]["planes"](), snap["uts_regs"], g_uniform,
                 is_local, seg)
             dvd, dwd = inputs.dense_v, inputs.dense_w
             t0 = time.perf_counter()
@@ -1889,6 +1850,22 @@ class MetricAggregator:
                 crows=crows, srows=srows,
                 dense_dev=None if donate else (dvd, dwd))
             return pend
+
+    def _launch_digests(self, dvd, dwd, mmd, uniform: bool, donate: bool):
+        """LAUNCH the unmeshed digest program on one chunk's device
+        operands — the one way it is ever called, whether the operands
+        were assembled on the device (resident) or built on the host
+        and put: the guard's key, the program form (uniform: dwd is the
+        depth vector and minmax stays on the host) and the donation
+        rule live here."""
+        shape = (int(dvd.shape[0]), int(dvd.shape[1]))
+        with self._CompileGuard(self, (shape, bool(uniform), donate)):
+            if uniform:
+                fn = (self.flush_fn.depth_variant_donated if donate
+                      else self.flush_fn.depth_variant)
+                return fn(dvd, dwd, self._pct_arr)
+            return self.flush_fn(dvd, dwd, mmd, self._pct_arr,
+                                 uniform=False, donate=donate)
 
     def _launch_meshed(self, dv, dw, minmax, lanes, planes, uts_regs,
                        uniform: bool, is_local: bool, seg: dict):
@@ -1977,6 +1954,7 @@ class MetricAggregator:
         m = self.moments
         uniform = mpart["uniform"]
         rpart = mpart.pop("resident", None)
+        t0 = time.perf_counter()
         if rpart is not None and not rpart["dirty"]:
             # resident delta path (flush_resident_arenas): dense sample
             # matrices assemble on device from the streamed chunks +
@@ -1984,45 +1962,29 @@ class MetricAggregator:
             # and the dense-id/tail cross the link at flush time.  The
             # moments program never donates, so the scatter chain runs
             # its copying form (donate=False).
-            t0 = time.perf_counter()
-            dvd, dwd, _, critical = m.assemble_resident(
+            dv, dw, _, critical = m.assemble_resident(
                 rpart, mpart["staged"], mpart["rows"],
                 mpart["d_min"], mpart["d_max"], uniform, donate=False)
-            imp, ab, lab = m.import_contrib(mpart, int(dvd.shape[0]))
-            seg["m_build_s"] = time.perf_counter() - t0
             seg["resident"] = 1.0
             seg["amortized_bytes"] = (seg.get("amortized_bytes", 0)
                                       + rpart["streamed_bytes"])
-            seg["upload_bytes"] = (seg.get("upload_bytes", 0)
-                                   + critical + imp.nbytes + ab.nbytes
-                                   + lab.nbytes)
-            t0 = time.perf_counter()
-            abd, labd, impd = (jnp.asarray(ab), jnp.asarray(lab),
-                               jnp.asarray(imp))
-            shape = (int(dvd.shape[0]), int(dvd.shape[1]))
-            with self._CompileGuard(self, ("moments", shape, uniform)):
-                if uniform:
-                    out = self.moments_fn.depth_variant(
-                        dvd, dwd, abd, labd, impd, self._pct_arr)
-                else:
-                    out = self.moments_fn(dvd, dwd, abd, labd, impd,
-                                          self._pct_arr)
-            seg["m_dispatch_s"] = time.perf_counter() - t0
-            return {"out": out, "nm": nm}
-        t0 = time.perf_counter()
-        dv, dw, _ = m.build_dense(
-            mpart["staged"], mpart["rows"],
-            mpart["d_min"], mpart["d_max"], uniform=uniform)
-        imp, ab, lab = m.import_contrib(mpart, dv.shape[0])
+        else:
+            dv, dw, _ = m.build_dense(
+                mpart["staged"], mpart["rows"],
+                mpart["d_min"], mpart["d_max"], uniform=uniform)
+            critical = dv.nbytes + dw.nbytes
+        shape = (int(dv.shape[0]), int(dv.shape[1]))
+        imp, ab, lab = m.import_contrib(mpart, shape[0])
         seg["m_build_s"] = time.perf_counter() - t0
-        seg["upload_bytes"] = (seg.get("upload_bytes", 0) + dv.nbytes
-                               + dw.nbytes + imp.nbytes + ab.nbytes
-                               + lab.nbytes)
+        seg["upload_bytes"] = (seg.get("upload_bytes", 0) + critical
+                               + imp.nbytes + ab.nbytes + lab.nbytes)
         t0 = time.perf_counter()
+        # the one launch of the moments program: asarray puts a
+        # host-built matrix and passes a device-assembled one through
         dvd, dwd, abd, labd, impd = (
             jnp.asarray(dv), jnp.asarray(dw), jnp.asarray(ab),
             jnp.asarray(lab), jnp.asarray(imp))
-        with self._CompileGuard(self, ("moments", dv.shape, uniform)):
+        with self._CompileGuard(self, ("moments", shape, uniform)):
             if uniform:
                 out = self.moments_fn.depth_variant(
                     dvd, dwd, abd, labd, impd, self._pct_arr)
@@ -2030,7 +1992,7 @@ class MetricAggregator:
                 out = self.moments_fn(dvd, dwd, abd, labd, impd,
                                       self._pct_arr)
         seg["m_dispatch_s"] = time.perf_counter() - t0
-        return {"out": out, "nm": nm}
+        return {"out": out, "n": nm}
 
     def _dispatch_compactors(self, snap: dict) -> Optional[dict]:
         """Fold and LAUNCH the compactor-family read-off on the
@@ -2061,7 +2023,7 @@ class MetricAggregator:
         with self._CompileGuard(self, ("compactor", u_pad)):
             out = self.compactor_fn(cvd, ccd, csd, mmd, self._pct_arr)
         seg["c_dispatch_s"] = time.perf_counter() - t0
-        return {"out": out, "nc": nc}
+        return {"out": out, "n": nc}
 
     def _fetch_flush(self, snap: dict, pend: dict, seg: dict) -> dict:
         """Wait on a dispatched flush's device outputs and read them
@@ -2073,23 +2035,19 @@ class MetricAggregator:
         nd = pend["nd"]
         n_cols = len(self._pct_arr)  # median + configured percentiles
         host: dict = {}
-        mp = pend.get("moments")
-        if mp is not None:
+        for name, tag, qs_key in self._VECTOR_FAMILIES:
+            fp = pend.get(name)
+            if fp is None:
+                continue
             t0 = time.perf_counter()
-            mout = serving.fetch(mp["out"])
-            seg["m_device_s"] = time.perf_counter() - t0
+            out = serving.fetch(fp["out"])
+            seg[tag + "_device_s"] = time.perf_counter() - t0
             seg["readback_bytes"] = (seg.get("readback_bytes", 0)
-                                     + mout.nbytes)
-            host["m_qs"] = mout[:mp["nm"], :n_cols]
-            host["m_resid"] = mout[:mp["nm"], -1]
-        cpend = pend.get("compactors")
-        if cpend is not None:
-            t0 = time.perf_counter()
-            cout = serving.fetch(cpend["out"])
-            seg["c_device_s"] = time.perf_counter() - t0
-            seg["readback_bytes"] = (seg.get("readback_bytes", 0)
-                                     + cout.nbytes)
-            host["comp_qs"] = cout[:cpend["nc"], :n_cols]
+                                     + out.nbytes)
+            host[qs_key] = out[:fp["n"], :n_cols]
+            if name == "moments":
+                # the solver's residual rides the last column
+                host["m_resid"] = out[:fp["n"], -1]
         if not pend["meshed"]:
             if "set_rows_dev" in pend:
                 # resident set registers: exact u8 readback of the
@@ -2201,17 +2159,14 @@ class MetricAggregator:
         return host
 
     def _snapshot_and_reset(self) -> dict:
-        """Under lock: sync staging, snapshot state+metadata of touched
-        rows, reset.  Device tensors are immutable so the snapshot is a
-        reference; host arrays are fancy-index copies."""
-        d, s, c, g, st = (self.digests, self.sets, self.counters,
-                          self.gauges, self.status)
+        """Under lock: sync staging, cut every arena's part of touched
+        rows (arena.snapshot_part: copies, never aliases of live
+        state), reset.  The parts' columns are the arenas' own."""
         self._import_row_cache.clear()
+        arenas = self._arenas()
         t_sync = time.perf_counter()
-        d.sync()
-        self.moments.sync()
-        self.compactors.sync()
-        s.sync()
+        for _, ar in arenas:
+            ar.sync()
         sync_s = time.perf_counter() - t_sync
         snap = {"counts": (self.processed, self.imported),
                 "ledger": self._ledger}
@@ -2224,6 +2179,7 @@ class MetricAggregator:
             self.unique_ts = hll_mod.HLLSketch(self.unique_ts.p)
         else:
             uts = None
+        snap["uts_host"] = None
         if self.mesh is None:
             # nothing to pmax over without a mesh: estimate on host (the
             # digest-only program never sees these registers).  The
@@ -2231,176 +2187,15 @@ class MetricAggregator:
             # reduction runs in flush_dispatch AFTER the lock releases
             # (blocking-propagation finding: ingest threads were queued
             # behind a numpy reduction over 16 KiB of registers)
-            snap["uts_host"] = None
             snap["uts_raw"] = uts
             snap["uts_regs"] = None
         else:
-            snap["uts_host"] = None
             snap["uts_regs"] = self._uts_lanes(uts)
 
-        for name, ar in (("gauges", g), ("status", st)):
-            rows = ar.touched_rows()
-            snap[name] = {
-                "rows": rows,
-                "names": ar.name_col[rows],
-                "tags": ar.tags_col[rows],
-                "scopes": ar.scope_col[rows].copy(),
-                "values": ar.values[rows].copy(),
-            }
-        snap["status"]["messages"] = {
-            int(r): st.messages.get(int(r), "")
-            for r in snap["status"]["rows"]}
-        snap["status"]["hostnames"] = {
-            int(r): st.hostnames.get(int(r), "")
-            for r in snap["status"]["rows"]}
-
-        crows = c.touched_rows()
-        snap["counters"] = {
-            "rows": crows,
-            "names": c.name_col[crows],
-            "tags": c.tags_col[crows],
-            "scopes": c.scope_col[crows].copy(),
-        }
-        if self.mesh is None:
-            # no mesh => no psum; total the float64 host stripes directly
-            # (exact below 2^53, and no plane upload at all)
-            snap["counters"]["host_totals"] = c.values.sum(axis=0)[crows]
-            cvals = None
-        else:
-            snap["counters"]["host_totals"] = None
-            cvals = c.snapshot_values()
-        snap["counter_planes"] = lambda: c.planes_from(cvals)
-
-        srows = s.touched_rows()
-        snap["sets"] = {
-            "rows": srows,
-            "names": s.name_col[srows],
-            "tags": s.tags_col[srows],
-            "scopes": s.scope_col[srows].copy(),
-            # migration side lane (legacy blake2b imports): host-side
-            # estimates to max against the primary lane at emission
-            "legacy_ests": s.legacy_estimates(srows),
-        }
-        if s.host_regs is not None:
-            # host registers: under the lock, copy and nothing else.
-            # The estimate runs from the copy at dispatch, on the chip
-            # (_dispatch_sets); a forwarding tier marshals its MIXED
-            # rows from the same copy (post-reset)
-            if len(srows):
-                snap["sets"]["host_regs"] = s.host_regs_copy(srows)
-        elif self.mesh is not None or len(srows):
-            # device lanes — meshed, or unmeshed-resident
-            # (flush_resident_arenas): the flush reads the pinned lane
-            # snapshot (pmax-merge meshed, set_gather_rows resident) and
-            # resident estimates compute at FETCH time on the exact u8
-            # readback.  Meshed always pins (the SPMD program takes the
-            # full lane plane every flush); resident pins only when set
-            # rows were touched — an untouched interval dispatches no
-            # set gather, so nothing would ever read the snapshot
-            snap["sets"]["lanes"] = s.snapshot_lanes()
-
-        drows = d.touched_rows()
-        # uniform is captured BEFORE take_staged resets the tracking, and
-        # the resident mirror is consumed right after take_staged with
-        # its result (the tail's (row, pos) coordinates come from the
-        # same consolidated arrays)
-        d_uniform = d.staged_uniform
-        t_staged = time.perf_counter()
-        d_staged = d.take_staged()
-        staged_s = time.perf_counter() - t_staged
-        snap["digests"] = {
-            "rows": drows,
-            "names": d.name_col[drows],
-            # hash(name) mirror for the query plane's vectorized slot
-            # lookups (maintained incrementally at registration)
-            "name_hashes": d.name_hash_col[drows].copy(),
-            "tags": d.tags_col[drows],
-            "kinds": d.kind_col[drows],
-            "scopes": d.scope_col[drows].copy(),
-            # the interval's staged weighted points (consumed); the flush
-            # program evaluates them in one dense pass outside the lock
-            # (uniform selects the key-only sort network as a static
-            # program choice, ops/sorted_eval.py)
-            "uniform": d_uniform,
-            "staged": d_staged,
-            "resident": d.take_resident(d_staged),
-            "l_weight": d.l_weight[drows].copy(),
-            "l_min": d.l_min[drows].copy(),
-            "l_max": d.l_max[drows].copy(),
-            "l_sum": d.l_sum[drows].copy(),
-            "l_rsum": d.l_rsum[drows].copy(),
-            "d_min": d.d_min[drows].copy(),
-            "d_max": d.d_max[drows].copy(),
-            "d_rsum": d.d_rsum[drows].copy(),
-            "d_weight": d.d_weight[drows].copy(),
-            "d_sum": d.d_sum[drows].copy(),
-        }
-
-        m = self.moments
-        mrows = m.touched_rows()
-        m_uniform = m.staged_uniform
-        t_staged = time.perf_counter()
-        m_staged = m.take_staged()
-        staged_s += time.perf_counter() - t_staged
-        snap["moments"] = {
-            "rows": mrows,
-            "names": m.name_col[mrows],
-            "name_hashes": m.name_hash_col[mrows].copy(),
-            "tags": m.tags_col[mrows],
-            "kinds": m.kind_col[mrows],
-            "scopes": m.scope_col[mrows].copy(),
-            "uniform": m_uniform,
-            "staged": m_staged,
-            "resident": m.take_resident(m_staged),
-            "l_weight": m.l_weight[mrows].copy(),
-            "l_min": m.l_min[mrows].copy(),
-            "l_max": m.l_max[mrows].copy(),
-            "l_sum": m.l_sum[mrows].copy(),
-            "l_rsum": m.l_rsum[mrows].copy(),
-            "d_min": m.d_min[mrows].copy(),
-            "d_max": m.d_max[mrows].copy(),
-            "d_rsum": m.d_rsum[mrows].copy(),
-            "d_weight": m.d_weight[mrows].copy(),
-            "d_sum": m.d_sum[mrows].copy(),
-            "d_logn": m.d_logn[mrows].copy(),
-            "ivec": m.ivec[mrows].copy(),
-            "iv_a": m.iv_a[mrows].copy(),
-            "iv_b": m.iv_b[mrows].copy(),
-        }
-
-        cp = self.compactors
-        prows = cp.touched_rows()
-        t_staged = time.perf_counter()
-        cp_staged = cp.take_staged()
-        staged_s += time.perf_counter() - t_staged
-        snap["compactors"] = {
-            "rows": prows,
-            "names": cp.name_col[prows],
-            "name_hashes": cp.name_hash_col[prows].copy(),
-            "tags": cp.tags_col[prows],
-            "kinds": cp.kind_col[prows],
-            "scopes": cp.scope_col[prows].copy(),
-            # staged points fold into the SNAPSHOT ladder copies at
-            # dispatch (arena.fold_flush, outside the lock); the live
-            # ladders reset below, so an overlapping interval can
-            # never alias the in-flight fold
-            "staged": cp_staged,
-            "cvals": cp.cvals[prows].copy(),
-            "ccnt": cp.ccnt[prows].copy(),
-            "ccomps": cp.ccomps[prows].copy(),
-            "cclip": cp.cclip[prows].copy(),
-            "l_weight": cp.l_weight[prows].copy(),
-            "l_min": cp.l_min[prows].copy(),
-            "l_max": cp.l_max[prows].copy(),
-            "l_sum": cp.l_sum[prows].copy(),
-            "l_rsum": cp.l_rsum[prows].copy(),
-            "d_min": cp.d_min[prows].copy(),
-            "d_max": cp.d_max[prows].copy(),
-            "d_rsum": cp.d_rsum[prows].copy(),
-            "d_weight": cp.d_weight[prows].copy(),
-            "d_sum": cp.d_sum[prows].copy(),
-        }
-
+        staged_s = 0.0
+        for name, ar in arenas:
+            snap[name] = ar.snapshot_part()
+            staged_s += ar.snapshot_staged_s
         # what the syncs and the take_staged consolidations took, for
         # flush_dispatch's split of snapshot_s
         snap["part_seconds"] = (sync_s, staged_s)
@@ -2412,21 +2207,11 @@ class MetricAggregator:
         # could tear against a concurrent registration and trip a
         # spurious lockstep error)
         snap["key_fingerprints"] = {
-            "digest": (d.keyset_checksum, d.key_checksum),
-            "moments": (m.keyset_checksum, m.key_checksum),
-            "compactor": (cp.keyset_checksum, cp.key_checksum),
-            "counter": (c.keyset_checksum, c.key_checksum),
-            "gauge": (g.keyset_checksum, g.key_checksum),
-            "set": (s.keyset_checksum, s.key_checksum),
-            "status": (st.keyset_checksum, st.key_checksum),
-        }
+            ar.family: (ar.keyset_checksum, ar.key_checksum)
+            for _, ar in arenas}
 
-        for ar, rows in ((c, crows),
-                         (g, snap["gauges"]["rows"]),
-                         (st, snap["status"]["rows"]),
-                         (s, srows), (d, drows), (m, mrows),
-                         (cp, prows)):
-            ar.reset_rows(rows)
+        for name, ar in arenas:
+            ar.reset_rows(snap[name]["rows"])
             ar.end_interval()
         if self.cardinality is not None:
             self._cardinality_end_interval()
@@ -2691,27 +2476,23 @@ class MetricAggregator:
         self._emit_histo_aggregates(res, part, qs, counts, sums,
                                     is_local, now, forwarded)
 
-    def _emit_moments(self, res, snap, host, is_local, now):
-        """Moments-family emission: identical aggregate/percentile
-        surface to the digest family (sinks cannot tell the families
-        apart), with forwarding as wire moments VECTORS instead of
-        centroid lists."""
-        part = snap["moments"]
-        rows = part["rows"]
-        if len(rows) == 0:
-            return
-        n = len(rows)
-        qs = host["m_qs"]
+    def _emit_vectors(self, res, part, qs, arena, is_local, now):
+        """Moments- and compactor-family emission: the same aggregate /
+        percentile surface as the digest family (sinks cannot tell the
+        families apart), with forwarding as wire VECTORS in the
+        ForwardMetric field the arena names (`wire_field`) instead of
+        centroid lists — the moments vector, or the compactor's
+        self-describing header + level items (the folded flush state,
+        shared with the eval via arena.fold_flush's part cache)."""
         counts = np.asarray(part["d_weight"], np.float64)
         sums = np.asarray(part["d_sum"], np.float64)
         if is_local:
             forwarded = part["scopes"] != int(MetricScope.LOCAL_ONLY)
         else:
-            forwarded = np.zeros(n, bool)
+            forwarded = np.zeros(len(part["rows"]), bool)
         if forwarded.any():
             fidx = np.nonzero(forwarded)[0]
-            vecs = self.moments.assemble_vectors(part, part["staged"],
-                                                 fidx)
+            vecs = arena.assemble_vectors(part, part["staged"], fidx)
             bases = part["names"].tolist()
             tags = part["tags"].tolist()
             kinds = part["kinds"]
@@ -2720,41 +2501,7 @@ class MetricAggregator:
                 res.forward.append(sm.ForwardMetric(
                     name=bases[i], tags=tags[i], kind=kinds[i],
                     scope=MetricScope(int(scopes[i])),
-                    moments=vecs[j].tolist()))
-        self._emit_histo_aggregates(res, part, qs, counts, sums,
-                                    is_local, now, forwarded)
-
-    def _emit_compactors(self, res, snap, host, is_local, now):
-        """Compactor-family emission: the same aggregate/percentile
-        surface as the other histogram families, with forwarding as
-        wire ladder VECTORS (self-describing header + level items —
-        the folded flush state, shared with the eval via
-        arena.fold_flush's part cache)."""
-        part = snap["compactors"]
-        rows = part["rows"]
-        if len(rows) == 0:
-            return
-        n = len(rows)
-        qs = host["comp_qs"]
-        counts = np.asarray(part["d_weight"], np.float64)
-        sums = np.asarray(part["d_sum"], np.float64)
-        if is_local:
-            forwarded = part["scopes"] != int(MetricScope.LOCAL_ONLY)
-        else:
-            forwarded = np.zeros(n, bool)
-        if forwarded.any():
-            fidx = np.nonzero(forwarded)[0]
-            vecs = self.compactors.assemble_vectors(
-                part, part["staged"], fidx)
-            bases = part["names"].tolist()
-            tags = part["tags"].tolist()
-            kinds = part["kinds"]
-            scopes = part["scopes"]
-            for j, i in enumerate(fidx.tolist()):
-                res.forward.append(sm.ForwardMetric(
-                    name=bases[i], tags=tags[i], kind=kinds[i],
-                    scope=MetricScope(int(scopes[i])),
-                    compactor=vecs[j].tolist()))
+                    **{arena.wire_field: vecs[j].tolist()}))
         self._emit_histo_aggregates(res, part, qs, counts, sums,
                                     is_local, now, forwarded)
 

@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import contextlib
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -90,6 +91,21 @@ class _ArenaBase:
     """Key dictionary + row lifecycle shared by all arenas."""
 
     _TRACK_KIND = False  # DigestArena opts in (kind_col)
+
+    # what the family is called outside the arena: its key fingerprint
+    # in the lockstep gather and its keys_* flush segment
+    family = ""
+    # the per-row host columns a flush snapshots and then resets, as
+    # (attribute, the value an untouched row holds), indexed by row
+    # along axis 0.  The ONE list of a family's columns: snapshot_part,
+    # reset_rows and the histogram arenas' growth walk it, subclasses
+    # extend it, and the part's keys are the attribute names — the
+    # format import_contrib / fold_flush / assemble_vectors here,
+    # query/rings.py and retention/timeline.py read back.
+    _COLUMNS: tuple = ()
+    # seconds the last snapshot_part() spent consolidating staged
+    # points (take_staged); 0 for a family that stages none
+    snapshot_staged_s = 0.0
 
     def __init__(self, capacity: int = _INITIAL_CAPACITY):
         self.capacity = capacity
@@ -200,8 +216,15 @@ class _ArenaBase:
         self._free.extend(range(self.capacity - 1, old - 1, -1))
         self._grow_state(old)
 
-    def _grow_state(self, old_capacity: int) -> None:
-        raise NotImplementedError
+    def _grow_state(self, old: int) -> None:
+        """Extend the family's per-row state by `old` fresh rows."""
+        for name, fill in self._COLUMNS:
+            setattr(self, name, self._grown(getattr(self, name), old, fill))
+
+    @staticmethod
+    def _grown(a: np.ndarray, old: int, fill) -> np.ndarray:
+        return np.concatenate(
+            [a, np.full((old,) + a.shape[1:], fill, a.dtype)])
 
     def row_for(self, key: MetricKey, scope: MetricScope,
                 tags: list[str]) -> int:
@@ -227,6 +250,37 @@ class _ArenaBase:
 
     def touched_rows(self) -> np.ndarray:
         return np.nonzero(self.touched)[0]
+
+    def sync(self) -> None:
+        """Fold staged input into the arena's state (the staging
+        families override; the rest stage nothing)."""
+
+    def staged_count(self) -> int:
+        return 0
+
+    def snapshot_part(self) -> dict:
+        """The flush's cut of this arena — call under the aggregator
+        lock, after sync() and before reset_rows(part["rows"]): the
+        touched rows' identity columns and a copy of every _COLUMNS
+        column, none aliasing live state, so the flush evaluates and
+        emits from the part outside the lock while ingest refills the
+        rows.  Families add what their flush needs on top."""
+        rows = self.touched_rows()
+        part = {"rows": rows,
+                "names": self.name_col[rows],
+                "tags": self.tags_col[rows],
+                "scopes": self.scope_col[rows].copy()}
+        if self.kind_col is not None:
+            part["kinds"] = self.kind_col[rows]
+        for name, _ in self._COLUMNS:
+            part[name] = getattr(self, name)[rows].copy()
+        return part
+
+    def reset_rows(self, rows: np.ndarray) -> None:
+        if len(rows) == 0:
+            return
+        for name, fill in self._COLUMNS:
+            getattr(self, name)[rows] = fill
 
     def release_keys(self, dks: list) -> int:
         """Immediately recycle the rows of the given (MetricKey, scope)
@@ -456,6 +510,8 @@ class CounterArena(_ArenaBase):
     axis — the device-collective form of Counter.Merge
     (`samplers/samplers.go:143-145` / `worker.go:402-459`)."""
 
+    family = "counter"
+
     def __init__(self, capacity: int = _INITIAL_CAPACITY, mesh=None):
         super().__init__(capacity)
         self.n_lanes = self._init_mesh_lanes(mesh, "counter")
@@ -482,10 +538,21 @@ class CounterArena(_ArenaBase):
         np.add.at(self.values, (rows % self.n_lanes, rows), vals)
         self.touched[rows] = True
 
-    def snapshot_values(self) -> np.ndarray:
-        """Cheap host copy of the lane stripes (call under the aggregator
-        lock, before reset zeroes them in place)."""
-        return self.values.copy()
+    def snapshot_part(self) -> dict:
+        part = super().snapshot_part()
+        if self.mesh is None:
+            # no mesh => no psum; total the float64 host stripes directly
+            # (exact below 2^53, and no plane upload at all)
+            part["host_totals"] = self.values.sum(axis=0)[part["rows"]]
+            vals = None
+        else:
+            # cheap host copy of the lane stripes, before reset zeroes
+            # them in place; the (hi, lo) split and the upload wait for
+            # the dispatch, outside the lock
+            part["host_totals"] = None
+            vals = self.values.copy()
+        part["planes"] = lambda: self.planes_from(vals)
+        return part
 
     def planes_from(self, vals: np.ndarray):
         """Device-put the (hi, lo) split of snapshotted lane stripes as
@@ -527,12 +594,12 @@ class CounterArena(_ArenaBase):
 class GaugeArena(_ArenaBase):
     """Last-write-wins gauges (samplers/samplers.go:152-202)."""
 
+    family = "gauge"
+    _COLUMNS = (("values", 0),)
+
     def __init__(self, capacity: int = _INITIAL_CAPACITY):
         super().__init__(capacity)
         self.values = np.zeros(capacity, np.float64)
-
-    def _grow_state(self, old: int) -> None:
-        self.values = np.concatenate([self.values, np.zeros(old, np.float64)])
 
     def sample(self, row: int, value: float) -> None:
         self.values[row] = value
@@ -556,9 +623,6 @@ class GaugeArena(_ArenaBase):
         self.values[rows] = vals
         self.touched[rows] = True
 
-    def reset_rows(self, rows: np.ndarray) -> None:
-        self.values[rows] = 0
-
     def _checkpoint_arrays(self) -> dict:
         return {"values": self.values.copy()}
 
@@ -570,14 +634,14 @@ class StatusArena(_ArenaBase):
     """Service-check state: last value + message + hostname
     (samplers/samplers.go:210-231)."""
 
+    family = "status"
+    _COLUMNS = (("values", 0),)
+
     def __init__(self, capacity: int = _INITIAL_CAPACITY):
         super().__init__(capacity)
         self.values = np.zeros(capacity, np.float64)
         self.messages: dict[int, str] = {}
         self.hostnames: dict[int, str] = {}
-
-    def _grow_state(self, old: int) -> None:
-        self.values = np.concatenate([self.values, np.zeros(old, np.float64)])
 
     def sample(self, row: int, value: float, message: str,
                hostname: str) -> None:
@@ -585,8 +649,15 @@ class StatusArena(_ArenaBase):
         self.messages[row] = message
         self.hostnames[row] = hostname
 
+    def snapshot_part(self) -> dict:
+        part = super().snapshot_part()
+        rows = part["rows"].tolist()
+        part["messages"] = {r: self.messages.get(r, "") for r in rows}
+        part["hostnames"] = {r: self.hostnames.get(r, "") for r in rows}
+        return part
+
     def reset_rows(self, rows: np.ndarray) -> None:
-        self.values[rows] = 0
+        super().reset_rows(rows)
         for r in rows:
             self.messages.pop(int(r), None)
             self.hostnames.pop(int(r), None)
@@ -624,6 +695,8 @@ class SetArena(_ArenaBase):
     program pmaxes the lanes over ICI and estimates all rows at once —
     the collective form of Set.Merge (`samplers/samplers.go:299-311`).
     """
+
+    family = "set"
 
     def __init__(self, capacity: int = _INITIAL_CAPACITY,
                  precision: int = hll_mod.DEFAULT_PRECISION, mesh=None,
@@ -903,6 +976,32 @@ class SetArena(_ArenaBase):
         out[n:] = 0
         return out
 
+    def snapshot_part(self) -> dict:
+        part = super().snapshot_part()
+        rows = part["rows"]
+        # migration side lane (legacy blake2b imports): host-side
+        # estimates to max against the primary lane at emission
+        part["legacy_ests"] = self.legacy_estimates(rows)
+        if self.host_regs is not None:
+            # host registers: under the lock, copy and nothing else.
+            # The estimate runs from the copy at dispatch, on the chip
+            # (aggregator._dispatch_sets); a forwarding tier marshals
+            # its MIXED rows from the same copy (post-reset)
+            if len(rows):
+                part["host_regs"] = self.host_regs_copy(rows)
+        elif self.mesh is not None or len(rows):
+            # device lanes — meshed, or unmeshed-resident
+            # (flush_resident_arenas): the flush reads the pinned lane
+            # snapshot (pmax-merge meshed, set_gather_rows resident) and
+            # resident estimates compute at FETCH time on the exact u8
+            # readback.  Meshed always pins (the SPMD program takes the
+            # full lane plane every flush); resident pins only when set
+            # rows were touched — an untouched interval dispatches no
+            # set gather, so nothing would ever read the snapshot.
+            # Whoever holds the part owes the unpin_lanes()
+            part["lanes"] = self.snapshot_lanes()
+        return part
+
     def reset_rows(self, rows: np.ndarray) -> None:
         self.sync()
         if self._legacy_regs:
@@ -1016,6 +1115,19 @@ class DigestArena(_ArenaBase):
     """
 
     _TRACK_KIND = True  # forwarding needs histogram-vs-timer per row
+
+    family = "digest"
+    # a histogram family's window ring (query plane, retention) and the
+    # ForwardMetric field its wire vectors travel in (the t-digest
+    # forwards centroid lists instead)
+    ring = "tdigest"
+    wire_field: Optional[str] = None
+    # the local-samples-only accumulators, then the true digest scalars
+    # (local samples + imports); __init__ says what each holds
+    _COLUMNS = (("l_weight", 0), ("l_min", np.inf), ("l_max", -np.inf),
+                ("l_sum", 0), ("l_rsum", 0),
+                ("d_min", np.inf), ("d_max", -np.inf), ("d_rsum", 0),
+                ("d_weight", 0), ("d_sum", 0))
 
     def __init__(self, capacity: int = _INITIAL_CAPACITY,
                  compression: float = td.DEFAULT_COMPRESSION,
@@ -1142,21 +1254,10 @@ class DigestArena(_ArenaBase):
                          if self.resident else None)
 
     def _grow_state(self, old: int) -> None:
-        pad = lambda a, fill: np.concatenate(
-            [a, np.full(old, fill, a.dtype)])
-        self.d_min = pad(self.d_min, np.inf)
-        self.d_max = pad(self.d_max, -np.inf)
-        self.d_rsum = pad(self.d_rsum, 0)
-        self.d_weight = pad(self.d_weight, 0)
-        self.d_sum = pad(self.d_sum, 0)
-        self.l_weight = pad(self.l_weight, 0)
-        self.l_min = pad(self.l_min, np.inf)
-        self.l_max = pad(self.l_max, -np.inf)
-        self.l_sum = pad(self.l_sum, 0)
-        self.l_rsum = pad(self.l_rsum, 0)
-        self._depth = pad(self._depth, 0)
+        super()._grow_state(old)
+        self._depth = self._grown(self._depth, old, 0)
         if self._res_pos is not None:
-            self._res_pos = pad(self._res_pos, 0)
+            self._res_pos = self._grown(self._res_pos, old, 0)
 
     # -- staging ----------------------------------------------------------
 
@@ -1384,6 +1485,25 @@ class DigestArena(_ArenaBase):
         self._acc = []
         self._staged_nonuniform = False
         return rows, vals, wts
+
+    def snapshot_part(self) -> dict:
+        part = super().snapshot_part()
+        # hash(name) mirror for the query plane's vectorized slot
+        # lookups (maintained incrementally at registration)
+        part["name_hashes"] = self.name_hash_col[part["rows"]].copy()
+        # the interval's staged weighted points (consumed); the flush
+        # program evaluates them in one dense pass outside the lock
+        # (uniform selects the key-only sort network as a static program
+        # choice, ops/sorted_eval.py).  uniform is captured BEFORE
+        # take_staged resets the tracking, and the resident mirror is
+        # consumed right after take_staged with its result (the tail's
+        # (row, pos) coordinates come from the same consolidated arrays)
+        part["uniform"] = self.staged_uniform
+        t0 = time.perf_counter()
+        part["staged"] = self.take_staged()
+        self.snapshot_staged_s = time.perf_counter() - t0
+        part["resident"] = self.take_resident(part["staged"])
+        return part
 
     # -- resident delta mirror (flush_resident_arenas) ---------------------
 
@@ -1837,18 +1957,7 @@ class DigestArena(_ArenaBase):
             self._res_pos[:] = 0
 
     def reset_rows(self, rows: np.ndarray) -> None:
-        if len(rows) == 0:
-            return
-        self.d_min[rows] = np.inf
-        self.d_max[rows] = -np.inf
-        self.d_rsum[rows] = 0
-        self.d_weight[rows] = 0
-        self.d_sum[rows] = 0
-        self.l_weight[rows] = 0
-        self.l_min[rows] = np.inf
-        self.l_max[rows] = -np.inf
-        self.l_sum[rows] = 0
-        self.l_rsum[rows] = 0
+        super().reset_rows(rows)
         self._depth[rows] = 0
 
 
@@ -1885,6 +1994,10 @@ class MomentsArena(DigestArena):
     Unmeshed only: the moments flush is a single-device program (config
     rejects ``sketch_family_*`` with a device mesh)."""
 
+    family = ring = wire_field = "moments"
+    _COLUMNS = DigestArena._COLUMNS + (
+        ("d_logn", 0), ("ivec", 0), ("iv_a", np.inf), ("iv_b", -np.inf))
+
     def __init__(self, capacity: int = _INITIAL_CAPACITY,
                  k: int = 0, mesh=None, **kw):
         from veneur_tpu.sketches import moments as mo
@@ -1902,16 +2015,6 @@ class MomentsArena(DigestArena):
                              np.float64)
         self.iv_a = np.full(self.capacity, np.inf)
         self.iv_b = np.full(self.capacity, -np.inf)
-
-    def _grow_state(self, old: int) -> None:
-        super()._grow_state(old)
-        # super() doubled self.capacity before calling; extend the
-        # moments-only state the same way
-        self.d_logn = np.concatenate([self.d_logn, np.zeros(old)])
-        self.ivec = np.concatenate(
-            [self.ivec, np.zeros((old, self.ivec.shape[1]))], axis=0)
-        self.iv_a = np.concatenate([self.iv_a, np.full(old, np.inf)])
-        self.iv_b = np.concatenate([self.iv_b, np.full(old, -np.inf)])
 
     def _sync_extra(self, rows, vals, wts, local) -> None:
         pos = vals > 0
@@ -2122,17 +2225,6 @@ class MomentsArena(DigestArena):
         lab[1, :n] = lb
         return imp, ab, lab
 
-    # -- lifecycle ---------------------------------------------------------
-
-    def reset_rows(self, rows: np.ndarray) -> None:
-        super().reset_rows(rows)
-        if len(rows) == 0:
-            return
-        self.d_logn[rows] = 0
-        self.ivec[rows] = 0
-        self.iv_a[rows] = np.inf
-        self.iv_b[rows] = -np.inf
-
     # -- crash checkpoint --------------------------------------------------
 
     def _checkpoint_arrays(self) -> dict:
@@ -2212,6 +2304,10 @@ class CompactorArena(DigestArena):
     Unmeshed only, like moments: one flush program per device, no
     cross-shard collective in the family's merge algebra yet."""
 
+    family = ring = wire_field = "compactor"
+    _COLUMNS = DigestArena._COLUMNS + (
+        ("cvals", 0), ("ccnt", 0), ("ccomps", 0), ("cclip", 0))
+
     def __init__(self, capacity: int = _INITIAL_CAPACITY,
                  cap: int = 0, levels: int = 0, seed: int = 0,
                  mesh=None, **kw):
@@ -2243,17 +2339,6 @@ class CompactorArena(DigestArena):
         self.ccnt = np.zeros((capacity, self.cc_levels), np.int64)
         self.ccomps = np.zeros(capacity, np.int64)
         self.cclip = np.zeros(capacity, np.int64)
-
-    def _grow_state(self, old: int) -> None:
-        super()._grow_state(old)
-        self.cvals = np.concatenate(
-            [self.cvals,
-             np.zeros((old,) + self.cvals.shape[1:], np.float32)])
-        self.ccnt = np.concatenate(
-            [self.ccnt, np.zeros((old, self.cc_levels), np.int64)])
-        self.ccomps = np.concatenate([self.ccomps,
-                                      np.zeros(old, np.int64)])
-        self.cclip = np.concatenate([self.cclip, np.zeros(old, np.int64)])
 
     # -- the batched fold (rounds of ONE compact_batch launch) -------------
 
@@ -2471,14 +2556,15 @@ class CompactorArena(DigestArena):
 
     # -- lifecycle ---------------------------------------------------------
 
-    def reset_rows(self, rows: np.ndarray) -> None:
-        super().reset_rows(rows)
-        if len(rows) == 0:
-            return
-        self.cvals[rows] = 0.0
-        self.ccnt[rows] = 0
-        self.ccomps[rows] = 0
-        self.cclip[rows] = 0
+    def snapshot_part(self) -> dict:
+        """The staged points fold into the part's ladder COPIES at
+        dispatch (fold_flush, outside the lock); the live ladders reset
+        right after the cut, so an overlapping interval can never alias
+        the in-flight fold.  No dense build at flush: the part carries
+        neither a kernel choice nor a resident mirror."""
+        part = super().snapshot_part()
+        del part["uniform"], part["resident"]
+        return part
 
     # -- crash checkpoint --------------------------------------------------
 

@@ -7,6 +7,3 @@ headroom at scale.  Each module exposes a drop-in replacement for its XLA
 twin and is validated against it in tests (interpret mode on CPU, native
 on TPU).
 """
-
-from veneur_tpu.ops import hll_estimate  # noqa: F401
-from veneur_tpu.ops import quantile_eval  # noqa: F401

@@ -34,7 +34,7 @@ assembled ON device from interval-streamed COO deltas
 (arena.MomentsArena.assemble_resident + serving.resident_scatter*), so
 by flush time the merge kernel's input is already in HBM and only the
 ``[2(k+1), U]`` moment write and the solver's quantile columns cross
-the link.  `sorted_eval.overlap_efficiency` measures both levels.
+the link.
 """
 
 from __future__ import annotations

@@ -51,8 +51,7 @@ shape).  Three coordinated changes, all output-preserving:
     bf16-representable, which is what the dispatch gate
     (`usable_compact` + the arena's bf16 staging) guarantees.  The
     permutation-apply costs O(D) selects per tile, so the packed form
-    pays off only at shallow depths; `scripts/sort_variants.py` carries
-    both formulations so the chip decides.
+    pays off only at shallow depths (`MAX_COMPACT_DEPTH`).
   * **generalized depth-vector scheduling.**  The 1024-wide lane tiles
     (previously only on the key-only depth-vector kernel) now apply to
     the paired (value, weight) network too, VMEM budget permitting
@@ -98,9 +97,8 @@ MAX_DEPTH = 1024
 
 # compact (packed-word) general network: the permutation-apply that
 # reconstructs the weights costs O(D) selects per tile, so the packed
-# form only wins at shallow depths (microbenched in
-# scripts/sort_variants.py; the dispatch gate keeps deeper shapes on
-# the f32 paired network)
+# form only wins at shallow depths (the dispatch gate keeps deeper
+# shapes on the f32 paired network)
 MAX_COMPACT_DEPTH = 64
 
 # double-buffered DMA pipeline: sub-tiles per coarse grid step, engaged
@@ -529,38 +527,6 @@ def _dma_pipeline(big_refs, scratch, sems, tile: int, nbuf: int,
     jax.lax.fori_loop(0, nbuf, body, 0)
 
 
-def overlap_efficiency(chunks: list[dict]) -> float:
-    """Overlap efficiency of a chunked transfer pipeline: the fraction
-    of total per-chunk work (upload + dispatch + drain + wait) hidden
-    behind other chunks' segments.  0.0 = fully serial (the summed
-    segments equal the pipeline's wall span), approaching 1.0 as more
-    of each chunk's transfer rides under its neighbours' compute.
-
-    Shared metric for BOTH pipeline levels: the VMEM sub-tile stream
-    above (`_dma_pipeline`) and its host↔HBM lift — the per-chunk
-    `device_chunks` stats the aggregator's delta flush records and the
-    chunk-size × nbuf sweep in scripts/profile_flush_kernel.py delta
-    mode reports.  Each chunk dict carries second-valued segments
-    (upload_s/dispatch_s/drain_s/wait_s, absent keys = 0) and the list
-    spans one pipeline run whose wall is dominated by the slowest
-    chain, so `1 - wall/sum` is computed from the chunks alone via the
-    serial lower bound max(per-segment totals)."""
-    if not chunks:
-        return 0.0
-    keys = ("upload_s", "dispatch_s", "drain_s", "wait_s")
-    total = sum(float(c.get(k, 0.0)) for c in chunks for k in keys)
-    if total <= 0.0:
-        return 0.0
-    # the pipeline's wall is bounded below by its busiest resource:
-    # the host link (uploads+drains) or the device (dispatch+waits)
-    wall = max(
-        sum(float(c.get("upload_s", 0.0)) + float(c.get("drain_s", 0.0))
-            for c in chunks),
-        sum(float(c.get("dispatch_s", 0.0)) + float(c.get("wait_s", 0.0))
-            for c in chunks))
-    return max(0.0, min(1.0, 1.0 - wall / total))
-
-
 def _kernel_dma(mean_ref, weight_ref, minmax_ref, qs_ref, out_ref,
                 m_scr, w_scr, sems, *, tile: int, nbuf: int,
                 uniform: bool, compact: bool):
@@ -744,33 +710,6 @@ def weighted_eval(mean: jax.Array, weight: jax.Array,
     return out.T                                                # [U, P+2]
 
 
-def stage_slice_kernel(mode: str):
-    """Bench/profiling support: a kernel computing a progressively
-    larger CUT of the production evaluation on a natural [T, D] block —
-    'read' (stream both operands + a row reduce), 'sort' (+ the paired
-    network), 'cumsum' (+ the prefix sum) — writing one [1, T] reduce
-    row.  Built from the SAME stage functions the production kernels
-    use (`_sort_pairs`, `_cumsum_depth`), so the cuts can never measure
-    a stale formulation.  Consumed by bench.bench_kernel_stages (the
-    `kernel_stage_ms` arm) and scripts/profile_flush_kernel.py."""
-    if mode not in ("read", "sort", "cumsum"):
-        raise ValueError(f"unknown stage slice {mode!r}")
-
-    def kernel(mean_ref, weight_ref, out_ref):
-        m = mean_ref[...].T           # [D, T]
-        w = weight_ref[...].T
-        d, t = m.shape
-        idx = jax.lax.broadcasted_iota(jnp.int32, (d, t), 0)
-        key = jnp.where(w > 0, m, _PAD_KEY)
-        if mode in ("sort", "cumsum"):
-            key, w = _sort_pairs(key, w, idx)
-        if mode == "cumsum":
-            out_ref[...] = _cumsum_depth(w)[d - 1:d, :]
-        else:
-            out_ref[...] = jnp.sum(key * w, axis=0, keepdims=True)
-    return kernel
-
-
 def usable(u: int, d: int, backend: str) -> bool:
     """Static predicate: can the Pallas path evaluate this dense shape?
     Depth must be a power of two (bitonic network) up to MAX_DEPTH; the
@@ -785,8 +724,8 @@ def usable(u: int, d: int, backend: str) -> bool:
 def usable_compact(u: int, d: int, backend: str) -> bool:
     """Static predicate for the packed compact-key general network: a
     usable() shape shallow enough that the O(D) permutation-apply
-    reconstruct stays cheaper than the paired network's extra passes
-    (microbenched in scripts/sort_variants.py).  The VALUE-exactness
+    reconstruct stays cheaper than the paired network's extra passes.
+    The VALUE-exactness
     half of the gate — every staged value bf16-representable — is the
     caller's (the arena's bf16 staging guarantees it by
     construction)."""

@@ -17,7 +17,7 @@ its K/n_shards rows; collectives ride ICI within the replica groups.
 Single-device use (entry() in __graft_entry__.py) is the same body with no
 collectives.  The body is shared with the production serving path
 (veneur_tpu/parallel/serving.py flush_body) — this module only packages it
-with example inputs for compile checks and the benchmark.
+with example inputs for compile checks and the parity tests.
 """
 
 from __future__ import annotations
@@ -42,21 +42,6 @@ def flush_step(inputs: FlushInputs, percentiles: jax.Array,
     """Single-device flush step (the compile-checked entry point)."""
     return serving.flush_body(inputs, percentiles, axis=None,
                               uniform=uniform)
-
-
-@functools.partial(jax.jit, static_argnames=("uniform",))
-def flush_step_packed(inputs: FlushInputs, percentiles: jax.Array,
-                      uniform: bool = False
-                      ) -> tuple[jax.Array, jax.Array]:
-    """flush_step with its f32 outputs packed into ONE flat buffer
-    (serving.pack_outputs) — the production launch shape: per-launch
-    dispatch cost scales with output-handle count, so the global tier's
-    flush hands the host (flat_f32, set_regs_u8) instead of six arrays.
-    `uniform` (static) selects the key-only sort when every staged
-    weight is 1 (see ops/sorted_eval.py)."""
-    out = serving.flush_body(inputs, percentiles, axis=None,
-                              uniform=uniform)
-    return serving.pack_outputs(out), out.set_regs
 
 
 def _sharded_body(mesh: Mesh):
@@ -92,113 +77,9 @@ def _sharded_body(mesh: Mesh):
 def make_sharded_flush_step(mesh: Mesh):
     """Build the shard_map'd multi-chip flush step over a
     (shard, replica) mesh, returning unpacked FlushOutputs (the
-    compile-check / parity-test shape; production and the benches use
-    make_sharded_flush_step_packed)."""
+    compile-check / parity-test shape; production launches the packed
+    form, serving.make_serving_flush)."""
     return jax.jit(_sharded_body(mesh))
-
-
-def make_sharded_flush_step_packed(mesh: Mesh, donate: bool = False):
-    """The production launch shape of the sharded step: ONE flat f32
-    buffer + the u8 set registers (serving.pack_outputs) — dispatch
-    cost scales with output-handle count.  `donate=True` donates the
-    PER-FLUSH f32 buffers (dense matrices, minmax, counter planes) the
-    way the serving path does — legal only when the caller stages fresh
-    buffers each flush; the register lanes (set + unique-ts) stay
-    undonated, mirroring their device-resident production role."""
-    body = _sharded_body(mesh)
-
-    def run(dense_v, dense_w, minmax, counter_planes, uts_regs,
-            hll_regs, pct):
-        out = body(FlushInputs(
-            dense_v=dense_v, dense_w=dense_w, minmax=minmax,
-            hll_regs=hll_regs, counter_planes=counter_planes,
-            uts_regs=uts_regs), pct)
-        return serving.pack_outputs(out), out.set_regs
-
-    jitted = jax.jit(run, donate_argnums=(0, 1, 2, 3) if donate else ())
-
-    def step(inputs: FlushInputs, pct):
-        return jitted(inputs.dense_v, inputs.dense_w, inputs.minmax,
-                      inputs.counter_planes, inputs.uts_regs,
-                      inputs.hll_regs, pct)
-
-    return step
-
-
-def example_depth_inputs(n_keys: int = 64, n_lanes: int = 2,
-                         depth: int = 32, seed: int = 0,
-                         bf16: bool = False):
-    """Synthetic (dense values, per-row depth vector) pair for the
-    depth-vector flush program (serving.digest_eval_uniform) — the
-    production unmeshed uniform-interval launch shape: the weight matrix
-    never crosses the link, occupancy is `col < depths[row]`.
-    bf16=True stages the values at wire width (digest_bf16_staging), the
-    shape whose sort network runs on compact 16-bit keys."""
-    import numpy as np
-    rng = np.random.default_rng(seed)
-    k = 1 << (n_keys - 1).bit_length() if n_keys > 1 else 1
-    d = n_lanes * depth
-    vals = rng.gamma(2.0, 10.0, (k, d)).astype(np.float32)
-    depths = np.zeros(k, np.int16)
-    depths[:n_keys] = d
-    vals[n_keys:] = 0.0
-    dv = jnp.asarray(vals)
-    if bf16:
-        dv = dv.astype(jnp.bfloat16)
-    return dv, jnp.asarray(depths)
-
-
-def example_delta_chunks(n_keys: int = 64, depth: int = 32,
-                         chunk_points: int = 1024, seed: int = 0,
-                         weighted: bool = False):
-    """Synthetic resident-delta stream for the scatter-assembly path
-    (serving.resident_scatter*): the interval's staged COO points cut
-    into fixed-size chunks of (rows, pos, vals[, wts]) exactly as
-    DigestArena.stream_resident emits them — rows padded with the
-    `capacity` sentinel, positions being per-row arrival ordinals — plus
-    the flush-time dense_id map and the dense [U, D] matrix the host
-    builder would have produced, for bit-parity checks and the
-    chunk-size × nbuf sweep in scripts/profile_flush_kernel.py delta
-    mode.  Returns (chunks, dense_id, expect_v, expect_w)."""
-    import numpy as np
-    rng = np.random.default_rng(seed)
-    cap = max(n_keys, 2 * n_keys)
-    k = 1 << (n_keys - 1).bit_length() if n_keys > 1 else 1
-    d = 1 << (depth - 1).bit_length() if depth > 1 else 2
-    rows = rng.integers(0, n_keys, n_keys * depth).astype(np.int64)
-    vals = rng.gamma(2.0, 10.0, len(rows)).astype(np.float32)
-    wts = (rng.integers(1, 9, len(rows)).astype(np.float32)
-           if weighted else np.ones(len(rows), np.float32))
-    dense_id = np.full(cap + 1, serving._RESIDENT_DROP, np.int32)
-    dense_id[:n_keys] = np.arange(n_keys, dtype=np.int32)
-    expect_v = np.zeros((k, d), np.float32)
-    expect_w = np.zeros((k, d), np.float32)
-    cursors = np.zeros(cap, np.int64)
-    chunks = []
-    for lo in range(0, len(rows), chunk_points):
-        cr, cv, cw = (a[lo:lo + chunk_points] for a in (rows, vals, wts))
-        order = np.argsort(cr, kind="stable")
-        sr, sv, sw = cr[order], cv[order], cw[order]
-        pos = (cursors[sr]
-               + (np.arange(len(sr)) - np.searchsorted(sr, sr)))
-        cursors[sr] = pos + 1
-        keep = pos < d            # overfull rows drop, like build_dense
-        expect_v[sr[keep], pos[keep]] = sv[keep]
-        expect_w[sr[keep], pos[keep]] = sw[keep]
-        pr = np.full(chunk_points, cap, np.int32)
-        pp = np.zeros(chunk_points, np.int32)
-        pv = np.zeros(chunk_points, np.float32)
-        pr[:len(sr)] = sr
-        pp[:len(sr)] = pos
-        pv[:len(sr)] = sv
-        ch = {"rows": jnp.asarray(pr), "pos": jnp.asarray(pp),
-              "vals": jnp.asarray(pv)}
-        if weighted:
-            pw = np.zeros(chunk_points, np.float32)
-            pw[:len(sr)] = sw
-            ch["wts"] = jnp.asarray(pw)
-        chunks.append(ch)
-    return chunks, jnp.asarray(dense_id), expect_v, expect_w
 
 
 def example_inputs(n_keys: int = 64, n_lanes: int = 2, n_sets: int = 8,

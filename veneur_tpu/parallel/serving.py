@@ -138,10 +138,8 @@ def place_dense_blocks(mesh: Mesh, dv, dw, minmax,
     make_array_from_single_device_arrays.  The mesh program consumes
     already-resident shards instead of re-laying-out one process-wide
     host matrix on entry, and the per-device transfers overlap on real
-    hardware.  Shared by DigestArena.put_dense_sharded (production) and
-    scripts/bench_mesh_scaling.py (so the bench times the REAL staging
-    path, not a copy of it).  minmax is key-sharded, replica-replicated:
-    every replica gets its shard's columns."""
+    hardware.  minmax is key-sharded, replica-replicated: every replica
+    gets its shard's columns."""
     from jax.sharding import SingleDeviceSharding
     S = int(mesh.shape[SHARD_AXIS])
     R = int(mesh.shape[REPLICA_AXIS])
@@ -180,7 +178,7 @@ def fetch(x):
 
 
 # ---------------------------------------------------------------------------
-# Flush body (shared by the serving path and the bench's flush_step)
+# Flush body (shared by the serving path and parallel/flush_step.py)
 # ---------------------------------------------------------------------------
 
 def pallas_eval_applies(u: int, d: int, dtype=jnp.float32) -> bool:

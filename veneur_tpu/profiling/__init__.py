@@ -36,7 +36,7 @@ STAGES = ("recvmmsg", "parse", "intern", "stage", "drain")
 
 # The unit each stage counts in (its counter key next to "ns").  Drain
 # additionally reports "calls" (consolidation passes).  Consumers
-# (ingest.stage_stats, bench.py, scripts/ingest_ceiling.py) are
+# (ingest.stage_stats, scripts/ingest_ceiling.py) are
 # table-driven off this so a stage rename/addition has one home.
 STAGE_UNITS = {"recvmmsg": "packets", "parse": "packets",
                "intern": "calls", "stage": "values", "drain": "packets"}

@@ -352,8 +352,14 @@ class Destinations:
     def reshard_commit(self, rec: dict) -> None:
         """Close a reshard window: record the achieved membership, the
         sampled key movement (bounded-movement evidence), and the
-        duration; publish as the /debug/vars reshard record."""
+        duration; publish as the /debug/vars reshard record.  A window
+        marked `void` (set_members: it had nothing left to do) gives its
+        epoch back and publishes nothing."""
         try:
+            if rec.get("void"):
+                with self._lock:
+                    self._reshard_epoch -= 1
+                return
             from veneur_tpu.proxy import consistent
             with self._lock:
                 after = sorted(self._ring.members())
@@ -458,6 +464,14 @@ class Destinations:
         from veneur_tpu import failpoints
         rec = self.reshard_begin(sorted(want))
         try:
+            if rec["members_before"] == sorted(want):
+                # the diff above was taken outside the window: a reshard
+                # in flight then (the discovery poll beside an operator's
+                # call, both offered this membership) has since committed
+                # it.  A second record would publish an empty reshard
+                # over the real one.
+                rec["void"] = True
+                return
             # vnlint: disable=blocking-propagation (the reshard
             #   failpoint edge deliberately sits inside the window —
             #   a chaos delay arm must stall the reshard itself;

@@ -383,14 +383,13 @@ class RetentionTimeline:
 
     # -- the flush-cut hook ---------------------------------------------
 
-    def compact_cut(self, dpart: dict, mpart: dict, cpart: dict,
-                    cut_ts: float, moments_arena,
-                    compactor_arena) -> None:
-        """Queue one flush cut's snapshot parts (the same immutable
-        parts the WindowRing slots hold — query threads already read
-        them lock-free, so the compaction worker may too).  The flush
-        path pays a handoff; `drain()` (and the checkpoint capture)
-        waits for the worker to go idle."""
+    def compact_cut(self, cut: dict, cut_ts: float) -> None:
+        """Queue one flush cut: {ring name: (snapshot part, its arena)}
+        over the histogram families — the same immutable parts the
+        WindowRing slots hold (query threads already read them
+        lock-free, so the compaction worker may too).  The flush path
+        pays a handoff; `drain()` (and the checkpoint capture) waits
+        for the worker to go idle."""
         with self._cv:
             if self._stopped:
                 return
@@ -399,8 +398,7 @@ class RetentionTimeline:
                     target=self._worker_loop, daemon=True,
                     name="retention-compact")
                 self._worker.start()
-            self._queued.append((dpart, mpart, cpart, cut_ts,
-                                 moments_arena, compactor_arena))
+            self._queued.append((cut, cut_ts))
             self._cv.notify_all()
 
     def _worker_loop(self) -> None:
@@ -449,14 +447,11 @@ class RetentionTimeline:
         if w is not None and w is not threading.current_thread():
             w.join(timeout=5.0)
 
-    def _compact_one(self, dpart: dict, mpart: dict, cpart: dict,
-                     cut_ts: float, moments_arena,
-                     compactor_arena) -> None:
-        td = summarize_digest_part(dpart, self.point_cap,
+    def _compact_one(self, cut: dict, cut_ts: float) -> None:
+        td = summarize_digest_part(cut["tdigest"][0], self.point_cap,
                                    self.compression)
-        mov = summarize_vector_part(mpart, moments_arena, "moments")
-        ccv = summarize_vector_part(cpart, compactor_arena,
-                                    "compactor")
+        mov = summarize_vector_part(*cut["moments"], "moments")
+        ccv = summarize_vector_part(*cut["compactor"], "compactor")
         self.absorb_summaries(td, mov, ccv, cut_ts)
 
     def absorb_summaries(self, td: dict, mov: dict, ccv: dict,

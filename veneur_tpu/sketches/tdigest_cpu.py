@@ -3,10 +3,9 @@
 A faithful re-implementation of the reference's sequential algorithm
 (`tdigest/merging_digest.go:115-262`): buffered Adds, sort temps, single
 in-order greedy merge pass with the arcsine scale function, shuffled re-Add
-on Merge (`merging_digest.go:374-389`).  Used (a) as the accuracy yardstick
-for the parallel TPU kernels and (b) as the 32-core-CPU-style baseline arm
-of bench.py.  Pure numpy/python — deliberately the "what a CPU global node
-does" algorithm, not a TPU design.
+on Merge (`merging_digest.go:374-389`).  Used as the accuracy yardstick
+for the parallel TPU kernels.  Pure numpy/python — deliberately the "what a
+CPU global node does" algorithm, not a TPU design.
 """
 
 from __future__ import annotations
