@@ -27,7 +27,8 @@ ROW_FIELDS = ("snapshot_lock_wait_ms", "snapshot_sync_ms",
               "snapshot_staged_ms", "snapshot_columns_ms", "import_rpcs",
               "import_lock_wait_ms", "import_scan_ms", "import_held_ms",
               "fold_calls", "fold_lines", "fold_lock_wait_ms", "fold_ms",
-              "import_digest_hits", "import_digest_misses")
+              "import_digest_hits", "import_digest_misses",
+              "staged_points", "staged_cut_copy_bytes", "staged_regrows")
 
 
 def _wait(cond, timeout_s=10.0):

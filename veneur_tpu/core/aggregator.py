@@ -105,6 +105,14 @@ _LEDGER_FIELDS = ("import_rpcs", "import_lock_wait_ns", "import_scan_ns",
                   # interval (parsed, resolved, cached).  Records that
                   # keep _import_slow_pb are in neither.
                   "import_digest_hits", "import_digest_misses")
+# what a flush's cut did with the staged points (arena._StagedPoints),
+# summed over the histogram families: the points it handed to the
+# flush, the bytes of accumulated points copied under the lock at the
+# cut (0: the hand-off engaged; not 0: the snapshot's own sync() regrew
+# a buffer or pre-reduced a hot row), and the buffers' doublings over
+# the interval.  On the timeline row and in /debug/vars.
+STAGED_LEDGER_KEYS = ("staged_points", "staged_cut_copy_bytes",
+                      "staged_regrows")
 LEDGER_SEGMENT_KEYS = frozenset(
     ["snapshot_lock_wait_s", "snapshot_sync_s", "snapshot_staged_s",
      "snapshot_columns_s"]
@@ -116,7 +124,8 @@ ROW_ONLY_SEGMENT_KEYS = LEDGER_SEGMENT_KEYS | {
     "set_rows_device", "set_upload_bytes", "set_device_s",
     # the meshed launch's own (_launch_meshed): the dense shape it ran
     # and the bytes its collectives move per device
-    "device_rows", "device_depth", "collective_bytes"}
+    "device_rows", "device_depth", "collective_bytes",
+    *STAGED_LEDGER_KEYS}
 
 
 def _new_ledger() -> dict:
@@ -1210,6 +1219,7 @@ class MetricAggregator:
         # reset); what remains of snapshot_s is the deferred estimate
         # above
         sync_s, staged_s = snap.pop("part_seconds")
+        seg.update(zip(STAGED_LEDGER_KEYS, snap.pop("staged_ledger")))
         seg["snapshot_lock_wait_s"] = t_held - t0
         seg["snapshot_sync_s"] = sync_s
         seg["snapshot_staged_s"] = staged_s
@@ -2164,6 +2174,7 @@ class MetricAggregator:
         state), reset.  The parts' columns are the arenas' own."""
         self._import_row_cache.clear()
         arenas = self._arenas()
+        copied = -sum(ar.staged_copied_bytes for _, ar in arenas)
         t_sync = time.perf_counter()
         for _, ar in arenas:
             ar.sync()
@@ -2193,12 +2204,18 @@ class MetricAggregator:
             snap["uts_regs"] = self._uts_lanes(uts)
 
         staged_s = 0.0
+        points = regrows = 0
         for name, ar in arenas:
             snap[name] = ar.snapshot_part()
             staged_s += ar.snapshot_staged_s
-        # what the syncs and the take_staged consolidations took, for
-        # flush_dispatch's split of snapshot_s
+            points += ar.snapshot_staged_points
+            regrows += ar.snapshot_staged_regrows
+            copied += ar.staged_copied_bytes
+        # what the syncs and the take_staged hand-offs took, for
+        # flush_dispatch's split of snapshot_s, and what they handed
+        # over (STAGED_LEDGER_KEYS)
         snap["part_seconds"] = (sync_s, staged_s)
+        snap["staged_ledger"] = (points, copied, regrows)
 
         # key-dictionary fingerprints for the multi-controller lockstep
         # gather — snapshotted HERE, under the lock and before the GC in

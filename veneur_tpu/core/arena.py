@@ -71,6 +71,85 @@ def _pow2(n: int) -> int:
 _INITIAL_CAPACITY = 1024
 
 
+class _StagedPoints:
+    """One interval's consolidated staged points: three preallocated
+    columns (rows int64, vals / wts float64) of which `[:n]` are filled,
+    in arrival order.  sync() writes each drain tick's points into the
+    next free slots (`extend`), so the flush's cut joins nothing:
+    `take()` hands the filled views to the part — theirs from then on —
+    and leaves a fresh, untouched buffer behind."""
+
+    __slots__ = ("rows", "vals", "wts", "n", "regrows", "copied_bytes")
+
+    POINT_BYTES = 24
+    # smallest buffer: an arena that stages nothing holds 96 KiB
+    FLOOR = 4096
+
+    def __init__(self):
+        self.n = 0
+        # buffer doublings since the last take()
+        self.regrows = 0
+        # running total of bytes of already-accumulated points copied
+        # again (a regrow, a replace); a flush that reads it before its
+        # sync() and after its cut knows what the cut copied
+        self.copied_bytes = 0
+        self._alloc(self.FLOOR)
+
+    def _alloc(self, capacity: int) -> None:
+        # np.empty: no page is touched until a tick writes it
+        self.rows = np.empty(capacity, np.int64)
+        self.vals = np.empty(capacity, np.float64)
+        self.wts = np.empty(capacity, np.float64)
+
+    def views(self):
+        """The filled (rows, vals, wts); views of the live buffer."""
+        n = self.n
+        return self.rows[:n], self.vals[:n], self.wts[:n]
+
+    def extend(self, k: int):
+        """Append k slots and return them as (rows, vals, wts) views
+        for the caller to fill; a full buffer doubles until they fit
+        (the points before them are copied across)."""
+        n, cap = self.n, len(self.rows)
+        if n + k > cap:
+            old = self.views()
+            while cap < n + k:
+                cap *= 2
+                self.regrows += 1
+            self._alloc(cap)
+            for dst, src in zip((self.rows, self.vals, self.wts), old):
+                dst[:n] = src
+            self.copied_bytes += n * self.POINT_BYTES
+        self.n = n + k
+        return (self.rows[n:n + k], self.vals[n:n + k],
+                self.wts[n:n + k])
+
+    def replace(self, rows, vals, wts) -> None:
+        """Make these points the whole content (a pre-reduce's
+        re-staged points, a restored checkpoint).  The arrays may be
+        built from views(), not be them."""
+        self.n = 0
+        for dst, src in zip(self.extend(len(rows)), (rows, vals, wts)):
+            dst[:] = src
+        self.copied_bytes += len(rows) * self.POINT_BYTES
+
+    def take(self):
+        """Hand the interval's points over: the filled views, whose
+        buffer the arena lets go of.  The next buffer is sized to what
+        this interval reached (a power of two), so a steady fleet's
+        second interval regrows nothing; an interval that staged
+        nothing keeps its buffer."""
+        n = self.n
+        self.regrows = 0
+        if n == 0:
+            z = np.zeros(0)
+            return z.astype(np.int64), z, z
+        staged = self.views()
+        self.n = 0
+        self._alloc(max(self.FLOOR, _pow2(n)))
+        return staged
+
+
 @dataclass
 class RowMeta:
     key: MetricKey
@@ -103,9 +182,17 @@ class _ArenaBase:
     # format import_contrib / fold_flush / assemble_vectors here,
     # query/rings.py and retention/timeline.py read back.
     _COLUMNS: tuple = ()
-    # seconds the last snapshot_part() spent consolidating staged
-    # points (take_staged); 0 for a family that stages none
+    # what the last snapshot_part() says of its staged points (a
+    # family that stages none leaves them 0): the seconds take_staged()
+    # took, the points it handed to the flush, and the doublings of the
+    # accumulator's buffer over the interval
     snapshot_staged_s = 0.0
+    snapshot_staged_points = 0
+    snapshot_staged_regrows = 0
+    # running total of bytes of accumulated staged points copied again
+    # (_StagedPoints.copied_bytes); _snapshot_and_reset reads it on both
+    # sides of the cut
+    staged_copied_bytes = 0
 
     def __init__(self, capacity: int = _INITIAL_CAPACITY):
         self.capacity = capacity
@@ -269,11 +356,12 @@ class _ArenaBase:
         part = {"rows": rows,
                 "names": self.name_col[rows],
                 "tags": self.tags_col[rows],
-                "scopes": self.scope_col[rows].copy()}
+                "scopes": self.scope_col[rows]}
         if self.kind_col is not None:
             part["kinds"] = self.kind_col[rows]
+        # indexing by a row array already copies: the part owns these
         for name, _ in self._COLUMNS:
-            part[name] = getattr(self, name)[rows].copy()
+            part[name] = getattr(self, name)[rows]
         return part
 
     def reset_rows(self, rows: np.ndarray) -> None:
@@ -1208,9 +1296,10 @@ class DigestArena(_ArenaBase):
         # forwarded centroids (merge_digest_batch; local False)
         self._chunks: list[
             tuple[np.ndarray, np.ndarray, np.ndarray, bool]] = []
-        # consolidated interval accumulator: scalar-applied (rows, vals,
-        # wts) parts + per-row staged depth
-        self._acc: list[tuple[np.ndarray, np.ndarray, np.ndarray]] = []
+        # the interval accumulator: the scalar-applied (rows, vals, wts)
+        # points of every sync() so far, in arrival order in ONE buffer
+        # the flush takes whole (take_staged), + per-row staged depth
+        self._acc = _StagedPoints()
         self._depth = np.zeros(capacity, np.int64)
         # True while every staged weight this interval is exactly 1.0
         # (raw unsampled samples) — lets the flush pick the key-only
@@ -1323,11 +1412,15 @@ class DigestArena(_ArenaBase):
     # -- consolidation / hot-key pre-reduction ----------------------------
 
     def sync(self) -> None:
-        """Consolidate raw staging into the interval accumulator: apply
-        the host scalar updates, track per-row depth, and pre-reduce any
-        row whose backlog outgrew DENSE_DEPTH_CAP.  Called from the P7
-        drain ticks (so flush-time work covers only the final partial
-        tick) and at snapshot."""
+        """Move raw staging into the interval accumulator: write the
+        staged points behind those already there (the lists first, then
+        the chunks, each in arrival order), apply the host scalar
+        updates, track per-row depth, and pre-reduce any row whose
+        backlog outgrew DENSE_DEPTH_CAP.  Called from the P7 drain
+        ticks and at snapshot: every point is written into the
+        accumulator once, by the sync() that finds it staged, so the
+        snapshot's own call covers the final partial tick and
+        take_staged() after it copies nothing."""
         if not self._rows and not self._chunks:
             return
         parts = []
@@ -1338,18 +1431,18 @@ class DigestArena(_ArenaBase):
                           np.asarray(self._local, bool)))
             self._rows, self._vals, self._wts, self._local = [], [], [], []
         for r, v, w, is_local in self._chunks:
-            parts.append((r.astype(np.int64, copy=False),
-                          v.astype(np.float64, copy=False),
-                          w.astype(np.float64, copy=False),
-                          np.full(len(r), is_local, bool)))
+            parts.append((r, v, w, np.full(len(r), is_local, bool)))
         self._chunks = []
-        if len(parts) == 1:
-            rows, vals, wts, local = parts[0]
-        else:
-            rows = np.concatenate([p[0] for p in parts])
-            vals = np.concatenate([p[1] for p in parts])
-            wts = np.concatenate([p[2] for p in parts])
-            local = np.concatenate([p[3] for p in parts])
+        # the accumulator's new slots: the scalar updates below read
+        # the points from where they stay
+        rows, vals, wts = self._acc.extend(sum(len(p[0]) for p in parts))
+        at = 0
+        for r, v, w, _ in parts:
+            end = at + len(r)
+            rows[at:end], vals[at:end], wts[at:end] = r, v, w
+            at = end
+        local = (parts[0][3] if len(parts) == 1
+                 else np.concatenate([p[3] for p in parts]))
 
         # host scalar updates (vectorized)
         np.minimum.at(self.d_min, rows, vals)
@@ -1370,7 +1463,6 @@ class DigestArena(_ArenaBase):
             np.add.at(self.l_rsum, lr, lw / lv)
         self._sync_extra(rows, vals, wts, local)
 
-        self._acc.append((rows, vals, wts))
         np.add.at(self._depth, rows, 1)
         # pre-reduce until every row fits the dense cap; each pass
         # collapses a row's samples ~HOT_CHUNK_WIDTH -> ccap, so this
@@ -1391,16 +1483,14 @@ class DigestArena(_ArenaBase):
         batch (MomentsArena tracks the positive-sample mass here)."""
 
     def _consolidated(self):
-        """Collapse _acc into single (rows, vals, wts) arrays."""
-        if not self._acc:
-            z = np.zeros(0)
-            return z.astype(np.int64), z, z
-        if len(self._acc) > 1:
-            rows = np.concatenate([p[0] for p in self._acc])
-            vals = np.concatenate([p[1] for p in self._acc])
-            wts = np.concatenate([p[2] for p in self._acc])
-            self._acc = [(rows, vals, wts)]
-        return self._acc[0]
+        """The accumulated (rows, vals, wts): views of the live buffer,
+        never copies — index or copy before keeping one past the next
+        sync()."""
+        return self._acc.views()
+
+    @property
+    def staged_copied_bytes(self) -> int:
+        return self._acc.copied_bytes
 
     def _pre_reduce(self) -> None:
         """Collapse rows deeper than DENSE_DEPTH_CAP into <= ccap weighted
@@ -1466,7 +1556,7 @@ class DigestArena(_ArenaBase):
         new_r = np.concatenate([keep[0]] + out_r)
         new_v = np.concatenate([keep[1]] + out_v)
         new_w = np.concatenate([keep[2]] + out_w)
-        self._acc = [(new_r, new_v, new_w)]
+        self._acc.replace(new_r, new_v, new_w)
         self._depth[:] = 0
         np.add.at(self._depth, new_r, 1)
 
@@ -1480,17 +1570,21 @@ class DigestArena(_ArenaBase):
 
     def take_staged(self):
         """Consume the interval accumulator (call under the aggregator
-        lock, after sync()): returns (rows, vals, wts) COO arrays."""
-        rows, vals, wts = self._consolidated()
-        self._acc = []
+        lock, after sync()): returns (rows, vals, wts) COO arrays in
+        arrival order.  A hand-off, not a copy: the arrays are the
+        accumulator's filled prefix, contiguous, and the caller's from
+        here on; the arena starts the next interval in a fresh buffer
+        sized to this one's fill."""
+        self.snapshot_staged_points = self._acc.n
+        self.snapshot_staged_regrows = self._acc.regrows
         self._staged_nonuniform = False
-        return rows, vals, wts
+        return self._acc.take()
 
     def snapshot_part(self) -> dict:
         part = super().snapshot_part()
         # hash(name) mirror for the query plane's vectorized slot
         # lookups (maintained incrementally at registration)
-        part["name_hashes"] = self.name_hash_col[part["rows"]].copy()
+        part["name_hashes"] = self.name_hash_col[part["rows"]]
         # the interval's staged weighted points (consumed); the flush
         # program evaluates them in one dense pass outside the lock
         # (uniform selects the key-only sort network as a static program
@@ -1529,7 +1623,7 @@ class DigestArena(_ArenaBase):
         completes); the lock hold covers the host-side slice + cast
         only.  Returns bytes moved off the flush critical path."""
         if (not self.resident or not self._res_device
-                or self._res_dirty or not self._acc):
+                or self._res_dirty or not self._acc.n):
             return 0
         rows, vals, wts = self._consolidated()
         cp = self._res_chunk_points
@@ -1937,13 +2031,8 @@ class DigestArena(_ArenaBase):
     def _restore_arrays(self, meta: dict, arrays: dict) -> None:
         for name in self._CKPT_SCALARS:
             self._restore_into(getattr(self, name), arrays[name])
-        rows = arrays["acc_rows"].astype(np.int64, copy=False)
-        if len(rows):
-            self._acc = [(rows,
-                          arrays["acc_vals"].astype(np.float64,
-                                                    copy=False),
-                          arrays["acc_wts"].astype(np.float64,
-                                                   copy=False))]
+        self._acc.replace(arrays["acc_rows"], arrays["acc_vals"],
+                          arrays["acc_wts"])
         self._staged_nonuniform = bool(meta.get("staged_nonuniform",
                                                 False))
         if self.resident:
@@ -2129,7 +2218,7 @@ class MomentsArena(DigestArena):
         self.ivec[deep, k + 1:] = log
         self.iv_a[deep], self.iv_b[deep] = a1, b1
         keep = ~sel
-        self._acc = [(rows[keep], vals[keep], wts[keep])]
+        self._acc.replace(rows[keep], vals[keep], wts[keep])
         self._depth[deep] = 0
 
     @staticmethod
@@ -2426,7 +2515,7 @@ class CompactorArena(DigestArena):
         self._fold_state(self._live_state(), rows[sel], vals[sel],
                          wts[sel])
         keep = ~sel
-        self._acc = [(rows[keep], vals[keep], wts[keep])]
+        self._acc.replace(rows[keep], vals[keep], wts[keep])
         self._depth[deep] = 0
 
     # -- imports (ladder merge: concatenate-then-compact) ------------------

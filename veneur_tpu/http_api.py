@@ -168,6 +168,14 @@ def debug_vars(server) -> dict:
             "moments_solver_resid": float(
                 getattr(agg, "last_moments_resid", 0.0)),
         }
+    # what the last flush's cut did with the staged points (also on
+    # the flush timeline's rows): points handed to the flush, bytes of
+    # them copied under the aggregator lock (0 = nothing joined at the
+    # tick), buffer doublings over the interval
+    from veneur_tpu.core.aggregator import STAGED_LEDGER_KEYS
+    segs = agg.last_flush_segments
+    stats["staged_accumulator"] = {
+        key: segs.get(key, 0) for key in STAGED_LEDGER_KEYS}
     guard = getattr(server.aggregator, "cardinality", None)
     if guard is not None:
         # per-tenant key-budget ledger: exact keys, evicted
