@@ -2344,6 +2344,15 @@ struct ImportScan {
   std::vector<double> cent_mean, cent_weight;  // flat, wire order
   std::vector<long long> cent_off, cent_n;     // the record's range
   std::vector<double> dmin, dmax, drsum, compression;
+  // SetValue.hyper_log_log (axiomhq MarshalBinary), per record: the
+  // form the scan found (0 none / anything python must look at itself,
+  // 1 sparse: decoded to (register, rank) pairs below, 2 dense: base
+  // 0, m/2 nibble bytes at hll_off + 8), its precision byte, the
+  // payload's byte range, and the record's range in the pair columns
+  std::vector<uint8_t> set_form, set_p;
+  std::vector<long long> hll_off, hll_len, set_off, set_n;
+  std::vector<int32_t> set_idx;    // flat, wire order
+  std::vector<uint8_t> set_rank;
 };
 
 // Skip one field's payload by wire type.  False = truncated, a group
@@ -2453,6 +2462,88 @@ inline bool scan_histogram(const uint8_t* s, const uint8_t* end,
   return true;
 }
 
+inline uint32_t be32(const uint8_t* p) {
+  return ((uint32_t)p[0] << 24) | ((uint32_t)p[1] << 16) |
+         ((uint32_t)p[2] << 8) | (uint32_t)p[3];
+}
+
+// One sparse key -> (register, rank): axiomhq decodeHash (vendor
+// sparse.go:24-40), as sketches/hll.py _decode_sparse_keys has it.
+inline void decode_sparse_key(uint32_t k, int p, int32_t& idx,
+                              uint8_t& rank) {
+  const int pp = 25;
+  const uint32_t mask = (1u << p) - 1;
+  if (k & 1) {
+    rank = (uint8_t)(((k >> 1) & 0x3F) + (pp - p));
+    idx = (int32_t)((k >> (32 - p)) & mask);
+  } else {
+    uint32_t w = k << (32 - pp + p - 1);
+    rank = (uint8_t)((w ? __builtin_clz(w) : 32) + 1);
+    idx = (int32_t)((k >> (pp - p + 1)) & mask);
+  }
+}
+
+// A forwarded set's sketch [h, hend): [version=1][p][b][sparse] then
+// either the dense nibble registers ([sz u32 BE][sz bytes]) or the
+// sparse tmpSet + compressedList ([n u32][n keys u32 BE][count u32]
+// [last u32][blen u32][blen bytes of delta varints]).  Returns the
+// form (see ImportScan); a sparse sketch's pairs are appended to the
+// flat columns.  0 = not a sketch this scan stages (legacy encoding,
+// rebased dense registers, a malformed list): python's unmarshal
+// decides what it is.
+inline uint8_t scan_hll(const uint8_t* h, const uint8_t* hend,
+                        ImportScan* res, uint8_t& p_out) {
+  if (hend - h < 8 || h[0] != 1) return 0;
+  const int p = h[1];
+  const uint8_t b = h[2], sparse = h[3];
+  if (p < 4 || p > 18) return 0;
+  p_out = (uint8_t)p;
+  if (sparse == 0) {
+    const uint64_t sz = be32(h + 4);
+    if (b != 0 || sz * 2 != (1ull << p) ||
+        (uint64_t)(hend - h) < 8 + sz) return 0;
+    return 2;
+  }
+  if (sparse != 1) return 0;
+  const size_t start = res->set_idx.size();
+  auto fail = [&]() -> uint8_t {
+    res->set_idx.resize(start);
+    res->set_rank.resize(start);
+    return 0;
+  };
+  auto push = [&](uint32_t k) {
+    int32_t idx; uint8_t rank;
+    decode_sparse_key(k, p, idx, rank);
+    res->set_idx.push_back(idx);
+    res->set_rank.push_back(rank);
+  };
+  const uint8_t* q = h + 4;
+  const uint64_t tssz = be32(q);
+  q += 4;
+  if ((uint64_t)(hend - q) < tssz * 4 + 12) return 0;
+  for (uint64_t i = 0; i < tssz; i++, q += 4) push(be32(q));
+  const uint64_t count = be32(q);
+  const uint64_t blen = be32(q + 8);
+  q += 12;
+  if ((uint64_t)(hend - q) < blen || count > blen) return fail();
+  const uint8_t* bend = q + blen;
+  uint32_t last = 0;
+  for (uint64_t i = 0; i < count; i++) {
+    uint64_t x = 0;
+    int shift = 0;
+    for (;;) {
+      if (q >= bend || shift > 28) return fail();
+      const uint8_t byte = *q++;
+      x |= (uint64_t)(byte & 0x7F) << shift;
+      if (!(byte & 0x80)) break;
+      shift += 7;
+    }
+    last = (uint32_t)(last + x);
+    push(last);
+  }
+  return 1;
+}
+
 }  // namespace
 
 extern "C" {
@@ -2480,6 +2571,7 @@ void* vn_import_scan(const uint8_t* data, long long len) {
     uint8_t which = 0;
     double value = 0.0;
     DigestAcc dig;
+    const uint8_t *hll = nullptr, *hll_end = nullptr;
     const size_t cent_start = res->cent_mean.size();
     auto drop_digest = [&] {
       res->cent_mean.resize(cent_start);
@@ -2536,6 +2628,16 @@ void* vn_import_scan(const uint8_t* data, long long len) {
       } else if (mf == 8 && mwt == 2) {          // SetValue
         if (!open_sub(q, mend, s, send_)) { ok = false; break; }
         which = 3;
+        // {hyper_log_log = 1}: bytes, the last one wins
+        while (s < send_ && ok) {
+          uint64_t st;
+          if (!read_varint(s, send_, st)) { ok = false; break; }
+          if ((st >> 3) == 1 && (st & 7) == 2) {
+            ok = open_sub(s, send_, hll, hll_end);
+          } else {
+            ok = skip_wire(s, send_, (int)(st & 7));
+          }
+        }
       } else {
         ok = skip_wire(q, mend, mwt);
       }
@@ -2570,6 +2672,15 @@ void* vn_import_scan(const uint8_t* data, long long len) {
     res->dmax.push_back(dig.dmax);
     res->drsum.push_back(dig.drsum);
     res->compression.push_back(dig.compression);
+    const size_t set_start = res->set_idx.size();
+    uint8_t form = 0, set_p = 0;
+    if (which == 3 && hll) form = scan_hll(hll, hll_end, res, set_p);
+    res->set_form.push_back(form);
+    res->set_p.push_back(set_p);
+    res->hll_off.push_back(form ? (long long)(hll - data) : 0);
+    res->hll_len.push_back(form ? (long long)(hll_end - hll) : 0);
+    res->set_off.push_back((long long)set_start);
+    res->set_n.push_back((long long)(res->set_idx.size() - set_start));
   }
   return res;
 }
@@ -2609,6 +2720,25 @@ void vn_import_scan_digests(void* handle, long long* n_cent,
   *cent_off = r->cent_off.data(); *cent_n = r->cent_n.data();
   *dmin = r->dmin.data(); *dmax = r->dmax.data();
   *drsum = r->drsum.data(); *compression = r->compression.data();
+}
+
+// The scanned set sketches: two flat pair columns of *n_pairs entries
+// (every sparse record's (register, rank) pairs, wire order), and per
+// record its form, precision byte, payload range and pair range.
+void vn_import_scan_sets(void* handle, long long* n_pairs,
+                         const int32_t** set_idx,
+                         const uint8_t** set_rank,
+                         const uint8_t** set_form, const uint8_t** set_p,
+                         const long long** hll_off,
+                         const long long** hll_len,
+                         const long long** set_off,
+                         const long long** set_n) {
+  auto* r = (ImportScan*)handle;
+  *n_pairs = (long long)r->set_idx.size();
+  *set_idx = r->set_idx.data(); *set_rank = r->set_rank.data();
+  *set_form = r->set_form.data(); *set_p = r->set_p.data();
+  *hll_off = r->hll_off.data(); *hll_len = r->hll_len.data();
+  *set_off = r->set_off.data(); *set_n = r->set_n.data();
 }
 
 void vn_import_scan_free(void* handle) { delete (ImportScan*)handle; }

@@ -235,6 +235,55 @@ def test_set_estimate_compiles_for_v5e(rows, one_chip):
     assert mem.temp_size_in_bytes < (rows << 14) // 8
 
 
+SETS50K = (1, 65536, 1 << 14)     # global-sets50k's resident lane plane
+
+
+@pytest.mark.parametrize("program", ["scatter", "scatter_small", "merge",
+                                     "reset", "estimate"])
+def test_resident_set_lane_programs_compile_for_v5e(program, one_chip):
+    """The programs an unmeshed resident set arena launches
+    (`SetArena.prewarm_lanes`), at `sets50k.union`'s plane: 1 GiB of
+    p = 14 registers on one chip.  The scatter at its two padded
+    lengths and the dense-row merge, in the donating form the chip runs between
+    flushes (in place: the result aliases the operand); the mask reset;
+    the whole-plane estimate, which reads the registers where they are
+    — 4 bytes a row out, and no f32 or gathered copy of the plane among
+    its temporaries.  The elementwise scatter is the one that takes a
+    plane of temporaries: the compiler flattens the tiled u8 operand
+    for it (a `copy` in, a `reshape` out), which is why its chunk is
+    large."""
+    s = lambda shape, dt: _struct(one_chip, shape, dt)  # noqa: E731
+    lanes = s(SETS50K, jnp.uint8)
+    plane = SETS50K[1] * SETS50K[2]
+    n = (serving.LANE_SCATTER_SMALL if program == "scatter_small"
+         else serving.LANE_SCATTER_CHUNK)
+    r = serving.LANE_MERGE_CHUNK
+    if program.startswith("scatter"):
+        compiled = serving.set_lane_scatter.lower(
+            lanes, s((n,), jnp.int32), s((n,), jnp.int32),
+            s((n,), jnp.uint8), lane=0).compile()
+    elif program == "merge":
+        compiled = serving.set_lane_merge_rows.lower(
+            lanes, s((r,), jnp.int32), s((r, SETS50K[2]), jnp.uint8),
+            lane=0).compile()
+    elif program == "reset":
+        compiled = serving.set_reset_mask.lower(
+            lanes, s((SETS50K[1],), jnp.uint8)).compile()
+    else:
+        compiled = serving.set_estimate_plane.lower(lanes).compile()
+    mem = compiled.memory_analysis()
+    if program in ("scatter", "scatter_small", "merge"):
+        assert mem.alias_size_in_bytes == plane
+    elif program == "estimate":
+        assert mem.output_size_in_bytes == 4 * SETS50K[1]
+    else:
+        assert mem.output_size_in_bytes == plane
+    if program.startswith("scatter"):
+        assert mem.temp_size_in_bytes < plane + plane // 8
+    else:
+        assert mem.temp_size_in_bytes < plane // 8
+
+
 def test_meshed_flush_program_compiles_for_v5e_2x2(topo, as_tpu):
     """The shard 2 x replica 2 program `chip_smoke.py --chips 4` runs,
     at its `[65536, 32]` dense shape: kernel present, the depth

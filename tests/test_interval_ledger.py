@@ -173,7 +173,7 @@ def test_snapshot_lock_wait_reads_the_hold_and_parts_sum_to_snapshot():
     assert min(seg[f"snapshot_{p}_s"]
                for p in ("lock_wait", "sync", "staged", "columns")) >= 0.0
     assert {k for k in seg if k.startswith(("snapshot_", "import_",
-                                            "fold_"))} \
+                                            "fold_", "set_import_"))} \
         == LEDGER_SEGMENT_KEYS | {"snapshot_s"}
 
 

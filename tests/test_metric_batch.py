@@ -1,6 +1,7 @@
 """`MetricBatch.materialize` / `MetricSegment.materialize`: the bulk form
 gives the records `metric(i)` gives one at a time, whatever the column
-holds, and leaves the cyclic collector as it found it.
+holds, and leaves the cyclic collector as it found it — with no young
+pass owed for the records it made.
 """
 
 import gc
@@ -89,6 +90,42 @@ def test_batch_materialize_restores_collector_when_a_column_is_bad():
     with pytest.raises(ValueError):
         batch.materialize()
     assert gc.isenabled()
+
+
+def test_batch_materialize_owes_no_young_pass_for_its_records():
+    """A batch far beyond the collector's young threshold: lifting the
+    pause sets off no pass over the records (they are in the oldest
+    generation, spliced there unlooked-at), nothing stays frozen, and
+    they die by reference count."""
+    n = 20 * gc.get_threshold()[0]
+    bases = [f"big.k{i}" for i in range(n)]
+    tags = [["env:prod"]] * n
+    batch = sm.MetricBatch()
+    batch.add_segment(sm.MetricSegment(bases, tags, ".count",
+                                       np.arange(n), sm.COUNTER, 1700000000))
+    passes = []
+
+    def on_pass(phase, info):
+        if phase == "start":
+            passes.append(info["generation"])
+
+    assert gc.isenabled()
+    gc.collect()
+    gc.callbacks.append(on_pass)
+    try:
+        got = batch.materialize()
+        young_owed = gc.get_count()[0]
+        handed_on = [got, {"n": len(got)}]      # the caller's next allocations
+    finally:
+        gc.callbacks.remove(on_pass)
+    assert len(handed_on[0]) == n and passes == []
+    assert young_owed < gc.get_threshold()[0]
+    assert gc.get_freeze_count() == 0
+    oldest = {id(o) for o in gc.get_objects(generation=2)}
+    assert id(got[0]) in oldest and id(got[-1]) in oldest
+    tracked = len(gc.get_objects())
+    del got, handed_on
+    assert len(gc.get_objects()) <= tracked - n
 
 
 @pytest.mark.parametrize("columnar", [True, False])

@@ -241,7 +241,16 @@ class Config:
     # set (HLL) rows are register-heavy (2^set_precision bytes per lane =
     # 16 KiB at p=14): size the set arena for its OWN expected cardinality.
     # 0 = follow arena_initial_capacity up to 8192 rows (128 MiB/lane);
-    # sets grow on demand past the pre-size either way
+    # sets grow on demand past the pre-size either way.  Above the
+    # arena's default of 1,024 rows the value also says where an
+    # unmeshed tier keeps the registers: ON THE DEVICE, as one resident
+    # lane (65,536 rows = 1 GiB of HBM) that forwarded sketches are
+    # unioned into during the interval and that the flush estimates in
+    # place — that many registers cannot be copied under the lock,
+    # uploaded and read back every flush.  The lane programs are
+    # launched once at boot, before the server listens.  At the default
+    # size the registers stay on the host (a flush uploads a copy of
+    # the touched rows).
     set_arena_initial_capacity: int = 0
     # cardinality defense (core/cardinality.py): per-tenant key budget.
     # 0 disables.  With a budget set, every metric key carrying the
@@ -391,6 +400,9 @@ class Config:
     # cost is amortized into the interval instead of paid at the p99.
     # Unmeshed (global single-device) tiers only; meshed tiers already
     # hold set/counter registers device-resident and ignore the gate.
+    # The SET family alone is resident without this flag wherever
+    # set_arena_initial_capacity pre-sized its arena (above); with the
+    # flag it is resident at any size.
     flush_resident_arenas: bool = False
     # granularity of the delta machinery (0 = defaults).  In the chunked
     # host-staged pipeline this is dense ROWS per upload chunk (overrides

@@ -104,7 +104,22 @@ _LEDGER_FIELDS = ("import_rpcs", "import_lock_wait_ns", "import_scan_ns",
                   # or the record was its key's first sighting in the
                   # interval (parsed, resolved, cached).  Records that
                   # keep _import_slow_pb are in neither.
-                  "import_digest_hits", "import_digest_misses")
+                  "import_digest_hits", "import_digest_misses",
+                  # forwarded set sketches import_payload staged from
+                  # the wire scan's columns, by wire form: sparse as
+                  # (row, register, rank) triples, dense as a register
+                  # row.  Sketches that keep _import_slow_pb (a legacy
+                  # encoding, another precision) are in neither.
+                  "set_import_sparse", "set_import_dense")
+# what the set arena's lanes did over the interval and in its flush, on
+# the timeline row and in /debug/vars (-> set_lanes): the register bytes
+# the device holds (0: host registers), the rows the chip estimated, the
+# bytes the flush uploaded for them (0 on a resident flush) and read
+# back, and SetArena.LANE_STATS as set_*
+SET_LEDGER_KEYS = ("set_resident_bytes", "set_rows_device",
+                   "set_upload_bytes", "set_readback_bytes",
+                   "set_scatter_points", "set_scatter_launches",
+                   "set_merge_rows", "set_sync_s")
 # what a flush's cut did with the staged points (arena._StagedPoints),
 # summed over the histogram families: the points it handed to the
 # flush, the bytes of accumulated points copied under the lock at the
@@ -121,7 +136,7 @@ LEDGER_SEGMENT_KEYS = frozenset(
 # loop: the ledger's, and what the device programs (_dispatch_sets,
 # _launch_meshed) say of themselves
 ROW_ONLY_SEGMENT_KEYS = LEDGER_SEGMENT_KEYS | {
-    "set_rows_device", "set_upload_bytes", "set_device_s",
+    *SET_LEDGER_KEYS, "set_device_s",
     # the meshed launch's own (_launch_meshed): the dense shape it ran
     # and the bytes its collectives move per device
     "device_rows", "device_depth", "collective_bytes",
@@ -140,13 +155,9 @@ _EXPORT_ELEM_BUDGET = 1 << 26
 # splitting for upload/evaluate overlap (dispatch overhead dominates).
 _CHUNK_MIN_ROWS = 8192
 
-# Fewer touched set rows than this are estimated by numpy at dispatch
-# (0.3 ms a row on a v5e's host, outside the lock) instead of on the
-# chip: a launch costs the host more than that below a few rows, and
-# the one row a server's own telemetry touches in a flush out of a
-# hundred (ssf.names_unique, sampled at 1 %) must not compile a
-# [1, m] program inside that flush.  8 = the rows of one f32 tile.
-_SET_DEVICE_MIN_ROWS = 8
+# fewer touched set rows than this are estimated by numpy, on either
+# side of the residency choice (see arena.SET_DEVICE_MIN_ROWS)
+_SET_DEVICE_MIN_ROWS = arena_mod.SET_DEVICE_MIN_ROWS
 
 
 class MetricAggregator:
@@ -264,6 +275,15 @@ class MetricAggregator:
         # there (the digest dense build stays the sharded all_to_all)
         self.flush_resident = bool(flush_resident_arenas)
         resident_unmeshed = self.flush_resident and mesh is None
+        # the set registers are resident also where the configuration
+        # pre-sized their arena: set_arena_initial_capacity says the
+        # deployment's set keys are many, and at 16 KiB a row that many
+        # registers cannot be copied under the lock, uploaded and read
+        # back every flush (50,000 keys: 0.8 GB each way).  An arena at
+        # its default size keeps host registers.
+        sets_resident = mesh is None and (
+            self.flush_resident
+            or set_initial_capacity > arena_mod._INITIAL_CAPACITY)
         # pow2-floored delta granularity, shared by both delta modes
         # (dense ROWS per upload chunk when chunking host-staged builds,
         # staged POINTS per streamed chunk when resident); 0 = defaults
@@ -361,7 +381,7 @@ class MetricAggregator:
             self.compactors.cc_cap, self.compactors.cc_levels)
         self.sets = arena_mod.SetArena(precision=set_precision, mesh=mesh,
                                        legacy_migration=hll_legacy_migration,
-                                       resident=resident_unmeshed,
+                                       resident=sets_resident,
                                        **set_kw)
         self.counters = arena_mod.CounterArena(mesh=mesh, **kw)
         self.gauges = arena_mod.GaugeArena(**kw)
@@ -871,10 +891,12 @@ class MetricAggregator:
         plain t-digest's centroids in C++, so python does one dict
         lookup per metric, one vectorized merge per family and ONE
         staging call for the payload's digests
-        (DigestArena.merge_digest_batch).  What the wire says decides
-        per record: sets, the moments / compactor markers (compression
-        < 0) and a key's first sighting in the interval parse
-        individually via their byte ranges.  Falls back to
+        (DigestArena.merge_digest_batch) and one for its set sketches
+        (_stage_scanned_sets: sparse ones as decoded triples).  What
+        the wire says decides per record: the moments / compactor
+        markers (compression < 0), a set sketch the scan did not read
+        and a key's first sighting in the interval parse individually
+        via their byte ranges.  Falls back to
         import_pb_batch when the native engine is unavailable or
         rejects the payload."""
         t_call = time.perf_counter_ns()
@@ -915,12 +937,23 @@ class MetricAggregator:
         route[histo & (((mtype != metric_pb2.Histogram)
                         & (mtype != metric_pb2.Timer))
                        | (scope == metric_pb2.Local))] = 6
-        wl = route.tolist()
         mtypes = mtype.tolist()
         scopes = scope.tolist()
         vals = scan["value"].tolist()
         offs = scan["rec_off"].tolist()
         lens = scan["rec_len"].tolist()
+        # a set record's route: 3 = a sketch the scan read at this
+        # arena's precision (staged from its columns), 7 = one python's
+        # unmarshal has to look at (the protobuf path: a legacy
+        # encoding, rebased registers, another precision), 6 = refused
+        # as above
+        sets = route == 3
+        if sets.any():
+            route[sets & ((scan["set_form"] == 0)
+                          | (scan["set_p"] != self.sets.precision))] = 7
+            route[sets & ((mtype != metric_pb2.Set)
+                          | (scope == metric_pb2.Local))] = 6
+        wl = route.tolist()
         cache = self._import_row_cache
         counters, gauges, digests = self.counters, self.gauges, self.digests
         c_rows: list = []
@@ -929,6 +962,8 @@ class MetricAggregator:
         g_vals: list = []
         d_recs: list = []       # plain digests staged: record index, row
         d_rows: list = []
+        s_recs: list = []       # set sketches staged: record index, row
+        s_rows: list = []
         misses = 0
         ok = failed = 0
         t_wait = time.perf_counter_ns()
@@ -999,7 +1034,28 @@ class MetricAggregator:
                         g_rows.append(row)
                         g_vals.append(vals[i])
                     ok += 1
-                elif w == 3 or w == 5:
+                elif w == 3:
+                    ck = (h_lo[i], h_hi[i], 3)
+                    row = cache.get(ck)
+                    if row is None:
+                        # first sighting this interval: parsed for its
+                        # name and tags, as a histogram's is
+                        try:
+                            pb = metric_pb2.Metric.FromString(
+                                payload[offs[i]:offs[i] + lens[i]])
+                            tags = list(pb.tags)
+                            row = self.sets.row_for(
+                                MetricKey(pb.name, sm.TYPE_SET,
+                                          ",".join(sorted(tags))),
+                                MetricScope.MIXED, tags)
+                        except Exception:
+                            failed += 1
+                            continue
+                        cache[ck] = row
+                    s_recs.append(i)
+                    s_rows.append(row)
+                    ok += 1
+                elif w == 5 or w == 7:
                     try:
                         pb = metric_pb2.Metric.FromString(
                             payload[offs[i]:offs[i] + lens[i]])
@@ -1007,7 +1063,7 @@ class MetricAggregator:
                         #   moments branch's asarray converts wire
                         #   vectors — host lists, no device wait)
                         self._import_slow_pb(
-                            pb, "set" if w == 3 else "histogram")
+                            pb, "set" if w == 7 else "histogram")
                         ok += 1
                     except Exception:
                         failed += 1
@@ -1027,8 +1083,49 @@ class MetricAggregator:
                 self._stage_scanned_digests(scan, d_recs, d_rows)
                 self._ledger["import_digest_hits"] += len(d_recs) - misses
                 self._ledger["import_digest_misses"] += misses
+            if s_recs:
+                # vnlint: disable=blocking-propagation (the flagged
+                #   asarray converts host lists — record indexes and
+                #   arena rows — never a device array)
+                self._stage_scanned_sets(scan, payload, s_recs, s_rows)
             self._ledger_import(t_call, t_wait, t_held)
         return ok, failed
+
+    def _stage_scanned_sets(self, scan: dict, payload: bytes, recs: list,
+                            rows: list) -> None:
+        """Stage the set sketches of one scanned payload (record
+        indexes `recs`, ascending, into arena rows `rows`) as what they
+        are: every sparse sketch's decoded (register, rank) pairs as
+        ONE chunk of (row, register, rank) triples, a dense sketch as
+        its register row.  Call under self.lock."""
+        recs = np.asarray(recs, np.int64)
+        rows = np.asarray(rows, np.int32)
+        sparse = scan["set_form"][recs] == 1
+        counts = scan["set_n"][recs][sparse]
+        idx, rank = scan["set_idx"], scan["set_rank"]
+        if int(counts.sum()) != len(idx):
+            # the flat columns also hold pairs of records not staged
+            # (refused, failed, another precision): take the staged
+            # records' ranges, in wire order
+            keep = np.zeros(scan["n"], bool)
+            keep[recs[sparse]] = True
+            keep = np.repeat(keep, scan["set_n"])
+            idx, rank = idx[keep], rank[keep]
+        if len(idx):
+            self.sets.stage_triples(np.repeat(rows[sparse], counts),
+                                    idx, rank)
+        half = self.sets.m // 2
+        for i, row in zip(recs[~sparse].tolist(),
+                          rows[~sparse].tolist()):
+            packed = np.frombuffer(payload, np.uint8, half,
+                                   int(scan["hll_off"][i]) + 8)
+            regs = np.empty(self.sets.m, np.uint8)
+            regs[0::2] = packed >> 4
+            regs[1::2] = packed & 0x0F
+            self.sets.merge_regs(row, regs)
+        n_sparse = int(sparse.sum())
+        self._ledger["set_import_sparse"] += n_sparse
+        self._ledger["set_import_dense"] += len(recs) - n_sparse
 
     def _stage_scanned_digests(self, scan: dict, recs: list,
                                rows: list) -> None:
@@ -1238,8 +1335,16 @@ class MetricAggregator:
             seg["keys_" + ar.family] = n = len(snap[name]["rows"])
             touched += n
         # rows whose estimate the chip computes this flush
-        # (_dispatch_sets); 0 on every flush that launches no set program
+        # (_dispatch_sets, _dispatch_sets_resident); 0 on every flush
+        # that launches no set program
         seg["set_rows_device"] = 0
+        lanes = self.sets.lanes_regs
+        seg["set_resident_bytes"] = 0 if lanes is None else lanes.nbytes
+        for name, v in snap.pop("set_lane_stats").items():
+            if name.endswith("_ns"):
+                seg[f"set_{name[:-3]}_s"] = v / 1e9
+            else:
+                seg[f"set_{name}"] = v
         # the window-ring cut timestamp is taken HERE (the cut), but
         # the slot is published at emit time — see _emit_pending
         snap["query_cut_ts"] = time.time()
@@ -1624,6 +1729,11 @@ class MetricAggregator:
             # first, so the register upload rides the transfer engine
             # under the digest build/layout below
             pend["sets"] = self._dispatch_sets(snap["sets"])
+        elif self.mesh is None and "lanes" in snap["sets"]:
+            # resident registers with rows touched: first too, so the
+            # program runs under the digest build
+            pend["sets"] = self._dispatch_sets_resident(snap["sets"],
+                                                        is_local)
         # the moments family launches its own program — a dense
         # segmented-sum merge + batched maxent solve, a different
         # compute class from the digest sort network — so it dispatches
@@ -1633,16 +1743,6 @@ class MetricAggregator:
         pend["moments"] = self._dispatch_moments(snap)
         pend["compactors"] = self._dispatch_compactors(snap)
         if self.mesh is None:
-            spart = snap["sets"]
-            if self.sets.host_regs is None and len(spart["rows"]):
-                # resident set registers (flush_resident_arenas):
-                # dispatch ONE device gather of the touched rows'
-                # lane-union registers; the fetch reads the exact u8
-                # rows back and estimates HOST-side
-                ps = self._padded_rows(spart["rows"])
-                pend["set_rows_dev"] = serving.set_gather_rows(
-                    spart["lanes"], jnp.asarray(ps))
-                pend["set_ps"] = ps
             if nd == 0:
                 return pend
             uniform = dpart["uniform"]
@@ -1944,9 +2044,37 @@ class MetricAggregator:
         seg["set_rows_device"] = n
         seg["set_upload_bytes"] = regs.nbytes
         seg["upload_bytes"] = seg.get("upload_bytes", 0) + regs.nbytes
-        return {"ests": ests, "t0": t0,
+        return {"out": ests, "form": "bucket", "t0": t0,
                 "stats": {"rows": n, "upload_s": t1 - t0,
                           "dispatch_s": time.perf_counter() - t1}}
+
+    def _dispatch_sets_resident(self, spart: dict, is_local: bool) -> dict:
+        """LAUNCH the flush's read of an unmeshed resident arena's
+        pinned lane snapshot (outside the lock; nothing is uploaded).
+        A tier that forwards no set this flush — a global, or a local
+        with no mixed-scope set row — estimates where the registers
+        live: the whole-plane program, [capacity] f32 back.  A
+        forwarding local needs the registers themselves to marshal, and
+        fewer than _SET_DEVICE_MIN_ROWS rows are not worth the plane's
+        pass: both gather the touched rows' u8 registers back, for
+        hll.estimate_np_rows at the fetch."""
+        seg = self.last_flush_segments
+        rows = spart["rows"]
+        n = len(rows)
+        t0 = time.perf_counter()
+        forwards = is_local and bool(
+            (spart["scopes"] == int(MetricScope.MIXED)).any())
+        if forwards or n < _SET_DEVICE_MIN_ROWS:
+            form, out = "regs", self.sets.lane_gather(
+                spart["lanes"], self._padded_rows(rows))
+        else:
+            form, out = "plane", self.sets.lane_estimate(spart["lanes"])
+            seg["set_rows_device"] = n
+        out.copy_to_host_async()
+        seg["set_upload_bytes"] = 0
+        return {"out": out, "form": form, "t0": t0,
+                "stats": {"rows": n, "upload_s": 0.0,
+                          "dispatch_s": time.perf_counter() - t0}}
 
     def _dispatch_moments(self, snap: dict) -> Optional[dict]:
         """Build, stage and LAUNCH the moments-family program on the
@@ -2059,35 +2187,32 @@ class MetricAggregator:
                 # the solver's residual rides the last column
                 host["m_resid"] = out[:fp["n"], -1]
         if not pend["meshed"]:
-            if "set_rows_dev" in pend:
-                # resident set registers: exact u8 readback of the
-                # touched rows, estimated HOST-side; the registers
-                # double as the forwarding marshal source
-                # (host["set_regs"])
-                srows = snap["sets"]["rows"]
-                t0 = time.perf_counter()
-                regs = serving.fetch(
-                    pend["set_rows_dev"])[:len(srows)]
-                seg["set_device_s"] = time.perf_counter() - t0
-                seg["readback_bytes"] = (seg.get("readback_bytes", 0)
-                                         + regs.nbytes)
-                host["set_ests"] = (
-                    hll_mod.estimate_np_rows(regs) if len(regs)
-                    else np.zeros(0, np.float64))
-                host["set_regs"] = regs
             sp = pend.get("sets", {})
             set_t0 = sp.get("t0")       # None: nothing was launched
             set_wait = 0.0
             if set_t0 is not None:
+                srows = snap["sets"]["rows"]
                 t0 = time.perf_counter()
-                ests = serving.fetch(sp["ests"])
+                got = serving.fetch(sp["out"])
                 set_wait = time.perf_counter() - t0
                 sp["stats"]["wait_s"] = seg["set_device_s"] = set_wait
                 seg["device_sets"] = sp["stats"]
+                seg["set_readback_bytes"] = got.nbytes
                 seg["readback_bytes"] = (seg.get("readback_bytes", 0)
-                                         + ests.nbytes)
-                # padding rows of the bucket stop here
-                host["set_ests"] = ests[:len(snap["sets"]["rows"])]
+                                         + got.nbytes)
+                if sp["form"] == "regs":
+                    # resident registers, gathered: exact u8 rows,
+                    # estimated HOST-side; they double as the
+                    # forwarding marshal source (host["set_regs"])
+                    regs = got[:len(srows)]
+                    host["set_ests"] = hll_mod.estimate_np_rows(regs)
+                    host["set_regs"] = regs
+                elif sp["form"] == "plane":
+                    # the resident plane's estimates, every row's
+                    host["set_ests"] = got[srows]
+                else:
+                    # the uploaded bucket's: its padding rows stop here
+                    host["set_ests"] = got[:len(srows)]
             elif sp:
                 host["set_ests"] = sp["ests"]
             if nd == 0:
@@ -2230,6 +2355,7 @@ class MetricAggregator:
         for name, ar in arenas:
             ar.reset_rows(snap[name]["rows"])
             ar.end_interval()
+        snap["set_lane_stats"] = self.sets.take_lane_stats()
         if self.cardinality is not None:
             self._cardinality_end_interval()
         if self.cubes is not None:

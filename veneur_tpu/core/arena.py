@@ -70,6 +70,17 @@ def _pow2(n: int) -> int:
 
 _INITIAL_CAPACITY = 1024
 
+# Fewer touched set rows than this are estimated by numpy (0.3 ms a row
+# on a v5e's host, outside the lock) instead of on the chip: a launch
+# costs the host more than that below a few rows, and the one row a
+# server's own telemetry touches in a flush out of a hundred
+# (ssf.names_unique, sampled at 1 %) must not compile a [1, m] program
+# inside that flush.  8 = the rows of one f32 tile.  Host registers
+# estimate from the snapshot's copy (aggregator._dispatch_sets); a
+# resident arena gathers the rows' u8 registers back first
+# (SetArena.lane_gather, at row buckets its boot has launched).
+SET_DEVICE_MIN_ROWS = 8
+
 
 class _StagedPoints:
     """One interval's consolidated staged points: three preallocated
@@ -771,11 +782,27 @@ class SetArena(_ArenaBase):
     """Unique-count sets as HLL register rows (Set sampler,
     `samplers/samplers.go:242-311`).
 
-    Without a mesh the registers live on HOST (`[capacity, m]` uint8):
-    inserts are one vectorized `np.maximum.at`, merges a register-wise
-    max, estimates a batched numpy LogLog-Beta — there is nothing to
-    reduce over on a single device, and keeping 16 KiB/row off the device
-    keeps flush traffic at zero for this family.
+    Where the registers live follows what the configuration says of the
+    arena's size.  At its default size an unmeshed arena keeps them on
+    the HOST (`[capacity, m]` uint8): inserts are one vectorized
+    `np.maximum.at`, merges a register-wise max, and a flush uploads a
+    copy of the touched rows for the device estimate
+    (`aggregator._dispatch_sets`) — a node's thousand set keys are
+    16 MB a flush.
+
+    Pre-sized for its deployment (`set_arena_initial_capacity`), or
+    with `flush_resident_arenas`, an unmeshed arena is RESIDENT: the
+    registers are one `[1, capacity, m]` device lane — 1 GiB at 65,536
+    rows of p = 14, which no flush could copy under the lock, upload
+    and read back.  Staged inserts and forwarded sparse sketches
+    scatter-max into it as (row, register, rank) triples during the
+    interval, forwarded dense sketches merge as rows, and the flush
+    estimates on the device and reads back 4 bytes a row
+    (`serving.set_estimate_plane`); only a tier that forwards its sets
+    reads u8 registers back.  Launches come at fixed lengths
+    (`serving.LANE_SCATTER_SMALL`, `LANE_SCATTER_CHUNK`,
+    `LANE_MERGE_CHUNK`), so the programs a window can need are known at
+    boot (`prewarm_lanes`).
 
     With a mesh the registers are device-resident lane stripes
     `[R_s, S, m]` sharded (rows over 'shard', lanes over 'replica');
@@ -785,6 +812,11 @@ class SetArena(_ArenaBase):
     """
 
     family = "set"
+    # the interval's lane work: triples scattered and the launches that
+    # took, dense rows merged, and the nanoseconds sync() held its
+    # caller (the aggregator lock) for
+    LANE_STATS = ("scatter_points", "scatter_launches", "merge_rows",
+                  "sync_ns")
 
     def __init__(self, capacity: int = _INITIAL_CAPACITY,
                  precision: int = hll_mod.DEFAULT_PRECISION, mesh=None,
@@ -794,15 +826,13 @@ class SetArena(_ArenaBase):
         self.precision = precision
         self.m = 1 << precision
         self.n_lanes = self._init_mesh_lanes(mesh, "set")
-        # flush_resident_arenas: an UNMESHED arena keeps its registers
-        # device-resident too — the same [1, capacity, m] lane machinery
-        # the meshed tiers run (scatter-max sync, pinned snapshots, the
-        # copying-kernel donation fallback), with one lane and no
-        # sharding.  Inserts then stream to HBM during the interval and
-        # the flush reads back only the touched rows' registers
-        # (serving.set_gather_rows); estimates still compute HOST-side
-        # on the exact u8 readback (hll.estimate_np_rows: within one
-        # count of the host-register path's device estimate).
+        # resident: an UNMESHED arena keeps its registers on the device
+        # too — the same [1, capacity, m] lane machinery the meshed
+        # tiers run (scatter-max sync, pinned snapshots, the copying-
+        # kernel donation fallback), with one lane and no sharding.
+        # The aggregator asks for it when the configuration pre-sized
+        # the arena (set_arena_initial_capacity) or says
+        # flush_resident_arenas; see the class docstring.
         self.resident = bool(resident) and mesh is None
         # Rolling-upgrade migration lane (hll_legacy_migration): legacy
         # 'VH' imports carry blake2b-hashed members which do NOT union
@@ -819,9 +849,7 @@ class SetArena(_ArenaBase):
             self.lanes_regs = None
         else:
             self.host_regs = None
-            self.lanes_regs = serving.put(
-                np.zeros((self.n_lanes, capacity, self.m), np.uint8),
-                self._lane_shd)
+            self.lanes_regs = self._zero_lanes()
         # count of dispatched-but-not-yet-fetched flushes holding a
         # lane-register snapshot (incremented by snapshot_lanes(),
         # decremented by unpin_lanes() after the flush fetch): while
@@ -841,8 +869,30 @@ class SetArena(_ArenaBase):
         self._stage_hashes: list[int] = []
         # pre-hashed array staging from the native ingest engine
         self._stage_chunks: list[tuple[np.ndarray, np.ndarray]] = []
-        # imported register rows, unioned host-side until sync
+        # (rows, register index, rank) arrays staged as what they are:
+        # forwarded sparse sketches, decoded by the import's wire scan
+        self._stage_triples: list[tuple] = []
+        # imported dense register rows, unioned host-side until sync
         self._merge_rows: dict[int, np.ndarray] = {}
+        # what the lane syncs of the open interval did, for the flush
+        # timeline's row (take_lane_stats, at the cut)
+        self._lane_stats = dict.fromkeys(self.LANE_STATS, 0)
+
+    def _zero_lanes(self):
+        """A fresh all-zero lane plane of the live one's shape and
+        sharding; unmeshed it is made on the device (no gigabyte of host
+        zeros to upload)."""
+        shape = (self.n_lanes, self.capacity, self.m)
+        if self._lane_shd is None:
+            return jnp.zeros(shape, jnp.uint8)
+        return serving.put(np.zeros(shape, np.uint8), self._lane_shd)
+
+    def take_lane_stats(self) -> dict:
+        """The open interval's LANE_STATS, handed over and zeroed (call
+        under the aggregator lock, at the cut)."""
+        out, self._lane_stats = (self._lane_stats,
+                                 dict.fromkeys(self.LANE_STATS, 0))
+        return out
 
     def _grow_state(self, old: int) -> None:
         if self.host_regs is not None:
@@ -869,13 +919,25 @@ class SetArena(_ArenaBase):
         """Stage members already metro-hashed by the native ingest engine."""
         self._stage_chunks.append((rows, hashes))
 
+    def stage_triples(self, rows: np.ndarray, idx: np.ndarray,
+                      rank: np.ndarray) -> None:
+        """Stage decoded sparse sketches: register `idx[i]` of arena
+        row `rows[i]` is at least `rank[i]` (a 17-member set is 17
+        triples, never a 16 KiB row)."""
+        self._stage_triples.append((rows, idx, rank))
+
     def staged_count(self) -> int:
         return (len(self._stage_rows)
                 + sum(len(r) for r, _ in self._stage_chunks)
+                + sum(len(r) for r, _, _ in self._stage_triples)
                 + len(self._merge_rows))
 
     def merge(self, row: int, payload: bytes) -> None:
         other, legacy = hll_mod.unmarshal_ex(payload)
+        if len(other) != self.m:
+            raise ValueError(
+                f"set sketch of {len(other)} registers, arena has "
+                f"{self.m}")
         if legacy and self.legacy_migration:
             mine = self._legacy_regs.get(row)
             if mine is None:
@@ -883,11 +945,16 @@ class SetArena(_ArenaBase):
             else:
                 np.maximum(mine, other, out=mine)
             return
+        self.merge_regs(row, other)
+
+    def merge_regs(self, row: int, regs: np.ndarray) -> None:
+        """Union one dense register row `[m]` into `row` (a forwarded
+        dense sketch; the caller's array is kept)."""
         mine = self._merge_rows.get(row)
         if mine is None:
-            self._merge_rows[row] = other.copy()
+            self._merge_rows[row] = regs
         else:
-            np.maximum(mine, other, out=mine)
+            np.maximum(mine, regs, out=mine)
 
     def legacy_estimates(self, rows: np.ndarray) -> "np.ndarray | None":
         """Per-row LogLog-Beta estimates of the migration side lane (0
@@ -906,8 +973,24 @@ class SetArena(_ArenaBase):
                 out[i] = e
         return out
 
-    def _staged_triples(self):
-        """Consume raw staging into (rows, register index, rank) arrays."""
+    def _scatter_pad(self, n: int) -> int:
+        """The length one launch of a tick's `n` staged triples is
+        padded to.  Unmeshed resident: the closed set prewarm_lanes
+        launched (serving.LANE_SCATTER_*), a large tick taking several
+        launches.  Meshed: the tick's own power of two, one launch."""
+        if not self.resident:
+            return self._pad_pow2(n)
+        if n == 1:
+            return 1
+        return (serving.LANE_SCATTER_SMALL
+                if n <= serving.LANE_SCATTER_SMALL
+                else serving.LANE_SCATTER_CHUNK)
+
+    def _staged_triples(self, padded: bool = False):
+        """Consume raw staging into (count, rows, register index, rank)
+        arrays, joined once.  `padded`: zero-padded up to a multiple of
+        the count's launch length (_scatter_pad) — the device launches'
+        chunks are then views of them, not second copies."""
         parts_r: list[np.ndarray] = []
         parts_h: list[np.ndarray] = []
         if self._stage_rows:
@@ -915,64 +998,78 @@ class SetArena(_ArenaBase):
             parts_h.append(np.asarray(self._stage_hashes, np.uint64))
             self._stage_rows, self._stage_hashes = [], []
         for r, h in self._stage_chunks:
-            parts_r.append(r.astype(np.int64, copy=False))
+            parts_r.append(r)
             parts_h.append(h)
         self._stage_chunks = []
-        rows = (parts_r[0] if len(parts_r) == 1
-                else np.concatenate(parts_r))
-        hs = parts_h[0] if len(parts_h) == 1 else np.concatenate(parts_h)
-        idx, rank = hll_mod.split_hashes(hs, self.precision)
-        return rows, idx, rank
+        triples = self._stage_triples
+        self._stage_triples = []
+        if parts_r:
+            triples.append((np.concatenate(parts_r), *hll_mod.split_hashes(
+                np.concatenate(parts_h), self.precision)))
+        n = sum(len(r) for r, _, _ in triples)
+        pad = self._scatter_pad(n) if padded else 1
+        out = [np.zeros(-(-n // pad) * pad, dt)
+               for dt in (np.int32, np.int32, np.uint8)]
+        for buf, col in zip(out, zip(*triples)):
+            np.concatenate(col, out=buf[:n], casting="same_kind")
+        return (n, *out)
 
     def sync(self) -> None:
         """Fold staged inserts and imported rows into the registers."""
-        if self.host_regs is not None:
-            if self._stage_rows or self._stage_chunks:
-                rows, idx, rank = self._staged_triples()
-                np.maximum.at(self.host_regs, (rows, idx), rank)
-            if self._merge_rows:
-                for row, regs in self._merge_rows.items():
-                    np.maximum(self.host_regs[row], regs,
-                               out=self.host_regs[row])
-                self._merge_rows = {}
+        staged = (self._stage_rows or self._stage_chunks
+                  or self._stage_triples)
+        if not staged and not self._merge_rows:
             return
-        # meshed: scatter into the device lanes (padding entries are
-        # all-zero ranks/registers, which max() ignores, so the pow-of-two
-        # padding only buys jit-cache reuse)
-        if self._stage_rows or self._stage_chunks:
-            rows, idx, rank = self._staged_triples()
-            n = len(rows)
-            padded = self._pad_pow2(n)
-            pr = np.zeros(padded, np.int32)
-            pi = np.zeros(padded, np.int32)
-            pk = np.zeros(padded, np.uint8)
-            pr[:n] = rows
-            pi[:n] = idx
-            pk[:n] = rank
+        t0 = time.perf_counter_ns()
+        stats = self._lane_stats
+        if self.host_regs is not None:
+            if staged:
+                n, rows, idx, rank = self._staged_triples()
+                np.maximum.at(self.host_regs, (rows, idx), rank)
+                stats["scatter_points"] += n
+            for row, regs in self._merge_rows.items():
+                np.maximum(self.host_regs[row], regs,
+                           out=self.host_regs[row])
+        else:
+            # device lanes (padding entries are all-zero ranks and
+            # registers, which max() ignores: the padding only buys
+            # program reuse)
+            if staged:
+                self._scatter_staged(*self._staged_triples(padded=True))
+            if self._merge_rows:
+                self._merge_staged(sorted(self._merge_rows.items()))
+        stats["merge_rows"] += len(self._merge_rows)
+        self._merge_rows = {}
+        stats["sync_ns"] += time.perf_counter_ns() - t0
+
+    def _scatter_staged(self, n: int, pr, pi, pk) -> None:
+        """Launch the padded triples (_staged_triples), _scatter_pad at
+        a time."""
+        pad = self._scatter_pad(n)
+        for off in range(0, len(pr), pad):
             lane = self._seq % self.n_lanes
             self._seq += 1
             self.lanes_regs = self._lane_scatter(
-                self.lanes_regs, pr, pi, pk, lane, self._lane_donate_ok())
-        if self._merge_rows:
-            items = sorted(self._merge_rows.items())
-            self._merge_rows = {}
-            n = len(items)
-            padded = self._pad_pow2(n)
-            pr = np.zeros(padded, np.int32)
-            mat = np.zeros((padded, self.m), np.uint8)
-            for i, (row, regs) in enumerate(items):
+                self.lanes_regs, pr[off:off + pad], pi[off:off + pad],
+                pk[off:off + pad], lane, self._lane_donate_ok())
+            self._lane_stats["scatter_launches"] += 1
+        self._lane_stats["scatter_points"] += n
+
+    def _merge_staged(self, items: list) -> None:
+        """Launch the imported dense rows: LANE_MERGE_CHUNK at a time
+        on the resident plane, the tick's own power of two meshed."""
+        chunk = (serving.LANE_MERGE_CHUNK if self.resident
+                 else self._pad_pow2(len(items)))
+        for off in range(0, len(items), chunk):
+            pr = np.zeros(chunk, np.int32)
+            mat = np.zeros((chunk, self.m), np.uint8)
+            for i, (row, regs) in enumerate(items[off:off + chunk]):
                 pr[i] = row
                 mat[i] = regs
             lane = self._seq % self.n_lanes
             self._seq += 1
-            donate = self._lane_donate_ok()
-            merge = (serving.set_lane_merge_rows if donate
-                     else serving.set_lane_merge_rows_copy)
-            with self._guard(("set_lane_merge", self.lanes_regs.shape,
-                              padded, lane, donate)):
-                self.lanes_regs = merge(
-                    self.lanes_regs, jnp.asarray(pr), jnp.asarray(mat),
-                    lane)
+            self.lanes_regs = self._lane_merge(
+                self.lanes_regs, pr, mat, lane, self._lane_donate_ok())
 
     def _guard(self, key):
         """The owner's compile guard around a lane-kernel launch (the
@@ -993,36 +1090,99 @@ class SetArena(_ArenaBase):
             return scatter(lanes, jnp.asarray(pr), jnp.asarray(pi),
                            jnp.asarray(pk), lane)
 
-    def _lane_reset(self, lanes, idx):
-        """Launch the row reset (a fresh buffer, rows `idx` zeroed)."""
+    def _lane_merge(self, lanes, pr, mat, lane: int, donate: bool):
+        """Launch the register-wise max of padded dense rows into
+        `lane` of `lanes`."""
+        merge = (serving.set_lane_merge_rows if donate
+                 else serving.set_lane_merge_rows_copy)
+        with self._guard(("set_lane_merge", lanes.shape, len(pr), lane,
+                          donate)):
+            return merge(lanes, jnp.asarray(pr), jnp.asarray(mat), lane)
+
+    def _lane_reset(self, lanes, rows: np.ndarray):
+        """Launch the row reset (a fresh buffer, `rows` zeroed): the
+        meshed lanes by a padded index vector, the unmeshed resident
+        plane by a keep-mask of its one shape."""
+        if self.resident:
+            keep = np.ones(lanes.shape[1], np.uint8)
+            keep[rows] = 0
+            with self._guard(("set_lane_reset", lanes.shape)):
+                return serving.set_reset_mask(lanes, jnp.asarray(keep))
+        idx = self._reset_index(rows)
         with self._guard(("set_lane_reset", lanes.shape, len(idx))):
             return serving.set_reset_rows(lanes, jnp.asarray(idx))
 
-    def prewarm_lanes(self) -> None:
+    def lane_estimate(self, lanes):
+        """Launch the whole-plane estimate on a pinned lane snapshot:
+        [capacity] f32, asynchronous (the flush of a resident arena
+        that forwards no set)."""
+        with self._guard(("set_estimate_plane", lanes.shape)):
+            return serving.set_estimate_plane(lanes)
+
+    def lane_gather(self, lanes, padded_rows: np.ndarray):
+        """Launch the u8 readback gather of `padded_rows`' lane-union
+        registers from a pinned lane snapshot (a forwarding tier's
+        marshal source; a handful of rows for the numpy estimate)."""
+        with self._guard(("set_gather_rows", lanes.shape,
+                          len(padded_rows))):
+            return serving.set_gather_rows(lanes,
+                                           jnp.asarray(padded_rows))
+
+    def prewarm_lanes(self) -> int:
         """Compile the lane programs whose shapes are known at boot, by
         running each once on a scratch all-zero lane buffer of the live
         one's shape and sharding (never the live registers: a donating
-        kernel would have to run under the aggregator lock): the
-        per-flush row reset at the one-row index an untouched interval
-        and a single touched row both use, and the one-sample scatter —
-        the server's own 1 %-sampled `ssf.names_unique` SET — into every
-        lane, in the donating form and the copying one a pinned
-        snapshot forces.  Imported register rows (set_lane_merge_rows)
-        come in the fleet's row counts, which no configuration names:
-        their first launch compiles, counted by the guard."""
+        kernel would have to run under the aggregator lock).  Returns
+        the programs launched.
+
+        Meshed: the per-flush row reset at the one-row index an
+        untouched interval and a single touched row both use, and the
+        one-sample scatter — the server's own 1 %-sampled
+        `ssf.names_unique` SET — into every lane, in the donating form
+        and the copying one a pinned snapshot forces.  What a fleet
+        sends beyond that compiles at its first launch, counted by the
+        guard.
+
+        Unmeshed resident — a closed set, so that no window compiles:
+        the mask reset; the scatter at its three launch lengths
+        (_scatter_pad) and the dense-row merge at LANE_MERGE_CHUNK, each
+        donating and copying; the whole-plane estimate; and the u8
+        gather at the row buckets below the device estimate's floor (an
+        interval that brought only the server's own set)."""
         if self.lanes_regs is None:
-            return
-        lanes = serving.put(
-            np.zeros(self.lanes_regs.shape, np.uint8), self._lane_shd)
-        lanes = self._lane_reset(lanes, self._reset_index(np.zeros(0)))
-        one = np.zeros(1, np.int32)
-        rank = np.zeros(1, np.uint8)
+            return 0
+        n = 1
+        lanes = self._lane_reset(self._zero_lanes(),
+                                 np.zeros(0, np.int64))
         forms = (True, False) if serving.lane_donation_ok() else (False,)
+        pads = ((1, serving.LANE_SCATTER_SMALL, serving.LANE_SCATTER_CHUNK)
+                if self.resident else (1,))
         for lane in range(self.n_lanes):
             for donate in forms:
-                lanes = self._lane_scatter(lanes, one, one, rank, lane,
-                                           donate)
+                for pad in pads:
+                    zeros = np.zeros(pad, np.int32)
+                    lanes = self._lane_scatter(
+                        lanes, zeros, zeros, np.zeros(pad, np.uint8),
+                        lane, donate)
+                    n += 1
+                if self.resident:
+                    lanes = self._lane_merge(
+                        lanes, np.zeros(serving.LANE_MERGE_CHUNK, np.int32),
+                        np.zeros((serving.LANE_MERGE_CHUNK, self.m),
+                                 np.uint8), lane, donate)
+                    n += 1
+        if self.resident:
+            outs = [self.lane_estimate(lanes)]
+            bucket = 1
+            while bucket <= _pow2(SET_DEVICE_MIN_ROWS - 1):
+                outs.append(self.lane_gather(
+                    lanes, np.zeros(bucket, np.int32)))
+                bucket *= 2
+            n += len(outs)
+            for out in outs:
+                out.block_until_ready()
         lanes.block_until_ready()
+        return n
 
     def _lane_donate_ok(self) -> bool:
         """In-place (donating) lane updates are legal only when no
@@ -1078,14 +1238,14 @@ class SetArena(_ArenaBase):
             if len(rows):
                 part["host_regs"] = self.host_regs_copy(rows)
         elif self.mesh is not None or len(rows):
-            # device lanes — meshed, or unmeshed-resident
-            # (flush_resident_arenas): the flush reads the pinned lane
-            # snapshot (pmax-merge meshed, set_gather_rows resident) and
-            # resident estimates compute at FETCH time on the exact u8
-            # readback.  Meshed always pins (the SPMD program takes the
-            # full lane plane every flush); resident pins only when set
-            # rows were touched — an untouched interval dispatches no
-            # set gather, so nothing would ever read the snapshot.
+            # device lanes — meshed, or unmeshed-resident: the flush
+            # reads the pinned lane snapshot (pmax-merge meshed; the
+            # whole-plane estimate resident, or the u8 gather where the
+            # tier forwards its sets).  Meshed always pins (the SPMD
+            # program takes the full lane plane every flush); resident
+            # pins only when set rows were touched — an untouched
+            # interval dispatches no set program, so nothing would ever
+            # read the snapshot.
             # Whoever holds the part owes the unpin_lanes()
             part["lanes"] = self.snapshot_lanes()
         return part
@@ -1110,8 +1270,7 @@ class SetArena(_ArenaBase):
         # runs even for empty rows while a snapshot is pinned: the
         # kernel swaps in a fresh buffer so the flush snapshot never
         # aliases the live (donatable) one
-        self.lanes_regs = self._lane_reset(self.lanes_regs,
-                                           self._reset_index(rows))
+        self.lanes_regs = self._lane_reset(self.lanes_regs, rows)
 
     def _checkpoint_arrays(self) -> dict:
         # call after sync(): staging and imported-row unions are folded

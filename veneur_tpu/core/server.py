@@ -542,6 +542,14 @@ class Server:
             # sender that cannot connect yet retries or spools; ~45 s
             # from a cold compile cache, ~1.5 s from a warm one.
             self._prewarm()
+        elif self.aggregator.sets.resident:
+            # an unmeshed arena whose set registers live on the device
+            # launches its lane programs — a closed set, sized by the
+            # arena's capacity (SetArena.prewarm_lanes) — on a scratch
+            # plane before it listens, for the meshed boot's reason: at
+            # 1 GiB of registers each compiles for seconds, and a first
+            # launch inside an interval is a late flush
+            self._prewarm_set_lanes()
         has_udp_statsd = any(
             parse_listen_addr(a)[0] == "udp"
             for a in self.config.statsd_listen_addresses)
@@ -1556,21 +1564,36 @@ class Server:
         (MetricAggregator.prewarm).  What it did is a `server.prewarm`
         span in the flight recorder and `prewarm_programs` /
         `prewarm_seconds` in /debug/vars."""
-        from veneur_tpu import trace as trace_mod
         cap = self.config.arena_initial_capacity or 8192
+        # prewarm rounds up to the arena's pow2 capacity internally,
+        # so the top bucket a ramp can reach is always covered
+        self._account_prewarm(
+            lambda: self.aggregator.prewarm(
+                list(self.config.prewarm_depths), cap,
+                stop=self._shutdown),
+            "flush prewarm failed; first flushes of each shape will "
+            "compile in place")
+
+    def _prewarm_set_lanes(self) -> None:
+        """Boot-time launch of a resident set arena's lane programs,
+        accounted like _prewarm."""
+        self._account_prewarm(
+            self.aggregator.sets.prewarm_lanes,
+            "set lane prewarm failed; first launches of each lane "
+            "program will compile in place")
+
+    def _account_prewarm(self, compile_programs, on_failure: str) -> None:
+        """Run one boot-time compile pass (returns programs compiled)
+        under a `server.prewarm` span, into prewarm_stats."""
+        from veneur_tpu import trace as trace_mod
         span = trace_mod.Span("server.prewarm",
                               service=self.config.hostname)
         t0 = time.perf_counter()
         try:
-            # prewarm rounds up to the arena's pow2 capacity internally,
-            # so the top bucket a ramp can reach is always covered
-            self.prewarm_stats["programs"] += self.aggregator.prewarm(
-                list(self.config.prewarm_depths), cap,
-                stop=self._shutdown)
+            self.prewarm_stats["programs"] += compile_programs()
         except Exception:
             span.error = True
-            logger.exception("flush prewarm failed; first flushes of "
-                             "each shape will compile in place")
+            logger.exception(on_failure)
         finally:
             self.prewarm_stats["seconds"] += time.perf_counter() - t0
             span.tags = {"programs": str(self.prewarm_stats["programs"])}

@@ -172,10 +172,20 @@ def debug_vars(server) -> dict:
     # the flush timeline's rows): points handed to the flush, bytes of
     # them copied under the aggregator lock (0 = nothing joined at the
     # tick), buffer doublings over the interval
-    from veneur_tpu.core.aggregator import STAGED_LEDGER_KEYS
+    from veneur_tpu.core.aggregator import (SET_LEDGER_KEYS,
+                                            STAGED_LEDGER_KEYS)
     segs = agg.last_flush_segments
     stats["staged_accumulator"] = {
         key: segs.get(key, 0) for key in STAGED_LEDGER_KEYS}
+    # what the set arena's lanes did in the last interval and its flush
+    # (also on the flush timeline's rows): register bytes resident on
+    # the device, rows estimated there, bytes uploaded and read back,
+    # triples scattered / launches / dense rows merged, the lane syncs'
+    # lock hold, and the forwarded sketches staged by wire form
+    stats["set_lanes"] = {
+        key: segs.get(key, 0)
+        for key in (*SET_LEDGER_KEYS, "set_import_sparse",
+                    "set_import_dense")}
     guard = getattr(server.aggregator, "cardinality", None)
     if guard is not None:
         # per-tenant key-budget ledger: exact keys, evicted
@@ -211,7 +221,8 @@ def debug_vars(server) -> dict:
         stats["ingest_overflow"] = dict(
             getattr(server, "ingest_overflow", None) or {})
     prewarm = getattr(server, "prewarm_stats", None)
-    if prewarm is not None and server.config.prewarm_flush_shapes:
+    if prewarm is not None and (server.config.prewarm_flush_shapes
+                                or prewarm["programs"]):
         # the boot-time compile of the configured flush shapes: programs
         # compiled so far and their wall seconds
         stats["prewarm_programs"] = prewarm["programs"]

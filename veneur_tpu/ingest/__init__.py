@@ -180,6 +180,9 @@ def load_library():
         lib.vn_import_scan_digests.argtypes = [
             ctypes.c_void_p, ctypes.POINTER(ctypes.c_longlong)
         ] + [ctypes.POINTER(ctypes.c_void_p)] * 8
+        lib.vn_import_scan_sets.argtypes = [
+            ctypes.c_void_p, ctypes.POINTER(ctypes.c_longlong)
+        ] + [ctypes.POINTER(ctypes.c_void_p)] * 8
         lib.vn_import_scan_free.argtypes = [ctypes.c_void_p]
         _lib = lib
         return lib
@@ -194,8 +197,14 @@ def import_scan(payload: bytes):
     {cent_mean, cent_weight (f64, every record's centroids in wire
     order), cent_off, cent_n (i64, the record's range in those two),
     dmin, dmax, drsum, compression (f64 per record; zeros for the other
-    kinds)} — copies, safe after free — or None if the payload failed
-    the wire scan (caller falls back to protobuf parsing)."""
+    kinds)} and, from each set record's axiomhq sketch, {set_form (u8:
+    1 = sparse, decoded; 2 = dense with base 0; 0 = for python's
+    unmarshal to look at), set_p (u8 precision), hll_off, hll_len (i64:
+    the sketch's bytes in the payload), set_idx (i32), set_rank (u8:
+    every sparse record's (register, rank) pairs in wire order),
+    set_off, set_n (i64, the record's range in those two)} — copies,
+    safe after free — or None if the payload failed the wire scan
+    (caller falls back to protobuf parsing)."""
     import numpy as np
 
     lib = load_library()
@@ -212,6 +221,10 @@ def import_scan(payload: bytes):
         dptrs = [ctypes.c_void_p() for _ in range(8)]
         lib.vn_import_scan_digests(handle, ctypes.byref(n_cent),
                                    *map(ctypes.byref, dptrs))
+        n_pairs = ctypes.c_longlong()
+        sptrs = [ctypes.c_void_p() for _ in range(8)]
+        lib.vn_import_scan_sets(handle, ctypes.byref(n_pairs),
+                                *map(ctypes.byref, sptrs))
 
         def arr(ptr, dtype, count=n):
             if count == 0:      # an empty vector's data() may be null
@@ -239,6 +252,14 @@ def import_scan(payload: bytes):
             "dmax": arr(dptrs[5], np.float64),
             "drsum": arr(dptrs[6], np.float64),
             "compression": arr(dptrs[7], np.float64),
+            "set_idx": arr(sptrs[0], np.int32, n_pairs.value),
+            "set_rank": arr(sptrs[1], np.uint8, n_pairs.value),
+            "set_form": arr(sptrs[2], np.uint8),
+            "set_p": arr(sptrs[3], np.uint8),
+            "hll_off": arr(sptrs[4], np.int64),
+            "hll_len": arr(sptrs[5], np.int64),
+            "set_off": arr(sptrs[6], np.int64),
+            "set_n": arr(sptrs[7], np.int64),
         }
     finally:
         lib.vn_import_scan_free(handle)

@@ -597,8 +597,29 @@ def partial_digests(dense_v: jax.Array, dense_w: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# Set (HLL) lane kernels — device-resident register state (meshed tiers)
+# Set (HLL) lane kernels — device-resident register state (meshed tiers,
+# and an unmeshed arena pre-sized for its deployment: SetArena.resident)
 # ---------------------------------------------------------------------------
+
+# An unmeshed resident arena launches a sync tick's staged (row,
+# register, rank) triples padded to one of three sizes — a lone triple
+# (the server's own 1 %-sampled `ssf.names_unique`) at 1, a tick of up
+# to LANE_SCATTER_SMALL at that, anything larger in chunks of
+# LANE_SCATTER_CHUNK — and its imported dense register rows in chunks of
+# LANE_MERGE_CHUNK rows: the shapes a window can need are then known at
+# boot (SetArena.prewarm_lanes launches each once), whatever a fleet
+# sends.  2^20 triples are 9 MB of operands a launch, and a large launch
+# is worth filling: the chip's compiler lays the tiled u8 plane out flat
+# for an elementwise scatter and back again, two passes over the whole
+# plane (tests/test_chip_compile.py) — 24 ms a launch on a 1 GiB plane
+# from 2^17 triples up, 9 ms at 2^12 (PERF.md section 6, PR 32).  2^12
+# triples (36 KB) are what a node's UDP set lines bring a drain tick;
+# 128 rows are 2 MiB at p = 14.  Meshed lanes pad each tick to its own power of
+# two, one launch, and compile what a fleet sends at its first launch.
+LANE_SCATTER_SMALL = 1 << 12
+LANE_SCATTER_CHUNK = 1 << 20
+LANE_MERGE_CHUNK = 128
+
 
 def _set_lane_scatter(lanes_regs: jax.Array, rows: jax.Array,
                       idx: jax.Array, rank: jax.Array,
@@ -607,7 +628,8 @@ def _set_lane_scatter(lanes_regs: jax.Array, rows: jax.Array,
     `lane` of the `[R_s, S, m]` register state — the device half of
     Sketch.Insert (`samplers/samplers.go:242-244`).  Padding entries with
     rank 0 are no-ops (max against an empty register)."""
-    return lanes_regs.at[lane, rows, idx].max(rank)
+    with jax.named_scope("set.lane_scatter"):
+        return lanes_regs.at[lane, rows, idx].max(rank)
 
 
 def _set_lane_merge_rows(lanes_regs: jax.Array, rows: jax.Array,
@@ -615,7 +637,8 @@ def _set_lane_merge_rows(lanes_regs: jax.Array, rows: jax.Array,
     """Register-wise max of imported full register rows `[n, m]` into lane
     `lane` (Set.Merge, `samplers/samplers.go:299-311`).  All-zero padding
     rows are no-ops."""
-    return lanes_regs.at[lane, rows].max(regmat)
+    with jax.named_scope("set.lane_merge"):
+        return lanes_regs.at[lane, rows].max(regmat)
 
 
 # In-place (donating) updates for the common case, plus COPYING twins.
@@ -654,7 +677,32 @@ def set_reset_rows(lanes_regs: jax.Array, rows: jax.Array) -> jax.Array:
     """Zero the given set rows in every lane.  NOT donating: the flush
     snapshot may still reference the pre-reset buffer while emission runs
     outside the aggregator lock."""
-    return lanes_regs.at[:, rows].set(0)
+    with jax.named_scope("set.lane_reset"):
+        return lanes_regs.at[:, rows].set(0)
+
+
+@jax.jit
+def set_reset_mask(lanes_regs: jax.Array, keep: jax.Array) -> jax.Array:
+    """The unmeshed resident arena's reset: rows whose `keep` ([S] u8)
+    is 0 come back zeroed, in every lane.  One elementwise pass whose
+    one shape is the plane's — a flush's touched count picks no program
+    (set_reset_rows' index vector follows it) — and NOT donating, for
+    set_reset_rows' reason."""
+    with jax.named_scope("set.lane_reset"):
+        return lanes_regs * keep[None, :, None]
+
+
+@jax.jit
+def set_estimate_plane(lanes_regs: jax.Array) -> jax.Array:
+    """[S] f32 LogLog-Beta estimates of every row's lane-union
+    registers, where the registers live: the flush of an unmeshed
+    resident arena that forwards no set reads 4 bytes a row back, not
+    2^p.  Whole plane, one shape (the host picks the touched rows out of
+    the answer): the arena was sized for its deployment's keys, so the
+    plane IS the touched rows' bucket, and no touched count compiles.
+    NOT donating: the flush pins the lanes (snapshot_lanes)."""
+    with jax.named_scope("flush.set_estimate"):
+        return hll_mod.estimate(jnp.max(lanes_regs, axis=0))
 
 
 @jax.jit

@@ -272,7 +272,17 @@ class MetricBatch:
         so the collections their allocation sets off (one young pass per
         700 records, and one or two passes over the whole server heap per
         batch) can free nothing, and the full passes land in one flush
-        and not in the next."""
+        and not in the next.
+
+        Lifting the pause with that many young objects counted would
+        set the deferred young pass off at the caller's next allocation
+        — over every record, before the caller has handed the list on
+        (a quarter of the whole call, and now and then an older
+        generation's pass with it).  The records are alive and hold no
+        cycle (strings, numbers, a tag list), so they go to the oldest
+        generation as they are: freeze() and unfreeze() splice the
+        generations' lists and look at no object.  They die by
+        reference count when the consumer drops the list."""
         paused = gc.isenabled()
         gc.disable()
         try:
@@ -282,6 +292,8 @@ class MetricBatch:
             out.extend(self.loose)
         finally:
             if paused:
+                gc.freeze()
+                gc.unfreeze()
                 gc.enable()
         return out
 
