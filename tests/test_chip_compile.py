@@ -218,6 +218,45 @@ def test_serving_flush_program_compiles_for_v5e(variant, one_chip,
     assert "tpu_custom_call" in lowered.compile().as_text()
 
 
+@pytest.mark.parametrize("program", ["hot_compress", "deep_tier",
+                                     "shallow_tier"])
+def test_hot_key_lane_programs_compile_for_v5e(program, one_chip, as_tpu):
+    """What `zipf.hotset` adds to a node's programs, at the shapes its
+    boot launches (`MetricAggregator.prewarm_launch`): the hot-key
+    compress on its one tile (`DigestArena._hot_compress`; plain XLA —
+    two sorts, two prefix sums, a [rows, width, ccap] counting reduce the
+    compiler must fuse, not materialise: 80 MB if it did); the deep
+    tier's program, the weighted Pallas network at DENSE_DEPTH_CAP deep
+    and DEEP_TIER_MIN_ROWS rows, donated as a standalone node launches
+    it; and the long tail's chunk of the depth-vector program at depth
+    DEEP_TIER_THRESHOLD (32,768 touched rows in two upload chunks)."""
+    from veneur_tpu.core import arena as arena_mod
+    from veneur_tpu.sketches import tdigest as td
+
+    s = lambda shape, dt=jnp.float32: _struct(one_chip, shape, dt)  # noqa
+    flush_fn = serving.make_serving_flush(None)
+    if program == "hot_compress":
+        tile = (arena_mod.HOT_TILE_ROWS, arena_mod.HOT_TILE_WIDTH)
+        compiled = serving.partial_digests.lower(
+            s(tile), s(tile), compression=100.0,
+            cap=td.centroid_capacity(100.0)).compile()
+        assert "tpu_custom_call" not in compiled.as_text()
+        mem = compiled.memory_analysis()
+        # operands 2 x 0.5 MiB; the counting reduce stays fused
+        assert mem.temp_size_in_bytes < 16 << 20
+        return
+    if program == "deep_tier":
+        u, d = arena_mod.DEEP_TIER_MIN_ROWS, arena_mod.DENSE_DEPTH_CAP
+        lowered = flush_fn.deep_tier_donated.lower(
+            s((u, d)), s((u, d)), s((2, u)), s((N_PCT,)))
+    else:
+        u, d = 16384, arena_mod.DEEP_TIER_THRESHOLD
+        lowered = flush_fn.depth_variant_donated.lower(
+            s((u, d)), s((u,), np.int16), s((N_PCT,)))
+    assert se.usable(u, d, "tpu")
+    assert "tpu_custom_call" in lowered.compile().as_text()
+
+
 @pytest.mark.parametrize("rows", [1024, 65536],
                          ids=["node1.fanout", "sets50k"])
 def test_set_estimate_compiles_for_v5e(rows, one_chip):

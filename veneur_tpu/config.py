@@ -363,12 +363,16 @@ class Config:
     # restarts near-free (on TPU backends; "" places it at
     # <checkout>/.jax_cache, and JAX_COMPILATION_CACHE_DIR in the
     # environment wins over any value here — util/compile_cache.py);
-    # prewarm compiles the configured
-    # depth buckets for every pow2 key count up to the arena pre-size in
-    # a background thread at boot, so a cardinality ramp never pays a
-    # compile inside a flush interval.  With a device mesh (mesh_devices
-    # > 0) one compile costs what that whole sweep does, and the tier is
-    # a global sized by its configuration: prewarm then compiles the
+    # prewarm: before it opens a listener, an unmeshed node LAUNCHES,
+    # once each on zeros, the closed list of programs a steady interval
+    # of its arena pre-size needs (MetricAggregator.prewarm_launch: the
+    # flush at the pre-size's two row buckets at prewarm_depths, both
+    # forms, the deep tier and the hot-key compress of a skewed
+    # interval), so that interval never pays a
+    # compile inside a flush or under the aggregator lock; a bucket
+    # below those compiles in its first flush.  With a device mesh
+    # (mesh_devices > 0) one compile takes tens of seconds, and the tier
+    # is a global sized by its configuration: prewarm then compiles the
     # meshed flush program at the bucket of arena_initial_capacity (at
     # prewarm_depths, first), the set-lane kernels, and the bucket an
     # interval of the server's own telemetry lands in — nothing between

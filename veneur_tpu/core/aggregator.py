@@ -128,6 +128,18 @@ SET_LEDGER_KEYS = ("set_resident_bytes", "set_rows_device",
 # the interval.  On the timeline row and in /debug/vars.
 STAGED_LEDGER_KEYS = ("staged_points", "staged_cut_copy_bytes",
                       "staged_regrows")
+# what the hot-key lane did over the interval and how the flush built its
+# operand, on the timeline row and in /debug/vars (-> hot_lane):
+# DigestArena.HOT_STATS as hot_* (rows pre-reduced, points into and out
+# of the compress, its launches, the passes' hold of the aggregator lock
+# summed over the interval's drain ticks and its cut), the bytes one
+# compress launch moves (operands + results, from the tile's shape), and
+# the unmeshed digest build: how many operands (tiers) it made and their
+# padded value-matrix elements, rows x depth summed (`staged_points`
+# over it is the build's fill)
+HOT_LEDGER_KEYS = ("hot_keys", "hot_points_in", "hot_points_out",
+                   "hot_compress_launches", "hot_compress_held_s",
+                   "hot_compress_tile_bytes", "dense_tiers", "dense_elems")
 LEDGER_SEGMENT_KEYS = frozenset(
     ["snapshot_lock_wait_s", "snapshot_sync_s", "snapshot_staged_s",
      "snapshot_columns_s"]
@@ -140,7 +152,7 @@ ROW_ONLY_SEGMENT_KEYS = LEDGER_SEGMENT_KEYS | {
     # the meshed launch's own (_launch_meshed): the dense shape it ran
     # and the bytes its collectives move per device
     "device_rows", "device_depth", "collective_bytes",
-    *STAGED_LEDGER_KEYS}
+    *STAGED_LEDGER_KEYS, *HOT_LEDGER_KEYS}
 
 
 def _new_ledger() -> dict:
@@ -454,6 +466,13 @@ class MetricAggregator:
         # lanes) are compile events like the flush program's
         self.sets.compile_guard = functools.partial(self._CompileGuard,
                                                     self)
+        # ... and so is the hot-key compress a drain tick launches
+        self.digests.compile_guard = self.sets.compile_guard
+        # host operands a tiered flush keeps from one build to the next
+        # (_build_tiers: the long tail's, the deep tier's), and the
+        # device results of the launches that last read them
+        self._tier_operands: tuple = ({}, {})
+        self._tier_inflight: list = []
         self._uts_m = self.unique_ts.m if self.unique_ts is not None \
             else 1 << hll_mod.DEFAULT_PRECISION
         self._pct_arr = jnp.asarray([0.5] + list(self.percentiles),
@@ -1340,11 +1359,17 @@ class MetricAggregator:
         seg["set_rows_device"] = 0
         lanes = self.sets.lanes_regs
         seg["set_resident_bytes"] = 0 if lanes is None else lanes.nbytes
-        for name, v in snap.pop("set_lane_stats").items():
-            if name.endswith("_ns"):
-                seg[f"set_{name[:-3]}_s"] = v / 1e9
-            else:
-                seg[f"set_{name}"] = v
+        # what the arenas' own lanes did over the interval (SetArena.
+        # LANE_STATS as set_*, DigestArena.HOT_STATS as hot_*)
+        for prefix, stats in (("set", snap.pop("set_lane_stats")),
+                              ("hot", snap.pop("hot_lane_stats"))):
+            for name, v in stats.items():
+                if name.endswith("_ns"):
+                    seg[f"{prefix}_{name[:-3]}_s"] = v / 1e9
+                else:
+                    seg[f"{prefix}_{name}"] = v
+        seg["hot_compress_tile_bytes"] = self.digests.hot_tile_bytes
+        seg["dense_tiers"] = seg["dense_elems"] = 0
         # the window-ring cut timestamp is taken HERE (the cut), but
         # the slot is published at emit time — see _emit_pending
         snap["query_cut_ts"] = time.time()
@@ -1623,6 +1648,64 @@ class MetricAggregator:
                 n += 1
         return n
 
+    def prewarm_launch(self, depths, max_keys: int) -> int:
+        """What an unmeshed node launches BEFORE it listens
+        (`prewarm_flush_shapes`; Server.start blocks on it, as the meshed
+        boot blocks on _prewarm_meshed): each program a steady interval
+        of a deployment sized for `max_keys` keys can need, once, on
+        zeros, through the flush's own launches (_launch_digests,
+        DigestArena._hot_compress) — the
+        executable a data flush looks up is the one compiled here.  A
+        closed list:
+
+          1. the digest program at the two row buckets an interval that
+             touches more than a quarter of `max_keys` lands in, cut
+             into upload chunks as a flush cuts them, at each of
+             `depths`, in the depth-vector form and the weighted one;
+          2. the deep tier's program at its smallest row bucket
+             (DEEP_TIER_MIN_ROWS x DENSE_DEPTH_CAP) and the hot-key
+             compress tile.
+
+        The set estimate is not in it: its row bucket follows the set
+        keys an interval touches, which no option states (a resident
+        arena has its own boot).
+        A bucket the list lacks (a cardinality ramp's, the first
+        intervals' of the server's own telemetry) compiles in its first
+        flush, under the guard; prewarm()'s ahead-of-time sweep of them
+        is no longer part of a server's boot.  Returns the programs
+        this call compiled."""
+        d = self.digests
+        donate = not self.is_local
+        # compact_general staging uploads bf16 general values
+        gen_dt = d.stage_dtype if d.compact_general else d.eval_dtype
+        with self._compile_lock:
+            before = set(self._compiled_shapes)
+        top = arena_mod._pow2(max_keys)
+        outs = []
+        # (rows, depth, deep tier) of the weighted launches
+        weighted = [(arena_mod.DEEP_TIER_MIN_ROWS,
+                     arena_mod.DENSE_DEPTH_CAP, True)]
+        for bucket in (top, max(top // 2, 1)):
+            rows = bucket // self._upload_chunk_count(bucket,
+                                                      self.is_local)
+            for depth in depths:
+                d_pad = d.dense_depth(depth)
+                weighted.append((rows, d_pad, False))
+                outs.append(self._launch_digests(
+                    *d.put_dense_uniform(
+                        np.zeros((rows, d_pad), d.stage_dtype),
+                        np.zeros(rows, np.int16)), None, True, donate))
+        for rows, depth, deep in weighted:
+            outs.append(self._launch_digests(
+                *d.put_dense(np.zeros((rows, depth), gen_dt),
+                             np.zeros((rows, depth), d.eval_dtype),
+                             np.zeros((2, rows), d.eval_dtype)),
+                False, donate, deep))
+        d.prewarm_hot()
+        jax.block_until_ready(outs)
+        with self._compile_lock:
+            return len(self._compiled_shapes - before)
+
     def _uts_lanes(self, uts: Optional[np.ndarray]):
         """[R, m] unique-timeseries register lanes on the mesh, this
         process's tally (if any) in lane 0; the program pmaxes over both
@@ -1745,10 +1828,8 @@ class MetricAggregator:
         if self.mesh is None:
             if nd == 0:
                 return pend
-            uniform = dpart["uniform"]
             donate = not is_local
             rpart = dpart.pop("resident", None)
-            n_chunks = 1
             t0 = time.perf_counter()
             if rpart is not None and not rpart["dirty"]:
                 # resident delta path: the dense matrices assemble ON
@@ -1758,100 +1839,94 @@ class MetricAggregator:
                 # already crossed the link during the interval
                 *dev, critical = self.digests.assemble_resident(
                     rpart, dpart["staged"], dpart["rows"],
-                    dpart["d_min"], dpart["d_max"], uniform, donate)
+                    dpart["d_min"], dpart["d_max"], dpart["uniform"],
+                    donate)
                 seg["resident"] = 1.0
                 seg["amortized_bytes"] = (
                     seg.get("amortized_bytes", 0)
                     + rpart["streamed_bytes"])
-                n_rows = int(dev[0].shape[0])
-
-                def operands(sl):
-                    return dev
+                builds = [{"sel": None, "deep": False,
+                           "uniform": dpart["uniform"],
+                           "shape": tuple(dev[0].shape), "n_chunks": 1,
+                           "operands": lambda sl: dev}]
             else:
-                dv, dw, minmax = self.digests.build_dense(
-                    dpart["staged"], dpart["rows"],
-                    dpart["d_min"], dpart["d_max"], uniform=uniform)
-                # uniform intervals: dw is the [U] int16 depth vector,
-                # not the [U, D] weight matrix, and minmax stays
-                # host-side — roughly half the build and the uploaded
-                # bytes
-                critical = (dv.nbytes + dw.nbytes
-                            + (0 if uniform else minmax.nbytes))
-                n_rows = dv.shape[0]
-                # Upload/evaluate/readback overlap (the _dma_pipeline
-                # double buffer lifted to the host<->HBM boundary): a
-                # big GLOBAL-tier flush splits into row chunks — chunk
-                # i+1's upload rides the transfer engine while chunk
-                # i's program runs and chunk i-1's readback drains
-                # (copy_to_host_async below), with at most _delta_nbuf
-                # chunks in flight before the host blocks.  Forwarding
-                # tiers keep one piece (the digest export gathers from
-                # the whole dense matrix).
-                if not is_local:
-                    if (self._delta_chunk
-                            and n_rows >= 2 * self._delta_chunk):
-                        # explicit rows-per-chunk override
-                        # (flush_delta_chunk_keys); pow2 over pow2 rows
-                        # always tiles exactly
-                        n_chunks = n_rows // self._delta_chunk
-                    elif (self._upload_chunks > 1 and n_rows
-                            >= self._upload_chunks * _CHUNK_MIN_ROWS):
-                        n_chunks = self._upload_chunks
-
-                def operands(sl):
-                    if uniform:
-                        return (*self.digests.put_dense_uniform(
-                            dv[sl], dw[sl]), None)
-                    return self.digests.put_dense(dv[sl], dw[sl],
-                                                  minmax[:, sl])
+                builds, critical = [], 0
+                for tier in self._build_tiers(dpart):
+                    dv, dw, minmax = tier.pop("dense")
+                    # uniform tiers: dw is the [U] int16 depth vector,
+                    # not the [U, D] weight matrix, and minmax stays
+                    # host-side — roughly half the build and the
+                    # uploaded bytes
+                    critical += (dv.nbytes + dw.nbytes
+                                 + (0 if tier["uniform"] else minmax.nbytes))
+                    tier.update(
+                        shape=dv.shape,
+                        n_chunks=self._upload_chunk_count(dv.shape[0],
+                                                          is_local),
+                        operands=functools.partial(
+                            self._put_tier, dv, dw, minmax,
+                            tier["uniform"]))
+                    builds.append(tier)
             seg["build_s"] = time.perf_counter() - t0
             seg["upload_bytes"] = seg.get("upload_bytes", 0) + critical
-            rows_per = n_rows // n_chunks
+            seg["dense_tiers"] = len(builds)
+            seg["dense_elems"] = sum(b["shape"][0] * b["shape"][1]
+                                     for b in builds)
             layout_s = dispatch_s = 0.0
-            outs = []
-            chunk_stats = [] if n_chunks > 1 else None
-            first_dev = None
             t_dispatch0 = None
-            for c in range(n_chunks):
-                t0 = time.perf_counter()
-                dvd, dwd, mmd = operands(
-                    slice(c * rows_per, (c + 1) * rows_per))
-                up_s = time.perf_counter() - t0
-                layout_s += up_s
-                t0 = time.perf_counter()
-                if first_dev is None:
-                    first_dev = (dvd, dwd)
-                    t_dispatch0 = t0
-                outs.append(self._launch_digests(dvd, dwd, mmd, uniform,
-                                                 donate))
-                d_s = time.perf_counter() - t0
-                dispatch_s += d_s
-                if chunk_stats is not None:
-                    chunk_stats.append({"rows": rows_per,
-                                        "upload_s": up_s,
-                                        "dispatch_s": d_s})
-                    # stage 3 of the pipeline: start this chunk's D2H
-                    # readback now, so it drains while the NEXT chunk
-                    # uploads and evaluates
-                    for leaf in jax.tree_util.tree_leaves(outs[-1]):
-                        leaf.copy_to_host_async()
-                    if c + 1 >= self._delta_nbuf:
-                        # backpressure at the in-flight window
-                        # (flush_delta_nbuf): wait for the OLDEST
-                        # in-flight chunk, not the one just dispatched
-                        # — the classic double-buffer drain
-                        j = c + 1 - self._delta_nbuf
-                        t0 = time.perf_counter()
-                        jax.block_until_ready(outs[j])
-                        chunk_stats[j]["drain_s"] = (
-                            time.perf_counter() - t0)
+            for b in builds:
+                n_chunks = b["n_chunks"]
+                rows_per = b["shape"][0] // n_chunks
+                # (the pending flush keeps the tier, not its host operand)
+                operands = b.pop("operands")
+                outs = []
+                chunk_stats = [] if n_chunks > 1 else None
+                first_dev = None
+                for c in range(n_chunks):
+                    t0 = time.perf_counter()
+                    dvd, dwd, mmd = operands(
+                        slice(c * rows_per, (c + 1) * rows_per))
+                    up_s = time.perf_counter() - t0
+                    layout_s += up_s
+                    t0 = time.perf_counter()
+                    if first_dev is None:
+                        first_dev = (dvd, dwd)
+                    if t_dispatch0 is None:
+                        t_dispatch0 = t0
+                    outs.append(self._launch_digests(
+                        dvd, dwd, mmd, b["uniform"], donate, b["deep"]))
+                    d_s = time.perf_counter() - t0
+                    dispatch_s += d_s
+                    if chunk_stats is not None:
+                        chunk_stats.append({"rows": rows_per,
+                                            "upload_s": up_s,
+                                            "dispatch_s": d_s})
+                        # stage 3 of the pipeline: start this chunk's
+                        # D2H readback now, so it drains while the NEXT
+                        # chunk uploads and evaluates
+                        for leaf in jax.tree_util.tree_leaves(outs[-1]):
+                            leaf.copy_to_host_async()
+                        if c + 1 >= self._delta_nbuf:
+                            # backpressure at the in-flight window
+                            # (flush_delta_nbuf): wait for the OLDEST
+                            # in-flight chunk, not the one just
+                            # dispatched — the classic double-buffer
+                            # drain
+                            j = c + 1 - self._delta_nbuf
+                            t0 = time.perf_counter()
+                            jax.block_until_ready(outs[j])
+                            chunk_stats[j]["drain_s"] = (
+                                time.perf_counter() - t0)
+                # donated buffers are consumed by the program; a
+                # forwarding tier (never donating) keeps the first
+                # chunk for export
+                b.update(outs=outs, chunk_stats=chunk_stats,
+                         first_dev=None if donate else first_dev)
             seg["layout_s"] = layout_s
             seg["dispatch_s"] = dispatch_s
-            # donated buffers are consumed by the program; a forwarding
-            # tier (never donating) keeps the first chunk for export
-            pend.update(outs=outs, n_chunks=n_chunks, uniform=uniform,
-                        chunk_stats=chunk_stats, t_dispatch0=t_dispatch0,
-                        first_dev=None if donate else first_dev)
+            if len(builds) > 1:
+                self._tier_inflight = [b["outs"] for b in builds]
+            pend.update(tiers=builds, t_dispatch0=t_dispatch0)
             return pend
         else:
             multi = jax.process_count() > 1
@@ -1961,14 +2036,95 @@ class MetricAggregator:
                 dense_dev=None if donate else (dvd, dwd))
             return pend
 
-    def _launch_digests(self, dvd, dwd, mmd, uniform: bool, donate: bool):
+    def _build_tiers(self, dpart: dict) -> list:
+        """The unmeshed flush's host-built operand(s) from a digest
+        part: one `build_dense` over every touched row — the parent's
+        single `[U, D]` operand — unless the snapshot named a deep tier
+        (`DigestArena.deep_rows`).  Then two: the long tail in the form
+        its weights allow at its own depth, and the deep rows weighted,
+        DENSE_DEPTH_CAP deep, at a pow2 row bucket of at least
+        DEEP_TIER_MIN_ROWS.  Each tier: `sel` (its rows' positions in
+        the part; None = all), `deep`, `uniform`, `dense` (build_dense's
+        triple)."""
+        d = self.digests
+        rows, vals, wts = staged = dpart["staged"]
+        touched, deep = dpart["rows"], dpart.get("deep")
+        if deep is not None and len(rows) and not (
+                0 <= int(rows.min()) and int(rows.max()) < d.capacity):
+            deep = None     # corrupt staging: build_dense drops it, loudly
+        if deep is None:
+            return [{"sel": None, "deep": False,
+                     "uniform": dpart["uniform"],
+                     "dense": d.build_dense(
+                         staged, touched, dpart["d_min"], dpart["d_max"],
+                         uniform=dpart["uniform"])}]
+        # a served node's flushes are serial and this returns at once; a
+        # caller that dispatches a second flush before it fetched the
+        # first's results waits here for the uploads it would overwrite
+        jax.block_until_ready(self._tier_inflight)
+        is_deep = np.zeros(d.capacity, bool)
+        is_deep[touched[deep]] = True
+        in_deep = is_deep[rows]
+        tail = np.nonzero(~is_deep[touched])[0]
+        tiers = []
+        for sel, mine, uniform, floors, keep in (
+                (tail, ~in_deep, dpart["shallow_uniform"], {},
+                 self._tier_operands[0]),
+                (deep, in_deep, False,
+                 {"u_floor": arena_mod.DEEP_TIER_MIN_ROWS,
+                  "d_floor": arena_mod.DENSE_DEPTH_CAP},
+                 self._tier_operands[1])):
+            # the tiers keep their operands from flush to flush (the
+            # wait above: the last flush's uploads have been consumed)
+            tiers.append({
+                "sel": sel, "deep": bool(floors), "uniform": uniform,
+                "dense": d.build_dense(
+                    (rows[mine], vals[mine], wts[mine]), touched[sel],
+                    dpart["d_min"][sel], dpart["d_max"][sel],
+                    uniform=uniform, keep=keep, **floors)})
+        return tiers
+
+    def _put_tier(self, dv, dw, minmax, uniform: bool, sl: slice):
+        """Device-put rows `sl` of one host-built tier."""
+        if uniform:
+            return (*self.digests.put_dense_uniform(dv[sl], dw[sl]), None)
+        return self.digests.put_dense(dv[sl], dw[sl], minmax[:, sl])
+
+    def _upload_chunk_count(self, n_rows: int, is_local: bool) -> int:
+        """Upload/evaluate/readback overlap (the _dma_pipeline double
+        buffer lifted to the host<->HBM boundary): a big GLOBAL-tier
+        operand splits into row chunks — chunk i+1's upload rides the
+        transfer engine while chunk i's program runs and chunk i-1's
+        readback drains (copy_to_host_async), with at most _delta_nbuf
+        chunks in flight before the host blocks.  Forwarding tiers keep
+        one piece (the digest export gathers from the whole dense
+        matrix)."""
+        if is_local:
+            return 1
+        if self._delta_chunk and n_rows >= 2 * self._delta_chunk:
+            # explicit rows-per-chunk override (flush_delta_chunk_keys);
+            # pow2 over pow2 rows always tiles exactly
+            return n_rows // self._delta_chunk
+        if (self._upload_chunks > 1
+                and n_rows >= self._upload_chunks * _CHUNK_MIN_ROWS):
+            return self._upload_chunks
+        return 1
+
+    def _launch_digests(self, dvd, dwd, mmd, uniform: bool, donate: bool,
+                        deep: bool = False):
         """LAUNCH the unmeshed digest program on one chunk's device
         operands — the one way it is ever called, whether the operands
-        were assembled on the device (resident) or built on the host
-        and put: the guard's key, the program form (uniform: dwd is the
-        depth vector and minmax stays on the host) and the donation
-        rule live here."""
+        were assembled on the device (resident), built on the host and
+        put, or zeros at boot (prewarm_launch): the guard's key, the
+        program form (uniform: dwd is the depth vector and minmax stays
+        on the host; deep: the deep tier's own program) and the
+        donation rule live here."""
         shape = (int(dvd.shape[0]), int(dvd.shape[1]))
+        if deep:
+            with self._CompileGuard(self, ("deep_tier", shape, donate)):
+                fn = (self.flush_fn.deep_tier_donated if donate
+                      else self.flush_fn.deep_tier)
+                return fn(dvd, dwd, mmd, self._pct_arr)
         with self._CompileGuard(self, (shape, bool(uniform), donate)):
             if uniform:
                 fn = (self.flush_fn.depth_variant_donated if donate
@@ -2220,17 +2376,35 @@ class MetricAggregator:
                     seg["device_s"] = set_wait
                 return host
             t0 = time.perf_counter()
-            cs = pend.get("chunk_stats")
-            if cs is not None:
-                # pipelined chunks fetch one at a time so each chunk's
-                # residual wait is attributable (the readbacks were
-                # started at dispatch via copy_to_host_async)
-                fetched = []
-                for i, o in enumerate(pend["outs"]):
-                    t1 = time.perf_counter()
-                    fetched.append(serving.fetch(o))
-                    cs[i]["wait_s"] = time.perf_counter() - t1
-                seg["device_chunks"] = cs
+            qs, piped, readback = None, [], 0
+            for tier in pend["tiers"]:
+                cs = tier["chunk_stats"]
+                if cs is not None:
+                    # pipelined chunks fetch one at a time so each
+                    # chunk's residual wait is attributable (the
+                    # readbacks were started at dispatch via
+                    # copy_to_host_async)
+                    fetched = []
+                    for i, o in enumerate(tier["outs"]):
+                        t1 = time.perf_counter()
+                        fetched.append(serving.fetch(o))
+                        cs[i]["wait_s"] = time.perf_counter() - t1
+                    piped += cs
+                else:
+                    fetched = serving.fetch(tuple(tier["outs"]))
+                ev = (fetched[0] if len(fetched) == 1
+                      else np.concatenate(fetched))
+                readback += ev.nbytes
+                sel = tier["sel"]
+                if sel is None:
+                    qs = ev[:nd, :n_cols]
+                else:
+                    # a tier answers for its own rows of the part
+                    if qs is None:
+                        qs = np.empty((nd, n_cols), ev.dtype)
+                    qs[sel] = ev[:len(sel), :n_cols]
+            if piped:
+                seg["device_chunks"] = piped
                 # device_s stays the residual blocking wait; the
                 # device-BUSY window since the first chunk's dispatch —
                 # which OVERLAPS the later chunks' layout/dispatch
@@ -2240,17 +2414,15 @@ class MetricAggregator:
                 seg["device_window_s"] = (
                     time.perf_counter()
                     - (pend["t_dispatch0"] if set_t0 is None else set_t0))
-            else:
-                fetched = serving.fetch(tuple(pend["outs"]))
-            ev = (fetched[0] if pend["n_chunks"] == 1
-                  else np.concatenate(fetched))
             # the flush's blocking wait on the device: the digest
             # outputs and, before them, the set estimates
             seg["device_s"] = time.perf_counter() - t0 + set_wait
             seg["readback_bytes"] = (seg.get("readback_bytes", 0)
-                                     + ev.nbytes)
-            host["dense_dev"] = pend["first_dev"]
-            host["dense_uniform"] = pend["uniform"]
+                                     + readback)
+            # what a forwarding tier's digest export gathers from
+            host["dense_tiers"] = [
+                (t["sel"], t["first_dev"], t["uniform"])
+                for t in pend["tiers"]]
             # counts/sums come from the exact f64 host accumulators on
             # BOTH staging shapes (they cover every staged point,
             # merged-digest centroids included) — sourcing only the
@@ -2259,7 +2431,7 @@ class MetricAggregator:
             # uniform/non-uniform between intervals (ADVICE r5 #6); the
             # device ev columns carry the same totals in eval dtype and
             # remain the meshed path's (collective-reduced) source
-            host["qs"] = ev[:nd, :n_cols]
+            host["qs"] = qs
             host["counts"] = np.asarray(dpart["d_weight"], np.float64)
             host["sums"] = np.asarray(dpart["d_sum"], np.float64)
             return host
@@ -2356,6 +2528,7 @@ class MetricAggregator:
             ar.reset_rows(snap[name]["rows"])
             ar.end_interval()
         snap["set_lane_stats"] = self.sets.take_lane_stats()
+        snap["hot_lane_stats"] = self.digests.take_hot_stats()
         if self.cardinality is not None:
             self._cardinality_end_interval()
         if self.cubes is not None:
@@ -2569,38 +2742,59 @@ class MetricAggregator:
             # forwarded rows' staged points (MergingDigest.Data,
             # merging_digest.go:474-483) — compute and readback scale with
             # the forwarded subset
-            dvd, dwd = host["dense_dev"]
             fidx = np.nonzero(forwarded)[0]
             compression = self.digests.compression
             ccap = self.digests.ccap
-            depth = int(dvd.shape[1])
-            # Chunk the export so the fused [rows, depth, ccap]
-            # comparison-sum inside td.compress stays under an element
-            # budget whether or not XLA fuses it (a 100k-key forwarding
-            # tier with 512-deep staging would otherwise imply a
-            # multi-GB logical intermediate).  Full chunks share one
-            # compiled shape; only the final partial chunk pads down.
-            max_rows = _EXPORT_ELEM_BUDGET // max(1, depth * ccap)
-            max_rows = 1 << max(3, max_rows.bit_length() - 1)
-            m_parts, w_parts = [], []
-            for off in range(0, len(fidx), max_rows):
-                chunk = fidx[off:off + max_rows]
-                fpad = self._padded_rows(chunk)
-                if host.get("dense_uniform"):
-                    # depth-vector build: dwd holds per-row depths; the
-                    # 0/1 weights rebuild on device for the subset
-                    mexp, wexp = serving.digest_export_uniform(
-                        dvd, dwd, jnp.asarray(fpad), compression, ccap)
+            # the dense operand(s) the flush evaluated, each with the
+            # positions in the part of the rows it holds (None: all, in
+            # order — the single operand, and the meshed one)
+            tiers = host.get("dense_tiers") or [
+                (None, host["dense_dev"], False)]
+            sel_mean = sel_weight = None
+            for sel, (dvd, dwd), t_uniform in tiers:
+                if sel is None:
+                    mine, local = slice(None), fidx
                 else:
-                    mexp, wexp = serving.digest_export(
-                        dvd, dwd, jnp.asarray(fpad), compression, ccap)
-                fetched_m, fetched_w = serving.fetch((mexp, wexp))
-                m_parts.append(fetched_m[:len(chunk)])
-                w_parts.append(fetched_w[:len(chunk)])
-            sel_mean = (m_parts[0] if len(m_parts) == 1
-                        else np.concatenate(m_parts))
-            sel_weight = (w_parts[0] if len(w_parts) == 1
-                          else np.concatenate(w_parts))
+                    at = np.full(n, -1, np.int64)
+                    at[sel] = np.arange(len(sel))
+                    mine = np.nonzero(at[fidx] >= 0)[0]
+                    local = at[fidx[mine]]
+                depth = int(dvd.shape[1])
+                # Chunk the export so the fused [rows, depth, ccap]
+                # comparison-sum inside td.compress stays under an
+                # element budget whether or not XLA fuses it (a 100k-key
+                # forwarding tier with 512-deep staging would otherwise
+                # imply a multi-GB logical intermediate).  Full chunks
+                # share one compiled shape; only the final partial chunk
+                # pads down.
+                max_rows = _EXPORT_ELEM_BUDGET // max(1, depth * ccap)
+                max_rows = 1 << max(3, max_rows.bit_length() - 1)
+                m_parts, w_parts = [], []
+                for off in range(0, len(local), max_rows):
+                    chunk = local[off:off + max_rows]
+                    fpad = self._padded_rows(chunk)
+                    if t_uniform:
+                        # depth-vector build: dwd holds per-row depths;
+                        # the 0/1 weights rebuild on device for the
+                        # subset
+                        mexp, wexp = serving.digest_export_uniform(
+                            dvd, dwd, jnp.asarray(fpad), compression,
+                            ccap)
+                    else:
+                        mexp, wexp = serving.digest_export(
+                            dvd, dwd, jnp.asarray(fpad), compression,
+                            ccap)
+                    fetched_m, fetched_w = serving.fetch((mexp, wexp))
+                    m_parts.append(fetched_m[:len(chunk)])
+                    w_parts.append(fetched_w[:len(chunk)])
+                if not m_parts:
+                    continue
+                if sel_mean is None:
+                    sel_mean = np.zeros((len(fidx), ccap),
+                                        m_parts[0].dtype)
+                    sel_weight = np.zeros_like(sel_mean)
+                sel_mean[mine] = np.concatenate(m_parts)
+                sel_weight[mine] = np.concatenate(w_parts)
             fwd = res.forward
             kinds = part["kinds"]
             scopes = part["scopes"]

@@ -49,11 +49,34 @@ DENSE_DEPTH_CAP = 512
 # staged-element count above which the dense build uses the native C++
 # single-pass fill (vn_fill_dense) instead of numpy argsort+scatter
 _NATIVE_FILL_MIN = 65536
-# per-row column bound inside one pre-reduction launch: a single key with
-# millions of staged samples splits into chunks of this depth
-HOT_CHUNK_WIDTH = 16_384
-# dense-matrix element bound per pre-reduction launch (32 MiB f32/array)
-HOT_DENSE_BUDGET = 1 << 23
+# The hot-key lane's compress tile: EVERY pre-reduction launch
+# (DigestArena._pre_reduce -> serving.partial_digests) has this one
+# shape, whatever a drain tick carries, so the program is known at boot
+# (prewarm_hot launches it once) and no window compiles it.  A row's
+# backlog splits into "virtual rows" of HOT_TILE_WIDTH points, a pass
+# launches them HOT_TILE_ROWS at a time, zero-padded.  Sized from the
+# tick of a skewed node (200,000 timer samples per 2 s over 50,000 keys,
+# Zipf 0.99, drained every 0.2 s): ~10 rows stand past the cap at a
+# tick, the hottest with ~3,500 new samples on its <= ccap centroids;
+# a key that brings more in one tick takes more virtual rows, not a
+# wider program.  2 x 0.5 MiB of f32 operands a launch.
+HOT_TILE_ROWS = 32
+HOT_TILE_WIDTH = 4096
+# The flush operand's tiers (DigestArena.deep_rows): touched rows with
+# more staged points than this — or re-staged by a pre-reduce, whose
+# centroids carry weights — are built apart from the long tail, in the
+# weighted form, DENSE_DEPTH_CAP deep, at pow2 row buckets no smaller
+# than DEEP_TIER_MIN_ROWS; the tail keeps the form its weights allow at
+# its own depth.  64: under Zipf 0.99 the keys above 64 samples are
+# ~0.9 % of the touched ones (276 of 30,000) and the tail's operand at
+# depth 64 is an eighth of the single one's 512; at 8 the deep tier
+# would hold 2,250 rows x 512 x 2 arrays (more bytes than the tail), at
+# 128 the tail doubles for 140 rows.  512 rows: the deep count of that
+# profile (276 +- 10) sits beside the 256 bucket's edge, and a program
+# per side of an edge is a compile in a window; below the floor the
+# padding is 1 MiB an array.
+DEEP_TIER_THRESHOLD = 64
+DEEP_TIER_MIN_ROWS = 512
 # flush intervals a key may stay untouched before its row is recycled
 IDLE_GC_INTERVALS = 10
 
@@ -293,6 +316,20 @@ class _ArenaBase:
         idx[:n] = rows
         idx[n:] = rows[0]
         return idx
+
+    # key -> context manager around a device launch the arena makes
+    # itself (set lane kernels, the hot-key compress); the aggregator
+    # installs its _CompileGuard here so that such a program's first
+    # launch is a compile event like any other
+    compile_guard = None
+
+    def _guard(self, key):
+        """The owner's compile guard around one of the arena's own
+        launches (the aggregator's _CompileGuard: a first launch of
+        `key` counts as a compile event); nothing for a bare arena."""
+        if self.compile_guard is None:
+            return contextlib.nullcontext()
+        return self.compile_guard(key)
 
     def _grow(self) -> None:
         old = self.capacity
@@ -860,10 +897,6 @@ class SetArena(_ArenaBase):
         # handed to XLA as scratch.
         self._snapshot_inflight = 0
         self._seq = 0
-        # key -> context manager around each lane-kernel launch; the
-        # aggregator installs its _CompileGuard here so that a lane
-        # program's first launch is a compile event like any other
-        self.compile_guard = None
         # staging: raw hashes per batch (vectorized split at sync)
         self._stage_rows: list[int] = []
         self._stage_hashes: list[int] = []
@@ -1070,14 +1103,6 @@ class SetArena(_ArenaBase):
             self._seq += 1
             self.lanes_regs = self._lane_merge(
                 self.lanes_regs, pr, mat, lane, self._lane_donate_ok())
-
-    def _guard(self, key):
-        """The owner's compile guard around a lane-kernel launch (the
-        aggregator's _CompileGuard: a first launch of `key` counts as a
-        compile event); nothing for a bare arena."""
-        if self.compile_guard is None:
-            return contextlib.nullcontext()
-        return self.compile_guard(key)
 
     def _lane_scatter(self, lanes, pr, pi, pk, lane: int, donate: bool):
         """Launch the scatter-max of padded (row, register, rank)
@@ -1342,14 +1367,26 @@ class DigestArena(_ArenaBase):
     There is NO persistent device centroid state.  An interval's samples —
     and imported digest centroids (`Histo.Merge`,
     `samplers/samplers.go:539-543`), which are just weighted points —
-    accumulate in host COO staging; flush uploads ONE compact dense
+    accumulate in host COO staging; flush uploads a compact dense
     `[K_t, D]` matrix (touched rows only, D = pow2 max per-key depth) and
     reads back one `[K_t, P+2]` evaluation.  Device traffic is therefore
     proportional to the interval's samples, and nothing rewrites
-    hundreds of MB of HBM state per flush.  Hot keys whose staged depth
-    outgrows DENSE_DEPTH_CAP pre-reduce on device into <= C weighted
-    points via `serving.partial_digests` and re-stage — the two-stage
-    amortization of `mergeAllTemps` (`merging_digest.go:105-137`).
+    hundreds of MB of HBM state per flush.
+
+    The hot-key lane.  Keys whose staged depth outgrows DENSE_DEPTH_CAP
+    pre-reduce on device into <= C weighted points via
+    `serving.partial_digests` and re-stage — the two-stage amortization
+    of `mergeAllTemps` (`merging_digest.go:105-137`) — in launches of
+    ONE tile shape (`HOT_TILE_ROWS` x `HOT_TILE_WIDTH`; `_pre_reduce`,
+    `prewarm_hot`).  And an unmeshed flush whose interval is skewed does
+    not pad every key to the hottest: `deep_rows` names the touched rows
+    past `DEEP_TIER_THRESHOLD` points (or re-staged with weights), the
+    aggregator builds those apart — weighted, DENSE_DEPTH_CAP deep, a
+    few hundred rows — and the long tail at its own depth in the form
+    its weights allow (`_dispatch_flush`); `build_dense` itself is the
+    single-operand build either tier is made with.  An interval with no
+    deep key, or one whose split would not halve the operand, builds
+    the one `[K_t, D]` matrix.
 
     With a mesh, the dense matrix shards keys over 'shard' and depth over
     'replica'; the flush all_gathers depth slices over ICI (the
@@ -1362,6 +1399,14 @@ class DigestArena(_ArenaBase):
     """
 
     _TRACK_KIND = True  # forwarding needs histogram-vs-timer per row
+    # the t-digest family alone splits a skewed flush into tiers (the
+    # other histogram families evaluate vectors, not staged depth)
+    _TIERED = True
+    # per interval: rows pre-reduced, staged points into and weighted
+    # points out of the compress, its launches, and the passes' hold of
+    # the caller (the aggregator lock: a drain tick's sync, the cut's)
+    HOT_STATS = ("keys", "points_in", "points_out", "compress_launches",
+                 "compress_held_ns")
 
     family = "digest"
     # a histogram family's window ring (query plane, retention) and the
@@ -1466,6 +1511,15 @@ class DigestArena(_ArenaBase):
         # any sample_rate != 1, forwarded centroid weight != 1, or
         # hot-key pre-reduction flips it off until the next interval
         self._staged_nonuniform = False
+        # rows a pre-reduce re-staged this interval (their points carry
+        # merged weights): they alone leave the uniform form — the
+        # interval's other rows keep it where the flush builds tiers
+        # (deep_rows), and a single-operand flush is weighted as a whole
+        self._reduced = np.zeros(capacity, bool)
+        self._reduced_any = False
+        # what the hot-key lane did over the open interval, for the
+        # flush timeline's row (take_hot_stats, at the cut)
+        self._hot_stats = dict.fromkeys(self.HOT_STATS, 0)
         # device-resident delta mirror (flush_resident_arenas): the host
         # COO above stays AUTHORITATIVE — checkpoints, forwarding
         # exports and the query rings read it unchanged, which is what
@@ -1504,6 +1558,7 @@ class DigestArena(_ArenaBase):
     def _grow_state(self, old: int) -> None:
         super()._grow_state(old)
         self._depth = self._grown(self._depth, old, 0)
+        self._reduced = self._grown(self._reduced, old, False)
         if self._res_pos is not None:
             self._res_pos = self._grown(self._res_pos, old, 0)
 
@@ -1624,7 +1679,7 @@ class DigestArena(_ArenaBase):
 
         np.add.at(self._depth, rows, 1)
         # pre-reduce until every row fits the dense cap; each pass
-        # collapses a row's samples ~HOT_CHUNK_WIDTH -> ccap, so this
+        # collapses a row's samples ~HOT_TILE_WIDTH -> ccap, so this
         # converges in O(log) passes even for absurd backlogs
         while int(self._depth.max()) > DENSE_DEPTH_CAP:
             before = int(self._depth.max())
@@ -1653,16 +1708,23 @@ class DigestArena(_ArenaBase):
 
     def _pre_reduce(self) -> None:
         """Collapse rows deeper than DENSE_DEPTH_CAP into <= ccap weighted
-        points each: group deep rows under a padded-element budget, run
-        one batched device compress per group (slim [U, C] readbacks), and
-        re-stage the centroids.  Scalars are NOT re-applied (the original
-        samples already updated them)."""
-        rows, vals, wts = self._consolidated()
+        points each: split every deep row's points into virtual rows of
+        HOT_TILE_WIDTH, compress them HOT_TILE_ROWS to a launch — one
+        tile shape, so one program (_hot_compress) — read the slim
+        [rows, ccap] results back and re-stage the centroids.  Every
+        tile launches before the first result is waited for.  Scalars
+        are NOT re-applied (the original samples already updated
+        them)."""
         deep = np.nonzero(self._depth > DENSE_DEPTH_CAP)[0]
         if len(deep) == 0:
             return
+        t0 = time.perf_counter_ns()
+        stats = self._hot_stats
+        rows, vals, wts = self._consolidated()
         # re-staged compressed centroids carry merged weights
-        self._staged_nonuniform = True
+        stats["keys"] += int(len(deep) - self._reduced[deep].sum())
+        self._reduced[deep] = True
+        self._reduced_any = True
         is_deep = np.zeros(self.capacity, bool)
         is_deep[deep] = True
         sel = is_deep[rows]
@@ -1670,62 +1732,111 @@ class DigestArena(_ArenaBase):
         drows, dvals, dwts = rows[sel], vals[sel], wts[sel]
         order = np.argsort(drows, kind="stable")
         drows, dvals, dwts = drows[order], dvals[order], dwts[order]
-        # split each row's samples into HOT_CHUNK_WIDTH-deep column
-        # chunks ("virtual rows"), so one pathological key never builds
-        # an unbounded-width dense matrix or a fresh jit shape per depth
-        rstarts = np.searchsorted(drows, drows)
-        rpos = np.arange(len(drows)) - rstarts
-        vrows = (drows << np.int64(20)) | (rpos // HOT_CHUNK_WIDTH)
+        # virtual rows: (row, chunk of HOT_TILE_WIDTH points), so one
+        # pathological key never asks for a wider program
+        rpos = np.arange(len(drows)) - np.searchsorted(drows, drows)
+        vrows = (drows << np.int64(20)) | (rpos // HOT_TILE_WIDTH)
         urows, counts = np.unique(vrows, return_counts=True)
-        row_starts = np.concatenate([[0], np.cumsum(counts)])
+        vidx = np.repeat(np.arange(len(urows)), counts)
+        pos = rpos % HOT_TILE_WIDTH
+        starts = np.concatenate([[0], np.cumsum(counts)])
+        launched = []
+        for g0 in range(0, len(urows), HOT_TILE_ROWS):
+            g1 = min(g0 + HOT_TILE_ROWS, len(urows))
+            sl = slice(int(starts[g0]), int(starts[g1]))
+            dv = np.zeros((HOT_TILE_ROWS, HOT_TILE_WIDTH), np.float32)
+            dw = np.zeros_like(dv)
+            dv[vidx[sl] - g0, pos[sl]] = dvals[sl]
+            dw[vidx[sl] - g0, pos[sl]] = dwts[sl]
+            launched.append((g0, g1, self._hot_compress(dv, dw)))
         out_r: list[np.ndarray] = []
         out_v: list[np.ndarray] = []
         out_w: list[np.ndarray] = []
-        g0 = 0
-        while g0 < len(urows):
-            g1 = g0 + 1
-            wmax = int(counts[g0])
-            while g1 < len(urows):
-                nw = max(wmax, int(counts[g1]))
-                if _pow2(g1 + 1 - g0) * _pow2(nw) > HOT_DENSE_BUDGET:
-                    break
-                wmax = nw
-                g1 += 1
-            slo, shi = int(row_starts[g0]), int(row_starts[g1])
-            group_rows = urows[g0:g1]
-            u_pad, w_pad = _pow2(g1 - g0), _pow2(wmax)
-            dv = np.zeros((u_pad, w_pad), np.float32)
-            dw = np.zeros_like(dv)
-            ridx = np.searchsorted(group_rows, vrows[slo:shi])
-            # position within virtual row = running index - its start
-            pos = np.arange(slo, shi) - row_starts[ridx + g0]
-            dv[ridx, pos] = dvals[slo:shi]
-            dw[ridx, pos] = dwts[slo:shi]
-            pm, pw = serving.partial_digests(
-                jnp.asarray(dv), jnp.asarray(dw), self.compression,
-                self.ccap)
-            pm = np.asarray(pm)[:len(group_rows)]
-            pw = np.asarray(pw)[:len(group_rows)]
+        for g0, g1, (pm, pw) in launched:
+            pm = np.asarray(pm)[:g1 - g0]
+            pw = np.asarray(pw)[:g1 - g0]
             occ = pw > 0
-            n_per = occ.sum(axis=1)
-            out_r.append(np.repeat(group_rows >> np.int64(20), n_per))
+            out_r.append(np.repeat(urows[g0:g1] >> np.int64(20),
+                                   occ.sum(axis=1)))
             out_v.append(pm[occ].astype(np.float64))
             out_w.append(pw[occ].astype(np.float64))
-            g0 = g1
-        new_r = np.concatenate([keep[0]] + out_r)
-        new_v = np.concatenate([keep[1]] + out_v)
-        new_w = np.concatenate([keep[2]] + out_w)
-        self._acc.replace(new_r, new_v, new_w)
-        self._depth[:] = 0
-        np.add.at(self._depth, new_r, 1)
+        red_r = np.concatenate(out_r)
+        self._acc.replace(np.concatenate([keep[0], red_r]),
+                          np.concatenate([keep[1]] + out_v),
+                          np.concatenate([keep[2]] + out_w))
+        self._depth[deep] = 0
+        np.add.at(self._depth, red_r, 1)
+        stats["points_in"] += len(drows)
+        stats["points_out"] += len(red_r)
+        stats["compress_launches"] += len(launched)
+        stats["compress_held_ns"] += time.perf_counter_ns() - t0
+
+    def _hot_compress(self, dv: np.ndarray, dw: np.ndarray):
+        """LAUNCH the hot-key compress on one tile — _pre_reduce's
+        launch, and the one prewarm_hot makes at boot."""
+        with self._guard(("hot_compress", dv.shape)):
+            return serving.partial_digests(
+                jnp.asarray(dv), jnp.asarray(dw), self.compression,
+                self.ccap)
+
+    def prewarm_hot(self) -> int:
+        """Launch the hot-key compress once on an all-zero tile, so that
+        the drain tick that first finds a row past DENSE_DEPTH_CAP — a
+        pass under the aggregator lock — compiles nothing.  Returns the
+        programs launched."""
+        tile = np.zeros((HOT_TILE_ROWS, HOT_TILE_WIDTH), np.float32)
+        for out in self._hot_compress(tile, tile):
+            out.block_until_ready()
+        return 1
+
+    @property
+    def hot_tile_bytes(self) -> int:
+        """Operands + results of one compress launch, from the shape
+        launched: two f32 tiles in, two `[HOT_TILE_ROWS, ccap]` out."""
+        return 2 * 4 * HOT_TILE_ROWS * (HOT_TILE_WIDTH + self.ccap)
+
+    def take_hot_stats(self) -> dict:
+        """The open interval's HOT_STATS, handed over and zeroed (call
+        under the aggregator lock, at the cut)."""
+        out, self._hot_stats = (self._hot_stats,
+                                dict.fromkeys(self.HOT_STATS, 0))
+        return out
+
+    def deep_rows(self, rows: np.ndarray) -> Optional[np.ndarray]:
+        """Positions in `rows` (a snapshot's touched rows; call before
+        take_staged) of the keys the flush should build apart as its
+        deep tier, or None where one operand serves: on a mesh, where
+        no row is deep or all are, and where the split would not halve
+        the padded value matrix (`fleet8.steady`'s 1,250 keys of 256
+        centroids each ARE the operand).  Deep = past
+        DEEP_TIER_THRESHOLD staged points, or re-staged by a pre-reduce
+        (weighted, whatever its depth)."""
+        if self.mesh is not None or not self._TIERED or not len(rows):
+            return None
+        depth = self._depth[rows]
+        deep = depth > DEEP_TIER_THRESHOLD
+        if self._reduced_any:
+            deep |= self._reduced[rows]
+        n_deep = int(deep.sum())
+        if n_deep == 0 or n_deep == len(rows):
+            return None
+        single = _pow2(len(rows)) * self.dense_depth(int(depth.max()))
+        tiered = (_pow2(len(rows) - n_deep)
+                  * self.dense_depth(int(depth[~deep].max()))
+                  + _pow2(max(n_deep, DEEP_TIER_MIN_ROWS))
+                  * DENSE_DEPTH_CAP)
+        if 2 * tiered > single:
+            return None
+        return np.nonzero(deep)[0]
 
     # -- flush ------------------------------------------------------------
 
     @property
     def staged_uniform(self) -> bool:
         """True iff every weight staged this interval equals exactly 1.0
-        (capture BEFORE take_staged resets the tracking)."""
-        return not self._staged_nonuniform
+        (capture BEFORE take_staged resets the tracking): no sample
+        rate, no forwarded centroid, no pre-reduced row."""
+        return not (self._staged_nonuniform or self._reduced_any)
 
     def take_staged(self):
         """Consume the interval accumulator (call under the aggregator
@@ -1737,6 +1848,9 @@ class DigestArena(_ArenaBase):
         self.snapshot_staged_points = self._acc.n
         self.snapshot_staged_regrows = self._acc.regrows
         self._staged_nonuniform = False
+        if self._reduced_any:
+            self._reduced[:] = False
+            self._reduced_any = False
         return self._acc.take()
 
     def snapshot_part(self) -> dict:
@@ -1752,6 +1866,13 @@ class DigestArena(_ArenaBase):
         # consumed right after take_staged with its result (the tail's
         # (row, pos) coordinates come from the same consolidated arrays)
         part["uniform"] = self.staged_uniform
+        # a skewed interval's deep tier (deep_rows), and whether the
+        # tail beside it is all unit weights; an interval with no deep
+        # key carries neither and is built as one operand
+        deep = self.deep_rows(part["rows"])
+        if deep is not None:
+            part["deep"] = deep
+            part["shallow_uniform"] = not self._staged_nonuniform
         t0 = time.perf_counter()
         part["staged"] = self.take_staged()
         self.snapshot_staged_s = time.perf_counter() - t0
@@ -1763,8 +1884,10 @@ class DigestArena(_ArenaBase):
     def _mark_resident_dirty(self) -> None:
         """Invalidate the interval's device mirror: drop the streamed
         chunks and fall back to the host-staged dense build at the next
-        flush.  Rare — pre-reduce past DENSE_DEPTH_CAP or corrupt staged
-        row ids; the host COO is authoritative either way."""
+        flush.  Every interval of a node with a hot key (a pre-reduce
+        past DENSE_DEPTH_CAP reorders the accumulator), else rare
+        (corrupt staged row ids); the host COO is authoritative either
+        way."""
         if not self.resident:
             return
         self._res_chunks = []
@@ -1995,10 +2118,29 @@ class DigestArena(_ArenaBase):
         staged points: a power of two to each replica's slice."""
         return max(2, self.n_replicas * _pow2(-(-depth // self.n_replicas)))
 
+    @staticmethod
+    def _operand(keep: Optional[dict], name: str, shape, dtype):
+        """An all-zero host operand for build_dense: a fresh `np.zeros`,
+        or — `keep` given — the caller's buffer of that name, zeroed in
+        place when shape and dtype still fit.  A fresh operand of
+        megabytes is first-touch page faults inside the fill (~1 us/KB
+        on the chip's host), and whether glibc serves it from mapped
+        heap or from new pages is an accident of the process's
+        allocation history: a flush that keeps its operands pays a
+        memset, the same in every run."""
+        buf = None if keep is None else keep.get(name)
+        if buf is None or buf.shape != tuple(shape) or buf.dtype != dtype:
+            buf = np.zeros(shape, dtype)
+            if keep is not None:
+                keep[name] = buf
+        else:
+            buf.fill(0)
+        return buf
+
     def build_dense(self, staged, touched: np.ndarray,
                     d_min_t: np.ndarray, d_max_t: np.ndarray,
                     u_floor: int = 0, d_floor: int = 0,
-                    uniform: bool = False):
+                    uniform: bool = False, keep: Optional[dict] = None):
         """Compact dense build for the flush program: map the staged COO
         onto touched-row-ordered dense matrices `[U, D]` (U = padded
         touched count, D = padded max depth), plus the stacked [2, U]
@@ -2012,7 +2154,12 @@ class DigestArena(_ArenaBase):
         points pack contiguously from column 0, so `col < depth[row]`
         is the occupancy.  Halves both the host build work and the
         bytes crossing the host->device link (the e2e flush's dominant
-        cost; VERDICT r4 items 3-4)."""
+        cost; VERDICT r4 items 3-4).
+
+        keep: a dict the caller owns, in which the operands' buffers
+        stay from one build to the next (_operand); the returned arrays
+        are then the caller's only until its next build with that
+        dict.  None = fresh operands, the parent's."""
         rows, vals, wts = staged
         if len(rows) and (int(rows.min()) < 0
                           or int(rows.max()) >= self.capacity):
@@ -2058,10 +2205,10 @@ class DigestArena(_ArenaBase):
             d_pad = self.dense_depth(depth)
             rows64 = np.ascontiguousarray(rows, np.int64)
             vals64 = np.ascontiguousarray(vals, np.float64)
-            dv = np.zeros((u_pad, d_pad), np.float32)
-            depths_vec = np.zeros(u_pad, np.int16)
-            dw = (None if uniform
-                  else np.zeros((u_pad, d_pad), np.float32))
+            dv = self._operand(keep, "dv", (u_pad, d_pad), np.float32)
+            depths_vec = self._operand(keep, "depths", (u_pad,), np.int16)
+            dw = (None if uniform else self._operand(
+                keep, "dw", (u_pad, d_pad), np.float32))
             wts64 = (None if uniform
                      else np.ascontiguousarray(wts, np.float64))
             dropped = native_fill(rows64, vals64, wts64, dense_id,
@@ -2069,7 +2216,8 @@ class DigestArena(_ArenaBase):
             if dropped == 0:
                 minmax = None
                 if not uniform:
-                    minmax = np.zeros((2, u_pad), self.eval_dtype)
+                    minmax = self._operand(keep, "minmax", (2, u_pad),
+                                           self.eval_dtype)
                     minmax[0, :nd] = d_min_t
                     minmax[1, :nd] = d_max_t
                 if self.stage_dtype != np.float32 and (
@@ -2090,11 +2238,13 @@ class DigestArena(_ArenaBase):
         if uniform:
             # bf16 staging narrows the VALUE matrix only; weights (0/1,
             # implicit here) and exported centroid weights stay exact
-            dv = np.zeros((u_pad, d_pad), self.stage_dtype)
+            dv = self._operand(keep, "dv", (u_pad, d_pad),
+                               self.stage_dtype)
             dv[r, pos] = v
             # int16 is exact (depths <= DENSE_DEPTH_CAP < 2^15) and
             # halves the vector's bytes on the link
-            depths_vec = np.zeros(u_pad, np.int16)
+            depths_vec = self._operand(keep, "depths", (u_pad,),
+                                       np.int16)
             if len(r):
                 depths_vec[:nd] = np.bincount(
                     r.astype(np.int64), minlength=nd)[:nd]
@@ -2103,14 +2253,15 @@ class DigestArena(_ArenaBase):
             return dv, depths_vec, None
         # compact_general: bf16 VALUES on the general path too (weights
         # and minmax stay eval_dtype — they feed exact accumulations)
-        dv = np.zeros((u_pad, d_pad),
-                      self.stage_dtype if self.compact_general
-                      else self.eval_dtype)
+        dv = self._operand(keep, "dv", (u_pad, d_pad),
+                           self.stage_dtype if self.compact_general
+                           else self.eval_dtype)
         dv[r, pos] = v
-        minmax = np.zeros((2, u_pad), self.eval_dtype)
+        minmax = self._operand(keep, "minmax", (2, u_pad),
+                               self.eval_dtype)
         minmax[0, :nd] = d_min_t
         minmax[1, :nd] = d_max_t
-        dw = np.zeros((u_pad, d_pad), self.eval_dtype)
+        dw = self._operand(keep, "dw", (u_pad, d_pad), self.eval_dtype)
         dw[r, pos] = wts[order]
         return dv, dw, minmax
 
@@ -2160,7 +2311,9 @@ class DigestArena(_ArenaBase):
         return out
 
     def _checkpoint_extra(self, meta: dict) -> None:
-        meta["staged_nonuniform"] = bool(self._staged_nonuniform)
+        # which rows a pre-reduce re-staged is not kept: a restored
+        # interval with any is weighted as a whole
+        meta["staged_nonuniform"] = not self.staged_uniform
         meta["compression"] = float(self.compression)
         # resident layout stamp (flush_resident_arenas): the host COO in
         # this checkpoint is authoritative either way — the resident
@@ -2242,6 +2395,7 @@ class MomentsArena(DigestArena):
     Unmeshed only: the moments flush is a single-device program (config
     rejects ``sketch_family_*`` with a device mesh)."""
 
+    _TIERED = False
     family = ring = wire_field = "moments"
     _COLUMNS = DigestArena._COLUMNS + (
         ("d_logn", 0), ("ivec", 0), ("iv_a", np.inf), ("iv_b", -np.inf))
@@ -2552,6 +2706,7 @@ class CompactorArena(DigestArena):
     Unmeshed only, like moments: one flush program per device, no
     cross-shard collective in the family's merge algebra yet."""
 
+    _TIERED = False
     family = ring = wire_field = "compactor"
     _COLUMNS = DigestArena._COLUMNS + (
         ("cvals", 0), ("ccnt", 0), ("ccomps", 0), ("cclip", 0))

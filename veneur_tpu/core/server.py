@@ -542,6 +542,24 @@ class Server:
             # sender that cannot connect yet retries or spools; ~45 s
             # from a cold compile cache, ~1.5 s from a warm one.
             self._prewarm()
+        elif self.config.prewarm_flush_shapes:
+            # an unmeshed node launches, before it listens, the closed
+            # list of programs a steady interval of its deployment can
+            # need — the flush at the arena pre-size's row buckets, the
+            # deep tier and the hot-key compress of a skewed interval
+            # (MetricAggregator.prewarm_launch) — for
+            # the meshed boot's reason: the hot-key compress first
+            # launches under the aggregator lock on the drain thread,
+            # and a compile there is a late flush.  Nothing compiles
+            # beside the live server any more: the pow2 sweep of every
+            # bucket below (MetricAggregator.prewarm, ahead-of-time)
+            # used to run as a thread from here, held a core for
+            # minutes from a cold cache, and marked shapes compiled
+            # that their first live launch compiled again; a bucket the
+            # list lacks compiles in its first flush, under the guard.
+            self._prewarm_launch()
+            if self.aggregator.sets.resident:
+                self._prewarm_set_lanes()
         elif self.aggregator.sets.resident:
             # an unmeshed arena whose set registers live on the device
             # launches its lane programs — a closed set, sized by the
@@ -664,11 +682,6 @@ class Server:
         if self.config.checkpoint_dir and self.config.checkpoint_interval > 0:
             t = threading.Thread(target=self._checkpoint_loop,
                                  daemon=True, name="checkpoint-loop")
-            t.start()
-            self._threads.append(t)
-        if self.config.prewarm_flush_shapes and self.mesh is None:
-            t = threading.Thread(target=self._prewarm, daemon=True,
-                                 name="flush-prewarm")
             t.start()
             self._threads.append(t)
         # self-metrics statsd client + runtime diagnostics loop
@@ -1554,15 +1567,14 @@ class Server:
             self.flight_recorder.record_span(child)
 
     def _prewarm(self) -> None:
-        """Boot-time compile of the configured flush buckets
+        """Boot-time compile of a meshed global's flush programs
         (compile-churn hardening; persists via the compilation cache,
-        so later boots replay from disk): mesh-less, in a background
-        thread, every pow2 key bucket up to the arena pre-size at
-        `prewarm_depths`; on a mesh, before start() opens a listener,
-        the pre-size's own bucket first, then the set-lane kernels and
-        the bucket of the server's own telemetry
-        (MetricAggregator.prewarm).  What it did is a `server.prewarm`
-        span in the flight recorder and `prewarm_programs` /
+        so later boots replay from disk), before start() opens a
+        listener: the pre-size's own bucket at `prewarm_depths` first,
+        then the set-lane kernels and the bucket of the server's own
+        telemetry (MetricAggregator.prewarm).  An unmeshed node's boot
+        is _prewarm_launch.  What either did is a `server.prewarm` span
+        in the flight recorder and `prewarm_programs` /
         `prewarm_seconds` in /debug/vars."""
         cap = self.config.arena_initial_capacity or 8192
         # prewarm rounds up to the arena's pow2 capacity internally,
@@ -1573,6 +1585,16 @@ class Server:
                 stop=self._shutdown),
             "flush prewarm failed; first flushes of each shape will "
             "compile in place")
+
+    def _prewarm_launch(self) -> None:
+        """Boot-time launch of an unmeshed node's closed program list
+        (MetricAggregator.prewarm_launch), accounted like _prewarm."""
+        self._account_prewarm(
+            lambda: self.aggregator.prewarm_launch(
+                list(self.config.prewarm_depths),
+                self.config.arena_initial_capacity or 8192),
+            "flush prewarm launch failed; first launches of each "
+            "program will compile in place")
 
     def _prewarm_set_lanes(self) -> None:
         """Boot-time launch of a resident set arena's lane programs,

@@ -172,7 +172,8 @@ def debug_vars(server) -> dict:
     # the flush timeline's rows): points handed to the flush, bytes of
     # them copied under the aggregator lock (0 = nothing joined at the
     # tick), buffer doublings over the interval
-    from veneur_tpu.core.aggregator import (SET_LEDGER_KEYS,
+    from veneur_tpu.core.aggregator import (HOT_LEDGER_KEYS,
+                                            SET_LEDGER_KEYS,
                                             STAGED_LEDGER_KEYS)
     segs = agg.last_flush_segments
     stats["staged_accumulator"] = {
@@ -186,6 +187,13 @@ def debug_vars(server) -> dict:
         key: segs.get(key, 0)
         for key in (*SET_LEDGER_KEYS, "set_import_sparse",
                     "set_import_dense")}
+    # what the hot-key lane did in the last interval and how its flush
+    # built the digest operand (also on the flush timeline's rows): rows
+    # pre-reduced, points into and out of the compress, launches, the
+    # passes' hold of the aggregator lock, one launch's bytes, the
+    # operand's tiers and padded elements
+    stats["hot_lane"] = {
+        key: segs.get(key, 0) for key in HOT_LEDGER_KEYS}
     guard = getattr(server.aggregator, "cardinality", None)
     if guard is not None:
         # per-tenant key-budget ledger: exact keys, evicted

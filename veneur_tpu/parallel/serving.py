@@ -456,6 +456,17 @@ def make_serving_flush(mesh: Optional[Mesh]):
             fn = general_d if donate else general
             return fn(dv, dw, minmax, pct, uniform=uniform)
 
+        # the deep tier of a skewed interval (aggregator._dispatch_flush):
+        # the few keys past DEEP_TIER_THRESHOLD staged points, weighted,
+        # at a fixed depth — the general program under a name and a
+        # scope of its own, so a trace tells the tiers apart
+        def flush_deep_tier(dv, dw, minmax, pct):
+            with jax.named_scope("flush.tier.deep"):
+                return digest_eval(dv, dw, minmax[0], minmax[1], pct)
+
+        unmeshed.deep_tier = jax.jit(flush_deep_tier)
+        unmeshed.deep_tier_donated = jax.jit(flush_deep_tier,
+                                             donate_argnums=(0, 1, 2))
         unmeshed.lower = general.lower
         unmeshed.lower_donated = general_d.lower
         # uniform intervals upload (values, per-row depths) instead of
@@ -592,8 +603,11 @@ def partial_digests(dense_v: jax.Array, dense_w: jax.Array,
     """One batched compress of a dense `[U, W]` sample matrix into per-row
     partial digests `[U, cap]` — the hot-key pre-reduction: an arbitrarily
     deep backlog collapses into <= cap weighted points per row, which
-    re-stage as ordinary samples (weight-preserving, order-invariant)."""
-    return td.compress(dense_v, dense_w, compression, cap)
+    re-stage as ordinary samples (weight-preserving, order-invariant).
+    The arena launches it at ONE shape, `[HOT_TILE_ROWS, HOT_TILE_WIDTH]`
+    (core/arena.py), whatever a tick carries."""
+    with jax.named_scope("digest.hot_compress"):
+        return td.compress(dense_v, dense_w, compression, cap)
 
 
 # ---------------------------------------------------------------------------
