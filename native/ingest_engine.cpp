@@ -2098,6 +2098,141 @@ long long vn_fill_dense(const long long* rows, const double* vals,
   return dropped.load();
 }
 
+// The LARGE dense build in one call (arena.build_dense above
+// _ONEPASS_MIN_BYTES): row -> dense-row map, per-row counts, tail
+// zeroing and the fill, straight from the staged COO into operands the
+// arena keeps from flush to flush.  Bit-equal to vn_fill_dense /
+// the numpy builder: the same (float) casts, and a row's points land in
+// ARRIVAL order — thread t counts and later fills the t-th contiguous
+// range of points, and its write cursor for a row starts where the
+// earlier ranges' counts for that row end, so every point is read once
+// by the count (its row id) and once by the fill, whatever n_threads.
+//
+// rows / vals / wts: the staged COO as for vn_fill_dense (wts null =
+//   uniform)
+// touched: int64[nd] arena rows in dense order; a point whose row is not
+//   among them, or an id outside [0, capacity) in either array, is BAD
+// map:    int32[capacity] scratch (row -> dense row, -1 = untouched)
+// cursors: int32[n_threads * u_pad] scratch
+// dv / dw / depths: the kept float32[u_pad * d_pad] (dw null = uniform)
+//   and int16[u_pad] operands, any content: each row's head is
+//   overwritten and its tail past the new depth zeroed here, in the
+//   threads' row ranges.  dv null = count only.
+// depth_out: the deepest row's point count.
+// Returns 0 when the operands were filled; -1 when they were not (none
+// given, or the deepest row does not fit d_pad): the caller makes
+// operands for *depth_out and calls again; > 0 the number of BAD points
+// or touched ids, nothing written: the caller falls back to the numpy
+// builder, which drops loudly.
+long long vn_build_dense(const long long* rows, const double* vals,
+                         const double* wts, long long n,
+                         const long long* touched, long long nd,
+                         long long capacity, int* map, int* cursors,
+                         float* dv, float* dw, short* depths,
+                         long long u_pad, long long d_pad,
+                         int n_threads, long long* depth_out) {
+  if (n_threads < 1) n_threads = 1;
+  if (nd > u_pad) return nd - u_pad;
+  long long bad = 0;
+  memset(map, 0xff, (size_t)capacity * sizeof(int));
+  for (long long i = 0; i < nd; i++) {
+    long long row = touched[i];
+    if (row < 0 || row >= capacity) bad++;
+    else map[row] = (int)i;
+  }
+  if (bad) return bad;
+
+  auto parallel = [&](auto&& fn) {
+    if (n_threads == 1) {
+      fn(0);
+      return;
+    }
+    std::vector<std::thread> ts;
+    for (int t = 0; t < n_threads; t++) ts.emplace_back(fn, t);
+    for (auto& t : ts) t.join();
+  };
+  auto span = [&](long long total, int t, long long* lo, long long* hi) {
+    long long per = (total + n_threads - 1) / n_threads;
+    *lo = std::min<long long>(total, t * per);
+    *hi = std::min<long long>(total, *lo + per);
+  };
+
+  // 1. count: thread t's point range into its own [u_pad] counts
+  std::atomic<long long> bad_points{0};
+  parallel([&](int t) {
+    int* cnt = cursors + (size_t)t * u_pad;
+    memset(cnt, 0, (size_t)u_pad * sizeof(int));
+    long long lo, hi, local_bad = 0;
+    span(n, t, &lo, &hi);
+    for (long long i = lo; i < hi; i++) {
+      long long row = rows[i];
+      int rid = (row < 0 || row >= capacity) ? -1 : map[row];
+      if (rid < 0) local_bad++;
+      else cnt[rid]++;
+    }
+    if (local_bad) bad_points.fetch_add(local_bad);
+  });
+  if (bad_points.load()) return bad_points.load();
+
+  // 2. per row: counts -> each thread's first write position (exclusive
+  //    prefix over the threads), the row's depth, the deepest row
+  std::atomic<long long> deepest{0};
+  const bool fill = dv != nullptr;
+  parallel([&](int t) {
+    long long lo, hi, local_max = 0;
+    span(u_pad, t, &lo, &hi);
+    for (long long r = lo; r < hi; r++) {
+      long long acc = 0;
+      for (int s = 0; s < n_threads; s++) {
+        int* c = cursors + (size_t)s * u_pad + r;
+        int mine = *c;
+        *c = (int)acc;
+        acc += mine;
+      }
+      if (acc > local_max) local_max = acc;
+      if (fill) depths[r] = (short)std::min<long long>(acc, 32767);
+    }
+    long long seen = deepest.load();
+    while (local_max > seen
+           && !deepest.compare_exchange_weak(seen, local_max)) {
+    }
+  });
+  *depth_out = deepest.load();
+  if (!fill || *depth_out > d_pad) return -1;
+
+  // 3. each thread zeroes the tails of its row range past their depth
+  //    (rows >= nd hold nothing: one memset), then fills its point
+  //    range — heads, so no cell is written by two threads
+  parallel([&](int t) {
+    long long lo, hi;
+    span(u_pad, t, &lo, &hi);
+    long long r = lo;
+    for (; r < hi && r < nd; r++) {
+      long long d = depths[r];
+      if (d < d_pad) {
+        size_t at = (size_t)(r * d_pad + d);
+        size_t bytes = (size_t)(d_pad - d) * sizeof(float);
+        memset(dv + at, 0, bytes);
+        if (dw) memset(dw + at, 0, bytes);
+      }
+    }
+    if (r < hi) {
+      size_t bytes = (size_t)((hi - r) * d_pad) * sizeof(float);
+      memset(dv + (size_t)(r * d_pad), 0, bytes);
+      if (dw) memset(dw + (size_t)(r * d_pad), 0, bytes);
+    }
+    int* cur = cursors + (size_t)t * u_pad;
+    span(n, t, &lo, &hi);
+    for (long long i = lo; i < hi; i++) {
+      long long rid = map[rows[i]];
+      size_t at = (size_t)(rid * d_pad + cur[rid]++);
+      dv[at] = (float)vals[i];
+      if (dw) dw[at] = (float)wts[i];
+    }
+  });
+  return 0;
+}
+
 }  // extern "C"
 
 // ---------------------------------------------------------------------------

@@ -26,7 +26,10 @@
 // surviving scan's columns read back whole.  Phase 3 feeds
 // vn_fill_dense adversarial COO rows (negative ids, ids past the
 // arena capacity, per-row overflow past the dense depth) and checks
-// the drop accounting and depth clamps hold.  Phase 4 (SPSC stress)
+// the drop accounting and depth clamps hold, and vn_build_dense the
+// same plus ids outside `touched`, operands too shallow or absent
+// (nothing may be written) and, on sound input, an operand that is the
+// same for every thread count.  Phase 4 (SPSC stress)
 // shrinks the staging rings to 2 slots so every handoff wraps and
 // backpressures, runs TWO concurrent drainers against the producers,
 // and checks exact packet conservation — a torn handoff (double-pop,
@@ -87,6 +90,13 @@ long long vn_fill_dense(const long long* rows, const double* vals,
                         float* dv, float* dw, short* depths,
                         long long u_pad, long long d_pad,
                         int n_threads);
+long long vn_build_dense(const long long* rows, const double* vals,
+                         const double* wts, long long n,
+                         const long long* touched, long long nd,
+                         long long capacity, int* map, int* cursors,
+                         float* dv, float* dw, short* depths,
+                         long long u_pad, long long d_pad,
+                         int n_threads, long long* depth_out);
 int vn_engine_opt(void* ep, const char* key, long long val);
 long long vn_drain_section(void* dp, int which, const void** a,
                            const void** b, const void** c);
@@ -389,6 +399,120 @@ int fill_dense_fuzz() {
     if (d2 != dropped) {
       fprintf(stderr, "fill fuzz: uniform path dropped %lld != %lld\n",
               d2, dropped);
+      return 1;
+    }
+  }
+  return 0;
+}
+
+// vn_build_dense: the same abuse — corrupt ids in rows and in touched,
+// a row deeper than the operands, no operands at all — must write
+// nothing; a sound input must fill every cell (the buffers start as
+// garbage: the call owns the zeroing) the same for every thread count,
+// each row's points in arrival order.
+int build_dense_fuzz() {
+  const long long n = 4099, cap = 64, nd = 13, u_pad = 16;
+  std::vector<long long> touched(nd), rows(n);
+  std::vector<double> vals(n), wts(n);
+  for (long long i = 0; i < nd; i++) touched[i] = i * 4 + 1;
+  long long deepest = 0;
+  std::vector<long long> count(nd, 0);
+  for (long long i = 0; i < n; i++) {
+    long long k = (i * 7 + i / 5) % nd;
+    rows[i] = touched[k];
+    vals[i] = (double)i;
+    wts[i] = (double)(i % 9) / 3.0;
+    if (++count[k] > deepest) deepest = count[k];
+  }
+  long long d_pad = 512;  // >= deepest (4099 / 13 = 316)
+  std::vector<float> want_v, want_w;
+  for (int threads : {1, 3, 4}) {
+    std::vector<int> map((size_t)cap, 12345);
+    std::vector<int> cursors((size_t)(threads * u_pad), -7);
+    std::vector<float> dv((size_t)(u_pad * d_pad), 9.f);
+    std::vector<float> dw((size_t)(u_pad * d_pad), 9.f);
+    std::vector<short> depths((size_t)u_pad, -1);
+    long long depth = -1;
+    // count only, then operands too shallow: nothing written
+    long long st = vn_build_dense(
+        rows.data(), vals.data(), wts.data(), n, touched.data(), nd, cap,
+        map.data(), cursors.data(), nullptr, nullptr, nullptr, u_pad, 0,
+        threads, &depth);
+    long long st2 = vn_build_dense(
+        rows.data(), vals.data(), wts.data(), n, touched.data(), nd, cap,
+        map.data(), cursors.data(), dv.data(), dw.data(), depths.data(),
+        u_pad, 8, threads, &depth);
+    if (st != -1 || st2 != -1 || depth != deepest || dv[0] != 9.f ||
+        dw[(size_t)(u_pad * 8)] != 9.f) {
+      fprintf(stderr, "build fuzz: count-only / shallow call wrong "
+                      "(threads=%d, %lld %lld depth %lld)\n",
+              threads, st, st2, depth);
+      return 1;
+    }
+    // corrupt ids: refused, nothing written
+    for (long long bad : {-5LL, cap + 3, 2LL /* not touched */}) {
+      long long keep = rows[n / 2];
+      rows[n / 2] = bad;
+      st = vn_build_dense(
+          rows.data(), vals.data(), wts.data(), n, touched.data(), nd,
+          cap, map.data(), cursors.data(), dv.data(), dw.data(),
+          depths.data(), u_pad, d_pad, threads, &depth);
+      rows[n / 2] = keep;
+      if (st <= 0 || dv[0] != 9.f) {
+        fprintf(stderr, "build fuzz: corrupt row id %lld not refused "
+                        "(threads=%d)\n", bad, threads);
+        return 1;
+      }
+    }
+    long long keep = touched[3];
+    touched[3] = cap;
+    st = vn_build_dense(
+        rows.data(), vals.data(), wts.data(), n, touched.data(), nd, cap,
+        map.data(), cursors.data(), dv.data(), dw.data(), depths.data(),
+        u_pad, d_pad, threads, &depth);
+    touched[3] = keep;
+    if (st <= 0 || dv[0] != 9.f) {
+      fprintf(stderr, "build fuzz: corrupt touched id not refused\n");
+      return 1;
+    }
+    // sound: filled, uniform path (null weights) legal too
+    st = vn_build_dense(
+        rows.data(), vals.data(), wts.data(), n, touched.data(), nd, cap,
+        map.data(), cursors.data(), dv.data(), dw.data(), depths.data(),
+        u_pad, d_pad, threads, &depth);
+    std::vector<float> uv((size_t)(u_pad * d_pad), 9.f);
+    st2 = vn_build_dense(
+        rows.data(), vals.data(), nullptr, n, touched.data(), nd, cap,
+        map.data(), cursors.data(), uv.data(), nullptr, depths.data(),
+        u_pad, d_pad, threads, &depth);
+    if (st != 0 || st2 != 0 || uv != dv) {
+      fprintf(stderr, "build fuzz: sound input not filled "
+                      "(threads=%d)\n", threads);
+      return 1;
+    }
+    for (long long r = 0; r < u_pad; r++) {
+      long long d = r < nd ? count[r] : 0;
+      if (depths[r] != d) {
+        fprintf(stderr, "build fuzz: depth of row %lld\n", r);
+        return 1;
+      }
+      for (long long c = 0; c < d_pad; c++) {
+        float v = dv[(size_t)(r * d_pad + c)];
+        // arrival order: a row's values ascend; its tail is zero
+        if (c >= d ? (v != 0.f || dw[(size_t)(r * d_pad + c)] != 0.f)
+                   : (c > 0 && v <= dv[(size_t)(r * d_pad + c - 1)])) {
+          fprintf(stderr, "build fuzz: cell [%lld, %lld] (threads=%d)\n",
+                  r, c, threads);
+          return 1;
+        }
+      }
+    }
+    if (want_v.empty()) {
+      want_v = dv;
+      want_w = dw;
+    } else if (dv != want_v || dw != want_w) {
+      fprintf(stderr, "build fuzz: %d threads built another operand\n",
+              threads);
       return 1;
     }
   }
@@ -732,6 +856,7 @@ int main() {
   vn_engine_free(e);
   rc |= wire_fuzz();
   rc |= fill_dense_fuzz();
+  rc |= build_dense_fuzz();
   rc |= spsc_stress();
   rc |= simd_parity();
   if (rc == 0)

@@ -136,10 +136,14 @@ STAGED_LEDGER_KEYS = ("staged_points", "staged_cut_copy_bytes",
 # compress launch moves (operands + results, from the tile's shape), and
 # the unmeshed digest build: how many operands (tiers) it made and their
 # padded value-matrix elements, rows x depth summed (`staged_points`
-# over it is the build's fill)
+# over it is the build's fill); and of either digest build
+# (DigestArena.BUILD_STATS): whether the one-pass kept-operand build made
+# the flush's operand, and the bytes of operands and row-index arrays
+# the build allocated anew (0 in a steady flush that engaged it)
 HOT_LEDGER_KEYS = ("hot_keys", "hot_points_in", "hot_points_out",
                    "hot_compress_launches", "hot_compress_held_s",
-                   "hot_compress_tile_bytes", "dense_tiers", "dense_elems")
+                   "hot_compress_tile_bytes", "dense_tiers", "dense_elems",
+                   "build_onepass", "build_fresh_bytes")
 LEDGER_SEGMENT_KEYS = frozenset(
     ["snapshot_lock_wait_s", "snapshot_sync_s", "snapshot_staged_s",
      "snapshot_columns_s"]
@@ -1370,6 +1374,7 @@ class MetricAggregator:
                     seg[f"{prefix}_{name}"] = v
         seg["hot_compress_tile_bytes"] = self.digests.hot_tile_bytes
         seg["dense_tiers"] = seg["dense_elems"] = 0
+        seg["build_onepass"] = seg["build_fresh_bytes"] = 0
         # the window-ring cut timestamp is taken HERE (the cut), but
         # the slot is published at emit time — see _emit_pending
         snap["query_cut_ts"] = time.time()
@@ -1926,6 +1931,9 @@ class MetricAggregator:
             seg["dispatch_s"] = dispatch_s
             if len(builds) > 1:
                 self._tier_inflight = [b["outs"] for b in builds]
+            self._account_build(
+                seg, [b["outs"] for b in builds],
+                [b["first_dev"] for b in builds if b["first_dev"]])
             pend.update(tiers=builds, t_dispatch0=t_dispatch0)
             return pend
         else:
@@ -2027,6 +2035,8 @@ class MetricAggregator:
                 set_regs_dev = serving.set_regs_pack(
                     set_regs_out, jnp.asarray(ps))
             seg["dispatch_s"] += time.perf_counter() - t0
+            self._account_build(seg, [flat_dev],
+                                [] if donate else [(dvd, dwd)])
             pend.update(
                 flat_dev=flat_dev, set_regs_dev=set_regs_dev, ps=ps,
                 k_rows=inputs.dense_v.shape[0],
@@ -2035,6 +2045,19 @@ class MetricAggregator:
                 crows=crows, srows=srows,
                 dense_dev=None if donate else (dvd, dwd))
             return pend
+
+    def _account_build(self, seg: dict, outs: list, kept_dev: list):
+        """After the digest launch(es): what the build did, onto the
+        timeline row, and — where the arena's kept operands served it —
+        what DigestArena.hold_dense asks of a caller: the launches'
+        results, and the device operands a forwarding tier keeps."""
+        stats = self.digests.take_build_stats()
+        seg["build_onepass"] = stats["onepass"]
+        seg["build_fresh_bytes"] = stats["fresh_bytes"]
+        if stats["onepass"]:
+            self.digests.hold_dense(outs)
+            for dev in kept_dev:
+                self.digests.lend_dense(dev)
 
     def _build_tiers(self, dpart: dict) -> list:
         """The unmeshed flush's host-built operand(s) from a digest

@@ -34,6 +34,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -49,6 +50,19 @@ DENSE_DEPTH_CAP = 512
 # staged-element count above which the dense build uses the native C++
 # single-pass fill (vn_fill_dense) instead of numpy argsort+scatter
 _NATIVE_FILL_MIN = 65536
+# padded operand bytes (the [U, D] f32 value matrix, and the weight
+# matrix when the build is not uniform) from which build_dense makes its
+# operand in ONE native call (vn_build_dense) into buffers the arena
+# keeps from flush to flush.  Below a few MiB the build is milliseconds
+# of work whichever way; above, the fresh pages of `np.zeros` (~1 us/KB
+# first touch on the chip's host, and whether glibc maps them anew
+# follows the process's allocation history) and the repeated passes over
+# the staged points (a gather, a bincount, one scan per fill thread) are
+# most of it: 77 of a meshed global's 237 ms from tick to sink at
+# [131072, 32] x 2 = 32 MiB.  16 MiB stands between that and the largest
+# operands that build the other way: a skewed node's 8 MiB tail (which
+# keeps its own buffers), a fleet's [2048, 256] x 2 = 4 MiB.
+_ONEPASS_MIN_BYTES = 16 << 20
 # The hot-key lane's compress tile: EVERY pre-reduction launch
 # (DigestArena._pre_reduce -> serving.partial_digests) has this one
 # shape, whatever a drain tick carries, so the program is known at boot
@@ -1407,6 +1421,10 @@ class DigestArena(_ArenaBase):
     # the caller (the aggregator lock: a drain tick's sync, the cut's)
     HOT_STATS = ("keys", "points_in", "points_out", "compress_launches",
                  "compress_held_ns")
+    # per flush: 1 where the one-pass kept-operand build made the
+    # operand (0: any other path, its fallback included), and the bytes
+    # of operands and row-index arrays the builds allocated anew
+    BUILD_STATS = ("onepass", "fresh_bytes")
 
     family = "digest"
     # a histogram family's window ring (query plane, retention) and the
@@ -1520,6 +1538,15 @@ class DigestArena(_ArenaBase):
         # what the hot-key lane did over the open interval, for the
         # flush timeline's row (take_hot_stats, at the cut)
         self._hot_stats = dict.fromkeys(self.HOT_STATS, 0)
+        # the large single operand's buffers (build_dense above
+        # _ONEPASS_MIN_BYTES), kept from flush to flush; what the last
+        # such build's launches returned, which the next waits for
+        # before it writes them again (hold_dense; None = nobody said,
+        # so the buffers are not the arena's to rewrite); and what the
+        # builds since take_build_stats did
+        self._dense_keep: dict = {}
+        self._dense_readers = None
+        self._build_stats = dict.fromkeys(self.BUILD_STATS, 0)
         # device-resident delta mirror (flush_resident_arenas): the host
         # COO above stays AUTHORITATIVE — checkpoints, forwarding
         # exports and the query rings read it unchanged, which is what
@@ -2118,8 +2145,8 @@ class DigestArena(_ArenaBase):
         staged points: a power of two to each replica's slice."""
         return max(2, self.n_replicas * _pow2(-(-depth // self.n_replicas)))
 
-    @staticmethod
-    def _operand(keep: Optional[dict], name: str, shape, dtype):
+    def _operand(self, keep: Optional[dict], name: str, shape, dtype,
+                 zero: bool = True):
         """An all-zero host operand for build_dense: a fresh `np.zeros`,
         or — `keep` given — the caller's buffer of that name, zeroed in
         place when shape and dtype still fit.  A fresh operand of
@@ -2127,15 +2154,129 @@ class DigestArena(_ArenaBase):
         on the chip's host), and whether glibc serves it from mapped
         heap or from new pages is an accident of the process's
         allocation history: a flush that keeps its operands pays a
-        memset, the same in every run."""
+        memset, the same in every run.  zero=False: whatever the buffer
+        holds (the one-pass build writes every cell itself)."""
         buf = None if keep is None else keep.get(name)
         if buf is None or buf.shape != tuple(shape) or buf.dtype != dtype:
-            buf = np.zeros(shape, dtype)
+            buf = (np.zeros if zero else np.empty)(shape, dtype)
+            self._build_stats["fresh_bytes"] += buf.nbytes
             if keep is not None:
                 keep[name] = buf
-        else:
+        elif zero:
             buf.fill(0)
         return buf
+
+    def take_build_stats(self) -> dict:
+        """BUILD_STATS of the builds since the last call, zeroed."""
+        out, self._build_stats = (self._build_stats,
+                                  dict.fromkeys(self.BUILD_STATS, 0))
+        return out
+
+    def hold_dense(self, readers) -> None:
+        """The rule for a caller that wants kept operands: after it
+        launched on what build_dense returned, it says here what the
+        launches returned (results that are ready have consumed their
+        inputs).  The next one-pass build waits for them before it
+        writes the kept buffers again — a served node's flushes are
+        serial, so that returns at once; a build nobody called this
+        after makes its own buffers."""
+        self._dense_readers = readers
+
+    def lend_dense(self, dev_operands) -> None:
+        """Device arrays of the last build's operands that outlive their
+        launch (a forwarding tier keeps them for its digest export).
+        Where device_put aliased an aligned host buffer instead of
+        copying it — the CPU backend's may — the array IS the kept
+        buffer: the arena lets go of it and the next build makes its
+        own."""
+        keep = self._dense_keep
+        for arr in dev_operands:
+            for shard in arr.addressable_shards:
+                if shard.device.platform != "cpu":
+                    continue
+                at = shard.data.unsafe_buffer_pointer()
+                for name in ("dv", "dw", "depths"):
+                    buf = keep.get(name)
+                    if (buf is not None and buf.ctypes.data <= at
+                            < buf.ctypes.data + buf.nbytes):
+                        del keep[name]
+
+    def _build_onepass(self, staged, touched: np.ndarray,
+                       d_min_t: np.ndarray, d_max_t: np.ndarray,
+                       u_pad: int, d_floor: int, uniform: bool):
+        """build_dense's large form: ONE native call maps rows to dense
+        rows, counts each row's points (so the depth is known without a
+        numpy gather or bincount), zeroes each row's tail and fills,
+        every point read once by the count and once by the fill, into
+        the buffers the arena keeps — no page is first touched in a
+        steady flush.  Same casts, same arrival order within a row: the
+        triple is bit-equal to the other builders'.  None where the
+        native engine is missing or a staged row id is out of range or
+        not in `touched`: the caller's numpy builder drops loudly."""
+        try:
+            from veneur_tpu import ingest as ingest_mod
+            ingest_mod.load_library()
+        except Exception:
+            return None
+        keep = self._dense_keep
+        if self._dense_readers is None:
+            # handed out, and nobody said who reads them
+            keep.clear()
+        else:
+            jax.block_until_ready(self._dense_readers)
+            self._dense_readers = []
+        rows, vals, wts = staged
+        rows = np.ascontiguousarray(rows, np.int64)
+        vals = np.ascontiguousarray(vals, np.float64)
+        wts = None if uniform else np.ascontiguousarray(wts, np.float64)
+        touched = np.ascontiguousarray(touched, np.int64)
+        row_map = self._operand(keep, "row_map", (self.capacity,),
+                                np.int32, zero=False)
+        cursors = self._operand(
+            keep, "cursors", (ingest_mod.BUILD_DENSE_THREADS * u_pad,),
+            np.int32, zero=False)
+
+        def attempt(d_pad: int):
+            """The call at depth d_pad (0: count only), into the kept
+            operands of that shape, made anew where they have another."""
+            dv = dw = depths = None
+            if d_pad:
+                dv = self._operand(keep, "dv", (u_pad, d_pad), np.float32,
+                                   zero=False)
+                depths = self._operand(keep, "depths", (u_pad,), np.int16,
+                                       zero=False)
+                if not uniform:
+                    dw = self._operand(keep, "dw", (u_pad, d_pad),
+                                       np.float32, zero=False)
+            status, depth = ingest_mod.build_dense(
+                rows, vals, wts, touched, row_map, cursors, dv, dw,
+                depths, u_pad, d_pad)
+            return status, self.dense_depth(max(depth, d_floor, 1)), \
+                dv, dw, depths
+
+        kept = keep.get("dv")
+        d_pad = (kept.shape[1] if kept is not None
+                 and kept.shape[0] == u_pad else 0)
+        # the kept shape first (the steady case: one call), then, where
+        # the interval's deepest row asks for another depth, that one
+        status, want, dv, dw, depths = attempt(d_pad)
+        if status <= 0 and want != d_pad:
+            status, want, dv, dw, depths = attempt(want)
+        if status != 0:
+            return None
+        self._dense_readers = None
+        self._build_stats["onepass"] = 1
+        if self.stage_dtype != np.float32 and (
+                uniform or self.compact_general):
+            dv = dv.astype(self.stage_dtype)
+            self._build_stats["fresh_bytes"] += dv.nbytes
+        if uniform:
+            return dv, depths, None
+        nd = len(touched)
+        minmax = self._operand(keep, "minmax", (2, u_pad), self.eval_dtype)
+        minmax[0, :nd] = d_min_t
+        minmax[1, :nd] = d_max_t
+        return dv, dw, minmax
 
     def build_dense(self, staged, touched: np.ndarray,
                     d_min_t: np.ndarray, d_max_t: np.ndarray,
@@ -2159,8 +2300,26 @@ class DigestArena(_ArenaBase):
         keep: a dict the caller owns, in which the operands' buffers
         stay from one build to the next (_operand); the returned arrays
         are then the caller's only until its next build with that
-        dict.  None = fresh operands, the parent's."""
+        dict.  None = fresh operands — or, where the padded operands
+        reach _ONEPASS_MIN_BYTES, the arena's own kept ones, built in
+        one native pass (_build_onepass; the caller then owes
+        hold_dense).  The size is judged before any pass over the
+        points, from what is known then: the deepest row holds at least
+        the mean."""
         rows, vals, wts = staged
+        nd = len(touched)
+        per_shard = self.dense_block_per_shard(max(nd, u_floor))
+        u_pad = self.n_shards * per_shard
+        if keep is None and self.eval_dtype == np.float32:
+            d_least = self.dense_depth(
+                max(-(-len(rows) // max(nd, 1)), d_floor, 1))
+            if (u_pad * d_least * 4 * (1 if uniform else 2)
+                    >= _ONEPASS_MIN_BYTES):
+                built = self._build_onepass(staged, touched, d_min_t,
+                                            d_max_t, u_pad, d_floor,
+                                            uniform)
+                if built is not None:
+                    return built
         if len(rows) and (int(rows.min()) < 0
                           or int(rows.max()) >= self.capacity):
             # corrupt staged row ids: a negative id would WRAP through
@@ -2174,9 +2333,6 @@ class DigestArena(_ArenaBase):
             keep_mask = ~bad
             rows, vals, wts = rows[keep_mask], vals[keep_mask], \
                 wts[keep_mask]
-        nd = len(touched)
-        per_shard = self.dense_block_per_shard(max(nd, u_floor))
-        u_pad = self.n_shards * per_shard
         dense_id = np.full(self.capacity, -1, np.int64)
         dense_id[touched] = np.arange(nd)
 
@@ -2195,6 +2351,7 @@ class DigestArena(_ArenaBase):
             except Exception:
                 native_fill = None
         rid = dense_id[rows]
+        self._build_stats["fresh_bytes"] += dense_id.nbytes + rid.nbytes
         if native_fill is not None and len(rid) and rid.min() < 0:
             # staged rows outside `touched` (shouldn't happen; invariant
             # is touched >= staged) — the numpy path is the debuggable one

@@ -156,6 +156,14 @@ def load_library():
             ctypes.c_longlong, ctypes.c_void_p, ctypes.c_longlong,
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
             ctypes.c_longlong, ctypes.c_longlong, ctypes.c_int]
+        lib.vn_build_dense.restype = ctypes.c_longlong
+        lib.vn_build_dense.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+            ctypes.c_longlong, ctypes.c_void_p, ctypes.c_longlong,
+            ctypes.c_longlong, ctypes.c_void_p, ctypes.c_void_p,
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+            ctypes.c_longlong, ctypes.c_longlong, ctypes.c_int,
+            ctypes.POINTER(ctypes.c_longlong)]
         lib.vn_route.restype = ctypes.c_void_p
         lib.vn_route.argtypes = [
             ctypes.c_char_p, ctypes.c_longlong,
@@ -315,6 +323,11 @@ def route_metric_list(payload: bytes, ring_hashes, ring_dests,
         lib.vn_route_free(handle)
 
 
+def _ptr(a):
+    """A numpy array's buffer for a `c_void_p` argument (None: null)."""
+    return a.ctypes.data_as(ctypes.c_void_p) if a is not None else None
+
+
 def fill_dense(rows, vals, wts, dense_id, dv, dw, depths,
                n_threads: int = 4) -> int:
     """Native COO->dense fill (see vn_fill_dense in ingest_engine.cpp).
@@ -329,10 +342,6 @@ def fill_dense(rows, vals, wts, dense_id, dv, dw, depths,
     import numpy as np
 
     lib = load_library()
-
-    def ptr(a):
-        return a.ctypes.data_as(ctypes.c_void_p) if a is not None else None
-
     assert rows.dtype == np.int64 and vals.dtype == np.float64
     assert dv.dtype == np.float32 and dense_id.dtype == np.int64
     capacity = len(dense_id)
@@ -341,9 +350,54 @@ def fill_dense(rows, vals, wts, dense_id, dv, dw, depths,
         return int(((rows < 0) | (rows >= capacity)).sum())
     u_pad, d_pad = dv.shape
     return int(lib.vn_fill_dense(
-        ptr(rows), ptr(vals), ptr(wts), len(rows), ptr(dense_id),
-        capacity, ptr(dv), ptr(dw), ptr(depths), u_pad, d_pad,
+        _ptr(rows), _ptr(vals), _ptr(wts), len(rows), _ptr(dense_id),
+        capacity, _ptr(dv), _ptr(dw), _ptr(depths), u_pad, d_pad,
         n_threads))
+
+
+# threads of one vn_build_dense call (fill_dense's default too); the
+# cursors scratch is sized by it
+BUILD_DENSE_THREADS = 4
+
+
+def build_dense(rows, vals, wts, touched, row_map, cursors,
+                dv, dw, depths, u_pad: int, d_pad: int) -> tuple[int, int]:
+    """The large dense build in one native call (vn_build_dense in
+    ingest_engine.cpp): map, count, zero the tails and fill, straight
+    from the staged COO into the caller's kept operands.  rows / touched
+    int64, vals / wts float64 (wts None = uniform), row_map int32
+    [capacity] and cursors int32 [BUILD_DENSE_THREADS * u_pad] scratch,
+    dv / dw float32 [u_pad, d_pad] (dv None = count only), depths int16
+    [u_pad]; all C-contiguous.  Returns (status, deepest row's count):
+    0 filled; -1 not filled (no operands, or the deepest row does not
+    fit d_pad); > 0 that many row ids out of range or not in `touched`,
+    nothing written."""
+    import numpy as np
+
+    lib = load_library()
+    for a, dtype, size in ((rows, np.int64, len(rows)),
+                           (vals, np.float64, len(rows)),
+                           (wts, np.float64, len(rows)),
+                           (touched, np.int64, len(touched)),
+                           (row_map, np.int32, len(row_map)),
+                           (cursors, np.int32, BUILD_DENSE_THREADS * u_pad),
+                           (dv, np.float32, u_pad * d_pad),
+                           (dw, np.float32, u_pad * d_pad),
+                           (depths, np.int16, u_pad)):
+        if a is not None and not (a.dtype == dtype and a.size == size
+                                  and a.flags.c_contiguous):
+            raise ValueError("build_dense: operand of the wrong dtype, "
+                             "size or layout")
+    if dv is not None and depths is None:
+        raise ValueError("build_dense: a fill needs the depth vector")
+
+    depth = ctypes.c_longlong(0)
+    status = lib.vn_build_dense(
+        _ptr(rows), _ptr(vals), _ptr(wts), len(rows), _ptr(touched),
+        len(touched), len(row_map), _ptr(row_map), _ptr(cursors),
+        _ptr(dv), _ptr(dw), _ptr(depths), u_pad, d_pad,
+        BUILD_DENSE_THREADS, ctypes.byref(depth))
+    return int(status), int(depth.value)
 
 
 def metro64(data: bytes) -> int:
