@@ -14,11 +14,13 @@ import pytest
 from veneur_tpu import config as config_mod
 from veneur_tpu import http_api
 from veneur_tpu import ingest as ingest_mod
-from veneur_tpu.core.aggregator import (LEDGER_SEGMENT_KEYS,
+from veneur_tpu.core.aggregator import (COLUMNS_PART_KEYS,
+                                        LEDGER_SEGMENT_KEYS,
                                         ROW_ONLY_SEGMENT_KEYS,
                                         MetricAggregator)
 from veneur_tpu.core.server import Server
 from veneur_tpu.forward.client import ForwardClient
+from veneur_tpu.profiling.timeline import FlushTimeline
 from veneur_tpu.protocol import forward_pb2, metric_pb2, tdigest_pb2
 from veneur_tpu.sinks import simple as simple_sinks
 from veneur_tpu.trace import assembly
@@ -28,7 +30,14 @@ ROW_FIELDS = ("snapshot_lock_wait_ms", "snapshot_sync_ms",
               "import_lock_wait_ms", "import_scan_ms", "import_held_ms",
               "fold_calls", "fold_lines", "fold_lock_wait_ms", "fold_ms",
               "import_digest_hits", "import_digest_misses",
-              "staged_points", "staged_cut_copy_bytes", "staged_regrows")
+              "staged_points", "staged_cut_copy_bytes", "staged_regrows",
+              "snapshot_cache_ms", "snapshot_cut_ms", "snapshot_reset_ms",
+              "snapshot_end_ms", "snapshot_rest_ms")
+# what the metric lane amends the row with once the sink had the batch
+LANE_ROW_FIELDS = ("lane_sink_cpu_ms", "lane_gc_passes",
+                   "lane_gc_full_passes")
+COLUMNS_PARTS = ("cache", "cut", "reset", "end", "rest")
+SINK_PARTS = ("records", "splice", "put")
 
 
 def _wait(cond, timeout_s=10.0):
@@ -183,8 +192,10 @@ def server():
 
     def boot(**kw):
         sink = simple_sinks.ChannelMetricSink()
+        kw.setdefault("interval", 10.0)
+        sink = kw.pop("sink", sink)
         cfg = config_mod.Config(
-            statsd_listen_addresses=["udp://127.0.0.1:0"], interval=10.0,
+            statsd_listen_addresses=["udp://127.0.0.1:0"],
             percentiles=[0.5], hostname="ledger-test", **kw)
         srv = Server(cfg, extra_metric_sinks=[sink])
         servers.append(srv)
@@ -225,7 +236,8 @@ def test_lane_spans_continue_the_flush_trace_under_the_sink_span(server):
     assert sink_span["trace_id"] == root["trace_id"]
     assert sink_span["parent_id"] == root["span_id"]
     lane = {s["name"]: s for s in spans
-            if s["name"].startswith("flush.seg.lane.")}
+            if s["name"].startswith("flush.seg.lane.")
+            and not s["name"].startswith("flush.seg.lane.sink.")}
     assert set(lane) == {"flush.seg.lane.wait", "flush.seg.lane.filter",
                          "flush.seg.lane.sink"}
     for s in lane.values():
@@ -238,8 +250,9 @@ def test_lane_spans_continue_the_flush_trace_under_the_sink_span(server):
     assert sum(s["duration_ms"] for s in lane.values()) \
         <= sink_span["duration_ms"] + 0.004
     # only metric lanes carry them: one of each per trace with one sink
+    # (and the sink call's own three parts under it)
     assert len([s for s in spans
-                if s["name"].startswith("flush.seg.lane.")]) == 3
+                if s["name"].startswith("flush.seg.lane.")]) == 3 + 3
 
 
 def test_critical_path_table_is_unchanged_by_the_new_grandchildren(server):
@@ -252,7 +265,8 @@ def test_critical_path_table_is_unchanged_by_the_new_grandchildren(server):
     new = [s for s in trace
            if s["name"].startswith(("flush.seg.snapshot.",
                                     "flush.seg.lane."))]
-    assert len(new) == 7
+    # the snapshot's 4 parts + the columns' 5, the lane's 3 + the sink's 3
+    assert len(new) == 4 + 5 + 3 + 3
     assert all(s["parent_id"] != root["span_id"] for s in new)
     with_new = assembly.interval_row(root, trace)
     without = assembly.interval_row(
@@ -264,7 +278,8 @@ def test_critical_path_table_is_unchanged_by_the_new_grandchildren(server):
     # the snapshot's parts are laid inside their parent
     snap = [s for s in trace if s["name"] == "flush.seg.snapshot"][0]
     parts = [s for s in trace
-             if s["name"].startswith("flush.seg.snapshot.")]
+             if s["name"].startswith("flush.seg.snapshot.")
+             and not s["name"].startswith("flush.seg.snapshot.columns.")]
     assert {s["parent_id"] for s in parts} == {snap["span_id"]}
     assert sum(s["duration_ms"] for s in parts) \
         <= snap["duration_ms"] + 0.004
@@ -435,3 +450,292 @@ def test_digest_hits_and_misses_add_up_to_the_plain_digests_imported():
     finally:
         client.close()
         glob.shutdown()
+
+
+# -- the cut and the hand-off, part by part ---------------------------------
+
+
+def _trace_of(srv, spans: list) -> dict:
+    """The last flush's spans by name (one metric sink: names are unique)."""
+    tid = int(srv.flush_timeline.snapshot()[-1]["trace_id"], 16)
+    return {s["name"]: s for s in spans if s["trace_id"] == tid}
+
+
+def _inside(child: dict, parent: dict, slack_ms: float = 0.004) -> bool:
+    # durations are rounded to the microsecond, each
+    return (child["start_ns"] >= parent["start_ns"]
+            and child["start_ns"] / 1e6 + child["duration_ms"]
+            <= parent["start_ns"] / 1e6 + parent["duration_ms"] + slack_ms)
+
+
+def test_columns_parts_sum_to_the_columns_span_and_lie_inside_it(server):
+    srv, _sink = server()
+    srv.start()
+    _send_and_drain(srv)
+    trace = _trace_of(srv, _flush_and_spans(srv))
+    row = srv.flush_timeline.snapshot()[-1]
+    parts_ms = [row[f"snapshot_{p}_ms"] for p in COLUMNS_PARTS]
+    # five fields rounded to the microsecond against a sixth
+    assert sum(parts_ms) == pytest.approx(row["snapshot_columns_ms"],
+                                          abs=0.004)
+    assert min(parts_ms[:4]) >= 0.0 and parts_ms[4] > -0.001
+    # the aggregator's own seconds add up exactly: the last is what is left
+    seg = srv.aggregator.last_flush_segments
+    assert sum(seg[k] for k in COLUMNS_PART_KEYS) == pytest.approx(
+        seg["snapshot_columns_s"], abs=1e-12)
+    columns = trace["flush.seg.snapshot.columns"]
+    children = [trace[f"flush.seg.snapshot.columns.{p}"]
+                for p in COLUMNS_PARTS]
+    assert all(c["parent_id"] == columns["span_id"] for c in children)
+    assert all(_inside(c, columns, slack_ms=0.008) for c in children)
+    # laid end to end, in order, from the parent's start
+    assert children[0]["start_ns"] == columns["start_ns"]
+    assert [c["start_ns"] for c in children] \
+        == sorted(c["start_ns"] for c in children)
+    for c, p in zip(children, COLUMNS_PARTS):
+        assert c["duration_ms"] == pytest.approx(
+            max(0.0, row[f"snapshot_{p}_ms"]), abs=0.002)
+
+
+@pytest.mark.parametrize("part", ["cut", "reset", "end"])
+def test_columns_parts_say_which_family(server, part):
+    """A flush that touched timers and counters: the span's tags carry
+    the milliseconds of each family the step walked, and they add up to
+    the row's field."""
+    srv, _sink = server()
+    srv._FAMILY_TAG_MIN_MS = 0.0       # tag every family, however small
+    srv.start()
+    _, addr = srv.statsd_addrs[0]
+    tx = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
+    tx.sendto(b"\n".join([b"led.t%d:%d|ms" % (i, i) for i in range(20)]
+                         + [b"led.c%d:1|c" % i for i in range(20)]), addr)
+    tx.close()
+    assert _wait(lambda: (srv._drain_native() or True)
+                 and srv.native.engine.totals()[0] >= 40)
+    trace = _trace_of(srv, _flush_and_spans(srv))
+    row = srv.flush_timeline.snapshot()[-1]
+    tags = trace[f"flush.seg.snapshot.columns.{part}"]["tags"]
+    assert set(tags) == set(MetricAggregator._FAMILIES)
+    assert sum(float(v) for v in tags.values()) == pytest.approx(
+        row[f"snapshot_{part}_ms"], abs=0.005)
+    # the default threshold leaves a family under 0.05 ms out
+    assert Server._FAMILY_TAG_MIN_MS == 0.05
+
+
+def _serve(srv, flushes: int, timeout_s: float = 20.0):
+    """Run the ticker until `flushes` rows are in and their lanes done."""
+    t = threading.Thread(target=srv.serve, daemon=True)
+    t.start()
+    assert _wait(lambda: len(srv.flush_timeline) >= flushes, timeout_s)
+    srv.stop_serving()
+    t.join(10)
+    assert srv.egress.settle(timeout_s=10.0)
+    return [r for r in srv.flush_timeline.snapshot() if "event" not in r]
+
+
+def test_a_served_flush_carries_its_tick_and_a_hand_called_one_does_not(
+        server):
+    srv, _sink = server(interval=0.2)
+    srv.start()
+    _send_and_drain(srv)
+    rows = _serve(srv, 2)
+    for row in rows[:2]:
+        # the ticker wakes at or after its tick, well inside an interval
+        assert 0.0 <= row["tick_late_ms"] < 200.0
+        assert row["tick_to_sink_ms"] >= row["tick_late_ms"]
+        # one process, one clock: tick -> flush start -> flush -> lane
+        assert row["tick_to_sink_ms"] <= (row["tick_late_ms"]
+                                          + row["total_ms"] + 2000.0)
+        for field in LANE_ROW_FIELDS:
+            assert field in row, field
+    srv.flush()
+    assert srv.egress.settle(timeout_s=10.0)
+    row = srv.flush_timeline.snapshot()[-1]
+    assert "tick_late_ms" not in row and "tick_to_sink_ms" not in row
+    for field in LANE_ROW_FIELDS:
+        assert field in row, field
+
+
+def test_a_lane_done_before_the_row_is_appended_still_reaches_it(server):
+    """The flush thread appends the row after it has enqueued the job: a
+    small batch's lane is done first, and its fields join the row as it
+    is appended."""
+    srv, _sink = server(interval=0.2)
+    srv.start()
+    record = srv.flush_timeline.record
+    early = []
+
+    def record_after_the_lane(**kw):
+        assert srv.egress.settle(timeout_s=10.0)
+        early.append(dict(srv.flush_timeline._early))
+        return record(**kw)
+
+    srv.flush_timeline.record = record_after_the_lane
+    rows = _serve(srv, 2)
+    # the lane's fields were waiting when the row came
+    assert early and all(e for e in early)
+    assert not srv.flush_timeline._early
+    for row in rows[:2]:
+        assert row["tick_to_sink_ms"] >= row["tick_late_ms"] >= 0.0
+        for field in LANE_ROW_FIELDS:
+            assert field in row, field
+
+
+@pytest.mark.parametrize("order", ["row_first", "lane_first"])
+def test_amend_reaches_the_row_whichever_came_first(order):
+    tl = FlushTimeline(capacity=4)
+    tl.record(interval=7, unix_ts=1.0, total_s=0.0, event="checkpoint")
+    if order == "row_first":
+        tl.record(interval=7, unix_ts=1.0, total_s=0.001)
+        tl.amend(7, tick_to_sink_ms=3.0, lane_gc_passes=0)
+    else:
+        tl.amend(7, tick_to_sink_ms=3.0, lane_gc_passes=0)
+        # an event row of the same interval takes nothing
+        tl.record(interval=7, unix_ts=1.0, total_s=0.0, event="restore")
+        tl.record(interval=7, unix_ts=1.0, total_s=0.001)
+    rows = tl.snapshot()
+    assert [r for r in rows if "event" in r and "tick_to_sink_ms" in r] == []
+    row = [r for r in rows if "event" not in r][0]
+    assert row["tick_to_sink_ms"] == 3.0 and row["lane_gc_passes"] == 0
+    # the lane that finished last stays on the row
+    tl.amend(7, tick_to_sink_ms=4.5)
+    assert tl.snapshot()[-1]["tick_to_sink_ms"] == 4.5
+    assert not tl._early
+    # rows that never come do not pile up
+    for interval in range(100, 100 + 3 * tl.AMEND_PENDING_MAX):
+        tl.amend(interval, tick_to_sink_ms=1.0)
+    assert len(tl._early) == tl.AMEND_PENDING_MAX
+    assert min(tl._early) == 100 + 2 * tl.AMEND_PENDING_MAX
+
+
+def _thread_clock_step_ms() -> float:
+    """The smallest step time.thread_time_ns() takes over 30 ms of work."""
+    steps, last = [], time.thread_time_ns()
+    deadline = time.perf_counter() + 0.03
+    while time.perf_counter() < deadline:
+        now = time.thread_time_ns()
+        if now != last:
+            steps.append(now - last)
+            last = now
+    return min(steps) / 1e6 if steps else 30.0
+
+
+def test_lane_cpu_time_is_inside_the_sink_span_and_its_parts_too(server):
+    srv, _sink = server()
+    srv.start()
+    _send_and_drain(srv)
+    trace = _trace_of(srv, _flush_and_spans(srv))
+    row = srv.flush_timeline.snapshot()[-1]
+    sink_span = trace["flush.seg.lane.sink"]
+    # a thread cannot use more CPU than wall time passes (the clocks
+    # differ: a millisecond of room, or one step of the CPU clock where
+    # the host counts thread time in ticks — 10 ms under gVisor)
+    assert 0.0 <= row["lane_sink_cpu_ms"] \
+        <= sink_span["duration_ms"] + max(1.0, _thread_clock_step_ms())
+    assert row["lane_gc_passes"] >= row["lane_gc_full_passes"] >= 0
+    children = [trace[f"flush.seg.lane.sink.{p}"] for p in SINK_PARTS]
+    for c in children:
+        assert c["parent_id"] == sink_span["span_id"]
+        assert c["tags"]["sink"] == "channel"
+        assert _inside(c, sink_span)
+    # real timestamps, one after the other, up to the sink's return
+    records, splice, put = children
+    assert records["start_ns"] >= sink_span["start_ns"]
+    assert records["start_ns"] <= splice["start_ns"] <= put["start_ns"]
+    assert sum(c["duration_ms"] for c in children) \
+        <= sink_span["duration_ms"] + 0.004
+    assert put["start_ns"] / 1e6 + put["duration_ms"] == pytest.approx(
+        sink_span["start_ns"] / 1e6 + sink_span["duration_ms"], abs=0.004)
+    # /debug/vars: each lane's own last call
+    last = http_api.debug_vars(srv)["egress"]["per_sink"][
+        "metric:channel"]["last_sink_call"]
+    assert last["interval"] == row["interval"]
+    assert last["lane_sink_cpu_ms"] == row["lane_sink_cpu_ms"]
+
+
+class _IteratingSink(simple_sinks.ChannelMetricSink):
+    """A sink that walks the batch and never materialises it."""
+
+    def flush(self, metrics):
+        self.queue.put([m.name for m in metrics])
+        return simple_sinks.sink_mod.MetricFlushResult(flushed=len(metrics))
+
+
+def test_a_sink_that_iterates_the_batch_gets_no_children(server):
+    srv, _sink = server(sink=_IteratingSink())
+    srv.start()
+    _send_and_drain(srv)
+    trace = _trace_of(srv, _flush_and_spans(srv))
+    assert "flush.seg.lane.sink" in trace
+    assert not [n for n in trace if n.startswith("flush.seg.lane.sink.")]
+    # the row's lane fields do not depend on the sink's way
+    row = srv.flush_timeline.snapshot()[-1]
+    for field in LANE_ROW_FIELDS:
+        assert field in row, field
+
+
+def test_a_stamp_from_outside_the_call_lays_no_children(server):
+    """Stamps are the batch's LAST materialize(): one made before this
+    lane's sink call (another lane's, an earlier attempt's owner) is not
+    this call's."""
+    from veneur_tpu.samplers.samplers import MetricBatch
+
+    class _Stale(_IteratingSink):
+        def flush(self, metrics):
+            if isinstance(metrics, MetricBatch):
+                metrics.stamps = (1, 2, 3)
+            return super().flush(metrics)
+
+    srv, _sink = server(sink=_Stale())
+    srv.start()
+    _send_and_drain(srv)
+    trace = _trace_of(srv, _flush_and_spans(srv))
+    assert not [n for n in trace if n.startswith("flush.seg.lane.sink.")]
+
+
+def test_the_new_keys_become_no_self_metric(server):
+    from tests.test_self_telemetry import FakeStatsd
+
+    assert set(COLUMNS_PART_KEYS) <= LEDGER_SEGMENT_KEYS \
+        <= ROW_ONLY_SEGMENT_KEYS
+    srv, _sink = server(interval=0.2)
+    srv.statsd = FakeStatsd()
+    srv.start()
+    _send_and_drain(srv)
+    _serve(srv, 2)
+    names = {c[1] for c in srv.statsd.calls}
+    assert "flush.segment.snapshot_ms" in names         # as before
+    for stem in ("snapshot_cache", "snapshot_cut", "snapshot_reset",
+                 "snapshot_end", "snapshot_rest", "columns_by_family",
+                 "tick_late", "tick_to_sink", "lane_sink_cpu",
+                 "lane_gc"):
+        assert not [n for n in names if stem in n], stem
+
+
+def test_tracing_off_records_no_new_span_and_keeps_the_new_fields(server):
+    srv, _sink = server(trace_flush_enabled=False, interval=0.2)
+    srv.start()
+    _send_and_drain(srv)
+    rows = _serve(srv, 2)
+    assert not [s for s in srv.flight_recorder.snapshot()
+                if s["name"].startswith(("flush.seg.", "flush.sink."))]
+    for field in (*(f"snapshot_{p}_ms" for p in COLUMNS_PARTS),
+                  *LANE_ROW_FIELDS, "tick_late_ms", "tick_to_sink_ms"):
+        assert field in rows[0], field
+
+
+def test_debug_vars_has_the_collector_counters(server):
+    srv, _sink = server()
+    srv.start()
+    dv = http_api.debug_vars(srv)["gc"]
+    assert len(dv["generations"]) == 3
+    for gen in dv["generations"]:
+        assert set(gen) == {"collections", "collected", "uncollectable"}
+    assert len(dv["count"]) == 3 and dv["frozen"] >= 0
+    assert dv["enabled"] is True
+    # read when asked for: a collection made in between shows
+    import gc
+    before = dv["generations"][2]["collections"]
+    gc.collect()
+    after = http_api.debug_vars(srv)["gc"]["generations"][2]["collections"]
+    assert after == before + 1

@@ -244,7 +244,7 @@ def test_ticker_drops_the_ticks_a_slow_flush_missed(fixture_server):
     starts = []
     slow = threading.Event()
 
-    def flush():
+    def flush(tick=None):
         starts.append(time.time())
         if len(starts) == 2:
             time.sleep(4.5 * interval)      # misses four ticks

@@ -257,15 +257,22 @@ def test_flush_trace_recorded_and_timeline_linked(traced_server):
     segs = [r for r in spans if r["name"].startswith("flush.seg.")]
     assert segs, spans
     # segment children hang off the root; their parts (the snapshot's,
-    # the egress lane's under flush.sink.<name>) one level below it
+    # the egress lane's under flush.sink.<name>) one level below it, and
+    # the parts of two of those (the snapshot's columns, the lane's sink
+    # call) one level further down, named after their parent
     by_id = {r["span_id"]: r for r in spans}
     direct = [s for s in segs if s["parent_id"] == root["span_id"]]
+    deep = ("flush.seg.snapshot.columns.", "flush.seg.lane.sink.")
     for s in segs:
         if s not in direct:
             parent = by_id[s["parent_id"]]
-            assert parent["parent_id"] == root["span_id"], s
             assert s["name"].startswith(
                 ("flush.seg.snapshot.", "flush.seg.lane.")), s
+            if s["name"].startswith(deep):
+                assert s["name"].rsplit(".", 1)[0] == parent["name"], s
+                parent = by_id[parent["parent_id"]]
+            assert parent["parent_id"] == root["span_id"], s
+    assert [s for s in segs if s["name"].startswith(deep[0])]
     assert {"snapshot", "emit", "fanout"} <= {
         s["name"].split(".")[-1] for s in direct}
     # the timeline row cross-links to the exact trace/span

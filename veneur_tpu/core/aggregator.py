@@ -144,9 +144,18 @@ HOT_LEDGER_KEYS = ("hot_keys", "hot_points_in", "hot_points_out",
                    "hot_compress_launches", "hot_compress_held_s",
                    "hot_compress_tile_bytes", "dense_tiers", "dense_elems",
                    "build_onepass", "build_fresh_bytes")
+# snapshot_columns_s in parts (_snapshot_and_reset measures the first
+# four where they happen, under the lock; the last is what is left): the
+# import row cache's clearing, the arenas' snapshot_part() less their
+# take_staged(), their reset_rows() and their end_interval(), and the
+# rest — the unique-timeseries swap, the key fingerprints, the lane
+# stats, the cardinality guard's and the cubes' end of interval
+COLUMNS_PART_KEYS = ("snapshot_cache_s", "snapshot_cut_s",
+                     "snapshot_reset_s", "snapshot_end_s",
+                     "snapshot_rest_s")
 LEDGER_SEGMENT_KEYS = frozenset(
     ["snapshot_lock_wait_s", "snapshot_sync_s", "snapshot_staged_s",
-     "snapshot_columns_s"]
+     "snapshot_columns_s", *COLUMNS_PART_KEYS]
     + [f[:-3] + "_s" if f.endswith("_ns") else f for f in _LEDGER_FIELDS])
 # every key of last_flush_segments that core/server.py keeps out of that
 # loop: the ledger's, and what the device programs (_dispatch_sets,
@@ -1343,7 +1352,14 @@ class MetricAggregator:
         seg["snapshot_lock_wait_s"] = t_held - t0
         seg["snapshot_sync_s"] = sync_s
         seg["snapshot_staged_s"] = staged_s
-        seg["snapshot_columns_s"] = t_cut - t_held - sync_s - staged_s
+        seg["snapshot_columns_s"] = columns_s = (
+            t_cut - t_held - sync_s - staged_s)
+        # the columns span in parts; cut, reset and end also say which
+        # family (a structured value: span tags, not a row field)
+        measured = snap.pop("columns_seconds")
+        seg.update(zip(COLUMNS_PART_KEYS,
+                       (*measured, columns_s - sum(measured))))
+        seg["columns_by_family"] = snap.pop("columns_by_family")
         # the interval ledger, swapped out at the cut
         for name, v in snap.pop("ledger").items():
             if name.endswith("_ns"):
@@ -2491,14 +2507,19 @@ class MetricAggregator:
     def _snapshot_and_reset(self) -> dict:
         """Under lock: sync staging, cut every arena's part of touched
         rows (arena.snapshot_part: copies, never aliases of live
-        state), reset.  The parts' columns are the arenas' own."""
+        state), reset.  The parts' columns are the arenas' own.  The
+        clock is read per family and step, never per row, for
+        flush_dispatch's split of the columns span."""
+        clock = time.perf_counter
+        t = clock()
         self._import_row_cache.clear()
+        cache_s = clock() - t
         arenas = self._arenas()
         copied = -sum(ar.staged_copied_bytes for _, ar in arenas)
-        t_sync = time.perf_counter()
+        t = clock()
         for _, ar in arenas:
             ar.sync()
-        sync_s = time.perf_counter() - t_sync
+        sync_s = clock() - t
         snap = {"counts": (self.processed, self.imported),
                 "ledger": self._ledger}
         self.processed = 0
@@ -2525,8 +2546,13 @@ class MetricAggregator:
 
         staged_s = 0.0
         points = regrows = 0
+        by_family = {"cut": {}, "reset": {}, "end": {}}
+        t = clock()
         for name, ar in arenas:
             snap[name] = ar.snapshot_part()
+            t, t_was = clock(), t
+            # take_staged() inside it is the staged span's, not the cut's
+            by_family["cut"][name] = t - t_was - ar.snapshot_staged_s
             staged_s += ar.snapshot_staged_s
             points += ar.snapshot_staged_points
             regrows += ar.snapshot_staged_regrows
@@ -2547,9 +2573,18 @@ class MetricAggregator:
             ar.family: (ar.keyset_checksum, ar.key_checksum)
             for _, ar in arenas}
 
+        t = clock()
         for name, ar in arenas:
             ar.reset_rows(snap[name]["rows"])
+            t_reset = clock()
             ar.end_interval()
+            t, t_was = clock(), t
+            by_family["reset"][name] = t_reset - t_was
+            by_family["end"][name] = t - t_reset
+        snap["columns_seconds"] = (cache_s, *(
+            sum(by_family[step].values())
+            for step in ("cut", "reset", "end")))
+        snap["columns_by_family"] = by_family
         snap["set_lane_stats"] = self.sets.take_lane_stats()
         snap["hot_lane_stats"] = self.digests.take_hot_stats()
         if self.cardinality is not None:

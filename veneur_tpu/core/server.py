@@ -410,7 +410,8 @@ class Server:
             routing_enabled=cfg.enable_metric_sink_routing,
             excluded_tags_for=self._excluded_tags_for,
             recorder=self.flight_recorder,
-            statsd_fn=lambda: self.statsd)
+            statsd_fn=lambda: self.statsd,
+            timeline=self.flush_timeline)
         for spec, sink in self.metric_sinks:
             self.egress.add_metric_sink(spec, sink)
         for sink in self.span_sinks:
@@ -1403,17 +1404,21 @@ class Server:
 
     # -- flush (flusher.go:26-122) ----------------------------------------
 
-    def flush(self) -> None:
+    def flush(self, tick: Optional[float] = None) -> None:
         """One flush interval, traced as a span through the server's own
         pipeline (flusher.go:26-122: Flush is itself a span, and the flush
         path reports the standard self-metrics).  Serialized: callers
         beyond the ticker (tests, /debug/profile, flush_on_shutdown) race
-        the non-atomic per-interval counters otherwise."""
+        the non-atomic per-interval counters otherwise.  `tick` is the
+        scheduled wall-clock time this flush serves (serve() passes it;
+        a flush nobody scheduled has none): the timeline row then says
+        how late the flush started (`tick_late_ms`) and, once the metric
+        lane is done, when the sink had the batch (`tick_to_sink_ms`)."""
         with self._flush_serial:
             # vnlint: disable=blocking-propagation (_flush_serial
             #   exists to hold the entire flush — device waits
             #   included; ingest threads never contend on it)
-            self._flush_locked()
+            self._flush_locked(tick)
             if self.config.checkpoint_dir:
                 # stamp the completed flush: a checkpoint OLDER than
                 # this marker must not restore its arenas (the data
@@ -1520,25 +1525,56 @@ class Server:
     # the wait for the aggregator lock, then under it the arenas' syncs,
     # the take_staged consolidations and the column copies + reset
     _SNAPSHOT_PARTS = ("lock_wait", "sync", "staged", "columns")
+    # the parts of snapshot_columns_s (aggregator.COLUMNS_PART_KEYS)
+    _COLUMNS_PARTS = ("cache", "cut", "reset", "end", "rest")
+    # a family's share of a columns part is a tag from here up (ms)
+    _FAMILY_TAG_MIN_MS = 0.05
 
-    def _emit_snapshot_part_spans(self, span, segs: dict) -> None:
-        """Grandchildren under flush.seg.snapshot, laid end to end from
-        its start (durations are measured, positions are not: take_staged
-        runs between column copies).  trace/assembly sums only the root's
-        direct children, so these leave the critical-path table alone."""
+    def _lay_spans(self, span, parts) -> dict:
+        """Children of `span` laid end to end from its start out of
+        measured durations — `parts` is (name, seconds, tags or None)
+        each — straight into the flight-recorder ring.  Returns them by
+        name."""
         off = span.start_ns
-        for part in self._SNAPSHOT_PARTS:
-            v = segs.get(f"snapshot_{part}_s")
-            if v is None:
-                continue
-            child = span.child(f"flush.seg.snapshot.{part}")
+        laid = {}
+        for name, seconds, tags in parts:
+            child = span.child(name)
             try:
                 child.start_ns = off
-                child.end_ns = off = off + int(float(v) * 1e9)
+                child.end_ns = off = off + int(float(seconds) * 1e9)
+                if tags:
+                    child.tags = tags
                 child.client = None
             finally:
                 child.finish()
             self.flight_recorder.record_span(child)
+            laid[name] = child
+        return laid
+
+    def _emit_snapshot_part_spans(self, span, segs: dict) -> None:
+        """Grandchildren under flush.seg.snapshot, laid end to end from
+        its start (durations are measured, positions are not: take_staged
+        runs between column copies), and under flush.seg.snapshot.columns
+        its own parts the same way; cut, reset and end carry the
+        milliseconds per family as tags.  trace/assembly sums only the
+        root's direct children, so these leave the critical-path table
+        alone."""
+        laid = self._lay_spans(span, [
+            (f"flush.seg.snapshot.{part}", segs[f"snapshot_{part}_s"], None)
+            for part in self._SNAPSHOT_PARTS
+            if f"snapshot_{part}_s" in segs])
+        columns = laid.get("flush.seg.snapshot.columns")
+        if columns is None:
+            return
+        by_family = segs.get("columns_by_family") or {}
+        self._lay_spans(columns, [
+            (f"flush.seg.snapshot.columns.{part}",
+             segs[f"snapshot_{part}_s"],
+             {fam: f"{s * 1e3:.3f}"
+              for fam, s in by_family.get(part, {}).items()
+              if s * 1e3 >= self._FAMILY_TAG_MIN_MS})
+            for part in self._COLUMNS_PARTS
+            if f"snapshot_{part}_s" in segs])
 
     def _emit_device_part_spans(self, span, segs: dict) -> None:
         """Grandchildren under flush.seg.device, laid end to end from
@@ -1552,19 +1588,12 @@ class Server:
             parts.append(("sets", segs["device_sets"]))
         parts += [(f"chunk{i}", c)
                   for i, c in enumerate(segs.get("device_chunks") or ())]
-        off = span.start_ns
-        for name, c in parts:
-            dur = (c.get("upload_s", 0.0) + c.get("dispatch_s", 0.0)
-                   + c.get("drain_s", 0.0) + c.get("wait_s", 0.0))
-            child = span.child(f"flush.seg.device.{name}")
-            try:
-                child.start_ns = off
-                child.end_ns = off = off + int(float(dur) * 1e9)
-                child.tags = {"rows": str(c.get("rows", 0))}
-                child.client = None
-            finally:
-                child.finish()
-            self.flight_recorder.record_span(child)
+        self._lay_spans(span, [
+            (f"flush.seg.device.{name}",
+             c.get("upload_s", 0.0) + c.get("dispatch_s", 0.0)
+             + c.get("drain_s", 0.0) + c.get("wait_s", 0.0),
+             {"rows": str(c.get("rows", 0))})
+            for name, c in parts])
 
     def _prewarm(self) -> None:
         """Boot-time compile of a meshed global's flush programs
@@ -1622,7 +1651,7 @@ class Server:
             span.finish()
             self.flight_recorder.record_span(span)
 
-    def _flush_locked(self) -> None:
+    def _flush_locked(self, tick: Optional[float] = None) -> None:
         from veneur_tpu import failpoints
         from veneur_tpu import scopedstatsd
 
@@ -1655,10 +1684,12 @@ class Server:
             #   contend on _flush_serial, and sink fan-out is a
             #   non-blocking egress-queue handoff.  Same rationale as
             #   the suppression at the wait itself)
-            self._flush_body_locked(span, statsd, flush_start, traced)
+            self._flush_body_locked(span, statsd, flush_start, traced,
+                                    tick)
 
     def _flush_body_locked(self, span, statsd, flush_start: float,
-                           traced: bool) -> None:
+                           traced: bool,
+                           tick: Optional[float] = None) -> None:
         from veneur_tpu import ssf as ssf_mod
 
         self._drain_native()
@@ -1777,7 +1808,8 @@ class Server:
         self.egress.submit_interval(
             res.metrics, events, statsd, self.flush_count,
             trace_id=span.trace_id, parent_span_id=span.span_id,
-            traced=traced)
+            traced=traced,
+            tick_ns=int(tick * 1e9) if tick is not None else 0)
         if traced:
             # segment children (staging/upload/kernel/readback) + the
             # egress handoff, as spans on the interval's own trace.
@@ -1818,6 +1850,10 @@ class Server:
             processed=res.processed, imported=res.imported,
             metrics_emitted=len(res.metrics),
             forward_metrics=len(res.forward),
+            # how late the ticker's flush started; left out of a flush
+            # nobody scheduled
+            tick_late_ms=(None if tick is None else round(
+                (self.last_flush_unix - tick) * 1e3, 3)),
             trace_id=f"{span.trace_id:x}",
             span_id=f"{span.span_id:x}",
             **self._ingest_overflow())
@@ -2088,6 +2124,7 @@ class Server:
             timeout = max(0.0, next_tick - time.time())
             if self._shutdown.wait(timeout):
                 break
+            tick = next_tick
             next_tick += interval
             late = time.time() - next_tick
             if late > 0:
@@ -2099,7 +2136,7 @@ class Server:
                 # back to back, a burst of empty flushes off the grid
                 next_tick += interval * -(-late // interval)
             try:
-                self.flush()
+                self.flush(tick)
             except Exception as e:
                 logger.exception("flush failed: %s", e)
 

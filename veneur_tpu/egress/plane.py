@@ -41,6 +41,7 @@ on the pending count without a lost-job leak.
 
 from __future__ import annotations
 
+import gc
 import json
 import logging
 import os
@@ -118,11 +119,12 @@ class EgressJob:
     """One sink's share of one flush interval."""
 
     __slots__ = ("metrics", "events", "statsd", "interval",
-                 "trace_id", "parent_span_id", "traced", "enqueued_ns")
+                 "trace_id", "parent_span_id", "traced", "enqueued_ns",
+                 "tick_ns")
 
     def __init__(self, metrics, events, statsd, interval: int,
                  trace_id: int = 0, parent_span_id: int = 0,
-                 traced: bool = False):
+                 traced: bool = False, tick_ns: int = 0):
         self.metrics = metrics
         self.events = events
         self.statsd = statsd
@@ -133,6 +135,9 @@ class EgressJob:
         # wall clock of the handoff onto a lane's queue (SinkLane.submit):
         # where the job's flush.sink.<name> span and its lane.wait start
         self.enqueued_ns = 0
+        # wall clock of the scheduled tick this interval's flush served
+        # (Server.serve); 0 for a flush nobody scheduled
+        self.tick_ns = tick_ns
 
 
 def _safe_dirname(name: str) -> str:
@@ -169,6 +174,8 @@ class SinkLane:
         self.dropped_points = 0      # exhausted + spool-less drops
         self.stragglers = 0          # deliveries slower than an interval
         self.busy_since = 0.0        # perf_counter at claim; 0 = idle
+        # what the last sink.flush call cost (_account_sink_call)
+        self.last_sink_call: dict = {}
         self._thread: Optional[threading.Thread] = None
 
     def _count(self, field: str, n: int = 1) -> None:
@@ -297,12 +304,15 @@ class SinkLane:
                 span.finish()
                 self.plane.record_span(span)
 
-    def _lane_span(self, span, part: str, start_ns: int,
-                   end_ns: int) -> None:
+    def _lane_span(self, span, part: str, start_ns: int, end_ns: int):
         """One measured part of a traced metric job (flush.seg.lane.wait
-        / .filter / .sink) as a child of its flush.sink.<name> span, on
-        the interval's own trace.  With several metric sinks the names
-        repeat per trace; the `sink` tag tells them apart."""
+        / .filter / .sink, and under .sink its own .records / .splice /
+        .put) as a child of `span` — its flush.sink.<name> span, or the
+        part above it — on the interval's own trace, with real
+        timestamps.  With several metric sinks the names repeat per
+        trace; the `sink` tag tells them apart.  trace/assembly sums
+        only the root's direct children, so none of these moves the
+        critical-path table."""
         child = span.child(f"flush.seg.lane.{part}",
                            tags={"sink": self.name})
         child.start_ns = start_ns
@@ -310,6 +320,44 @@ class SinkLane:
         child.client = None      # ring fast path, like the flush segments
         child.finish()
         self.plane.record_span(child)
+        return child
+
+    def _sink_part_spans(self, sink_span, filtered) -> None:
+        """The sink call in parts, from the instants the batch's last
+        materialize() stamped (samplers.MetricBatch.stamps): building
+        the records, the collector's splice, and from there to the
+        sink's return (queue.put, the result).  A sink that never
+        materialised the batch left no stamp inside the call and gets
+        no children; a retried call keeps the last attempt's."""
+        stamps = getattr(filtered, "stamps", None)
+        if (not stamps or stamps[0] < sink_span.start_ns
+                or stamps[2] > sink_span.end_ns):
+            return
+        start, built, spliced = stamps
+        self._lane_span(sink_span, "sink.records", start, built)
+        self._lane_span(sink_span, "sink.splice", built, spliced)
+        self._lane_span(sink_span, "sink.put", spliced, sink_span.end_ns)
+
+    def _account_sink_call(self, job: EgressJob, done_ns: int,
+                           cpu_ns: int, gc_before: list) -> None:
+        """What one sink.flush call (all attempts) cost beyond its wall
+        time, onto the interval's timeline row and into stats(): this
+        thread's CPU time over the call (wall less this is waiting, for
+        the interpreter lock or the scheduler), the collections of any
+        generation / of the oldest that started in the process
+        meanwhile, and — for a flush serve() scheduled — the time from
+        its tick to the sink's return."""
+        passes = [after["collections"] - before["collections"]
+                  for before, after in zip(gc_before, gc.get_stats())]
+        call = {"lane_sink_cpu_ms": round(cpu_ns / 1e6, 3),
+                "lane_gc_passes": sum(passes),
+                "lane_gc_full_passes": passes[-1]}
+        if job.tick_ns:
+            call["tick_to_sink_ms"] = round(
+                (done_ns - job.tick_ns) / 1e6, 3)
+        with self._stats_lock:
+            self.last_sink_call = dict(call, interval=job.interval)
+        self.plane.amend_row(job.interval, call)
 
     def _deliver_metric(self, job: EgressJob, statsd, span) -> None:
         t_filter = time.time_ns()
@@ -334,13 +382,20 @@ class SinkLane:
                              tags=self.sink_tags)
                 logger.error("sink %s flush_other_samples failed: %s",
                              self.name, e)
+            gc_before = gc.get_stats()
+            cpu0 = time.thread_time_ns()
             t_sink = time.time_ns()
             try:
                 self._attempt_flush(filtered, job, statsd, span)
             finally:
+                t_done = time.time_ns()
+                self._account_sink_call(
+                    job, t_done, time.thread_time_ns() - cpu0, gc_before)
                 if span is not None:
                     # the sink.flush call, all attempts and backoffs
-                    self._lane_span(span, "sink", t_sink, time.time_ns())
+                    self._sink_part_spans(
+                        self._lane_span(span, "sink", t_sink, t_done),
+                        filtered)
         finally:
             statsd.timing("sink.metric_flush_total_duration_ms",
                           (time.perf_counter() - start) * 1e3,
@@ -552,6 +607,10 @@ class SinkLane:
                     (time.perf_counter() - self.busy_since)
                     if self.busy_since else 0.0, 3),
             }
+            if self.last_sink_call:
+                # the lane's own last sink.flush call: tick to sink,
+                # its CPU time, the collector's passes during it
+                out["last_sink_call"] = dict(self.last_sink_call)
         out["breaker"] = self.breaker.snapshot()
         if self.spool is not None:
             out["spool"] = self.spool.stats()
@@ -585,7 +644,8 @@ class EgressPlane:
                  routing_enabled: bool = False,
                  excluded_tags_for: Optional[Callable] = None,
                  recorder=None,
-                 statsd_fn: Optional[Callable] = None):
+                 statsd_fn: Optional[Callable] = None,
+                 timeline=None):
         self.interval_s = float(interval_s)
         self.queue_depth = max(1, int(queue_depth))
         self.retry = retry or RetryPolicy()
@@ -599,6 +659,9 @@ class EgressPlane:
         self.routing_enabled = routing_enabled
         self.excluded_tags_for = excluded_tags_for or (lambda name: None)
         self.recorder = recorder
+        # the flush timeline (profiling/timeline.py) whose rows the
+        # metric lanes amend with what they learn of a flush
+        self.timeline = timeline
         # self-metrics client for deliveries with no flush-path job to
         # carry one (spool replays); defaults to a no-op client
         self._statsd_fn = statsd_fn
@@ -675,11 +738,15 @@ class EgressPlane:
         if self.recorder is not None:
             self.recorder.record_span(span)
 
+    def amend_row(self, interval: int, fields: dict) -> None:
+        if self.timeline is not None:
+            self.timeline.amend(interval, **fields)
+
     # -- the flush path's handoff ------------------------------------------
 
     def submit_interval(self, metrics, events, statsd, interval: int,
                         trace_id: int = 0, parent_span_id: int = 0,
-                        traced: bool = False) -> None:
+                        traced: bool = False, tick_ns: int = 0) -> None:
         """Enqueue one job per lane and return immediately.  Lanes are
         lazily started so a pre-`start()` flush (tests, tooling) still
         delivers — asynchronously, like every other flush."""
@@ -692,7 +759,7 @@ class EgressPlane:
                 metrics if lane.kind == "metric" else None,
                 events, statsd, interval,
                 trace_id=trace_id, parent_span_id=parent_span_id,
-                traced=traced))
+                traced=traced, tick_ns=tick_ns))
 
     # -- quiescence / teardown ---------------------------------------------
 

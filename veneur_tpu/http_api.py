@@ -26,6 +26,7 @@ optional /quitquitquit, the live query plane, and the debug suite
 
 from __future__ import annotations
 
+import gc
 import http.server
 import json
 import logging
@@ -111,6 +112,19 @@ def debug_vars(server) -> dict:
         # metrics dropped because every forward slot was
         # stalled (bounded-buffering loss, core/server.py)
         "forward_slots_dropped": server.forward_dropped,
+    }
+    # the cyclic collector, read now: per generation the collections
+    # made and the objects they freed or could not, the allocation
+    # counts toward the next pass of each, and the objects frozen out
+    # of its sight.  MetricBatch.materialize() splices a flush's
+    # records into the oldest generation, which zeroes the counts every
+    # flush: a quiet long-lived server whose oldest generation is never
+    # collected shows here
+    stats["gc"] = {
+        "enabled": gc.isenabled(),
+        "generations": gc.get_stats(),
+        "count": gc.get_count(),
+        "frozen": gc.get_freeze_count(),
     }
     egress = getattr(server, "egress", None)
     if egress is not None:

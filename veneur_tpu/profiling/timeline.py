@@ -69,11 +69,37 @@ class FlushTimeline:
         self._ring: deque = deque(maxlen=self.capacity)
         self._lock = threading.Lock()
         self.total_recorded = 0
+        # fields amended before their flush's row was appended, by
+        # interval (the newest AMEND_PENDING_MAX intervals)
+        self._early: dict = {}
+
+    # how many intervals' early amendments wait for their row at most
+    # (a flush that raised after its hand-off never appends one)
+    AMEND_PENDING_MAX = 8
 
     def append(self, rec: FlushRecord) -> None:
         with self._lock:
+            if self._early and "event" not in rec:
+                rec.update(self._early.pop(rec.get("interval"), ()))
             self._ring.append(rec)
             self.total_recorded += 1
+
+    def amend(self, interval: int, **fields) -> None:
+        """Add fields to the flush row of `interval` — what a thread
+        other than the flush thread learned of that flush after its
+        hand-off (the egress lane: when the sink had the batch).  The
+        flush thread appends the row AFTER it has enqueued the lane's
+        job, so a small batch's lane can be done first: the fields then
+        wait here and join the row as it is appended.  A later
+        amendment of the same field overwrites the earlier."""
+        with self._lock:
+            for rec in reversed(self._ring):
+                if rec["interval"] == interval and "event" not in rec:
+                    rec.update(fields)
+                    return
+            self._early.setdefault(interval, {}).update(fields)
+            while len(self._early) > self.AMEND_PENDING_MAX:
+                del self._early[min(self._early)]
 
     def record(self, interval: int, unix_ts: float, total_s: float,
                segments: Optional[dict] = None, devices: int = 1,

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import enum
 import gc
+import time
 from dataclasses import dataclass
 from typing import Optional
 
@@ -211,11 +212,15 @@ class MetricBatch:
     threads.  Columnar-aware consumers read `segments` directly.
     """
 
-    __slots__ = ("segments", "loose")
+    __slots__ = ("segments", "loose", "stamps")
 
     def __init__(self, segments=None, loose=None):
         self.segments: list[MetricSegment] = segments or []
         self.loose: list[InterMetric] = loose if loose is not None else []
+        # wall-clock ns of the last materialize(): (start, records
+        # built, collector splice done); None until one ran.  The egress
+        # lane lays them as flush.seg.lane.sink.* spans
+        self.stamps: Optional[tuple] = None
 
     def append(self, m: InterMetric) -> None:
         self.loose.append(m)
@@ -283,6 +288,7 @@ class MetricBatch:
         generation as they are: freeze() and unfreeze() splice the
         generations' lists and look at no object.  They die by
         reference count when the consumer drops the list."""
+        t_start = t_built = time.time_ns()
         paused = gc.isenabled()
         gc.disable()
         try:
@@ -290,11 +296,13 @@ class MetricBatch:
             for seg in self.segments:
                 out.extend(seg.materialize())
             out.extend(self.loose)
+            t_built = time.time_ns()
         finally:
             if paused:
                 gc.freeze()
                 gc.unfreeze()
                 gc.enable()
+            self.stamps = (t_start, t_built, time.time_ns())
         return out
 
     def apply_routing(self, rules, match_fn) -> None:
