@@ -532,8 +532,9 @@ def test_import_rejects_type_value_oneof_mismatch():
 
 def test_import_row_cache_survives_flush_and_gc_cycles():
     """The V1 import identity->row cache must never serve a stale row:
-    it clears at every flush (before end_interval's GC can free rows),
-    and re-imports after GC re-register cleanly with correct totals."""
+    it outlives a flush that recycled no row (PR 41), the cut at which
+    end_interval's GC frees rows clears it, and re-imports after GC
+    re-register cleanly with correct totals."""
     from veneur_tpu.core import arena as arena_mod
     from veneur_tpu.core.aggregator import MetricAggregator
 
@@ -555,7 +556,7 @@ def test_import_row_cache_survives_flush_and_gc_cycles():
     assert agg._import_row_cache          # populated
     by = flush_values()
     assert by["a"] == 10.0 and by["b"] == 12.0
-    assert not agg._import_row_cache      # cleared at snapshot
+    assert len(agg._import_row_cache) == 2    # no row recycled: kept
 
     # idle 'a' and 'b' long enough for the arena GC to free their rows,
     # interleaving other keys so rows get recycled
@@ -566,6 +567,10 @@ def test_import_row_cache_survives_flush_and_gc_cycles():
         ).SerializeToString()
         agg.import_payload(filler)
         flush_values()
+    # 'a' and 'b' (and the first filler) were freed, and every cut that
+    # freed a row cleared the cache: what it holds was resolved since
+    assert agg.counters.recycled >= 3
+    assert len(agg._import_row_cache) <= 1
 
     # re-import the original identities: fresh rows, exact totals
     agg.import_payload(pay)
@@ -807,8 +812,9 @@ def _case_invalid_utf8_first_sighting():
                         bad, _histo("u8.a", td)])]
 
 
-# case -> (steps, plain digests that were a key's first sighting in
-# their interval, plain digests whose row came from the cache)
+# case -> (steps, plain digests that were a key's first sighting
+# since the row cache was last cleared, plain digests whose row came
+# from the cache)
 _COLUMNAR_CASES = {
     "singleton_centroids": (_case_singletons, 12, 0),
     "weighted_centroids": (_case_weighted, 12, 1),
@@ -821,7 +827,8 @@ _COLUMNAR_CASES = {
     "oneof_switches": (_case_oneof_switches, 2, 1),
     "counters_gauges_sets_interleaved": (_case_interleaved_families, 6, 6),
     "same_payload_twice": (_case_twice_in_one_interval, 12, 12),
-    "across_a_flush": (_case_across_a_flush, 12 + 5 + 7 + 12, 5),
+    # 12 keys, resolved once: the cache outlives both flushes (PR 41)
+    "across_a_flush": (_case_across_a_flush, 12, 5 + 12 + 12),
     "invalid_utf8_first_sighting": (_case_invalid_utf8_first_sighting,
                                     2, 1),
 }
@@ -878,7 +885,9 @@ def test_columnar_digest_import_matches_pb_path(case):
                 seen["flushes"].append((res.imported, sorted(
                     (m.name, tuple(m.tags), m.value)
                     for m in res.metrics)))
-                assert not agg._import_row_cache
+                # no row was recycled: the cache outlives the flush
+                # (the reference's pb path caches no digest)
+                assert bool(agg._import_row_cache) == native
             elif native:
                 seen["counts"].append(agg.import_payload(step))
             else:

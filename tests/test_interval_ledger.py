@@ -417,8 +417,14 @@ def test_digest_hits_and_misses_add_up_to_the_plain_digests_imported():
     """The row of a flush that closed an importing interval says how
     often the columnar path engaged: per interval, first sightings and
     cache hits are disjoint and add up to the plain digests imported;
-    a marker record (python merges it) and counters are in neither."""
-    assert {"import_digest_hits", "import_digest_misses"} \
+    a marker record (python merges it) and counters are in neither.
+    The row cache outlives the flush (PR 41): a key is a first
+    sighting once, not once an interval, and `import_row_hits` /
+    `import_row_misses` count the same over every family the cache
+    serves (here also the counter key)."""
+    assert {"import_digest_hits", "import_digest_misses",
+            "import_row_hits", "import_row_misses",
+            "import_row_cache_clears"} \
         <= LEDGER_SEGMENT_KEYS <= ROW_ONLY_SEGMENT_KEYS
     glob = Server(config_mod.Config(grpc_address="127.0.0.1:0",
                                     interval=10.0, percentiles=[0.5],
@@ -440,13 +446,19 @@ def test_digest_hits_and_misses_add_up_to_the_plain_digests_imported():
             glob.flush()
             row = glob.flush_timeline.snapshot()[-1]
             assert row["import_rpcs"] == sends
-            assert row["import_digest_misses"] == 30
-            assert row["import_digest_hits"] == 30 * (sends - 1)
+            first = epoch == 1      # the only interval that resolves keys
+            assert row["import_digest_misses"] == 30 * first
+            assert row["import_digest_hits"] == 30 * (sends - first)
+            # + the counter key: 5 records a send, one row
+            assert row["import_row_misses"] == 31 * first
+            assert row["import_row_hits"] == 35 * sends - 31 * first
+            assert row["import_row_cache_clears"] == 0
             assert row["imported"] == 35 * sends
         glob.flush()        # an interval nobody forwarded in
         row = glob.flush_timeline.snapshot()[-1]
         assert row["import_rpcs"] == 0
         assert row["import_digest_hits"] == row["import_digest_misses"] == 0
+        assert row["import_row_hits"] == row["import_row_misses"] == 0
     finally:
         client.close()
         glob.shutdown()

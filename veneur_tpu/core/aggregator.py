@@ -101,10 +101,24 @@ _LEDGER_FIELDS = ("import_rpcs", "import_lock_wait_ns", "import_scan_ns",
                   "fold_lock_wait_ns", "fold_ns",
                   # plain t-digests import_payload staged as arrays: the
                   # row came from the identity cache (no protobuf parse),
-                  # or the record was its key's first sighting in the
-                  # interval (parsed, resolved, cached).  Records that
-                  # keep _import_slow_pb are in neither.
+                  # or the record was its key's first sighting since
+                  # the cache was last cleared (parsed, resolved,
+                  # cached).  Records that keep _import_slow_pb are in
+                  # neither.
                   "import_digest_hits", "import_digest_misses",
+                  # every record whose row the V1 import asked the
+                  # identity->row cache for, of all four families it
+                  # serves (counters, gauges, sets, plain digests; both
+                  # import_pb_batch and the scanned payload): the cache
+                  # had the row, or the record was parsed and resolved
+                  # through row_for and cached.  Records that keep
+                  # _import_slow_pb, that were refused or failed, and
+                  # every record of a guard-armed import are in neither.
+                  # And whether the cut that closed the interval cleared
+                  # the cache (0 or 1: a row was recycled, or the cache
+                  # outgrew the keys it serves)
+                  "import_row_hits", "import_row_misses",
+                  "import_row_cache_clears",
                   # forwarded set sketches import_payload staged from
                   # the wire scan's columns, by wire form: sparse as
                   # (row, register, rank) triples, dense as a register
@@ -146,7 +160,8 @@ HOT_LEDGER_KEYS = ("hot_keys", "hot_points_in", "hot_points_out",
                    "build_onepass", "build_fresh_bytes")
 # snapshot_columns_s in parts (_snapshot_and_reset measures the first
 # four where they happen, under the lock; the last is what is left): the
-# import row cache's clearing, the arenas' snapshot_part() less their
+# import row cache's check against the rows the cut recycled (and its
+# clearing, when one was), the arenas' snapshot_part() less their
 # take_staged(), their reset_rows() and their end_interval(), and the
 # rest — the unique-timeseries swap, the key fingerprints, the lane
 # stats, the cardinality guard's and the cubes' end of interval
@@ -441,9 +456,16 @@ class MetricAggregator:
         # the calling thread's last import RPC as (scan, lock wait, held)
         # nanoseconds, for the global.import span's tags
         self._import_tls = threading.local()
-        # V1 import identity->row cache; cleared at every snapshot so a
-        # later end_interval GC can never recycle a cached row
+        # V1 import identity->row cache.  An entry is good for as long
+        # as its row holds its key, and only a recycled row (the idle GC
+        # in end_interval, release_keys) can break that: the cut clears
+        # the whole cache when the arenas' `recycled` totals moved
+        # (_check_import_row_cache) and leaves it alone otherwise, so a
+        # steady fleet resolves each key once, not once an interval.  A
+        # row the cache answers for must be marked touched by the
+        # import itself (row_for is not called on a hit).
         self._import_row_cache: dict = {}
+        self._import_recycled_seen = 0
         self._native_import = None   # False once the engine is ruled out
         self.count_unique_timeseries = count_unique_timeseries
         self.unique_ts = hll_mod.HLLSketch() if count_unique_timeseries else None
@@ -743,12 +765,13 @@ class MetricAggregator:
     def import_pb_batch(self, pbs, t_call: Optional[int] = None
                         ) -> tuple[int, int]:
         """Batched V1 import: ONE lock for the whole MetricList, direct
-        protobuf field access, an identity->row cache (cleared every
-        flush, BEFORE end_interval's GC can recycle rows), and
-        vectorized counter/gauge merges — the per-metric dataclass
-        conversion, key construction, and numpy scalar stores of
-        import_metric are the global tier's V1 inbound bottleneck at
-        fleet rates.  Scope/nil/local semantics match import_metric;
+        protobuf field access, an identity->row cache (kept across
+        flushes; the cut clears it when a row was recycled:
+        _check_import_row_cache), and vectorized counter/gauge merges
+        (merge_batch marks the rows touched, a cache hit's included) —
+        the per-metric dataclass conversion, key construction, and
+        numpy scalar stores of import_metric are the global tier's V1
+        inbound bottleneck at fleet rates.  Scope/nil/local semantics match import_metric;
         metrics whose `type` field contradicts their value oneof are
         rejected (see _ONEOF_LEGAL_TYPES — the legacy convert.from_pb
         path instead trusted `type` and mis-filed the payload).
@@ -768,6 +791,7 @@ class MetricAggregator:
         c_vals: list = []
         g_rows: list = []
         g_vals: list = []
+        misses = 0
         t_wait = time.perf_counter_ns()
         with self.lock:
             t_held = time.perf_counter_ns()
@@ -796,6 +820,7 @@ class MetricAggregator:
                             row = counters.row_for(key, cls, tags)
                             if ck is not None:
                                 cache[ck] = row
+                                misses += 1
                         c_rows.append(row)
                         c_vals.append(pb.counter.value)
                     elif which == "gauge":
@@ -811,6 +836,7 @@ class MetricAggregator:
                             row = gauges.row_for(key, cls, tags)
                             if ck is not None:
                                 cache[ck] = row
+                                misses += 1
                         g_rows.append(row)
                         g_vals.append(pb.gauge.value)
                     elif which in ("set", "histogram"):
@@ -830,6 +856,10 @@ class MetricAggregator:
             if g_rows:
                 gauges.merge_batch(np.asarray(g_rows, np.int64),
                                    np.asarray(g_vals, np.float64))
+            if self.cardinality is None:
+                self._ledger["import_row_hits"] += (
+                    len(c_rows) + len(g_rows) - misses)
+                self._ledger["import_row_misses"] += misses
             self._ledger_import(t_call, t_wait, t_held)
         return ok, failed
 
@@ -927,8 +957,8 @@ class MetricAggregator:
         (_stage_scanned_sets: sparse ones as decoded triples).  What
         the wire says decides per record: the moments / compactor
         markers (compression < 0), a set sketch the scan did not read
-        and a key's first sighting in the interval parse individually
-        via their byte ranges.  Falls back to
+        and a key the row cache does not know parse individually via
+        their byte ranges.  Falls back to
         import_pb_batch when the native engine is unavailable or
         rejects the payload."""
         t_call = time.perf_counter_ns()
@@ -996,7 +1026,7 @@ class MetricAggregator:
         d_rows: list = []
         s_recs: list = []       # set sketches staged: record index, row
         s_rows: list = []
-        misses = 0
+        misses = d_misses = 0       # of all four families; of digests
         ok = failed = 0
         t_wait = time.perf_counter_ns()
         with self.lock:
@@ -1007,11 +1037,13 @@ class MetricAggregator:
                     ck = (h_lo[i], h_hi[i], 4, scopes[i])
                     row = cache.get(ck)
                     if row is None:
-                        # first sighting this interval: the record is
-                        # parsed for its name and tags (and one bad
-                        # record, e.g. invalid UTF-8 the wire scanner
-                        # can't see, must not abort the payload);
-                        # row_for marks the row touched
+                        # a key the cache does not know (its first
+                        # sighting since the cache was last cleared):
+                        # the record is parsed for its name and tags
+                        # (and one bad record, e.g. invalid UTF-8 the
+                        # wire scanner can't see, must not abort the
+                        # payload).  row_for marks the row touched; a
+                        # hit's row is marked by _stage_scanned_digests
                         try:
                             pb = metric_pb2.Metric.FromString(
                                 payload[offs[i]:offs[i] + lens[i]])
@@ -1022,6 +1054,7 @@ class MetricAggregator:
                             continue
                         cache[ck] = row
                         misses += 1
+                        d_misses += 1
                     d_recs.append(i)
                     d_rows.append(row)
                     ok += 1
@@ -1059,6 +1092,7 @@ class MetricAggregator:
                             failed += 1
                             continue
                         cache[ck] = row
+                        misses += 1
                     if w == 1:
                         c_rows.append(row)
                         c_vals.append(vals[i])
@@ -1070,8 +1104,9 @@ class MetricAggregator:
                     ck = (h_lo[i], h_hi[i], 3)
                     row = cache.get(ck)
                     if row is None:
-                        # first sighting this interval: parsed for its
-                        # name and tags, as a histogram's is
+                        # a key the cache does not know: parsed for its
+                        # name and tags, as a histogram's is (a hit's
+                        # row is marked touched by _stage_scanned_sets)
                         try:
                             pb = metric_pb2.Metric.FromString(
                                 payload[offs[i]:offs[i] + lens[i]])
@@ -1084,6 +1119,7 @@ class MetricAggregator:
                             failed += 1
                             continue
                         cache[ck] = row
+                        misses += 1
                     s_recs.append(i)
                     s_rows.append(row)
                     ok += 1
@@ -1113,13 +1149,18 @@ class MetricAggregator:
                 #   asarray converts host lists — record indexes and
                 #   arena rows — never a device array)
                 self._stage_scanned_digests(scan, d_recs, d_rows)
-                self._ledger["import_digest_hits"] += len(d_recs) - misses
-                self._ledger["import_digest_misses"] += misses
+                self._ledger["import_digest_hits"] += (
+                    len(d_recs) - d_misses)
+                self._ledger["import_digest_misses"] += d_misses
             if s_recs:
                 # vnlint: disable=blocking-propagation (the flagged
                 #   asarray converts host lists — record indexes and
                 #   arena rows — never a device array)
                 self._stage_scanned_sets(scan, payload, s_recs, s_rows)
+            self._ledger["import_row_hits"] += (
+                len(c_rows) + len(g_rows) + len(d_rows) + len(s_rows)
+                - misses)
+            self._ledger["import_row_misses"] += misses
             self._ledger_import(t_call, t_wait, t_held)
         return ok, failed
 
@@ -1129,9 +1170,12 @@ class MetricAggregator:
         indexes `recs`, ascending, into arena rows `rows`) as what they
         are: every sparse sketch's decoded (register, rank) pairs as
         ONE chunk of (row, register, rank) triples, a dense sketch as
-        its register row.  Call under self.lock."""
+        its register row.  Marks the rows touched: staging does not,
+        and a row the cache answered for has not been through row_for.
+        Call under self.lock."""
         recs = np.asarray(recs, np.int64)
         rows = np.asarray(rows, np.int32)
+        self.sets.touched[rows] = True
         sparse = scan["set_form"][recs] == 1
         counts = scan["set_n"][recs][sparse]
         idx, rank = scan["set_idx"], scan["set_rank"]
@@ -1163,8 +1207,12 @@ class MetricAggregator:
                                rows: list) -> None:
         """Stage the plain digests of one scanned payload (record
         indexes `recs`, ascending, into arena rows `rows`) as one
-        columnar chunk.  Call under self.lock."""
+        columnar chunk.  Marks the rows touched: merge_digest_batch
+        does not, and a row the cache answered for has not been through
+        row_for.  Call under self.lock."""
         recs = np.asarray(recs, np.int64)
+        rows = np.asarray(rows, np.int64)
+        self.digests.touched[rows] = True
         counts = scan["cent_n"][recs]
         means, weights = scan["cent_mean"], scan["cent_weight"]
         if int(counts.sum()) != len(means):
@@ -1176,7 +1224,7 @@ class MetricAggregator:
             keep = np.repeat(keep, scan["cent_n"])
             means, weights = means[keep], weights[keep]
         self.digests.merge_digest_batch(
-            np.asarray(rows, np.int64), counts, means, weights,
+            rows, counts, means, weights,
             scan["dmin"][recs], scan["dmax"][recs], scan["drsum"][recs])
 
     def sync_staged(self, min_samples: int = 0) -> bool:
@@ -1279,6 +1327,10 @@ class MetricAggregator:
                 per_family[name] = (fmeta, farr)
             for name, (fmeta, farr) in per_family.items():
                 getattr(self, name).restore_state(fmeta, farr)
+            # rows now sit where the checkpoint says: nothing cached
+            # before holds, and the recycled total is compared afresh
+            self._import_row_cache.clear()
+            self._check_import_row_cache()
             self.processed = int(meta.get("processed", 0))
             self.imported = int(meta.get("imported", 0))
             uts = arrays.get("unique_ts/regs")
@@ -2507,13 +2559,12 @@ class MetricAggregator:
     def _snapshot_and_reset(self) -> dict:
         """Under lock: sync staging, cut every arena's part of touched
         rows (arena.snapshot_part: copies, never aliases of live
-        state), reset.  The parts' columns are the arenas' own.  The
+        state), reset, end the interval (the idle GC, the eviction
+        passes) and, last, look whether the import row cache survives
+        it.  The parts' columns are the arenas' own.  The
         clock is read per family and step, never per row, for
         flush_dispatch's split of the columns span."""
         clock = time.perf_counter
-        t = clock()
-        self._import_row_cache.clear()
-        cache_s = clock() - t
         arenas = self._arenas()
         copied = -sum(ar.staged_copied_bytes for _, ar in arenas)
         t = clock()
@@ -2581,17 +2632,52 @@ class MetricAggregator:
             t, t_was = clock(), t
             by_family["reset"][name] = t_reset - t_was
             by_family["end"][name] = t - t_reset
-        snap["columns_seconds"] = (cache_s, *(
-            sum(by_family[step].values())
-            for step in ("cut", "reset", "end")))
-        snap["columns_by_family"] = by_family
         snap["set_lane_stats"] = self.sets.take_lane_stats()
         snap["hot_lane_stats"] = self.digests.take_hot_stats()
         if self.cardinality is not None:
             self._cardinality_end_interval()
         if self.cubes is not None:
             self._cube_end_interval()
+        # after everything that can recycle a row (the idle GC above,
+        # the two eviction passes' release_keys)
+        t = clock()
+        reason = self._check_import_row_cache()
+        cache_s = clock() - t
+        if reason:
+            snap["ledger"]["import_row_cache_clears"] = 1
+            by_family["cache"] = {reason: cache_s}
+        snap["columns_seconds"] = (cache_s, *(
+            sum(by_family[step].values())
+            for step in ("cut", "reset", "end")))
+        snap["columns_by_family"] = by_family
         return snap
+
+    def _check_import_row_cache(self) -> str:
+        """The cut's look at the V1 import's identity->row cache (under
+        the lock): clear all of it if any arena put a row back on its
+        free list since the last cut ("recycled": a cached row may now
+        hold another key), or if it holds more than twice the keys of
+        the arenas it serves ("size": import_pb_batch keys on the tags
+        in wire order, so a sender that permutes them mints many keys
+        for one row).  Returns the reason, "" when the cache stays —
+        as it does at every cut of a fleet whose keys are steady."""
+        recycled = sum(ar.recycled for _, ar in self._arenas())
+        moved = recycled != self._import_recycled_seen
+        self._import_recycled_seen = recycled
+        cache = self._import_row_cache
+        if not cache:
+            return ""           # a node that imports nothing
+        if moved:
+            reason = "recycled"
+        elif len(cache) > 2 * (len(self.counters.kdict)
+                               + len(self.gauges.kdict)
+                               + len(self.sets.kdict)
+                               + len(self.digests.kdict)):
+            reason = "size"
+        else:
+            return ""
+        cache.clear()
+        return reason
 
     def _arena_for_type(self, mtype: str, key: Optional[MetricKey] = None):
         if mtype == sm.TYPE_COUNTER:

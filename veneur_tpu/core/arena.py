@@ -268,6 +268,16 @@ class _ArenaBase:
         self.touched = np.zeros(capacity, bool)
         self.idle = np.zeros(capacity, np.int32)
         self._free: list[int] = list(range(capacity - 1, -1, -1))
+        # high-water row: every row ever handed out lies in [0, hw) —
+        # the free list hands rows out in ascending order and freed rows
+        # are re-used first, so a row at or beyond hw has never held a
+        # key (touched false, no name) and end_interval leaves it alone.
+        # Raised in row_for, rebuilt in restore_state, never lowered.
+        self.hw = 0
+        # running total of rows put back on the free list (the idle GC
+        # in end_interval + release_keys): a cache of key -> row kept
+        # outside the arena is good for as long as this has not moved
+        self.recycled = 0
         self.lock = threading.Lock()
         # incremental fingerprints of the key dictionary: XOR-folds of
         # fnv1a per live mapping (XOR is its own inverse, so register/GC
@@ -384,6 +394,8 @@ class _ArenaBase:
             if not self._free:
                 self._grow()
             row = self._free.pop()
+            if row >= self.hw:
+                self.hw = row + 1
             self.kdict[dk] = row
             self._fold_key_fingerprints(key, scope, row)
             self.meta[row] = RowMeta(key=key, tags=tags, scope=scope)
@@ -461,6 +473,7 @@ class _ArenaBase:
             rows.append(int(row))
         if rows:
             self.reset_rows(np.asarray(rows, np.int64))
+            self.recycled += len(rows)
         return len(rows)
 
     # -- crash checkpoint (core/checkpoint.py) -----------------------------
@@ -596,6 +609,7 @@ class _ArenaBase:
             used.add(row)
         self._free = [r for r in range(self.capacity - 1, -1, -1)
                       if r not in used]
+        self.hw = max(used) + 1 if used else 0
         self._restore_arrays(meta, arrays)
 
     def _checkpoint_arrays(self) -> dict:
@@ -623,15 +637,24 @@ class _ArenaBase:
         else:
             dst[:, :src.shape[1]] = src
 
-    def end_interval(self) -> None:
-        """Reset touched state and GC idle rows (after flush)."""
-        self.idle[self.touched] = 0
-        self.idle[~self.touched] += 1
-        # liveness from the name column (live rows always have a name):
-        # an elementwise object-vs-None compare, not an O(capacity)
-        # Python walk per flush
-        dead = np.nonzero((self.idle >= IDLE_GC_INTERVALS)
-                          & (self.name_col != None))[0]  # noqa: E711
+    def end_interval(self) -> int:
+        """Reset touched state and GC idle rows (after flush), over the
+        rows ever handed out ([0, hw)) and not over the capacity: a
+        pre-sized arena that holds a handful of keys pays for the
+        handful.  Returns the rows freed (also added to `recycled`)."""
+        hw = self.hw
+        touched = self.touched[:hw]
+        idle = self.idle[:hw]
+        # touched rows restart at 0, every other row counts one more
+        idle += 1
+        np.putmask(idle, touched, 0)
+        # liveness from the name column (live rows always have a name),
+        # asked only of the rows old enough to die: a freed row under hw
+        # keeps counting and stays a candidate the name test drops; in a
+        # steady interval there is no candidate and no object compare
+        cand = np.nonzero(idle >= IDLE_GC_INTERVALS)[0]
+        dead = (cand[self.name_col[cand] != None]  # noqa: E711
+                if len(cand) else cand)
         for row in dead:
             m = self.meta[row]
             self.meta[row] = None
@@ -645,7 +668,9 @@ class _ArenaBase:
             del self.kdict[(m.key, m.scope)]
             self._fold_key_fingerprints(m.key, m.scope, int(row))
             self._free.append(int(row))
-        self.touched[:] = False
+        touched[:] = False
+        self.recycled += len(dead)
+        return len(dead)
 
 
 class CounterArena(_ArenaBase):
