@@ -99,6 +99,18 @@ class PendingFlush:
 _LEDGER_FIELDS = ("import_rpcs", "import_lock_wait_ns", "import_scan_ns",
                   "import_held_ns", "fold_calls", "fold_lines",
                   "fold_lock_wait_ns", "fold_ns",
+                  # the V2 streams' share of the import (sources/
+                  # proxy.py send_metrics_v2; their scan, lock wait and
+                  # hold are in the three above, with the V1 RPCs'):
+                  # streams whose first chunk was imported in the
+                  # interval (counted in import_rpcs too), messages
+                  # received, the chunks they were imported in, and the
+                  # handlers' wall time inside the request iterator
+                  # (python-grpc's and the sender's share) and framing
+                  # the chunks as MetricList bytes, outside the lock
+                  "import_stream_rpcs", "import_stream_msgs",
+                  "import_stream_chunks", "import_stream_recv_ns",
+                  "import_stream_frame_ns",
                   # plain t-digests import_payload staged as arrays: the
                   # row came from the identity cache (no protobuf parse),
                   # or the record was its key's first sighting since
@@ -762,8 +774,8 @@ class MetricAggregator:
         "histogram": (2, 4),    # metric_pb2.Histogram / Timer
     }
 
-    def import_pb_batch(self, pbs, t_call: Optional[int] = None
-                        ) -> tuple[int, int]:
+    def import_pb_batch(self, pbs, t_call: Optional[int] = None,
+                        stream=None) -> tuple[int, int]:
         """Batched V1 import: ONE lock for the whole MetricList, direct
         protobuf field access, an identity->row cache (kept across
         flushes; the cut clears it when a row was recycled:
@@ -777,7 +789,8 @@ class MetricAggregator:
         path instead trusted `type` and mis-filed the payload).
         Returns (imported, failed).  `t_call` (perf_counter_ns) is when
         the RPC's import began, for the interval ledger: import_payload
-        hands its own over so the protobuf parse counts as scan time."""
+        hands its own over so the protobuf parse counts as scan time,
+        and the V2 stream's chunk (`stream`) it was called for."""
         from veneur_tpu.protocol import metric_pb2
 
         if t_call is None:
@@ -860,16 +873,28 @@ class MetricAggregator:
                 self._ledger["import_row_hits"] += (
                     len(c_rows) + len(g_rows) - misses)
                 self._ledger["import_row_misses"] += misses
-            self._ledger_import(t_call, t_wait, t_held)
+            self._ledger_import(t_call, t_wait, t_held, stream)
         return ok, failed
 
-    def _ledger_import(self, t_call: int, t_wait: int, t_held: int) -> None:
-        """Account one import RPC to the open interval (perf_counter_ns
-        marks: the RPC's import began, it started waiting for the lock,
-        it held the lock).  Call under self.lock, last."""
+    def _ledger_import(self, t_call: int, t_wait: int, t_held: int,
+                       stream=None) -> None:
+        """Account one batch import to the open interval
+        (perf_counter_ns marks: the import began, it started waiting
+        for the lock, it held the lock): a V1 RPC, or one chunk of a
+        V2 stream (`stream`: its sources.proxy.StreamChunk) — a stream
+        is one RPC, counted with its first chunk.  Call under
+        self.lock, last."""
         held = time.perf_counter_ns() - t_held
         led = self._ledger
-        led["import_rpcs"] += 1
+        if stream is None:
+            led["import_rpcs"] += 1
+        else:
+            led["import_rpcs"] += stream.first
+            led["import_stream_rpcs"] += stream.first
+            led["import_stream_chunks"] += 1
+            led["import_stream_msgs"] += len(stream.messages)
+            led["import_stream_recv_ns"] += stream.recv_ns
+            led["import_stream_frame_ns"] += stream.frame_ns
         led["import_scan_ns"] += t_wait - t_call
         led["import_lock_wait_ns"] += t_held - t_wait
         led["import_held_ns"] += held
@@ -878,7 +903,7 @@ class MetricAggregator:
     def take_import_timing(self) -> Optional[tuple]:
         """(scan, lock wait, held) nanoseconds of the batch import this
         thread just made, once; None when it made none since the last
-        take (a per-metric stream import is not timed)."""
+        take (import_metric, the per-metric path, is not timed)."""
         timing = getattr(self._import_tls, "timing", None)
         self._import_tls.timing = None
         return timing
@@ -947,8 +972,12 @@ class MetricAggregator:
             [c.weight for c in dig.main_centroids],
             dig.min, dig.max, dig.reciprocalSum)
 
-    def import_payload(self, payload: bytes) -> tuple[int, int]:
-        """V1 import from the RAW MetricList bytes: the native scanner
+    def import_payload(self, payload: bytes, stream=None
+                       ) -> tuple[int, int]:
+        """Batch import from RAW MetricList bytes — a V1 RPC's request,
+        or a chunk of a V2 stream framed as one (`stream`: its
+        sources.proxy.StreamChunk, for the ledger and for the fallback
+        below): the native scanner
         (ingest.import_scan) extracts identity hashes, values and every
         plain t-digest's centroids in C++, so python does one dict
         lookup per metric, one vectorized merge per family and ONE
@@ -959,8 +988,11 @@ class MetricAggregator:
         markers (compression < 0), a set sketch the scan did not read
         and a key the row cache does not know parse individually via
         their byte ranges.  Falls back to
-        import_pb_batch when the native engine is unavailable or
-        rejects the payload."""
+        import_pb_batch when the native engine is unavailable, the
+        cardinality guard is armed, or the scan rejects the payload: a
+        V1 payload is then parsed whole (one malformed record fails the
+        RPC), a stream's chunk message by message — its bad message
+        fails alone and is counted."""
         t_call = time.perf_counter_ns()
         scan = None
         # the native wire scan never materializes tags, which the
@@ -978,10 +1010,22 @@ class MetricAggregator:
             except Exception:
                 self._native_import = False
         if scan is None:
-            from veneur_tpu.protocol import forward_pb2
-            return self.import_pb_batch(
-                forward_pb2.MetricList.FromString(payload).metrics,
-                t_call)
+            from veneur_tpu.protocol import forward_pb2, metric_pb2
+            if stream is None:
+                return self.import_pb_batch(
+                    forward_pb2.MetricList.FromString(payload).metrics,
+                    t_call)
+            pbs, bad = [], 0
+            for raw in stream.messages:
+                try:
+                    pbs.append(metric_pb2.Metric.FromString(raw))
+                # vnlint: disable=silent-loss (counted: `bad` joins the
+                #   returned `failed`, which the stream handler adds to
+                #   import_errors — /debug/vars import_errors_total)
+                except Exception:
+                    bad += 1
+            ok, failed = self.import_pb_batch(pbs, t_call, stream)
+            return ok, failed + bad
         n = scan["n"]
         if n == 0:
             return 0, 0
@@ -1161,7 +1205,7 @@ class MetricAggregator:
                 len(c_rows) + len(g_rows) + len(d_rows) + len(s_rows)
                 - misses)
             self._ledger["import_row_misses"] += misses
-            self._ledger_import(t_call, t_wait, t_held)
+            self._ledger_import(t_call, t_wait, t_held, stream)
         return ok, failed
 
     def _stage_scanned_sets(self, scan: dict, payload: bytes, recs: list,

@@ -618,8 +618,11 @@ class Server:
                 self.proto_received["grpc"] += 1
                 self.aggregator.import_metric(fm)
 
-            def _import_payload_counted(payload):
-                ok, failed = self.aggregator.import_payload(payload)
+            def _import_payload_counted(payload, stream=None):
+                # a V1 RPC's MetricList, or a chunk of a V2 stream
+                # (`stream`: sources.proxy.StreamChunk)
+                ok, failed = self.aggregator.import_payload(payload,
+                                                            stream)
                 with self._proto_lock:
                     self.proto_received["grpc"] += ok
                 return ok, failed
@@ -1438,14 +1441,17 @@ class Server:
     IMPORTED_TRACES_TAG_MAX = 64
 
     def _record_import_span(self, ctxs, n_metrics: int, start_ns: int,
-                            transport: str) -> None:
+                            transport: str,
+                            stream_tags: Optional[dict] = None) -> None:
         """gRPC import trace hook (sources/proxy.py): continue each
         inbound RPC's propagated trace context with one child span
         covering the import, and remember the trace ids so the next
-        flush's root span can tag the intervals it settles."""
+        flush's root span can tag the intervals it settles.  A V2
+        stream's span also says how many messages it carried and in
+        how many chunks they were imported (`stream_tags`)."""
         from veneur_tpu.trace import recorder as trace_rec
         tags = {"metrics": str(n_metrics), "transport": transport,
-                "host": self.config.hostname}
+                "host": self.config.hostname, **(stream_tags or {})}
         # a batch import (this handler thread's, just made) says where
         # its time went: outside the aggregator lock, waiting for it,
         # holding it

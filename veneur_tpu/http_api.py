@@ -165,6 +165,13 @@ def debug_vars(server) -> dict:
     ckpt = getattr(server, "checkpoint_stats", None)
     if ckpt is not None and ckpt.get("enabled"):
         stats["checkpoint"] = dict(ckpt)
+    gi = getattr(server, "grpc_import", None)
+    if gi is not None and hasattr(gi, "stream_stats"):
+        # the V2 stream import since boot (sources/proxy.py): streams,
+        # messages, the chunks they were imported in, the handlers'
+        # wall time in the request iterator and framing, streams open
+        # now (the flush timeline's rows: import_stream_* per interval)
+        stats["import_stream"] = gi.stream_stats()
     dedup = getattr(server, "dedup", None)
     if dedup is not None:
         # exactly-once ledger: recorded chunk identities and
