@@ -35,7 +35,8 @@ ROW_FIELDS = ("snapshot_lock_wait_ms", "snapshot_sync_ms",
               "snapshot_end_ms", "snapshot_rest_ms")
 # what the metric lane amends the row with once the sink had the batch
 LANE_ROW_FIELDS = ("lane_sink_cpu_ms", "lane_gc_passes",
-                   "lane_gc_full_passes")
+                   "lane_gc_full_passes", "lane_records",
+                   "lane_records_native")
 COLUMNS_PARTS = ("cache", "cut", "reset", "end", "rest")
 SINK_PARTS = ("records", "splice", "put")
 
@@ -665,6 +666,41 @@ def test_lane_cpu_time_is_inside_the_sink_span_and_its_parts_too(server):
     assert last["lane_sink_cpu_ms"] == row["lane_sink_cpu_ms"]
 
 
+def test_the_lane_counts_the_records_it_built_and_which_way(server,
+                                                            monkeypatch):
+    """lane_records: what the sink's call built from the batch's
+    segments; lane_records_native: how many of them in native code —
+    all of them where this host has the builder, none where it has not;
+    the parts of the sink's span still add up to it."""
+    from veneur_tpu.samplers import samplers as sm
+
+    srv, sink = server()
+    srv.start()
+    have_native = sm._record_builder() is not None
+    for way in ("native", "interpreter"):
+        with monkeypatch.context() as patch:
+            if way == "interpreter":
+                patch.setattr(sm, "_builder", None)
+            _send_and_drain(srv)
+            trace = _trace_of(srv, _flush_and_spans(srv))
+        got = sink.queue.get_nowait()
+        loose = sum(1 for m in got if m.type == sm.STATUS)
+        row = srv.flush_timeline.snapshot()[-1]
+        assert row["lane_records"] == len(got) - loose > 0
+        assert row["lane_records_native"] == (
+            row["lane_records"] if way == "native" and have_native else 0)
+        last = http_api.debug_vars(srv)["egress"]["per_sink"][
+            "metric:channel"]["last_sink_call"]
+        assert last["interval"] == row["interval"]
+        assert last["lane_records"] == row["lane_records"]
+        assert last["lane_records_native"] == row["lane_records_native"]
+        sink_span = trace["flush.seg.lane.sink"]
+        parts = [trace[f"flush.seg.lane.sink.{p}"] for p in SINK_PARTS]
+        assert sum(c["duration_ms"] for c in parts) == pytest.approx(
+            sink_span["duration_ms"], abs=0.5)
+        assert all(_inside(c, sink_span) for c in parts)
+
+
 class _IteratingSink(simple_sinks.ChannelMetricSink):
     """A sink that walks the batch and never materialises it."""
 
@@ -680,10 +716,12 @@ def test_a_sink_that_iterates_the_batch_gets_no_children(server):
     trace = _trace_of(srv, _flush_and_spans(srv))
     assert "flush.seg.lane.sink" in trace
     assert not [n for n in trace if n.startswith("flush.seg.lane.sink.")]
-    # the row's lane fields do not depend on the sink's way
+    # the row's lane fields do not depend on the sink's way; it built
+    # its records a segment at a time, outside materialize()'s count
     row = srv.flush_timeline.snapshot()[-1]
     for field in LANE_ROW_FIELDS:
         assert field in row, field
+    assert row["lane_records"] == row["lane_records_native"] == 0
 
 
 def test_a_stamp_from_outside_the_call_lays_no_children(server):

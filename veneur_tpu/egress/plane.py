@@ -140,6 +140,17 @@ class EgressJob:
         self.tick_ns = tick_ns
 
 
+def _materialized_within(metrics, start_ns: int, end_ns: int):
+    """The stamps of the batch's last materialize()
+    (samplers.MetricBatch.stamps) when it ran inside [start_ns,
+    end_ns]; None for a list, a batch no one materialised, or a
+    materialize() of another call."""
+    stamps = getattr(metrics, "stamps", None)
+    if not stamps or stamps[0] < start_ns or stamps[2] > end_ns:
+        return None
+    return stamps
+
+
 def _safe_dirname(name: str) -> str:
     return re.sub(r"[^A-Za-z0-9._-]", "_", name) or "sink"
 
@@ -329,29 +340,40 @@ class SinkLane:
         sink's return (queue.put, the result).  A sink that never
         materialised the batch left no stamp inside the call and gets
         no children; a retried call keeps the last attempt's."""
-        stamps = getattr(filtered, "stamps", None)
-        if (not stamps or stamps[0] < sink_span.start_ns
-                or stamps[2] > sink_span.end_ns):
+        stamps = _materialized_within(filtered, sink_span.start_ns,
+                                      sink_span.end_ns)
+        if stamps is None:
             return
         start, built, spliced = stamps
         self._lane_span(sink_span, "sink.records", start, built)
         self._lane_span(sink_span, "sink.splice", built, spliced)
         self._lane_span(sink_span, "sink.put", spliced, sink_span.end_ns)
 
-    def _account_sink_call(self, job: EgressJob, done_ns: int,
-                           cpu_ns: int, gc_before: list) -> None:
+    def _account_sink_call(self, job: EgressJob, filtered, start_ns: int,
+                           done_ns: int, cpu_ns: int,
+                           gc_before: list) -> None:
         """What one sink.flush call (all attempts) cost beyond its wall
         time, onto the interval's timeline row and into stats(): this
         thread's CPU time over the call (wall less this is waiting, for
         the interpreter lock or the scheduler), the collections of any
         generation / of the oldest that started in the process
-        meanwhile, and — for a flush serve() scheduled — the time from
-        its tick to the sink's return."""
+        meanwhile, the records the call built from the batch's segments
+        and how many of them in native code (0 and 0 for a sink that
+        did not materialise the batch; native 0 beside records > 0: the
+        interpreter built them, samplers.MetricSegment.extend_records)
+        and — for a flush serve() scheduled — the time from its tick to
+        the sink's return."""
         passes = [after["collections"] - before["collections"]
                   for before, after in zip(gc_before, gc.get_stats())]
+        native, interpreted = (
+            filtered.built
+            if _materialized_within(filtered, start_ns, done_ns)
+            else (0, 0))
         call = {"lane_sink_cpu_ms": round(cpu_ns / 1e6, 3),
                 "lane_gc_passes": sum(passes),
-                "lane_gc_full_passes": passes[-1]}
+                "lane_gc_full_passes": passes[-1],
+                "lane_records": native + interpreted,
+                "lane_records_native": native}
         if job.tick_ns:
             call["tick_to_sink_ms"] = round(
                 (done_ns - job.tick_ns) / 1e6, 3)
@@ -390,7 +412,8 @@ class SinkLane:
             finally:
                 t_done = time.time_ns()
                 self._account_sink_call(
-                    job, t_done, time.thread_time_ns() - cpu0, gc_before)
+                    job, filtered, t_sink, t_done,
+                    time.thread_time_ns() - cpu0, gc_before)
                 if span is not None:
                     # the sink.flush call, all attempts and backoffs
                     self._sink_part_spans(

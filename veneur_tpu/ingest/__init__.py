@@ -22,7 +22,6 @@ import ctypes
 import logging
 import os
 import struct
-import subprocess
 import threading
 import time
 from dataclasses import dataclass
@@ -32,13 +31,12 @@ import numpy as np
 
 from veneur_tpu.samplers.metric_key import (MetricKey, MetricScope,
                                             metric_digest)
+from veneur_tpu.util import native_build
 
 logger = logging.getLogger("veneur.ingest")
 
-_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-_SRC = os.path.join(_REPO_ROOT, "native", "ingest_engine.cpp")
-_SO = os.path.join(_REPO_ROOT, "native", ".build", "libvningest.so")
+_SRC = os.path.join(native_build.NATIVE_DIR, "ingest_engine.cpp")
+_SO = os.path.join(native_build.BUILD_DIR, "libvningest.so")
 
 _TYPE_NAMES = ("counter", "gauge", "histogram", "timer", "set")
 
@@ -59,33 +57,13 @@ _build_lock = threading.Lock()
 _lib = None
 
 
-def _compile() -> None:
-    os.makedirs(os.path.dirname(_SO), exist_ok=True)
-    tmp = _SO + f".tmp.{os.getpid()}"
-    cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-           "-Wall", "-Wextra"]
-    if os.environ.get("VENEUR_TPU_TEST"):
-        # the test build path promotes warnings to errors so a warning
-        # introduced by a change fails the suite, not just stderr
-        cmd.append("-Werror")
-    cmd += ["-o", tmp, _SRC]
-    build = subprocess.run(cmd, capture_output=True, text=True)
-    if build.returncode != 0:
-        raise RuntimeError(
-            f"native ingest engine build failed ({' '.join(cmd)}):\n"
-            f"{build.stderr[-4000:]}")
-    os.replace(tmp, _SO)
-
-
 def load_library():
     """Build (if stale) and load the native engine; raises on failure."""
     global _lib
     with _build_lock:
         if _lib is not None:
             return _lib
-        if (not os.path.exists(_SO)
-                or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-            _compile()
+        native_build.build_if_stale(_SRC, _SO)
         lib = ctypes.CDLL(_SO)
         lib.vn_engine_new.restype = ctypes.c_void_p
         lib.vn_engine_new.argtypes = [ctypes.c_int, ctypes.c_char_p]

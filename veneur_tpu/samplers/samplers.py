@@ -18,6 +18,8 @@ from typing import Optional
 
 import numpy as np
 
+from veneur_tpu.samplers import record_builder
+
 # Metric type constants (samplers/samplers.go:50-60).
 COUNTER = "counter"
 GAUGE = "gauge"
@@ -90,6 +92,19 @@ class InterMetric:
     hostname: str = ""
     # sink routing allowlist; None = all sinks (RouteInformation)
     sinks: Optional[set[str]] = None
+
+
+_UNLOADED = object()
+# record_builder.RecordBuilder of InterMetric from the first bulk build
+# on; None on a host that cannot have one (record_builder.load)
+_builder = _UNLOADED
+
+
+def _record_builder() -> Optional[record_builder.RecordBuilder]:
+    global _builder
+    if _builder is _UNLOADED:
+        _builder = record_builder.load(InterMetric)
+    return _builder
 
 
 @dataclass
@@ -178,24 +193,44 @@ class MetricSegment:
             sinks=self.sinks[i] if self.sinks is not None else None)
 
     def materialize(self) -> list[InterMetric]:
-        """Every record of the segment at once: the value column leaves
-        numpy in one `tolist`, names and (for a sparse column) rows are
-        taken column-wise, and the records are built positionally — a
-        third of the per-record cost of taking them one `metric(i)` at a
-        time."""
+        """Every record of the segment at once."""
+        out: list[InterMetric] = []
+        self.extend_records(out)
+        return out
+
+    def extend_records(self, out: list) -> bool:
+        """Append every record of the segment to `out`: the value column
+        leaves numpy in one `tolist`, names and (for a sparse column)
+        rows are taken column-wise, and the records are built in native
+        code, a chunk a call and no interpreter frame a record
+        (record_builder: a quarter to a third of the cost on the chip's
+        host, PERF.md §6, PR 43) — True then.  Where this
+        host has no builder, or the columns are not lists of one length,
+        the comprehension below builds the same records (a third of the
+        cost of taking them one `metric(i)` at a time) — False."""
         vals = np.asarray(self.values, np.float64).tolist()
-        bases, tags, suffix = self.bases, self.tags, self.suffix
+        bases, tags, sinks = self.bases, self.tags, self.sinks
         if self.sel is not None:
             rows = np.asarray(self.sel).tolist()
             bases = [bases[r] for r in rows]
             tags = [tags[r] for r in rows]
+        suffix, ts, typ = self.suffix, self.timestamp, self.type
+        builder = _record_builder()
+        if (builder is not None and type(bases) is list
+                and type(tags) is list and type(suffix) is str
+                and len(bases) == len(tags) == len(vals)
+                and (sinks is None or (type(sinks) is list
+                                       and len(sinks) == len(vals)))):
+            builder.extend(out, bases, suffix, ts, vals, tags, typ, sinks)
+            return True
         names = [b + suffix for b in bases] if suffix else bases
-        ts, typ = self.timestamp, self.type
-        if self.sinks is None:
-            return [InterMetric(n, ts, v, t, typ)
+        if sinks is None:
+            out += [InterMetric(n, ts, v, t, typ)
                     for n, v, t in zip(names, vals, tags)]
-        return [InterMetric(n, ts, v, t, typ, "", "", s)
-                for n, v, t, s in zip(names, vals, tags, self.sinks)]
+        else:
+            out += [InterMetric(n, ts, v, t, typ, "", "", s)
+                    for n, v, t, s in zip(names, vals, tags, sinks)]
+        return False
 
     def __iter__(self):
         return iter(self.materialize())
@@ -212,7 +247,7 @@ class MetricBatch:
     threads.  Columnar-aware consumers read `segments` directly.
     """
 
-    __slots__ = ("segments", "loose", "stamps")
+    __slots__ = ("segments", "loose", "stamps", "built")
 
     def __init__(self, segments=None, loose=None):
         self.segments: list[MetricSegment] = segments or []
@@ -221,6 +256,10 @@ class MetricBatch:
         # built, collector splice done); None until one ran.  The egress
         # lane lays them as flush.seg.lane.sink.* spans
         self.stamps: Optional[tuple] = None
+        # records the last materialize() built from segments: (in
+        # native code, by the interpreter); the lane's lane_records /
+        # lane_records_native
+        self.built: tuple = (0, 0)
 
     def append(self, m: InterMetric) -> None:
         self.loose.append(m)
@@ -291,10 +330,15 @@ class MetricBatch:
         t_start = t_built = time.time_ns()
         paused = gc.isenabled()
         gc.disable()
+        native = interpreted = 0
+        out: list[InterMetric] = []
         try:
-            out: list[InterMetric] = []
             for seg in self.segments:
-                out.extend(seg.materialize())
+                before = len(out)
+                if seg.extend_records(out):
+                    native += len(out) - before
+                else:
+                    interpreted += len(out) - before
             out.extend(self.loose)
             t_built = time.time_ns()
         finally:
@@ -303,6 +347,7 @@ class MetricBatch:
                 gc.unfreeze()
                 gc.enable()
             self.stamps = (t_start, t_built, time.time_ns())
+            self.built = (native, interpreted)
         return out
 
     def apply_routing(self, rules, match_fn) -> None:
