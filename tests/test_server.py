@@ -211,11 +211,13 @@ def test_events_reach_sink_other_samples(fixture_server):
     s = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
     s.sendto(b"_e{5,5}:hello|world|t:info", addr)
     s.close()
-    time.sleep(0.2)
-    srv.flush()
-    deadline = time.time() + 2
+    # the event reaches the sink with the flush that follows its drain,
+    # and under a loaded machine the drain can be late: flush until it
+    # did, not once after a fixed sleep
+    deadline = time.time() + 20
     while time.time() < deadline and not sink.other_samples:
-        time.sleep(0.05)
+        time.sleep(0.1)
+        srv.flush()
     assert sink.other_samples
     assert sink.other_samples[0].name == "hello"
 

@@ -99,6 +99,12 @@ class PendingFlush:
 _LEDGER_FIELDS = ("import_rpcs", "import_lock_wait_ns", "import_scan_ns",
                   "import_held_ns", "fold_calls", "fold_lines",
                   "fold_lock_wait_ns", "fold_ns",
+                  # the part of fold_ns the drain spent on keys it did
+                  # not know: registering the engine's new identities
+                  # and resolving an id to its row through row_for (a
+                  # birth, or a row found again after the idle GC or an
+                  # intern clear) — ingest.NativeIngest._drain_apply
+                  "key_birth_held_ns",
                   # the V2 streams' share of the import (sources/
                   # proxy.py send_metrics_v2; their scan, lock wait and
                   # hold are in the three above, with the V1 RPCs'):
@@ -170,6 +176,22 @@ HOT_LEDGER_KEYS = ("hot_keys", "hot_points_in", "hot_points_out",
                    "hot_compress_launches", "hot_compress_held_s",
                    "hot_compress_tile_bytes", "dense_tiers", "dense_elems",
                    "build_onepass", "build_fresh_bytes")
+# a row's life over the interval the cut closed, summed over the arenas
+# (_ArenaBase.births / .recycled / .grows, read at the cut after the
+# idle GC and the eviction passes): rows handed to a new key, rows put
+# back on a free list, doublings of an arena, and where that leaves the
+# arenas — live keys and rows ever handed out.  On the timeline row and
+# in /debug/vars (-> key_lifecycle); the deaths of the idle GC by family
+# are columns_by_family["deaths"], tags of the columns.end span
+KEY_LEDGER_KEYS = ("key_births", "key_deaths", "arena_grows",
+                   "arena_rows_live", "arena_rows_hw")
+# written by the native drain (ledger_intern_clear) only into the ledger
+# of an interval in which it cleared the engine's intern table: the
+# clears, the drain calls that made them (readers parked, table wiped)
+# and the identities registered again for a row that was still live,
+# from the clear to the cut
+INTERN_LEDGER_KEYS = ("intern_clears", "intern_clear_s",
+                      "intern_reregistered")
 # snapshot_columns_s in parts (_snapshot_and_reset measures the first
 # four where they happen, under the lock; the last is what is left): the
 # import row cache's check against the rows the cut recycled (and its
@@ -192,7 +214,8 @@ ROW_ONLY_SEGMENT_KEYS = LEDGER_SEGMENT_KEYS | {
     # the meshed launch's own (_launch_meshed): the dense shape it ran
     # and the bytes its collectives move per device
     "device_rows", "device_depth", "collective_bytes",
-    *STAGED_LEDGER_KEYS, *HOT_LEDGER_KEYS}
+    *STAGED_LEDGER_KEYS, *HOT_LEDGER_KEYS, *KEY_LEDGER_KEYS,
+    *INTERN_LEDGER_KEYS}
 
 
 def _new_ledger() -> dict:
@@ -465,6 +488,8 @@ class MetricAggregator:
         self.processed = 0
         self.imported = 0
         self._ledger = _new_ledger()    # the interval ledger (see above)
+        # the arenas' births / recycled / grows at the last cut
+        self._key_life_seen = (0, 0, 0)
         # the calling thread's last import RPC as (scan, lock wait, held)
         # nanoseconds, for the global.import span's tags
         self._import_tls = threading.local()
@@ -908,15 +933,27 @@ class MetricAggregator:
         self._import_tls.timing = None
         return timing
 
-    def ledger_fold(self, lines: int, t_wait: int, t_held: int) -> None:
+    def ledger_fold(self, lines: int, t_wait: int, t_held: int,
+                    birth_ns: int = 0) -> None:
         """Account one fold of a native drain into the arenas (ingest.
-        NativeIngest._drain_apply) to the open interval.  Call under
-        self.lock, last."""
+        NativeIngest._drain_apply) to the open interval; `birth_ns` is
+        the part of it spent on keys the drain did not know.  Call
+        under self.lock, last."""
         led = self._ledger
         led["fold_calls"] += 1
         led["fold_lines"] += lines
         led["fold_lock_wait_ns"] += t_held - t_wait
         led["fold_ns"] += time.perf_counter_ns() - t_held
+        led["key_birth_held_ns"] += birth_ns
+
+    def ledger_intern_clear(self, clear_ns: int) -> None:
+        """Account one clear of the native intern table to the open
+        interval (INTERN_LEDGER_KEYS; only such an interval's ledger
+        holds them).  Call under self.lock."""
+        led = self._ledger
+        led["intern_clears"] = led.get("intern_clears", 0) + 1
+        led["intern_clear_ns"] = led.get("intern_clear_ns", 0) + clear_ns
+        led.setdefault("intern_reregistered", 0)
 
     def _import_histo_identity(self, pb):
         """(key, class, tags) a forwarded histogram / timer record
@@ -1456,6 +1493,7 @@ class MetricAggregator:
         seg.update(zip(COLUMNS_PART_KEYS,
                        (*measured, columns_s - sum(measured))))
         seg["columns_by_family"] = snap.pop("columns_by_family")
+        seg.update(zip(KEY_LEDGER_KEYS, snap.pop("key_ledger")))
         # the interval ledger, swapped out at the cut
         for name, v in snap.pop("ledger").items():
             if name.endswith("_ns"):
@@ -2669,10 +2707,11 @@ class MetricAggregator:
             for _, ar in arenas}
 
         t = clock()
+        deaths = {}
         for name, ar in arenas:
             ar.reset_rows(snap[name]["rows"])
             t_reset = clock()
-            ar.end_interval()
+            deaths[name] = ar.end_interval()
             t, t_was = clock(), t
             by_family["reset"][name] = t_reset - t_was
             by_family["end"][name] = t - t_reset
@@ -2693,7 +2732,17 @@ class MetricAggregator:
         snap["columns_seconds"] = (cache_s, *(
             sum(by_family[step].values())
             for step in ("cut", "reset", "end")))
+        by_family["deaths"] = {name: n for name, n in deaths.items() if n}
         snap["columns_by_family"] = by_family
+        # a row's life over this interval (KEY_LEDGER_KEYS), after
+        # everything that can free a row
+        life = tuple(sum(getattr(ar, attr) for _, ar in arenas)
+                     for attr in ("births", "recycled", "grows"))
+        snap["key_ledger"] = (
+            *(now - was for now, was in zip(life, self._key_life_seen)),
+            sum(len(ar.kdict) for _, ar in arenas),
+            sum(ar.hw for _, ar in arenas))
+        self._key_life_seen = life
         return snap
 
     def _check_import_row_cache(self) -> str:

@@ -278,6 +278,12 @@ class _ArenaBase:
         # in end_interval + release_keys): a cache of key -> row kept
         # outside the arena is good for as long as this has not moved
         self.recycled = 0
+        # running totals of the other two events of a row's life: rows
+        # handed to a new key (row_for's allocation) and doublings of the
+        # arena (_grow).  births - recycled moves with len(kdict); the
+        # aggregator's cut reads all three (KEY_LEDGER_KEYS)
+        self.births = 0
+        self.grows = 0
         self.lock = threading.Lock()
         # incremental fingerprints of the key dictionary: XOR-folds of
         # fnv1a per live mapping (XOR is its own inverse, so register/GC
@@ -358,6 +364,7 @@ class _ArenaBase:
     def _grow(self) -> None:
         old = self.capacity
         self.capacity = old * 2
+        self.grows += 1
         self.meta.extend([None] * old)
         self.name_col = np.concatenate(
             [self.name_col, np.empty(old, object)])
@@ -406,6 +413,7 @@ class _ArenaBase:
                 self.kind_col[row] = key.type
             self.scope_col[row] = int(scope)
             self.idle[row] = 0
+            self.births += 1
         self.touched[row] = True
         return row
 

@@ -1573,12 +1573,21 @@ class Server:
         if columns is None:
             return
         by_family = segs.get("columns_by_family") or {}
+
+        def tags(part: str) -> dict:
+            out = {fam: f"{s * 1e3:.3f}"
+                   for fam, s in by_family.get(part, {}).items()
+                   if s * 1e3 >= self._FAMILY_TAG_MIN_MS}
+            if part == "end":
+                # the rows the idle GC freed, beside its milliseconds
+                out.update((f"deaths.{fam}", str(n))
+                           for fam, n in by_family.get("deaths",
+                                                       {}).items())
+            return out
+
         self._lay_spans(columns, [
             (f"flush.seg.snapshot.columns.{part}",
-             segs[f"snapshot_{part}_s"],
-             {fam: f"{s * 1e3:.3f}"
-              for fam, s in by_family.get(part, {}).items()
-              if s * 1e3 >= self._FAMILY_TAG_MIN_MS})
+             segs[f"snapshot_{part}_s"], tags(part))
             for part in self._COLUMNS_PARTS
             if f"snapshot_{part}_s" in segs])
 

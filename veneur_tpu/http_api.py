@@ -194,6 +194,8 @@ def debug_vars(server) -> dict:
     # them copied under the aggregator lock (0 = nothing joined at the
     # tick), buffer doublings over the interval
     from veneur_tpu.core.aggregator import (HOT_LEDGER_KEYS,
+                                            INTERN_LEDGER_KEYS,
+                                            KEY_LEDGER_KEYS,
                                             SET_LEDGER_KEYS,
                                             STAGED_LEDGER_KEYS)
     segs = agg.last_flush_segments
@@ -215,6 +217,16 @@ def debug_vars(server) -> dict:
     # operand's tiers and padded elements
     stats["hot_lane"] = {
         key: segs.get(key, 0) for key in HOT_LEDGER_KEYS}
+    # a row's life over the last interval (also on the flush timeline's
+    # rows): keys born and rows freed, arena doublings, live keys and
+    # rows ever handed out, the drain's lock hold on keys it did not
+    # know — and, if that interval cleared the native intern table, the
+    # clears, their drain calls and the identities registered again
+    stats["key_lifecycle"] = {
+        key: segs.get(key, 0)
+        for key in (*KEY_LEDGER_KEYS, "key_birth_held_s")}
+    stats["key_lifecycle"].update(
+        (key, segs[key]) for key in INTERN_LEDGER_KEYS if key in segs)
     guard = getattr(server.aggregator, "cardinality", None)
     if guard is not None:
         # per-tenant key-budget ledger: exact keys, evicted

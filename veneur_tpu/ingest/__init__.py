@@ -722,6 +722,11 @@ class NativeIngest:
         self._cube_inert: set = set()
         self.malformed = 0
         self.too_long = 0
+        # clears of the engine's intern table so far, and the nanoseconds
+        # of the drain in progress spent on keys it did not know
+        # (aggregator.ledger_fold's birth_ns)
+        self.intern_clears = 0
+        self._birth_ns = 0
         self._drain_lock = threading.Lock()
 
     # -- key registration --------------------------------------------------
@@ -781,18 +786,31 @@ class NativeIngest:
                     info.card_epoch = guard.epoch
                     row = -1
             if row < 0 or arena.meta[row] is not info.meta:
+                t_birth = time.perf_counter_ns()
                 key, scope, tags = (resolved if resolved is not None
                                     else (info.key, info.row_scope,
                                           info.tags))
+                births = arena.births
                 row = arena.row_for(key, scope, tags)
+                self._note_resolved(info, arena.births == births)
                 info.row = row
                 info.meta = arena.meta[row]
+                self._birth_ns += time.perf_counter_ns() - t_birth
             else:
                 arena.touched[row] = True
             lut[uid] = row
             if uts is not None and info.uts_bytes is not None:
                 uts.insert(info.uts_bytes)
         return lut[ids]
+
+    def _note_resolved(self, info, found: bool) -> None:
+        """An id's first resolution found its key's row live: after an
+        intern clear that is a re-registration, and the interval's
+        ledger counts it (aggregator.INTERN_LEDGER_KEYS)."""
+        if found and info.meta is None:
+            led = self.agg._ledger
+            if "intern_reregistered" in led:
+                led["intern_reregistered"] += 1
 
     def _hrows_for(self, ids: np.ndarray
                    ) -> tuple[np.ndarray, np.ndarray]:
@@ -824,11 +842,15 @@ class NativeIngest:
                 key, scope, tags = (resolved if resolved is not None
                                     else (info.key, info.row_scope,
                                           info.tags))
+                t_birth = time.perf_counter_ns()
                 arena = agg._histo_arena(key, tags)
+                births = arena.births
                 row = arena.row_for(key, scope, tags)
+                self._note_resolved(info, arena.births == births)
                 info.row = row
                 info.meta = arena.meta[row]
                 info.arena = arena
+                self._birth_ns += time.perf_counter_ns() - t_birth
             else:
                 arena.touched[row] = True
             lut[uid] = row
@@ -882,18 +904,22 @@ class NativeIngest:
     def _drain_apply(self, clear_intern: bool = False) -> DrainBatch:
         if self.engine._closed:
             return DrainBatch.void()
+        t_drain = time.perf_counter_ns()
         batch = self.engine.drain(clear_intern)
+        clear_ns = time.perf_counter_ns() - t_drain
         if batch.malformed:
             self.malformed += batch.malformed
         if batch.too_long:
             self.too_long += batch.too_long
+        agg = self.agg
         if not batch.empty:
-            agg = self.agg
             t_wait = time.perf_counter_ns()
             with agg.lock:
                 t_held = time.perf_counter_ns()
                 for nk in batch.new_keys:
                     self._register(nk)
+                self._birth_ns = (time.perf_counter_ns() - t_held
+                                  if batch.new_keys else 0)
                 agg.processed += batch.processed
                 if len(batch.c_ids):
                     rows = self._rows_for(agg.counters, batch.c_ids)
@@ -925,7 +951,14 @@ class NativeIngest:
                     agg.sets.stage_hash_batch(rows, batch.s_hashes)
                 # interval ledger: this fold belongs to the interval the
                 # next snapshot closes (last statement under the lock)
-                agg.ledger_fold(batch.processed, t_wait, t_held)
+                agg.ledger_fold(batch.processed, t_wait, t_held,
+                                self._birth_ns)
+        if clear_intern:
+            # the engine's table is empty now: every identity that
+            # comes again registers again, and the interval says so
+            self.intern_clears += 1
+            with agg.lock:
+                agg.ledger_intern_clear(clear_ns)
         return batch
 
     def _apply_cube_rollups(self, agg, cubes, batch) -> None:
@@ -972,7 +1005,8 @@ class NativeIngest:
             lines, malformed, packets, too_long = self.engine.totals()
             return {"lines": lines, "malformed": malformed,
                     "packets": packets, "too_long": too_long,
-                    "intern_count": self.engine.intern_count()}
+                    "intern_count": self.engine.intern_count(),
+                    "intern_clears": self.intern_clears}
 
     def stage_stats(self) -> Optional[dict]:
         """Per-stage counters for /debug/vars, under the drain lock so a
