@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from veneur_tpu.parallel import serving
+from veneur_tpu.samplers import metric_key
 from veneur_tpu.samplers.metric_key import MetricKey, MetricScope
 from veneur_tpu.sketches import hll as hll_mod
 from veneur_tpu.sketches import tdigest as td
@@ -286,9 +287,17 @@ class _ArenaBase:
         self.grows = 0
         self.lock = threading.Lock()
         # incremental fingerprints of the key dictionary: XOR-folds of
-        # fnv1a per live mapping (XOR is its own inverse, so register/GC
-        # keep them O(1)).  keyset_checksum covers the keys alone;
-        # key_checksum additionally binds each key's row.  Multi-
+        # fnv1a per live mapping.  keyset_checksum covers the keys alone
+        # (fnv1a_64 of identity_string); key_checksum additionally binds
+        # each key's row (the same hash continued over "\0<row>").  A
+        # row's pair is hashed ONCE, at its birth (row_for,
+        # restore_state), and kept in fp_col[row] for as long as the row
+        # is live: XOR is its own inverse and neither the key nor the
+        # row changes in between, so a death folds out exactly what the
+        # birth folded in and hashes nothing (_recycle: one vector XOR
+        # over the freed rows).  A free row holds (0, 0), so each
+        # checksum is the XOR of its whole column.  Process state like
+        # name_hash_col, never checkpointed.  Multi-
         # controller serving gathers both per flush (lockstep contract,
         # parallel/multihost.py): identical key sets with different row
         # assignments — the silent-misalignment case — fail loudly,
@@ -297,17 +306,47 @@ class _ArenaBase:
         # membership analog) differs in BOTH and stays legal
         self.key_checksum = 0
         self.keyset_checksum = 0
+        # [:, 0] folds into keyset_checksum, [:, 1] into key_checksum
+        self.fp_col = np.zeros((capacity, 2), np.uint64)
         # (key_checksum, rendered key-table arrays): the checkpoint
         # writer's memo — a stable key table re-renders nothing
         self._ckpt_render_cache = None
 
-    def _fold_key_fingerprints(self, key: MetricKey, scope: MetricScope,
-                               row: int) -> None:
-        from veneur_tpu.samplers.metric_key import (fnv1a_64,
-                                                    identity_string)
-        base = identity_string(key, scope)
-        self.keyset_checksum ^= fnv1a_64(base)
-        self.key_checksum ^= fnv1a_64(f"{base}\x00{row}")
+    def _fold_in_key(self, key: MetricKey, scope: MetricScope,
+                     row: int) -> None:
+        """A key's birth on `row`: hash its two fingerprints (the one
+        place they are computed), keep them in fp_col and fold them
+        into the checksums."""
+        keys_fp, rows_fp = metric_key.key_fingerprints(
+            metric_key.identity_string(key, scope), row)
+        fp = self.fp_col[row]
+        fp[0] = keys_fp
+        fp[1] = rows_fp
+        self.keyset_checksum ^= keys_fp
+        self.key_checksum ^= rows_fp
+
+    def _recycle(self, rows: np.ndarray) -> None:
+        """Put `rows` (an index array: distinct, live until now, in the
+        order the free list is to take them) back on the free list — the
+        part of a death that has a vector form, shared by the idle GC
+        and release_keys: the rows' stored fingerprints XOR out of the
+        checksums in one reduce, every metadata column is cleared by
+        one indexed write.  What has none (meta[row] = None, which
+        NativeIngest's row binding is revalidated against, and the
+        kdict entry) is the caller's one tight pass."""
+        fp = np.bitwise_xor.reduce(self.fp_col[rows], axis=0)
+        self.keyset_checksum ^= int(fp[0])
+        self.key_checksum ^= int(fp[1])
+        self.fp_col[rows] = 0
+        self.name_col[rows] = None
+        self.tags_col[rows] = None
+        self.name_hash_col[rows] = 0
+        if self.kind_col is not None:
+            self.kind_col[rows] = None
+        self.scope_col[rows] = 0
+        self.idle[rows] = 0
+        self._free.extend(rows.tolist())
+        self.recycled += len(rows)
 
     def _init_mesh_lanes(self, mesh, family: str) -> int:
         """Shared mesh plumbing for device-resident arenas: validate the
@@ -372,6 +411,8 @@ class _ArenaBase:
             [self.tags_col, np.empty(old, object)])
         self.name_hash_col = np.concatenate(
             [self.name_hash_col, np.zeros(old, np.int64)])
+        self.fp_col = np.concatenate(
+            [self.fp_col, np.zeros((old, 2), np.uint64)])
         if self.kind_col is not None:
             self.kind_col = np.concatenate(
                 [self.kind_col, np.empty(old, object)])
@@ -404,7 +445,7 @@ class _ArenaBase:
             if row >= self.hw:
                 self.hw = row + 1
             self.kdict[dk] = row
-            self._fold_key_fingerprints(key, scope, row)
+            self._fold_in_key(key, scope, row)
             self.meta[row] = RowMeta(key=key, tags=tags, scope=scope)
             self.name_col[row] = key.name
             self.tags_col[row] = tags
@@ -454,34 +495,26 @@ class _ArenaBase:
 
     def release_keys(self, dks: list) -> int:
         """Immediately recycle the rows of the given (MetricKey, scope)
-        pairs (cardinality eviction, core/cardinality.py): clear the
-        metadata columns, fold the key fingerprints back out, zero the
-        rows' state in ONE batched reset, and return them to the free
-        list — the eager form of the idle GC in end_interval, for keys a
-        tenant's budget has demoted to the rollup.  Call under the
-        aggregator lock, after the flush snapshot has copied everything
-        it needs.  Returns rows released."""
+        pairs (cardinality eviction, core/cardinality.py): the eager
+        form of the idle GC in end_interval, for keys a tenant's budget
+        has demoted to the rollup, and the same batched free (_recycle:
+        the fingerprints kept from each row's birth fold back out, no
+        key is hashed again), with the touched flags and the rows' state
+        reset on top.  Call under the aggregator lock, after the flush
+        snapshot has copied everything it needs.  Returns rows
+        released."""
         rows: list[int] = []
+        kdict, meta = self.kdict, self.meta
         for dk in dks:
-            row = self.kdict.pop(dk, None)
-            if row is None:
-                continue
-            m = self.meta[row]
-            self.meta[row] = None
-            self.name_col[row] = None
-            self.tags_col[row] = None
-            self.name_hash_col[row] = 0
-            if self.kind_col is not None:
-                self.kind_col[row] = None
-            self.scope_col[row] = 0
-            self.idle[row] = 0
-            self.touched[row] = False
-            self._fold_key_fingerprints(m.key, m.scope, int(row))
-            self._free.append(int(row))
-            rows.append(int(row))
+            row = kdict.pop(dk, None)
+            if row is not None:
+                meta[row] = None
+                rows.append(row)
         if rows:
-            self.reset_rows(np.asarray(rows, np.int64))
-            self.recycled += len(rows)
+            idx = np.asarray(rows, np.int64)
+            self._recycle(idx)
+            self.touched[idx] = False
+            self.reset_rows(idx)
         return len(rows)
 
     # -- crash checkpoint (core/checkpoint.py) -----------------------------
@@ -613,7 +646,7 @@ class _ArenaBase:
             self.scope_col[row] = int(scope)
             self.idle[row] = int(idle)
             self.touched[row] = bool(touched)
-            self._fold_key_fingerprints(key, scope, row)
+            self._fold_in_key(key, scope, row)
             used.add(row)
         self._free = [r for r in range(self.capacity - 1, -1, -1)
                       if r not in used]
@@ -649,7 +682,12 @@ class _ArenaBase:
         """Reset touched state and GC idle rows (after flush), over the
         rows ever handed out ([0, hw)) and not over the capacity: a
         pre-sized arena that holds a handful of keys pays for the
-        handful.  Returns the rows freed (also added to `recycled`)."""
+        handful.  The interval's dead rows are freed in one batch
+        (_recycle): their fingerprints were hashed at their birth and
+        are read back from fp_col, so the cut hashes no key and writes
+        no column row by row; an interval without a death stops at the
+        empty candidate list.  Returns the rows freed (also added to
+        `recycled`)."""
         hw = self.hw
         touched = self.touched[:hw]
         idle = self.idle[:hw]
@@ -663,21 +701,14 @@ class _ArenaBase:
         cand = np.nonzero(idle >= IDLE_GC_INTERVALS)[0]
         dead = (cand[self.name_col[cand] != None]  # noqa: E711
                 if len(cand) else cand)
-        for row in dead:
-            m = self.meta[row]
-            self.meta[row] = None
-            self.name_col[row] = None
-            self.tags_col[row] = None
-            self.name_hash_col[row] = 0
-            if self.kind_col is not None:
-                self.kind_col[row] = None
-            self.scope_col[row] = 0
-            self.idle[row] = 0
-            del self.kdict[(m.key, m.scope)]
-            self._fold_key_fingerprints(m.key, m.scope, int(row))
-            self._free.append(int(row))
+        if len(dead):
+            kdict, meta = self.kdict, self.meta
+            for row in dead.tolist():
+                m = meta[row]
+                meta[row] = None
+                del kdict[(m.key, m.scope)]
+            self._recycle(dead)
         touched[:] = False
-        self.recycled += len(dead)
         return len(dead)
 
 

@@ -56,6 +56,17 @@ def fnv1a_64(s: str, seed: int = 0) -> int:
     return h
 
 
+def key_fingerprints(base: str, row: int) -> tuple[int, int]:
+    """An arena row's two key fingerprints (core/arena.py):
+    (fnv1a_64(base), fnv1a_64(f"{base}\x00{row}")), of the key's identity
+    alone and of the identity bound to its row.  FNV-1a is a running
+    hash, so the second is the first one's state continued over
+    "\x00<row>" and `base` is hashed once: a seed of INIT ^ state makes
+    fnv1a_64 start from `state`."""
+    keys = fnv1a_64(base)
+    return keys, fnv1a_64(f"\x00{row}", _FNV1A_INIT64 ^ keys)
+
+
 def identity_string(key: "MetricKey", scope: "MetricScope") -> str:
     """THE canonical (key, scope) identity encoding — shared by the
     arena key-dictionary fingerprints (core/arena.py) and the
