@@ -29,6 +29,11 @@
 //                     the kernel/seccomp profile permits (runtime-probed)
 //   * drain ABI     — consolidation into contiguous arrays for ctypes
 //   * vn_blast_udp  — sendmmsg packet generator for the ingest benchmark
+//   * dense builds  — the flush's host-side operand from the staged COO:
+//                     vn_fill_dense (the fill alone), vn_build_dense (one
+//                     large operand: map, count, zero, fill) and
+//                     vn_build_tiers (a skewed interval's two operands in
+//                     one pass, zeroing by what the kept buffers hold)
 //
 // Build: g++ -O3 -std=c++17 -shared -fPIC -pthread -o libvningest.so
 //
@@ -2228,6 +2233,171 @@ long long vn_build_dense(const long long* rows, const double* vals,
       size_t at = (size_t)(rid * d_pad + cur[rid]++);
       dv[at] = (float)vals[i];
       if (dw) dw[at] = (float)wts[i];
+    }
+  });
+  return 0;
+}
+
+// BOTH operands of a tiered flush in one call (aggregator._build_tiers
+// -> arena.build_tiers): the long tail (tier 0) and the deep rows
+// (tier 1) of one staged COO, every point read once by the count (its
+// row id) and once by the fill, into operands the aggregator keeps from
+// flush to flush.  Bit-equal to two builds over the tiers' own points
+// by vn_fill_dense / the numpy builder: the same (float) casts, zeros
+// wherever no point lands, and a row's points in ARRIVAL order —
+// vn_build_dense's rule: thread t counts and later fills the t-th
+// contiguous range of points, and its write cursor for a row starts
+// where the earlier ranges' counts for that row end.
+//
+// rows / vals / wts: the staged COO (wts always given: the deep tier is
+//   weighted)
+// touched: int64[nd] arena rows, the snapshot's order
+// deep:   int64[n_deep] positions in `touched` of tier 1's rows, strictly
+//   ascending; tier 1's dense row j is touched[deep[j]], tier 0's are
+//   the others in touched order
+// map:    int32[capacity] scratch: row -> dense row of tier 0, or
+//   u_pad[0] + dense row of tier 1; -1 = untouched
+// cursors: int32[(n_threads + 1) * (u_pad[0] + u_pad[1])] scratch (the
+//   threads' counts, then the rows' totals)
+// dv / dw / depths, u_pad / d_pad: per tier ([2]) the kept
+//   float32[u_pad * d_pad] operands (dw[k] null = tier k in the uniform
+//   form, no weights written) and the int16[u_pad] RECORD of how many
+//   cells of each dense row the last call filled — the uniform form's
+//   depth operand.  The operands must be what that record says (a row's
+//   first depths[r] cells anything, zeros past them; new buffers: all
+//   zeros, record 0): only a row's cells from its new count up to the
+//   recorded one are zeroed, never the whole operand.  dv[0] null =
+//   count only.
+// depth_out: [2] each tier's deepest row's point count.
+// Returns 0 when both tiers were filled and the records updated; -1
+// when nothing was written because no operands were given or a tier's
+// deepest row does not fit its d_pad: the caller makes operands for
+// depth_out and calls again; > 0 the number of BAD ids (a point's row
+// or a touched id outside [0, capacity), a point whose row is not in
+// `touched`, a deep position out of range or out of order, more rows
+// than a tier's u_pad, a d_pad the int16 record cannot hold), nothing
+// written: the caller falls back to two numpy builds, which drop loudly.
+long long vn_build_tiers(const long long* rows, const double* vals,
+                         const double* wts, long long n,
+                         const long long* touched, long long nd,
+                         const long long* deep, long long n_deep,
+                         long long capacity, int* map, int* cursors,
+                         float* const* dv, float* const* dw,
+                         short* const* depths, const long long* u_pad,
+                         const long long* d_pad, int n_threads,
+                         long long* depth_out) {
+  if (n_threads < 1) n_threads = 1;
+  const long long u0 = u_pad[0], u_tot = u_pad[0] + u_pad[1];
+  if (n_deep < 0 || n_deep > nd || n_deep > u_pad[1] || nd - n_deep > u0)
+    return 1;
+  if (d_pad[0] > 32767 || d_pad[1] > 32767) return 1;
+  long long bad = 0;
+  memset(map, 0xff, (size_t)capacity * sizeof(int));
+  {
+    long long k = 0, tail = 0;
+    for (long long i = 0; i < nd; i++) {
+      long long row = touched[i];
+      bool is_deep = k < n_deep && deep[k] == i;
+      if (row < 0 || row >= capacity) bad++;
+      else map[row] = is_deep ? (int)(u0 + k) : (int)tail;
+      if (is_deep) k++;
+      else tail++;
+    }
+    // a position that is negative, past nd or not above the one before
+    // it was never met by the walk
+    bad += n_deep - k;
+  }
+  if (bad) return bad;
+
+  auto parallel = [&](auto&& fn) {
+    if (n_threads == 1) {
+      fn(0);
+      return;
+    }
+    std::vector<std::thread> ts;
+    for (int t = 1; t < n_threads; t++) ts.emplace_back(fn, t);
+    fn(0);
+    for (auto& t : ts) t.join();
+  };
+  auto span = [&](long long total, int t, long long* lo, long long* hi) {
+    long long per = (total + n_threads - 1) / n_threads;
+    *lo = std::min<long long>(total, t * per);
+    *hi = std::min<long long>(total, *lo + per);
+  };
+
+  // 1. count: thread t's point range into its own [u_tot] counts
+  std::atomic<long long> bad_points{0};
+  parallel([&](int t) {
+    int* cnt = cursors + (size_t)t * u_tot;
+    memset(cnt, 0, (size_t)u_tot * sizeof(int));
+    long long lo, hi, local_bad = 0;
+    span(n, t, &lo, &hi);
+    for (long long i = lo; i < hi; i++) {
+      long long row = rows[i];
+      int rid = (row < 0 || row >= capacity) ? -1 : map[row];
+      if (rid < 0) local_bad++;
+      else cnt[rid]++;
+    }
+    if (local_bad) bad_points.fetch_add(local_bad);
+  });
+  if (bad_points.load()) return bad_points.load();
+
+  // 2. per row (few: one thread): counts -> each thread's first write
+  //    position (exclusive prefix over the threads), the row's total,
+  //    each tier's deepest row
+  int* total = cursors + (size_t)n_threads * u_tot;
+  long long deepest[2] = {0, 0};
+  for (long long r = 0; r < u_tot; r++) {
+    int acc = 0;
+    for (int s = 0; s < n_threads; s++) {
+      int* c = cursors + (size_t)s * u_tot + r;
+      int mine = *c;
+      *c = acc;
+      acc += mine;
+    }
+    total[r] = acc;
+    long long* d = &deepest[r >= u0];
+    if (acc > *d) *d = acc;
+  }
+  depth_out[0] = deepest[0];
+  depth_out[1] = deepest[1];
+  if (!dv[0] || deepest[0] > d_pad[0] || deepest[1] > d_pad[1]) return -1;
+
+  // 3. each thread brings the records of its row range up to date —
+  //    zeroing what the last call filled past a row's new count — then
+  //    fills its point range: heads, so no cell is written by two threads
+  parallel([&](int t) {
+    long long lo, hi;
+    span(u_tot, t, &lo, &hi);
+    for (long long r = lo; r < hi; r++) {
+      int k = r >= u0;
+      long long rr = k ? r - u0 : r, d = d_pad[k];
+      long long now = total[r];
+      long long was = std::min<long long>(depths[k][rr], d);
+      if (was > now) {
+        size_t at = (size_t)(rr * d + now);
+        size_t bytes = (size_t)(was - now) * sizeof(float);
+        memset(dv[k] + at, 0, bytes);
+        if (dw[k]) memset(dw[k] + at, 0, bytes);
+      }
+      depths[k][rr] = (short)now;
+    }
+    int* cur = cursors + (size_t)t * u_tot;
+    span(n, t, &lo, &hi);
+    const long long d0 = d_pad[0], d1 = d_pad[1];
+    float *v0 = dv[0], *w0 = dw[0], *v1 = dv[1], *w1 = dw[1];
+    for (long long i = lo; i < hi; i++) {
+      long long rid = map[rows[i]];
+      long long p = cur[rid]++;
+      if (rid < u0) {
+        size_t at = (size_t)(rid * d0 + p);
+        v0[at] = (float)vals[i];
+        if (w0) w0[at] = (float)wts[i];
+      } else {
+        size_t at = (size_t)((rid - u0) * d1 + p);
+        v1[at] = (float)vals[i];
+        if (w1) w1[at] = (float)wts[i];
+      }
     }
   });
   return 0;

@@ -29,7 +29,10 @@
 // the drop accounting and depth clamps hold, and vn_build_dense the
 // same plus ids outside `touched`, operands too shallow or absent
 // (nothing may be written) and, on sound input, an operand that is the
-// same for every thread count.  Phase 4 (SPSC stress)
+// same for every thread count; vn_build_tiers likewise, with deep
+// positions out of order or out of range, and operands left by a deeper
+// interval that must come out as operands filled from zeros do.  Phase 4
+// (SPSC stress)
 // shrinks the staging rings to 2 slots so every handoff wraps and
 // backpressures, runs TWO concurrent drainers against the producers,
 // and checks exact packet conservation — a torn handoff (double-pop,
@@ -97,6 +100,15 @@ long long vn_build_dense(const long long* rows, const double* vals,
                          float* dv, float* dw, short* depths,
                          long long u_pad, long long d_pad,
                          int n_threads, long long* depth_out);
+long long vn_build_tiers(const long long* rows, const double* vals,
+                         const double* wts, long long n,
+                         const long long* touched, long long nd,
+                         const long long* deep, long long n_deep,
+                         long long capacity, int* map, int* cursors,
+                         float* const* dv, float* const* dw,
+                         short* const* depths, const long long* u_pad,
+                         const long long* d_pad, int n_threads,
+                         long long* depth_out);
 int vn_engine_opt(void* ep, const char* key, long long val);
 long long vn_drain_section(void* dp, int which, const void** a,
                            const void** b, const void** c);
@@ -775,6 +787,196 @@ int simd_parity() {
 
 }  // namespace
 
+// vn_build_tiers: two operands from one COO.  Corrupt ids (in rows, in
+// touched, in the deep positions), a tier too shallow or no operands:
+// nothing written, not a cell and not a record.  Sound input over
+// operands that a deeper, wider interval left (the call zeroes only what
+// its record says was filled past a row's new count): the same cells as
+// a fill of zeroed operands, for every thread count, each row's points
+// in arrival order.
+struct TierSet {
+  std::vector<float> v[2], w[2];
+  std::vector<short> rec[2];
+  float* dv[2];
+  float* dw[2];
+  short* depths[2];
+  TierSet(const long long* u_pad, const long long* d_pad, bool uniform) {
+    for (int k = 0; k < 2; k++) {
+      v[k].assign((size_t)(u_pad[k] * d_pad[k]), 0.f);
+      w[k].assign((size_t)(u_pad[k] * d_pad[k]), 0.f);
+      rec[k].assign((size_t)u_pad[k], 0);
+      dv[k] = v[k].data();
+      dw[k] = (k == 0 && uniform) ? nullptr : w[k].data();
+      depths[k] = rec[k].data();
+    }
+  }
+  bool untouched() const {
+    for (int k = 0; k < 2; k++) {
+      for (float x : v[k]) if (x != 0.f) return false;
+      for (float x : w[k]) if (x != 0.f) return false;
+      for (short x : rec[k]) if (x != 0) return false;
+    }
+    return true;
+  }
+};
+
+int build_tiers_fuzz() {
+  const long long n = 4099, cap = 64, nd = 13, n_deep = 3;
+  const long long u_pad[2] = {16, 4}, d_pad[2] = {512, 512};
+  const long long shallow[2] = {8, 512}, none[2] = {0, 0};
+  std::vector<long long> touched(nd), rows(n), deep = {2, 7, 11};
+  std::vector<double> vals(n), wts(n);
+  for (long long i = 0; i < nd; i++) touched[i] = i * 4 + 1;
+  // dense slot of touched position k: tail rows in order, then the deep
+  std::vector<long long> slot(nd), count(nd, 0);
+  {
+    long long t = 0, d = 0;
+    for (long long k = 0; k < nd; k++)
+      slot[k] = (d < n_deep && deep[d] == k) ? u_pad[0] + d++ : t++;
+  }
+  for (long long i = 0; i < n; i++) {
+    long long k = (i * 7 + i / 5) % nd;
+    rows[i] = touched[k];
+    vals[i] = (double)(i + 1);
+    wts[i] = (double)(i % 9 + 1) / 3.0;
+    count[k]++;
+  }
+  // the interval under test: every third point, and none of row 5's
+  std::vector<long long> rows2;
+  std::vector<double> vals2, wts2;
+  std::vector<long long> count2(nd, 0);
+  for (long long i = 0; i < n; i += 3) {
+    long long k = (i * 7 + i / 5) % nd;
+    if (k == 5) continue;
+    rows2.push_back(rows[i]);
+    vals2.push_back(vals[i]);
+    wts2.push_back(wts[i]);
+    count2[k]++;
+  }
+  const long long n2 = (long long)rows2.size();
+  float* no_ops[2] = {nullptr, nullptr};
+  short* no_rec[2] = {nullptr, nullptr};
+  for (bool uniform : {false, true}) {
+    std::vector<float> want_v[2], want_w[2];
+    for (int threads : {1, 3, 4}) {
+      std::vector<int> map((size_t)cap, 12345);
+      std::vector<int> cursors((size_t)((threads + 1) * 20), -7);
+      long long depth[2] = {-1, -1};
+      TierSet ops(u_pad, d_pad, uniform);
+      long long st = vn_build_tiers(
+          rows.data(), vals.data(), wts.data(), n, touched.data(), nd,
+          deep.data(), n_deep, cap, map.data(), cursors.data(), no_ops,
+          no_ops, no_rec, u_pad, none, threads, depth);
+      long long deepest[2] = {0, 0};
+      for (long long k = 0; k < nd; k++) {
+        long long* d = &deepest[slot[k] >= u_pad[0]];
+        if (count[k] > *d) *d = count[k];
+      }
+      TierSet thin(u_pad, shallow, uniform);
+      long long st2 = vn_build_tiers(
+          rows.data(), vals.data(), wts.data(), n, touched.data(), nd,
+          deep.data(), n_deep, cap, map.data(), cursors.data(), thin.dv,
+          thin.dw, thin.depths, u_pad, shallow, threads, depth);
+      if (st != -1 || st2 != -1 || depth[0] != deepest[0] ||
+          depth[1] != deepest[1] || !thin.untouched()) {
+        fprintf(stderr, "tiers fuzz: count-only / shallow call wrong "
+                        "(threads=%d, %lld %lld)\n", threads, st, st2);
+        return 1;
+      }
+      // corrupt ids: refused, nothing written
+      for (long long bad : {-5LL, cap + 3, 2LL /* not touched */}) {
+        long long keep = rows[n / 2];
+        rows[n / 2] = bad;
+        st = vn_build_tiers(
+            rows.data(), vals.data(), wts.data(), n, touched.data(), nd,
+            deep.data(), n_deep, cap, map.data(), cursors.data(), ops.dv,
+            ops.dw, ops.depths, u_pad, d_pad, threads, depth);
+        rows[n / 2] = keep;
+        if (st <= 0 || !ops.untouched()) {
+          fprintf(stderr, "tiers fuzz: corrupt row id %lld not refused "
+                          "(threads=%d)\n", bad, threads);
+          return 1;
+        }
+      }
+      for (int which = 0; which < 4; which++) {
+        std::vector<long long> t2 = touched, d2 = deep;
+        if (which == 0) t2[3] = cap;
+        if (which == 1) std::swap(d2[0], d2[1]);
+        if (which == 2) d2[2] = nd;
+        if (which == 3) d2[0] = -1;
+        st = vn_build_tiers(
+            rows.data(), vals.data(), wts.data(), n, t2.data(), nd,
+            d2.data(), n_deep, cap, map.data(), cursors.data(), ops.dv,
+            ops.dw, ops.depths, u_pad, d_pad, threads, depth);
+        if (st <= 0 || !ops.untouched()) {
+          fprintf(stderr, "tiers fuzz: corrupt touched / deep (%d) not "
+                          "refused\n", which);
+          return 1;
+        }
+      }
+      // the deeper, wider interval first; then the one under test into
+      // what it left, against the same from zeros
+      st = vn_build_tiers(
+          rows.data(), vals.data(), wts.data(), n, touched.data(), nd,
+          deep.data(), n_deep, cap, map.data(), cursors.data(), ops.dv,
+          ops.dw, ops.depths, u_pad, d_pad, threads, depth);
+      st2 = vn_build_tiers(
+          rows2.data(), vals2.data(), wts2.data(), n2, touched.data(), nd,
+          deep.data(), n_deep, cap, map.data(), cursors.data(), ops.dv,
+          ops.dw, ops.depths, u_pad, d_pad, threads, depth);
+      TierSet clean(u_pad, d_pad, uniform);
+      long long st3 = vn_build_tiers(
+          rows2.data(), vals2.data(), wts2.data(), n2, touched.data(), nd,
+          deep.data(), n_deep, cap, map.data(), cursors.data(), clean.dv,
+          clean.dw, clean.depths, u_pad, d_pad, threads, depth);
+      if (st != 0 || st2 != 0 || st3 != 0) {
+        fprintf(stderr, "tiers fuzz: sound input not filled "
+                        "(threads=%d)\n", threads);
+        return 1;
+      }
+      for (int k = 0; k < 2; k++) {
+        if (ops.v[k] != clean.v[k] || ops.rec[k] != clean.rec[k] ||
+            (ops.dw[k] && ops.w[k] != clean.w[k])) {
+          fprintf(stderr, "tiers fuzz: tier %d keeps a stale cell "
+                          "(threads=%d)\n", k, threads);
+          return 1;
+        }
+      }
+      for (long long k = 0; k < nd; k++) {
+        int tier = slot[k] >= u_pad[0];
+        long long r = tier ? slot[k] - u_pad[0] : slot[k];
+        if (ops.rec[tier][(size_t)r] != count2[k]) {
+          fprintf(stderr, "tiers fuzz: record of row %lld\n", k);
+          return 1;
+        }
+        for (long long c = 0; c < d_pad[tier]; c++) {
+          float v = ops.v[tier][(size_t)(r * d_pad[tier] + c)];
+          // arrival order: a row's values ascend; its tail is zero
+          if (c >= count2[k]
+                  ? v != 0.f
+                  : (c > 0 &&
+                     v <= ops.v[tier][(size_t)(r * d_pad[tier] + c - 1)])) {
+            fprintf(stderr, "tiers fuzz: cell [%lld, %lld] of tier %d "
+                            "(threads=%d)\n", r, c, tier, threads);
+            return 1;
+          }
+        }
+      }
+      for (int k = 0; k < 2; k++) {
+        if (want_v[k].empty()) {
+          want_v[k] = ops.v[k];
+          want_w[k] = ops.w[k];
+        } else if (ops.v[k] != want_v[k] || ops.w[k] != want_w[k]) {
+          fprintf(stderr, "tiers fuzz: %d threads built another "
+                          "operand\n", threads);
+          return 1;
+        }
+      }
+    }
+  }
+  return 0;
+}
+
 int main() {
   void* e = vn_engine_new(4096, "env:tsan");
   const int kIngestThreads = env_int("VN_SAN_THREADS", 4);
@@ -857,6 +1059,7 @@ int main() {
   rc |= wire_fuzz();
   rc |= fill_dense_fuzz();
   rc |= build_dense_fuzz();
+  rc |= build_tiers_fuzz();
   rc |= spsc_stress();
   rc |= simd_parity();
   if (rc == 0)

@@ -541,8 +541,10 @@ class MetricAggregator:
         # ... and so is the hot-key compress a drain tick launches
         self.digests.compile_guard = self.sets.compile_guard
         # host operands a tiered flush keeps from one build to the next
-        # (_build_tiers: the long tail's, the deep tier's), and the
-        # device results of the launches that last read them
+        # (_build_tiers: the long tail's dict, the deep tier's — with
+        # the one-pass build's scratch and its record of what it filled,
+        # DigestArena.build_tiers), and the device results of the
+        # launches that last read them
         self._tier_operands: tuple = ({}, {})
         self._tier_inflight: list = []
         self._uts_m = self.unique_ts.m if self.unique_ts is not None \
@@ -2079,10 +2081,18 @@ class MetricAggregator:
                          first_dev=None if donate else first_dev)
             seg["layout_s"] = layout_s
             seg["dispatch_s"] = dispatch_s
-            if len(builds) > 1:
+            tiered = len(builds) > 1
+            if tiered:
+                # the tiers' operands are this aggregator's, not the
+                # arena's: their readers wait here, and where a
+                # forwarding tier's export keeps one on the device its
+                # dict lets go of it
                 self._tier_inflight = [b["outs"] for b in builds]
+                for b, keep in zip(builds, self._tier_operands):
+                    if b["first_dev"]:
+                        self.digests.lend_dense(b["first_dev"], keep)
             self._account_build(
-                seg, [b["outs"] for b in builds],
+                seg, None if tiered else [b["outs"] for b in builds],
                 [b["first_dev"] for b in builds if b["first_dev"]])
             pend.update(tiers=builds, t_dispatch0=t_dispatch0)
             return pend
@@ -2196,15 +2206,19 @@ class MetricAggregator:
                 dense_dev=None if donate else (dvd, dwd))
             return pend
 
-    def _account_build(self, seg: dict, outs: list, kept_dev: list):
+    def _account_build(self, seg: dict, outs: Optional[list],
+                       kept_dev: list):
         """After the digest launch(es): what the build did, onto the
         timeline row, and — where the arena's kept operands served it —
         what DigestArena.hold_dense asks of a caller: the launches'
-        results, and the device operands a forwarding tier keeps."""
+        results, and the device operands a forwarding tier keeps.
+        outs None: a tiered flush, whose one pass filled operands the
+        arena does not own (it is told of no reader, and its own kept
+        buffers stay as they were)."""
         stats = self.digests.take_build_stats()
         seg["build_onepass"] = stats["onepass"]
         seg["build_fresh_bytes"] = stats["fresh_bytes"]
-        if stats["onepass"]:
+        if stats["onepass"] and outs is not None:
             self.digests.hold_dense(outs)
             for dev in kept_dev:
                 self.digests.lend_dense(dev)
@@ -2216,46 +2230,62 @@ class MetricAggregator:
         (`DigestArena.deep_rows`).  Then two: the long tail in the form
         its weights allow at its own depth, and the deep rows weighted,
         DENSE_DEPTH_CAP deep, at a pow2 row bucket of at least
-        DEEP_TIER_MIN_ROWS.  Each tier: `sel` (its rows' positions in
-        the part; None = all), `deep`, `uniform`, `dense` (build_dense's
-        triple)."""
+        DEEP_TIER_MIN_ROWS — both from one native pass over the staged
+        points into the operands `_tier_operands` keeps
+        (`DigestArena.build_tiers`; the row says `build_onepass` 1).
+        Where that call declines (no native engine, a dtype other than
+        float32, corrupt staging), from two `build_dense(keep=)` calls
+        over each tier's own copy of the points: the plain form the one
+        pass is tested against, bit for bit.  Each tier: `sel` (its
+        rows' positions in the part; None = all), `deep`, `uniform`,
+        `dense` (build_dense's triple)."""
         d = self.digests
         rows, vals, wts = staged = dpart["staged"]
         touched, deep = dpart["rows"], dpart.get("deep")
-        if deep is not None and len(rows) and not (
-                0 <= int(rows.min()) and int(rows.max()) < d.capacity):
-            deep = None     # corrupt staging: build_dense drops it, loudly
         if deep is None:
-            return [{"sel": None, "deep": False,
-                     "uniform": dpart["uniform"],
-                     "dense": d.build_dense(
-                         staged, touched, dpart["d_min"], dpart["d_max"],
-                         uniform=dpart["uniform"])}]
+            return [self._single_tier(dpart)]
         # a served node's flushes are serial and this returns at once; a
         # caller that dispatches a second flush before it fetched the
         # first's results waits here for the uploads it would overwrite
         jax.block_until_ready(self._tier_inflight)
-        is_deep = np.zeros(d.capacity, bool)
-        is_deep[touched[deep]] = True
-        in_deep = is_deep[rows]
-        tail = np.nonzero(~is_deep[touched])[0]
-        tiers = []
-        for sel, mine, uniform, floors, keep in (
-                (tail, ~in_deep, dpart["shallow_uniform"], {},
-                 self._tier_operands[0]),
-                (deep, in_deep, False,
-                 {"u_floor": arena_mod.DEEP_TIER_MIN_ROWS,
-                  "d_floor": arena_mod.DENSE_DEPTH_CAP},
-                 self._tier_operands[1])):
-            # the tiers keep their operands from flush to flush (the
-            # wait above: the last flush's uploads have been consumed)
-            tiers.append({
-                "sel": sel, "deep": bool(floors), "uniform": uniform,
-                "dense": d.build_dense(
+        in_tail = np.ones(len(touched), bool)
+        in_tail[deep] = False
+        sels = (np.nonzero(in_tail)[0], deep)
+        forms = (dpart["shallow_uniform"], False)
+        built = d.build_tiers(staged, touched, sels, dpart["d_min"],
+                              dpart["d_max"], forms[0],
+                              self._tier_operands)
+        if built is None:
+            if len(rows) and not (0 <= int(rows.min())
+                                  and int(rows.max()) < d.capacity):
+                # corrupt staging: build_dense drops it, loudly
+                return [self._single_tier(dpart)]
+            is_deep = np.zeros(d.capacity, bool)
+            is_deep[touched[deep]] = True
+            in_deep = is_deep[rows]
+            built = []
+            for sel, mine, uniform, floors, keep in zip(
+                    sels, (~in_deep, in_deep), forms,
+                    ({}, {"u_floor": arena_mod.DEEP_TIER_MIN_ROWS,
+                          "d_floor": arena_mod.DENSE_DEPTH_CAP}),
+                    self._tier_operands):
+                # (the whole operand is zeroed and filled: what a one
+                # pass recorded of its content no longer holds)
+                keep.pop("filled", None)
+                built.append(d.build_dense(
                     (rows[mine], vals[mine], wts[mine]), touched[sel],
                     dpart["d_min"][sel], dpart["d_max"][sel],
-                    uniform=uniform, keep=keep, **floors)})
-        return tiers
+                    uniform=uniform, keep=keep, **floors))
+        return [{"sel": sel, "deep": k == 1, "uniform": uniform,
+                 "dense": dense}
+                for k, (sel, uniform, dense) in enumerate(
+                    zip(sels, forms, built))]
+
+    def _single_tier(self, dpart: dict) -> dict:
+        return {"sel": None, "deep": False, "uniform": dpart["uniform"],
+                "dense": self.digests.build_dense(
+                    dpart["staged"], dpart["rows"], dpart["d_min"],
+                    dpart["d_max"], uniform=dpart["uniform"])}
 
     def _put_tier(self, dv, dw, minmax, uniform: bool, sl: slice):
         """Device-put rows `sl` of one host-built tier."""

@@ -61,8 +61,11 @@ _NATIVE_FILL_MIN = 65536
 # the staged points (a gather, a bincount, one scan per fill thread) are
 # most of it: 77 of a meshed global's 237 ms from tick to sink at
 # [131072, 32] x 2 = 32 MiB.  16 MiB stands between that and the largest
-# operands that build the other way: a skewed node's 8 MiB tail (which
-# keeps its own buffers), a fleet's [2048, 256] x 2 = 4 MiB.
+# single operand that builds the other way: a fleet's [2048, 256] x 2 =
+# 4 MiB.  (A skewed node's tiers — an 8 MiB tail and a 1 MiB x 2 deep
+# tier — are neither: both come from one native pass of their own into
+# buffers the aggregator keeps, build_tiers / vn_build_tiers, and only
+# that pass's fallback builds them through the code below.)
 _ONEPASS_MIN_BYTES = 16 << 20
 # The hot-key lane's compress tile: EVERY pre-reduction launch
 # (DigestArena._pre_reduce -> serving.partial_digests) has this one
@@ -1461,9 +1464,11 @@ class DigestArena(_ArenaBase):
     past `DEEP_TIER_THRESHOLD` points (or re-staged with weights), the
     aggregator builds those apart — weighted, DENSE_DEPTH_CAP deep, a
     few hundred rows — and the long tail at its own depth in the form
-    its weights allow (`_dispatch_flush`); `build_dense` itself is the
-    single-operand build either tier is made with.  An interval with no
-    deep key, or one whose split would not halve the operand, builds
+    its weights allow (`_dispatch_flush`), both from one native pass
+    over the staged points into operands the aggregator keeps
+    (`build_tiers`); `build_dense` is the single-operand build, and what
+    either tier is made with where that pass declines.  An interval with
+    no deep key, or one whose split would not halve the operand, builds
     the one `[K_t, D]` matrix.
 
     With a mesh, the dense matrix shards keys over 'shard' and depth over
@@ -2246,14 +2251,16 @@ class DigestArena(_ArenaBase):
         after makes its own buffers."""
         self._dense_readers = readers
 
-    def lend_dense(self, dev_operands) -> None:
+    def lend_dense(self, dev_operands, keep: Optional[dict] = None) -> None:
         """Device arrays of the last build's operands that outlive their
         launch (a forwarding tier keeps them for its digest export).
         Where device_put aliased an aligned host buffer instead of
         copying it — the CPU backend's may — the array IS the kept
         buffer: the arena lets go of it and the next build makes its
-        own."""
-        keep = self._dense_keep
+        own.  keep: the caller's dict where the operands were its own
+        (a tier's, build_tiers)."""
+        if keep is None:
+            keep = self._dense_keep
         for arr in dev_operands:
             for shard in arr.addressable_shards:
                 if shard.device.platform != "cpu":
@@ -2341,6 +2348,116 @@ class DigestArena(_ArenaBase):
         minmax[0, :nd] = d_min_t
         minmax[1, :nd] = d_max_t
         return dv, dw, minmax
+
+    def _tier_buffers(self, keep: dict, uniform: bool, u_pad: int,
+                      d_pad: int) -> tuple:
+        """One tier's (dv, dw, depths, u_pad, d_pad) for build_tiers out
+        of the caller's dict: the kept buffers as they are where
+        `filled` says they are what `depths` records (each row's first
+        depths[r] cells filled, zeros past them) and their shapes still
+        fit; else all of them zeroed, made anew where they do not fit.
+        d_pad 0: none (the native call only counts)."""
+        if not d_pad:
+            return None, None, None, u_pad, 0
+        wanted = [("dv", (u_pad, d_pad), np.float32),
+                  ("depths", (u_pad,), np.int16)]
+        if uniform:
+            # (what a weighted interval left would not follow the record)
+            keep.pop("dw", None)
+        else:
+            wanted.append(("dw", (u_pad, d_pad), np.float32))
+        stale = not keep.get("filled") or any(
+            name not in keep or keep[name].shape != shape
+            or keep[name].dtype != dtype for name, shape, dtype in wanted)
+        bufs = {name: self._operand(keep, name, shape, dtype, zero=stale)
+                for name, shape, dtype in wanted}
+        keep["filled"] = True
+        return bufs["dv"], bufs.get("dw"), bufs["depths"], u_pad, d_pad
+
+    def build_tiers(self, staged, touched: np.ndarray, sels,
+                    d_min_t: np.ndarray, d_max_t: np.ndarray,
+                    shallow_uniform: bool, keeps):
+        """Both operands of a tiered flush in ONE native call
+        (vn_build_tiers): a row -> (tier, dense row) map, each row's
+        count from the first read of the points, the fill from the
+        second, into the buffers the caller keeps in `keeps` (the long
+        tail's dict, the deep rows') — and no cell zeroed but those the
+        last build filled past a row's new count (`depths`, the uniform
+        form's operand, is that record for either form; `filled` in a
+        dict says its buffers follow it).  sels: the tail's and the deep
+        rows' positions in `touched`, each ascending, together all of
+        them.  Returns build_dense's triple for each tier — the tail in
+        the form `shallow_uniform` allows at its own depth, the deep
+        rows weighted, DENSE_DEPTH_CAP deep, a pow2 bucket of at least
+        DEEP_TIER_MIN_ROWS — bit-equal to two build_dense(keep=) calls
+        over the tiers' own points, and the caller's only until its
+        next build.  None, and nothing built, where that cannot be
+        promised: no native engine, a staging or eval dtype other than
+        float32, a staged id out of range or not in `touched`; the
+        caller's two numpy builds then drop loudly."""
+        if (self.eval_dtype != np.float32
+                or self.stage_dtype != np.float32):
+            return None
+        try:
+            from veneur_tpu import ingest as ingest_mod
+            ingest_mod.load_library()
+        except Exception:
+            return None
+        rows, vals, wts = staged
+        rows = np.ascontiguousarray(rows, np.int64)
+        vals = np.ascontiguousarray(vals, np.float64)
+        wts = np.ascontiguousarray(wts, np.float64)
+        touched = np.ascontiguousarray(touched, np.int64)
+        deep = np.ascontiguousarray(sels[1], np.int64)
+        forms = (bool(shallow_uniform), False)
+        floors = (0, DENSE_DEPTH_CAP)
+        u_pads = tuple(
+            self.n_shards * self.dense_block_per_shard(max(len(sel), floor))
+            for sel, floor in zip(sels, (0, DEEP_TIER_MIN_ROWS)))
+        row_map = self._operand(keeps[0], "row_map", (self.capacity,),
+                                np.int32, zero=False)
+        cursors = self._operand(
+            keeps[0], "cursors",
+            ((ingest_mod.BUILD_DENSE_THREADS + 1) * sum(u_pads),),
+            np.int32, zero=False)
+
+        def attempt(d_pads):
+            """The call at these depths (the tail's 0: count only)."""
+            if not d_pads[0]:
+                d_pads = (0, 0)
+            tiers = [self._tier_buffers(*tier)
+                     for tier in zip(keeps, forms, u_pads, d_pads)]
+            status, depths = ingest_mod.build_tiers(
+                rows, vals, wts, touched, deep, row_map, cursors, tiers)
+            want = tuple(self.dense_depth(max(depth, floor, 1))
+                         for depth, floor in zip(depths, floors))
+            return status, want, tiers
+
+        # the kept shapes first (the steady case: one call), then, where
+        # a tier's deepest row asks for another depth, that one
+        d_pads = tuple(
+            keep["dv"].shape[1] if "dv" in keep
+            and keep["dv"].shape[0] == u_pad else floor
+            for keep, u_pad, floor in zip(keeps, u_pads, floors))
+        status, want, tiers = attempt(d_pads)
+        if status <= 0 and want != d_pads:
+            status, want, tiers = attempt(want)
+        if status != 0:
+            return None
+        self._build_stats["onepass"] = 1
+        built = []
+        for (dv, dw, depths, u_pad, _d), uniform, sel, keep in zip(
+                tiers, forms, sels, keeps):
+            if uniform:
+                built.append((dv, depths, None))
+                continue
+            minmax = self._operand(keep, "minmax", (2, u_pad),
+                                   self.eval_dtype, zero=False)
+            minmax[0, :len(sel)] = d_min_t[sel]
+            minmax[1, :len(sel)] = d_max_t[sel]
+            minmax[:, len(sel):] = 0
+            built.append((dv, dw, minmax))
+        return built
 
     def build_dense(self, staged, touched: np.ndarray,
                     d_min_t: np.ndarray, d_max_t: np.ndarray,

@@ -142,6 +142,17 @@ def load_library():
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
             ctypes.c_longlong, ctypes.c_longlong, ctypes.c_int,
             ctypes.POINTER(ctypes.c_longlong)]
+        lib.vn_build_tiers.restype = ctypes.c_longlong
+        lib.vn_build_tiers.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+            ctypes.c_longlong, ctypes.c_void_p, ctypes.c_longlong,
+            ctypes.c_void_p, ctypes.c_longlong, ctypes.c_longlong,
+            ctypes.c_void_p, ctypes.c_void_p,
+            ctypes.POINTER(ctypes.c_void_p), ctypes.POINTER(ctypes.c_void_p),
+            ctypes.POINTER(ctypes.c_void_p),
+            ctypes.POINTER(ctypes.c_longlong),
+            ctypes.POINTER(ctypes.c_longlong), ctypes.c_int,
+            ctypes.POINTER(ctypes.c_longlong)]
         lib.vn_route.restype = ctypes.c_void_p
         lib.vn_route.argtypes = [
             ctypes.c_char_p, ctypes.c_longlong,
@@ -376,6 +387,66 @@ def build_dense(rows, vals, wts, touched, row_map, cursors,
         _ptr(dv), _ptr(dw), _ptr(depths), u_pad, d_pad,
         BUILD_DENSE_THREADS, ctypes.byref(depth))
     return int(status), int(depth.value)
+
+
+def build_tiers(rows, vals, wts, touched, deep, row_map, cursors,
+                tiers) -> tuple[int, tuple[int, int]]:
+    """Both operands of a tiered flush in one native call
+    (vn_build_tiers in ingest_engine.cpp): map, count, zero what the
+    last call filled past a row's new count, and fill, straight from the
+    staged COO into the caller's kept operands.  rows / touched / deep
+    int64 (deep: the deep tier's positions in touched, ascending), vals
+    / wts float64, row_map int32 [capacity] and cursors int32
+    [(BUILD_DENSE_THREADS + 1) * (the tiers' u_pad summed)] scratch;
+    tiers: the long tail's and the deep rows' (dv, dw, depths, u_pad,
+    d_pad) — dv / dw float32 [u_pad, d_pad] (dw None = the uniform form;
+    the tail's dv None = count only), depths int16 [u_pad], the record
+    of what the last call filled, which the operands must match; all
+    C-contiguous.  Returns (status, each tier's deepest row's count):
+    0 filled; -1 nothing written (no operands, or a tier's deepest row
+    does not fit its d_pad); > 0 that many ids out of range, out of
+    order or not in `touched`, nothing written."""
+    import numpy as np
+
+    lib = load_library()
+    u_tot = sum(t[3] for t in tiers)
+    checks = [(rows, np.int64, len(rows)), (vals, np.float64, len(rows)),
+              (wts, np.float64, len(rows)),
+              (touched, np.int64, len(touched)),
+              (deep, np.int64, len(deep)),
+              (row_map, np.int32, len(row_map)),
+              (cursors, np.int32, (BUILD_DENSE_THREADS + 1) * u_tot)]
+    fill = tiers[0][0] is not None
+    for dv, dw, depths, u_pad, d_pad in tiers:
+        checks += [(dv, np.float32, u_pad * d_pad),
+                   (dw, np.float32, u_pad * d_pad),
+                   (depths, np.int16, u_pad)]
+        if fill and (dv is None or depths is None):
+            raise ValueError("build_tiers: a fill needs every tier's "
+                             "operand and its record")
+    for a, dtype, size in checks:
+        if a is not None and not (a.dtype == dtype and a.size == size
+                                  and a.flags.c_contiguous):
+            raise ValueError("build_tiers: operand of the wrong dtype, "
+                             "size or layout")
+    if wts is None:
+        raise ValueError("build_tiers: the deep tier needs the weights")
+
+    def pointers(i):
+        return (ctypes.c_void_p * 2)(*(
+            t[i].ctypes.data if fill and t[i] is not None else None
+            for t in tiers))
+
+    def sizes(i):
+        return (ctypes.c_longlong * 2)(*(t[i] for t in tiers))
+
+    depth = (ctypes.c_longlong * 2)(0, 0)
+    status = lib.vn_build_tiers(
+        _ptr(rows), _ptr(vals), _ptr(wts), len(rows), _ptr(touched),
+        len(touched), _ptr(deep), len(deep), len(row_map), _ptr(row_map),
+        _ptr(cursors), pointers(0), pointers(1), pointers(2), sizes(3),
+        sizes(4), BUILD_DENSE_THREADS, depth)
+    return int(status), (int(depth[0]), int(depth[1]))
 
 
 def metro64(data: bytes) -> int:
