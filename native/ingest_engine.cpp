@@ -29,11 +29,10 @@
 //                     the kernel/seccomp profile permits (runtime-probed)
 //   * drain ABI     — consolidation into contiguous arrays for ctypes
 //   * vn_blast_udp  — sendmmsg packet generator for the ingest benchmark
-//   * dense builds  — the flush's host-side operand from the staged COO:
-//                     vn_fill_dense (the fill alone), vn_build_dense (one
-//                     large operand: map, count, zero, fill) and
-//                     vn_build_tiers (a skewed interval's two operands in
-//                     one pass, zeroing by what the kept buffers hold)
+//   * dense build   — the flush's host-side operand(s) from the staged
+//                     COO: vn_build_tiers (one operand, or a skewed
+//                     interval's two, in one pass: map, count, fill,
+//                     zeroing by what the kept buffers hold)
 //
 // Build: g++ -O3 -std=c++17 -shared -fPIC -pthread -o libvningest.so
 //
@@ -2031,226 +2030,20 @@ long long vn_blast_udp(const char* ip, int port, long long n_packets,
   return sent;
 }
 
-// COO -> dense fill for the flush's dense build (the aggregator's
-// host-side hot loop at 1M keys; VERDICT r4 item 4).  Single pass with
-// per-dense-row write cursors; threads partition the DENSE ROW space
-// into disjoint ranges (each scans the whole COO input and fills only
-// its rows), so there are no races and no atomics on the fill path.
-// Within-row ordering is arrival order per thread — quantile evaluation
-// is order-invariant, so any bijection (row, position) is valid.
+// The dense build of a digest flush in one call (DigestArena.build_dense):
+// one operand — a single [U, D] matrix, n_deep 0 and an empty tier 1 —
+// or the two of a skewed interval, the long tail (tier 0) and the deep
+// rows (tier 1), from one staged COO, every point read once by the count
+// (its row id) and once by the fill, into operands the arena keeps from
+// flush to flush.  Bit-equal to the numpy builder over each tier's own
+// points (DigestArena.build_dense_numpy): the same (float) casts, zeros
+// wherever no point lands, and a row's points in ARRIVAL order — thread
+// t counts and later fills the t-th contiguous range of points, and its
+// write cursor for a row starts where the earlier ranges' counts for
+// that row end, so the operands are the same whatever n_threads.
 //
-// rows:  int64[n] arena row ids
-// vals:  float64[n] staged values
-// wts:   float64[n] staged weights, or null for the uniform (all-1) path
-// dense_id: int64[capacity] arena row -> dense row (-1 = untouched)
-// capacity: length of dense_id — rows[i] outside [0, capacity) is a
-//   CORRUPT staged row id and is dropped (never indexed: NumPy-side
-//   negative indices would wrap, and here they would read out of
-//   bounds, so the guard lives on both sides of the FFI)
-// dv/dw: float32[u_pad*d_pad] outputs (dw null on the uniform path)
-// depths: int16[u_pad] per-dense-row fill counts (may be null)
-// Returns the number of DROPPED elements (row id out of bounds,
-// rid < 0, or row overflow past d_pad); the caller falls back to the
-// numpy builder when nonzero.
-long long vn_fill_dense(const long long* rows, const double* vals,
-                        const double* wts, long long n,
-                        const long long* dense_id, long long capacity,
-                        float* dv, float* dw, short* depths,
-                        long long u_pad, long long d_pad,
-                        int n_threads) {
-  std::vector<int> cursor((size_t)u_pad, 0);
-  std::atomic<long long> dropped{0};
-  auto work = [&](long long lo, long long hi) {
-    long long local_dropped = 0;
-    for (long long i = 0; i < n; i++) {
-      long long row = rows[i];
-      if (row < 0 || row >= capacity) {
-        if (lo == 0) local_dropped++;  // count once, thread 0
-        continue;
-      }
-      long long rid = dense_id[row];
-      if (rid < lo || rid >= hi) {
-        if (rid < 0 && lo == 0) local_dropped++;  // count once, thread 0
-        continue;
-      }
-      int p = cursor[(size_t)rid]++;
-      if (p >= d_pad) {
-        local_dropped++;
-        continue;
-      }
-      dv[rid * d_pad + p] = (float)vals[i];
-      if (dw) dw[rid * d_pad + p] = (float)wts[i];
-    }
-    if (local_dropped) dropped.fetch_add(local_dropped);
-  };
-  if (n_threads <= 1) {
-    work(0, u_pad);
-  } else {
-    std::vector<std::thread> ts;
-    long long per = (u_pad + n_threads - 1) / n_threads;
-    for (int t = 0; t < n_threads; t++) {
-      long long lo = t * per;
-      long long hi = std::min<long long>(u_pad, lo + per);
-      if (lo >= hi) break;
-      ts.emplace_back(work, lo, hi);
-    }
-    for (auto& t : ts) t.join();
-  }
-  if (depths) {
-    for (long long r = 0; r < u_pad; r++)
-      depths[r] = (short)std::min<int>(cursor[(size_t)r], (int)d_pad);
-  }
-  return dropped.load();
-}
-
-// The LARGE dense build in one call (arena.build_dense above
-// _ONEPASS_MIN_BYTES): row -> dense-row map, per-row counts, tail
-// zeroing and the fill, straight from the staged COO into operands the
-// arena keeps from flush to flush.  Bit-equal to vn_fill_dense /
-// the numpy builder: the same (float) casts, and a row's points land in
-// ARRIVAL order — thread t counts and later fills the t-th contiguous
-// range of points, and its write cursor for a row starts where the
-// earlier ranges' counts for that row end, so every point is read once
-// by the count (its row id) and once by the fill, whatever n_threads.
-//
-// rows / vals / wts: the staged COO as for vn_fill_dense (wts null =
-//   uniform)
-// touched: int64[nd] arena rows in dense order; a point whose row is not
-//   among them, or an id outside [0, capacity) in either array, is BAD
-// map:    int32[capacity] scratch (row -> dense row, -1 = untouched)
-// cursors: int32[n_threads * u_pad] scratch
-// dv / dw / depths: the kept float32[u_pad * d_pad] (dw null = uniform)
-//   and int16[u_pad] operands, any content: each row's head is
-//   overwritten and its tail past the new depth zeroed here, in the
-//   threads' row ranges.  dv null = count only.
-// depth_out: the deepest row's point count.
-// Returns 0 when the operands were filled; -1 when they were not (none
-// given, or the deepest row does not fit d_pad): the caller makes
-// operands for *depth_out and calls again; > 0 the number of BAD points
-// or touched ids, nothing written: the caller falls back to the numpy
-// builder, which drops loudly.
-long long vn_build_dense(const long long* rows, const double* vals,
-                         const double* wts, long long n,
-                         const long long* touched, long long nd,
-                         long long capacity, int* map, int* cursors,
-                         float* dv, float* dw, short* depths,
-                         long long u_pad, long long d_pad,
-                         int n_threads, long long* depth_out) {
-  if (n_threads < 1) n_threads = 1;
-  if (nd > u_pad) return nd - u_pad;
-  long long bad = 0;
-  memset(map, 0xff, (size_t)capacity * sizeof(int));
-  for (long long i = 0; i < nd; i++) {
-    long long row = touched[i];
-    if (row < 0 || row >= capacity) bad++;
-    else map[row] = (int)i;
-  }
-  if (bad) return bad;
-
-  auto parallel = [&](auto&& fn) {
-    if (n_threads == 1) {
-      fn(0);
-      return;
-    }
-    std::vector<std::thread> ts;
-    for (int t = 0; t < n_threads; t++) ts.emplace_back(fn, t);
-    for (auto& t : ts) t.join();
-  };
-  auto span = [&](long long total, int t, long long* lo, long long* hi) {
-    long long per = (total + n_threads - 1) / n_threads;
-    *lo = std::min<long long>(total, t * per);
-    *hi = std::min<long long>(total, *lo + per);
-  };
-
-  // 1. count: thread t's point range into its own [u_pad] counts
-  std::atomic<long long> bad_points{0};
-  parallel([&](int t) {
-    int* cnt = cursors + (size_t)t * u_pad;
-    memset(cnt, 0, (size_t)u_pad * sizeof(int));
-    long long lo, hi, local_bad = 0;
-    span(n, t, &lo, &hi);
-    for (long long i = lo; i < hi; i++) {
-      long long row = rows[i];
-      int rid = (row < 0 || row >= capacity) ? -1 : map[row];
-      if (rid < 0) local_bad++;
-      else cnt[rid]++;
-    }
-    if (local_bad) bad_points.fetch_add(local_bad);
-  });
-  if (bad_points.load()) return bad_points.load();
-
-  // 2. per row: counts -> each thread's first write position (exclusive
-  //    prefix over the threads), the row's depth, the deepest row
-  std::atomic<long long> deepest{0};
-  const bool fill = dv != nullptr;
-  parallel([&](int t) {
-    long long lo, hi, local_max = 0;
-    span(u_pad, t, &lo, &hi);
-    for (long long r = lo; r < hi; r++) {
-      long long acc = 0;
-      for (int s = 0; s < n_threads; s++) {
-        int* c = cursors + (size_t)s * u_pad + r;
-        int mine = *c;
-        *c = (int)acc;
-        acc += mine;
-      }
-      if (acc > local_max) local_max = acc;
-      if (fill) depths[r] = (short)std::min<long long>(acc, 32767);
-    }
-    long long seen = deepest.load();
-    while (local_max > seen
-           && !deepest.compare_exchange_weak(seen, local_max)) {
-    }
-  });
-  *depth_out = deepest.load();
-  if (!fill || *depth_out > d_pad) return -1;
-
-  // 3. each thread zeroes the tails of its row range past their depth
-  //    (rows >= nd hold nothing: one memset), then fills its point
-  //    range — heads, so no cell is written by two threads
-  parallel([&](int t) {
-    long long lo, hi;
-    span(u_pad, t, &lo, &hi);
-    long long r = lo;
-    for (; r < hi && r < nd; r++) {
-      long long d = depths[r];
-      if (d < d_pad) {
-        size_t at = (size_t)(r * d_pad + d);
-        size_t bytes = (size_t)(d_pad - d) * sizeof(float);
-        memset(dv + at, 0, bytes);
-        if (dw) memset(dw + at, 0, bytes);
-      }
-    }
-    if (r < hi) {
-      size_t bytes = (size_t)((hi - r) * d_pad) * sizeof(float);
-      memset(dv + (size_t)(r * d_pad), 0, bytes);
-      if (dw) memset(dw + (size_t)(r * d_pad), 0, bytes);
-    }
-    int* cur = cursors + (size_t)t * u_pad;
-    span(n, t, &lo, &hi);
-    for (long long i = lo; i < hi; i++) {
-      long long rid = map[rows[i]];
-      size_t at = (size_t)(rid * d_pad + cur[rid]++);
-      dv[at] = (float)vals[i];
-      if (dw) dw[at] = (float)wts[i];
-    }
-  });
-  return 0;
-}
-
-// BOTH operands of a tiered flush in one call (aggregator._build_tiers
-// -> arena.build_tiers): the long tail (tier 0) and the deep rows
-// (tier 1) of one staged COO, every point read once by the count (its
-// row id) and once by the fill, into operands the aggregator keeps from
-// flush to flush.  Bit-equal to two builds over the tiers' own points
-// by vn_fill_dense / the numpy builder: the same (float) casts, zeros
-// wherever no point lands, and a row's points in ARRIVAL order —
-// vn_build_dense's rule: thread t counts and later fills the t-th
-// contiguous range of points, and its write cursor for a row starts
-// where the earlier ranges' counts for that row end.
-//
-// rows / vals / wts: the staged COO (wts always given: the deep tier is
-//   weighted)
+// rows / vals / wts: the staged COO — int64 arena row ids, float64
+//   values and weights (wts null: legal only when no tier is weighted)
 // touched: int64[nd] arena rows, the snapshot's order
 // deep:   int64[n_deep] positions in `touched` of tier 1's rows, strictly
 //   ascending; tier 1's dense row j is touched[deep[j]], tier 0's are
@@ -2259,24 +2052,26 @@ long long vn_build_dense(const long long* rows, const double* vals,
 //   u_pad[0] + dense row of tier 1; -1 = untouched
 // cursors: int32[(n_threads + 1) * (u_pad[0] + u_pad[1])] scratch (the
 //   threads' counts, then the rows' totals)
-// dv / dw / depths, u_pad / d_pad: per tier ([2]) the kept
-//   float32[u_pad * d_pad] operands (dw[k] null = tier k in the uniform
-//   form, no weights written) and the int16[u_pad] RECORD of how many
-//   cells of each dense row the last call filled — the uniform form's
-//   depth operand.  The operands must be what that record says (a row's
-//   first depths[r] cells anything, zeros past them; new buffers: all
-//   zeros, record 0): only a row's cells from its new count up to the
-//   recorded one are zeroed, never the whole operand.  dv[0] null =
-//   count only.
+// dv / dw / depths, u_pad / d_pad: per tier ([2]; an absent tier 1 is
+//   u_pad 0 and nulls) the kept float32[u_pad * d_pad] operands (dw[k]
+//   null = tier k in the uniform form, no weights written) and the
+//   int16[u_pad] RECORD of how many cells of each dense row the last
+//   call filled — the uniform form's depth operand.  The operands must
+//   be what that record says (a row's first depths[r] cells anything,
+//   zeros past them; new buffers: all zeros, record 0): only a row's
+//   cells from its new count up to the recorded one are zeroed, never
+//   the whole operand.  dv[0] null = count only.
 // depth_out: [2] each tier's deepest row's point count.
-// Returns 0 when both tiers were filled and the records updated; -1
+// Returns 0 when every tier was filled and the records updated; -1
 // when nothing was written because no operands were given or a tier's
 // deepest row does not fit its d_pad: the caller makes operands for
 // depth_out and calls again; > 0 the number of BAD ids (a point's row
-// or a touched id outside [0, capacity), a point whose row is not in
-// `touched`, a deep position out of range or out of order, more rows
-// than a tier's u_pad, a d_pad the int16 record cannot hold), nothing
-// written: the caller falls back to two numpy builds, which drop loudly.
+// or a touched id outside [0, capacity) — never indexed: they would
+// read out of bounds —, a point whose row is not in `touched`, a deep
+// position out of range or out of order, more rows than a tier's u_pad,
+// a d_pad the int16 record cannot hold, a weighted tier without
+// weights), nothing written: the caller falls back to the numpy
+// builder, which drops loudly.
 long long vn_build_tiers(const long long* rows, const double* vals,
                          const double* wts, long long n,
                          const long long* touched, long long nd,
@@ -2291,6 +2086,7 @@ long long vn_build_tiers(const long long* rows, const double* vals,
   if (n_deep < 0 || n_deep > nd || n_deep > u_pad[1] || nd - n_deep > u0)
     return 1;
   if (d_pad[0] > 32767 || d_pad[1] > 32767) return 1;
+  if (!wts && dv[0] && (dw[0] || dw[1])) return 1;
   long long bad = 0;
   memset(map, 0xff, (size_t)capacity * sizeof(int));
   {
@@ -2342,9 +2138,9 @@ long long vn_build_tiers(const long long* rows, const double* vals,
   });
   if (bad_points.load()) return bad_points.load();
 
-  // 2. per row (few: one thread): counts -> each thread's first write
-  //    position (exclusive prefix over the threads), the row's total,
-  //    each tier's deepest row
+  // 2. per row (a handful of integer operations each: one thread):
+  //    counts -> each thread's first write position (exclusive prefix
+  //    over the threads), the row's total, each tier's deepest row
   int* total = cursors + (size_t)n_threads * u_tot;
   long long deepest[2] = {0, 0};
   for (long long r = 0; r < u_tot; r++) {

@@ -1,6 +1,6 @@
 // Sanitizer exercise of the ingest engine: the concurrency arm
 // (stage-counter accounting under TSan) plus single-threaded
-// memory/UB arms (protobuf wire fuzz, dense-fill boundary abuse) that
+// memory/UB arms (protobuf wire fuzz, dense-build boundary abuse) that
 // give ASan and UBSan builds something to bite on.
 //
 // Built and run by tests/test_native_sanitizers.py (slow-marked) and
@@ -24,14 +24,13 @@
 // through vn_import_scan's digest descent: decoded values checked
 // intact, then truncated at every cut and mutated at every byte, each
 // surviving scan's columns read back whole.  Phase 3 feeds
-// vn_fill_dense adversarial COO rows (negative ids, ids past the
-// arena capacity, per-row overflow past the dense depth) and checks
-// the drop accounting and depth clamps hold, and vn_build_dense the
-// same plus ids outside `touched`, operands too shallow or absent
-// (nothing may be written) and, on sound input, an operand that is the
-// same for every thread count; vn_build_tiers likewise, with deep
-// positions out of order or out of range, and operands left by a deeper
-// interval that must come out as operands filled from zeros do.  Phase 4
+// vn_build_tiers — as one operand and as two tiers — adversarial COO
+// rows (negative ids, ids past the arena capacity, ids outside
+// `touched`), corrupt touched ids and deep positions (out of order, out
+// of range), operands too shallow or absent and a weighted tier without
+// weights (nothing may be written), and, on sound input, operands left
+// by a deeper interval that must come out as operands filled from zeros
+// do, the same for every thread count.  Phase 4
 // (SPSC stress)
 // shrinks the staging rings to 2 slots so every handoff wraps and
 // backpressures, runs TWO concurrent drainers against the producers,
@@ -87,19 +86,6 @@ void vn_import_scan_digests(void* handle, long long* n_cent,
                             const double** drsum,
                             const double** compression);
 void vn_import_scan_free(void* handle);
-long long vn_fill_dense(const long long* rows, const double* vals,
-                        const double* wts, long long n,
-                        const long long* dense_id, long long capacity,
-                        float* dv, float* dw, short* depths,
-                        long long u_pad, long long d_pad,
-                        int n_threads);
-long long vn_build_dense(const long long* rows, const double* vals,
-                         const double* wts, long long n,
-                         const long long* touched, long long nd,
-                         long long capacity, int* map, int* cursors,
-                         float* dv, float* dw, short* depths,
-                         long long u_pad, long long d_pad,
-                         int n_threads, long long* depth_out);
 long long vn_build_tiers(const long long* rows, const double* vals,
                          const double* wts, long long n,
                          const long long* touched, long long nd,
@@ -371,166 +357,6 @@ int wire_fuzz() {
   return digest_wire_fuzz();
 }
 
-int fill_dense_fuzz() {
-  const long long n = 4096, cap = 64, u_pad = 16, d_pad = 8;
-  std::vector<long long> rows(n);
-  std::vector<double> vals(n), wts(n);
-  std::vector<long long> dense_id(cap, -1);
-  for (int i = 0; i < (int)u_pad; i++) dense_id[i * 4] = i;
-  for (long long i = 0; i < n; i++) {
-    // mix of corrupt (negative / past capacity) and valid arena rows
-    rows[i] = (i % 13 == 0) ? -5
-              : (i % 17 == 0) ? cap + 3
-                              : (i % cap);
-    vals[i] = (double)i;
-    wts[i] = 1.0;
-  }
-  for (int threads : {1, 3}) {
-    std::vector<float> dv((size_t)(u_pad * d_pad), 0.f);
-    std::vector<float> dw((size_t)(u_pad * d_pad), 0.f);
-    std::vector<short> depths((size_t)u_pad, 0);
-    long long dropped = vn_fill_dense(
-        rows.data(), vals.data(), wts.data(), n, dense_id.data(), cap,
-        dv.data(), dw.data(), depths.data(), u_pad, d_pad, threads);
-    if (dropped <= 0) {
-      fprintf(stderr, "fill fuzz: adversarial rows were not dropped "
-                      "(threads=%d)\n", threads);
-      return 1;
-    }
-    for (long long rr = 0; rr < u_pad; rr++) {
-      if (depths[rr] < 0 || depths[rr] > d_pad) {
-        fprintf(stderr, "fill fuzz: depth %d out of [0, %lld] "
-                        "(threads=%d)\n", depths[rr], d_pad, threads);
-        return 1;
-      }
-    }
-    // uniform path: null weights + null depths must also be legal
-    long long d2 = vn_fill_dense(
-        rows.data(), vals.data(), nullptr, n, dense_id.data(), cap,
-        dv.data(), nullptr, nullptr, u_pad, d_pad, threads);
-    if (d2 != dropped) {
-      fprintf(stderr, "fill fuzz: uniform path dropped %lld != %lld\n",
-              d2, dropped);
-      return 1;
-    }
-  }
-  return 0;
-}
-
-// vn_build_dense: the same abuse — corrupt ids in rows and in touched,
-// a row deeper than the operands, no operands at all — must write
-// nothing; a sound input must fill every cell (the buffers start as
-// garbage: the call owns the zeroing) the same for every thread count,
-// each row's points in arrival order.
-int build_dense_fuzz() {
-  const long long n = 4099, cap = 64, nd = 13, u_pad = 16;
-  std::vector<long long> touched(nd), rows(n);
-  std::vector<double> vals(n), wts(n);
-  for (long long i = 0; i < nd; i++) touched[i] = i * 4 + 1;
-  long long deepest = 0;
-  std::vector<long long> count(nd, 0);
-  for (long long i = 0; i < n; i++) {
-    long long k = (i * 7 + i / 5) % nd;
-    rows[i] = touched[k];
-    vals[i] = (double)i;
-    wts[i] = (double)(i % 9) / 3.0;
-    if (++count[k] > deepest) deepest = count[k];
-  }
-  long long d_pad = 512;  // >= deepest (4099 / 13 = 316)
-  std::vector<float> want_v, want_w;
-  for (int threads : {1, 3, 4}) {
-    std::vector<int> map((size_t)cap, 12345);
-    std::vector<int> cursors((size_t)(threads * u_pad), -7);
-    std::vector<float> dv((size_t)(u_pad * d_pad), 9.f);
-    std::vector<float> dw((size_t)(u_pad * d_pad), 9.f);
-    std::vector<short> depths((size_t)u_pad, -1);
-    long long depth = -1;
-    // count only, then operands too shallow: nothing written
-    long long st = vn_build_dense(
-        rows.data(), vals.data(), wts.data(), n, touched.data(), nd, cap,
-        map.data(), cursors.data(), nullptr, nullptr, nullptr, u_pad, 0,
-        threads, &depth);
-    long long st2 = vn_build_dense(
-        rows.data(), vals.data(), wts.data(), n, touched.data(), nd, cap,
-        map.data(), cursors.data(), dv.data(), dw.data(), depths.data(),
-        u_pad, 8, threads, &depth);
-    if (st != -1 || st2 != -1 || depth != deepest || dv[0] != 9.f ||
-        dw[(size_t)(u_pad * 8)] != 9.f) {
-      fprintf(stderr, "build fuzz: count-only / shallow call wrong "
-                      "(threads=%d, %lld %lld depth %lld)\n",
-              threads, st, st2, depth);
-      return 1;
-    }
-    // corrupt ids: refused, nothing written
-    for (long long bad : {-5LL, cap + 3, 2LL /* not touched */}) {
-      long long keep = rows[n / 2];
-      rows[n / 2] = bad;
-      st = vn_build_dense(
-          rows.data(), vals.data(), wts.data(), n, touched.data(), nd,
-          cap, map.data(), cursors.data(), dv.data(), dw.data(),
-          depths.data(), u_pad, d_pad, threads, &depth);
-      rows[n / 2] = keep;
-      if (st <= 0 || dv[0] != 9.f) {
-        fprintf(stderr, "build fuzz: corrupt row id %lld not refused "
-                        "(threads=%d)\n", bad, threads);
-        return 1;
-      }
-    }
-    long long keep = touched[3];
-    touched[3] = cap;
-    st = vn_build_dense(
-        rows.data(), vals.data(), wts.data(), n, touched.data(), nd, cap,
-        map.data(), cursors.data(), dv.data(), dw.data(), depths.data(),
-        u_pad, d_pad, threads, &depth);
-    touched[3] = keep;
-    if (st <= 0 || dv[0] != 9.f) {
-      fprintf(stderr, "build fuzz: corrupt touched id not refused\n");
-      return 1;
-    }
-    // sound: filled, uniform path (null weights) legal too
-    st = vn_build_dense(
-        rows.data(), vals.data(), wts.data(), n, touched.data(), nd, cap,
-        map.data(), cursors.data(), dv.data(), dw.data(), depths.data(),
-        u_pad, d_pad, threads, &depth);
-    std::vector<float> uv((size_t)(u_pad * d_pad), 9.f);
-    st2 = vn_build_dense(
-        rows.data(), vals.data(), nullptr, n, touched.data(), nd, cap,
-        map.data(), cursors.data(), uv.data(), nullptr, depths.data(),
-        u_pad, d_pad, threads, &depth);
-    if (st != 0 || st2 != 0 || uv != dv) {
-      fprintf(stderr, "build fuzz: sound input not filled "
-                      "(threads=%d)\n", threads);
-      return 1;
-    }
-    for (long long r = 0; r < u_pad; r++) {
-      long long d = r < nd ? count[r] : 0;
-      if (depths[r] != d) {
-        fprintf(stderr, "build fuzz: depth of row %lld\n", r);
-        return 1;
-      }
-      for (long long c = 0; c < d_pad; c++) {
-        float v = dv[(size_t)(r * d_pad + c)];
-        // arrival order: a row's values ascend; its tail is zero
-        if (c >= d ? (v != 0.f || dw[(size_t)(r * d_pad + c)] != 0.f)
-                   : (c > 0 && v <= dv[(size_t)(r * d_pad + c - 1)])) {
-          fprintf(stderr, "build fuzz: cell [%lld, %lld] (threads=%d)\n",
-                  r, c, threads);
-          return 1;
-        }
-      }
-    }
-    if (want_v.empty()) {
-      want_v = dv;
-      want_w = dw;
-    } else if (dv != want_v || dw != want_w) {
-      fprintf(stderr, "build fuzz: %d threads built another operand\n",
-              threads);
-      return 1;
-    }
-  }
-  return 0;
-}
-
 int env_int(const char* name, int dflt) {
   const char* v = getenv(name);
   if (v == nullptr || *v == '\0') return dflt;
@@ -787,13 +613,14 @@ int simd_parity() {
 
 }  // namespace
 
-// vn_build_tiers: two operands from one COO.  Corrupt ids (in rows, in
-// touched, in the deep positions), a tier too shallow or no operands:
-// nothing written, not a cell and not a record.  Sound input over
-// operands that a deeper, wider interval left (the call zeroes only what
-// its record says was filled past a row's new count): the same cells as
-// a fill of zeroed operands, for every thread count, each row's points
-// in arrival order.
+// vn_build_tiers: one operand (n_deep 0, an absent tier 1) or two from
+// one COO.  Corrupt ids (in rows, in touched, in the deep positions), a
+// row past its tier's d_pad, no operands, a weighted tier without
+// weights: nothing written, not a cell and not a record.  Sound input
+// over operands that a deeper, wider interval left (the call zeroes only
+// what its record says was filled past a row's new count): the same
+// cells as a fill of zeroed operands, for every thread count, each row's
+// points in arrival order.
 struct TierSet {
   std::vector<float> v[2], w[2];
   std::vector<short> rec[2];
@@ -805,9 +632,9 @@ struct TierSet {
       v[k].assign((size_t)(u_pad[k] * d_pad[k]), 0.f);
       w[k].assign((size_t)(u_pad[k] * d_pad[k]), 0.f);
       rec[k].assign((size_t)u_pad[k], 0);
-      dv[k] = v[k].data();
-      dw[k] = (k == 0 && uniform) ? nullptr : w[k].data();
-      depths[k] = rec[k].data();
+      dv[k] = u_pad[k] ? v[k].data() : nullptr;
+      dw[k] = ((k == 0 && uniform) || !u_pad[k]) ? nullptr : w[k].data();
+      depths[k] = u_pad[k] ? rec[k].data() : nullptr;
     }
   }
   bool untouched() const {
@@ -820,11 +647,14 @@ struct TierSet {
   }
 };
 
-int build_tiers_fuzz() {
-  const long long n = 4099, cap = 64, nd = 13, n_deep = 3;
-  const long long u_pad[2] = {16, 4}, d_pad[2] = {512, 512};
-  const long long shallow[2] = {8, 512}, none[2] = {0, 0};
+int build_tiers_fuzz(const long long n_deep) {
+  const long long n = 4099, cap = 64, nd = 13;
+  const long long has_deep = n_deep ? 1 : 0;
+  const long long u_pad[2] = {16, 4 * has_deep};
+  const long long d_pad[2] = {512, 512 * has_deep};
+  const long long shallow[2] = {8, 512 * has_deep}, none[2] = {0, 0};
   std::vector<long long> touched(nd), rows(n), deep = {2, 7, 11};
+  deep.resize((size_t)n_deep);
   std::vector<double> vals(n), wts(n);
   for (long long i = 0; i < nd; i++) touched[i] = i * 4 + 1;
   // dense slot of touched position k: tail rows in order, then the deep
@@ -857,14 +687,19 @@ int build_tiers_fuzz() {
   float* no_ops[2] = {nullptr, nullptr};
   short* no_rec[2] = {nullptr, nullptr};
   for (bool uniform : {false, true}) {
+    // a build with no weighted tier takes no weights at all
+    const bool weightless = uniform && !n_deep;
+    const double* w1 = weightless ? nullptr : wts.data();
+    const double* w2 = weightless ? nullptr : wts2.data();
     std::vector<float> want_v[2], want_w[2];
-    for (int threads : {1, 3, 4}) {
+    for (int threads : {1, 2, 3, 4}) {
       std::vector<int> map((size_t)cap, 12345);
-      std::vector<int> cursors((size_t)((threads + 1) * 20), -7);
+      std::vector<int> cursors(
+          (size_t)((threads + 1) * (u_pad[0] + u_pad[1])), -7);
       long long depth[2] = {-1, -1};
       TierSet ops(u_pad, d_pad, uniform);
       long long st = vn_build_tiers(
-          rows.data(), vals.data(), wts.data(), n, touched.data(), nd,
+          rows.data(), vals.data(), w1, n, touched.data(), nd,
           deep.data(), n_deep, cap, map.data(), cursors.data(), no_ops,
           no_ops, no_rec, u_pad, none, threads, depth);
       long long deepest[2] = {0, 0};
@@ -872,15 +707,17 @@ int build_tiers_fuzz() {
         long long* d = &deepest[slot[k] >= u_pad[0]];
         if (count[k] > *d) *d = count[k];
       }
+      // a row past its tier's d_pad: the depth comes back, no cell written
       TierSet thin(u_pad, shallow, uniform);
       long long st2 = vn_build_tiers(
-          rows.data(), vals.data(), wts.data(), n, touched.data(), nd,
+          rows.data(), vals.data(), w1, n, touched.data(), nd,
           deep.data(), n_deep, cap, map.data(), cursors.data(), thin.dv,
           thin.dw, thin.depths, u_pad, shallow, threads, depth);
       if (st != -1 || st2 != -1 || depth[0] != deepest[0] ||
           depth[1] != deepest[1] || !thin.untouched()) {
         fprintf(stderr, "tiers fuzz: count-only / shallow call wrong "
-                        "(threads=%d, %lld %lld)\n", threads, st, st2);
+                        "(deep=%lld threads=%d, %lld %lld)\n", n_deep,
+                threads, st, st2);
         return 1;
       }
       // corrupt ids: refused, nothing written
@@ -888,57 +725,79 @@ int build_tiers_fuzz() {
         long long keep = rows[n / 2];
         rows[n / 2] = bad;
         st = vn_build_tiers(
-            rows.data(), vals.data(), wts.data(), n, touched.data(), nd,
+            rows.data(), vals.data(), w1, n, touched.data(), nd,
             deep.data(), n_deep, cap, map.data(), cursors.data(), ops.dv,
             ops.dw, ops.depths, u_pad, d_pad, threads, depth);
         rows[n / 2] = keep;
         if (st <= 0 || !ops.untouched()) {
           fprintf(stderr, "tiers fuzz: corrupt row id %lld not refused "
-                          "(threads=%d)\n", bad, threads);
+                          "(deep=%lld threads=%d)\n", bad, n_deep, threads);
           return 1;
         }
       }
-      for (int which = 0; which < 4; which++) {
+      for (int which = 0; which < 6; which++) {
         std::vector<long long> t2 = touched, d2 = deep;
+        long long nd2 = nd, n_deep2 = n_deep;
+        const double* w = w1;
         if (which == 0) t2[3] = cap;
-        if (which == 1) std::swap(d2[0], d2[1]);
-        if (which == 2) d2[2] = nd;
-        if (which == 3) d2[0] = -1;
+        if (which == 1) t2[3] = -2;
+        if (which == 2) {
+          // a weighted tier and no weights
+          if (weightless) continue;
+          w = nullptr;
+        }
+        if (which >= 3 && !n_deep) {
+          // (no deep positions to spoil: more deep rows than tier 1
+          // holds, more touched rows than tier 0 does)
+          if (which == 3) {
+            d2 = {4};
+            n_deep2 = 1;
+          } else if (which == 4) {
+            t2.resize(17, 3);
+            nd2 = 17;
+          } else {
+            continue;
+          }
+        } else {
+          if (which == 3) std::swap(d2[0], d2[1]);
+          if (which == 4) d2[2] = nd;
+          if (which == 5) d2[0] = -1;
+        }
         st = vn_build_tiers(
-            rows.data(), vals.data(), wts.data(), n, t2.data(), nd,
-            d2.data(), n_deep, cap, map.data(), cursors.data(), ops.dv,
-            ops.dw, ops.depths, u_pad, d_pad, threads, depth);
+            rows.data(), vals.data(), w, n, t2.data(), nd2, d2.data(),
+            n_deep2, cap, map.data(), cursors.data(), ops.dv, ops.dw,
+            ops.depths, u_pad, d_pad, threads, depth);
         if (st <= 0 || !ops.untouched()) {
-          fprintf(stderr, "tiers fuzz: corrupt touched / deep (%d) not "
-                          "refused\n", which);
+          fprintf(stderr, "tiers fuzz: corrupt touched / deep / weights "
+                          "(%d) not refused (deep=%lld)\n", which, n_deep);
           return 1;
         }
       }
       // the deeper, wider interval first; then the one under test into
       // what it left, against the same from zeros
       st = vn_build_tiers(
-          rows.data(), vals.data(), wts.data(), n, touched.data(), nd,
+          rows.data(), vals.data(), w1, n, touched.data(), nd,
           deep.data(), n_deep, cap, map.data(), cursors.data(), ops.dv,
           ops.dw, ops.depths, u_pad, d_pad, threads, depth);
       st2 = vn_build_tiers(
-          rows2.data(), vals2.data(), wts2.data(), n2, touched.data(), nd,
+          rows2.data(), vals2.data(), w2, n2, touched.data(), nd,
           deep.data(), n_deep, cap, map.data(), cursors.data(), ops.dv,
           ops.dw, ops.depths, u_pad, d_pad, threads, depth);
       TierSet clean(u_pad, d_pad, uniform);
       long long st3 = vn_build_tiers(
-          rows2.data(), vals2.data(), wts2.data(), n2, touched.data(), nd,
+          rows2.data(), vals2.data(), w2, n2, touched.data(), nd,
           deep.data(), n_deep, cap, map.data(), cursors.data(), clean.dv,
           clean.dw, clean.depths, u_pad, d_pad, threads, depth);
       if (st != 0 || st2 != 0 || st3 != 0) {
         fprintf(stderr, "tiers fuzz: sound input not filled "
-                        "(threads=%d)\n", threads);
+                        "(deep=%lld threads=%d)\n", n_deep, threads);
         return 1;
       }
       for (int k = 0; k < 2; k++) {
         if (ops.v[k] != clean.v[k] || ops.rec[k] != clean.rec[k] ||
             (ops.dw[k] && ops.w[k] != clean.w[k])) {
           fprintf(stderr, "tiers fuzz: tier %d keeps a stale cell "
-                          "(threads=%d)\n", k, threads);
+                          "(deep=%lld threads=%d)\n", k, n_deep, threads);
           return 1;
         }
       }
@@ -957,7 +816,8 @@ int build_tiers_fuzz() {
                   : (c > 0 &&
                      v <= ops.v[tier][(size_t)(r * d_pad[tier] + c - 1)])) {
             fprintf(stderr, "tiers fuzz: cell [%lld, %lld] of tier %d "
-                            "(threads=%d)\n", r, c, tier, threads);
+                            "(deep=%lld threads=%d)\n", r, c, tier, n_deep,
+                    threads);
             return 1;
           }
         }
@@ -968,7 +828,7 @@ int build_tiers_fuzz() {
           want_w[k] = ops.w[k];
         } else if (ops.v[k] != want_v[k] || ops.w[k] != want_w[k]) {
           fprintf(stderr, "tiers fuzz: %d threads built another "
-                          "operand\n", threads);
+                          "operand (deep=%lld)\n", threads, n_deep);
           return 1;
         }
       }
@@ -1057,15 +917,14 @@ int main() {
   }
   vn_engine_free(e);
   rc |= wire_fuzz();
-  rc |= fill_dense_fuzz();
-  rc |= build_dense_fuzz();
-  rc |= build_tiers_fuzz();
+  rc |= build_tiers_fuzz(3);
+  rc |= build_tiers_fuzz(0);
   rc |= spsc_stress();
   rc |= simd_parity();
   if (rc == 0)
     fprintf(stderr,
             "sanitize driver ok: %llu pkts, %llu values, wire fuzz + "
-            "dense fill + spsc stress + simd parity clean\n",
+            "dense build + spsc stress + simd parity clean\n",
             parse_pkts, stage_vals);
   return rc;
 }

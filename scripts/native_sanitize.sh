@@ -6,7 +6,7 @@
 # -fno-sanitize-recover so the first report is fatal) and runs the
 # driver: concurrent stage-counter hammering + conservation checks,
 # protobuf wire fuzz (vn_route / vn_import_scan truncation + bit-flip
-# sweeps, forwarded t-digests included), vn_fill_dense / vn_build_dense boundary abuse, SPSC staging-ring stress
+# sweeps, forwarded t-digests included), vn_build_tiers boundary abuse, SPSC staging-ring stress
 # (2-slot rings, two concurrent drainers, exact packet conservation),
 # and scalar/SIMD parity (vn_key_hash / vn_scan_tokens over random
 # bytes plus byte-identical drains from a shared fuzz corpus).

@@ -502,10 +502,11 @@ def test_reference_vectors_cross_path():
 
 
 def test_native_dense_fill_matches_numpy_builder():
-    """vn_fill_dense must produce a dense build equivalent to the numpy
-    path: same per-row depth counts and the same per-row value
-    multisets (within-row order is free — quantile evaluation is
-    order-invariant), for both the uniform and weighted paths."""
+    """The native dense build (vn_build_tiers, through
+    DigestArena.build_dense) must produce the numpy builder's operand:
+    the same per-row depth counts, every row's values in arrival order,
+    the same casts — bit for bit, for both the uniform and weighted
+    forms."""
     import numpy as np
 
     from veneur_tpu.core import arena as arena_mod
@@ -514,7 +515,6 @@ def test_native_dense_fill_matches_numpy_builder():
     # test must fail, not silently compare numpy against numpy
     import veneur_tpu.ingest as ingest_mod
     ingest_mod.load_library()
-    assert ingest_mod.fill_dense is not None
 
     rng = np.random.default_rng(7)
     n_keys = 3000
@@ -531,59 +531,29 @@ def test_native_dense_fill_matches_numpy_builder():
     d_min = np.zeros(n_keys)
     d_max = np.full(n_keys, 1e3)
 
-    # force the native path despite the small input
-    orig_min = arena_mod._NATIVE_FILL_MIN
-    arena_mod._NATIVE_FILL_MIN = 0
-    try:
-        built = {}
-        for uniform in (True, False):
-            w_in = np.ones_like(wts) if uniform else wts
-            staged = (staged_rows, vals, w_in)
-            built[uniform] = a.build_dense(staged, touched, d_min,
-                                           d_max, uniform=uniform)
-    finally:
-        arena_mod._NATIVE_FILL_MIN = orig_min
-
     for uniform in (True, False):
         w_in = np.ones_like(wts) if uniform else wts
-        got = built[uniform]
-        # numpy-style reference build for comparison
-        dense_id = np.full(a.capacity, -1, np.int64)
-        dense_id[touched] = np.arange(n_keys)
-        r = dense_id[staged_rows]
-        order = np.argsort(r, kind="stable")
-        rs, vs, ws = r[order], vals[order], w_in[order]
-        first = np.searchsorted(rs, np.arange(n_keys))
-        pos = np.arange(len(rs)) - first[rs]
-        depth = int(pos.max()) + 1
-        d_pad = max(2, 1 << (depth - 1).bit_length())
-
+        staged = (staged_rows, vals, w_in)
+        got, = a.build_dense(staged, touched, d_min, d_max,
+                             uniform=uniform)
+        assert a.take_build_stats()["onepass"] == 1   # the native call
+        a.hold_dense([])
+        want = a.build_dense_numpy(staged, touched, d_min, d_max,
+                                   uniform=uniform)
+        assert got[0].shape == (4096, 8)
+        assert (got[2] is None) == uniform
+        for g, w in zip(got, want):
+            if w is not None:
+                assert g.dtype == w.dtype and g.shape == w.shape
+                assert np.array_equal(g, w)
+        counts = np.bincount(staged_rows, minlength=n_keys)
         if uniform:
-            dv, depths_vec, mm = got
-            assert mm is None
-            assert dv.shape[1] >= depth
-            counts = np.bincount(r, minlength=n_keys)
             assert np.array_equal(
-                np.asarray(depths_vec[:n_keys], np.int64), counts)
-            for row in rng.integers(0, n_keys, 50):
-                mine = np.sort(np.asarray(
-                    dv[row][:counts[row]], np.float64))
-                ref = np.sort(vs[rs == row])
-                np.testing.assert_allclose(
-                    mine, ref.astype(np.float32), rtol=1e-6)
-        else:
-            dv, dw, mm = got
-            assert mm is not None and dv.shape == dw.shape
-            counts = np.bincount(r, minlength=n_keys)
-            for row in rng.integers(0, n_keys, 50):
-                k = counts[row]
-                pairs = sorted(zip(
-                    np.asarray(dv[row][:k], np.float64),
-                    np.asarray(dw[row][:k], np.float64)))
-                ref = sorted(zip(vs[rs == row].astype(np.float32),
-                                 ws[rs == row].astype(np.float32)))
-                np.testing.assert_allclose(
-                    np.asarray(pairs), np.asarray(ref), rtol=1e-6)
+                np.asarray(got[1][:n_keys], np.int64), counts)
+        for row in rng.integers(0, n_keys, 50):
+            assert np.array_equal(
+                got[0][row][:counts[row]],
+                vals[staged_rows == row].astype(np.float32))
 
 
 # ---------------------------------------------------------------------------

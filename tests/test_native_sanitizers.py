@@ -7,9 +7,8 @@ phase 1 is the concurrent stage-counter workload (the TSan story),
 phases 2-3 are single-threaded wire fuzz (vn_route / vn_import_scan
 truncation + bit-flip sweeps; for vn_import_scan's descent into a
 forwarded t-digest, a list of digests decoded intact, truncated at
-every cut and mutated at every byte) and vn_fill_dense /
-vn_build_dense / vn_build_tiers boundary abuse —
-the memory-safety surface ASan/UBSan exist for.  The UBSan arm is what
+every cut and mutated at every byte) and vn_build_tiers boundary abuse,
+as one operand and as two tiers — the memory-safety surface ASan/UBSan exist for.  The UBSan arm is what
 caught the vn_route chunk_max=0 division by zero (now guarded:
 degenerate routing args return null, the Python-fallback contract).
 """

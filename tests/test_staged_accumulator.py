@@ -196,7 +196,7 @@ def _same(got, want):
 def _dense(ar, staged, uniform):
     touched = np.unique(staged[0])
     return ar.build_dense(staged, touched, ar.d_min[touched],
-                          ar.d_max[touched], uniform=uniform)
+                          ar.d_max[touched], uniform=uniform)[0]
 
 
 def _same_dense(a, b):

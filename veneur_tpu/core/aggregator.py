@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from veneur_tpu.core import arena as arena_mod
-from veneur_tpu.parallel import serving
+from veneur_tpu.parallel import multihost, serving
 from veneur_tpu.samplers import samplers as sm
 from veneur_tpu.samplers.metric_key import MetricKey, MetricScope, UDPMetric
 from veneur_tpu.sketches import hll as hll_mod
@@ -540,13 +540,6 @@ class MetricAggregator:
                                                     self)
         # ... and so is the hot-key compress a drain tick launches
         self.digests.compile_guard = self.sets.compile_guard
-        # host operands a tiered flush keeps from one build to the next
-        # (_build_tiers: the long tail's dict, the deep tier's — with
-        # the one-pass build's scratch and its record of what it filled,
-        # DigestArena.build_tiers), and the device results of the
-        # launches that last read them
-        self._tier_operands: tuple = ({}, {})
-        self._tier_inflight: list = []
         self._uts_m = self.unique_ts.m if self.unique_ts is not None \
             else 1 << hll_mod.DEFAULT_PRECISION
         self._pct_arr = jnp.asarray([0.5] + list(self.percentiles),
@@ -2081,18 +2074,8 @@ class MetricAggregator:
                          first_dev=None if donate else first_dev)
             seg["layout_s"] = layout_s
             seg["dispatch_s"] = dispatch_s
-            tiered = len(builds) > 1
-            if tiered:
-                # the tiers' operands are this aggregator's, not the
-                # arena's: their readers wait here, and where a
-                # forwarding tier's export keeps one on the device its
-                # dict lets go of it
-                self._tier_inflight = [b["outs"] for b in builds]
-                for b, keep in zip(builds, self._tier_operands):
-                    if b["first_dev"]:
-                        self.digests.lend_dense(b["first_dev"], keep)
             self._account_build(
-                seg, None if tiered else [b["outs"] for b in builds],
+                seg, [b["outs"] for b in builds],
                 [b["first_dev"] for b in builds if b["first_dev"]])
             pend.update(tiers=builds, t_dispatch0=t_dispatch0)
             return pend
@@ -2109,70 +2092,18 @@ class MetricAggregator:
             crows = snap["counters"]["rows"]
             srows = snap["sets"]["rows"]
             if multi:
-                # lockstep agreement: every controller must run the same
-                # program on the same global shapes and the same fetch
-                # sequence, whatever ITS families touched this interval —
-                # one tiny DCN gather of (touched counts, depth) decides
-                # for everyone.  The same gather carries each arena's
-                # key-dictionary fingerprint: a registration-order
-                # divergence between controllers would silently misalign
-                # rows (every process indexes the same global arrays), so
-                # it must fail loudly here instead
-                from jax.experimental import multihost_utils
-                local_depth = self.digests.staged_depth(dpart["staged"])
-                fams = snap["key_fingerprints"]   # lock-coherent snapshot
-                names = ("digest", "moments", "compactor", "counter",
-                         "gauge", "set", "status")
-                cks = np.asarray(
-                    [fams[n][0] for n in names]
-                    + [fams[n][1] for n in names],
-                    np.uint64).view(np.int64)
-                flags = multihost_utils.process_allgather(np.concatenate(
-                    [np.asarray([nd, local_depth, len(crows), len(srows),
-                                 int(snap["digests"]["uniform"])],
-                                np.int64), cks]))
-                g_nd, g_depth, g_nc, g_ns = \
-                    flags[:, :4].max(axis=0).tolist()
-                # the uniform kernel is a STATIC program choice — legal
-                # only when every controller's staging was uniform
-                g_uniform = bool(flags[:, 4].min())
-                nf = len(names)
-                keyset_all = flags[:, 5:5 + nf]
-                keyrow_all = flags[:, 5 + nf:5 + 2 * nf]
-                # same key SET everywhere but different key->row
-                # assignment = silent row misalignment (a registration-
-                # order divergence).  Differing key sets pass: with O(1)
-                # gathered state per family, a shared-key row conflict
-                # cannot be distinguished from benign one-sided keys, so
-                # this is a best-effort tripwire — it catches the
-                # canonical ordering bug outright, and catches an
-                # asymmetric-registration row conflict as soon as GC (or
-                # registration) makes the key sets converge (at which
-                # point the dictionaries genuinely ARE misaligned for
-                # the shared keys).  The strict contract remains: shared
-                # keys must be registered in the same order everywhere
-                diverged = [
-                    name for i, name in enumerate(names)
-                    if (keyset_all[:, i] == keyset_all[0, i]).all()
-                    and not (keyrow_all[:, i] == keyrow_all[0, i]).all()]
-                if diverged:
-                    raise RuntimeError(
-                        "lockstep violation: controllers hold the same "
-                        f"keys with DIFFERENT row assignments for famil"
-                        f"{'ies' if len(diverged) > 1 else 'y'} "
-                        f"{', '.join(diverged)} (process "
-                        f"{jax.process_index()} of "
-                        f"{jax.process_count()}).  All controllers must "
-                        "register shared keys in the same order "
-                        "(parallel/multihost.py lockstep contract); "
-                        "flushing with misaligned rows would silently "
-                        "merge unrelated timeseries")
+                g_nd, g_depth, g_nc, g_ns, g_uniform = \
+                    multihost.lockstep_agree(
+                        nd, self.digests.staged_depth(dpart["staged"]),
+                        len(crows), len(srows),
+                        snap["digests"]["uniform"],
+                        snap["key_fingerprints"])   # lock-coherent
             else:
                 g_nd, g_depth = nd, 0
                 g_nc, g_ns = len(crows), len(srows)
                 g_uniform = snap["digests"]["uniform"]
             t0 = time.perf_counter()
-            dv, dw, minmax = self.digests.build_dense(
+            (dv, dw, minmax), = self.digests.build_dense(
                 dpart["staged"], dpart["rows"],
                 dpart["d_min"], dpart["d_max"],
                 u_floor=g_nd, d_floor=g_depth)
@@ -2206,86 +2137,42 @@ class MetricAggregator:
                 dense_dev=None if donate else (dvd, dwd))
             return pend
 
-    def _account_build(self, seg: dict, outs: Optional[list],
-                       kept_dev: list):
+    def _account_build(self, seg: dict, outs: list, kept_dev: list):
         """After the digest launch(es): what the build did, onto the
         timeline row, and — where the arena's kept operands served it —
         what DigestArena.hold_dense asks of a caller: the launches'
-        results, and the device operands a forwarding tier keeps.
-        outs None: a tiered flush, whose one pass filled operands the
-        arena does not own (it is told of no reader, and its own kept
-        buffers stay as they were)."""
+        results, and the device operands a forwarding tier keeps."""
         stats = self.digests.take_build_stats()
         seg["build_onepass"] = stats["onepass"]
         seg["build_fresh_bytes"] = stats["fresh_bytes"]
-        if stats["onepass"] and outs is not None:
+        if stats["onepass"]:
             self.digests.hold_dense(outs)
             for dev in kept_dev:
                 self.digests.lend_dense(dev)
 
     def _build_tiers(self, dpart: dict) -> list:
         """The unmeshed flush's host-built operand(s) from a digest
-        part: one `build_dense` over every touched row — the parent's
-        single `[U, D]` operand — unless the snapshot named a deep tier
-        (`DigestArena.deep_rows`).  Then two: the long tail in the form
-        its weights allow at its own depth, and the deep rows weighted,
-        DENSE_DEPTH_CAP deep, at a pow2 row bucket of at least
-        DEEP_TIER_MIN_ROWS — both from one native pass over the staged
-        points into the operands `_tier_operands` keeps
-        (`DigestArena.build_tiers`; the row says `build_onepass` 1).
-        Where that call declines (no native engine, a dtype other than
-        float32, corrupt staging), from two `build_dense(keep=)` calls
-        over each tier's own copy of the points: the plain form the one
-        pass is tested against, bit for bit.  Each tier: `sel` (its
-        rows' positions in the part; None = all), `deep`, `uniform`,
-        `dense` (build_dense's triple)."""
-        d = self.digests
-        rows, vals, wts = staged = dpart["staged"]
-        touched, deep = dpart["rows"], dpart.get("deep")
+        part (`DigestArena.build_dense`): one over every touched row —
+        the single `[U, D]` operand — unless the snapshot named a deep
+        tier (`DigestArena.deep_rows`).  Then two: the long tail in the
+        form its weights allow at its own depth, and the deep rows
+        weighted, DENSE_DEPTH_CAP deep, at a pow2 row bucket of at
+        least DEEP_TIER_MIN_ROWS.  Each tier: `sel` (its rows'
+        positions in the part; None = all), `deep`, `uniform`, `dense`
+        (its triple)."""
+        deep = dpart.get("deep")
         if deep is None:
-            return [self._single_tier(dpart)]
-        # a served node's flushes are serial and this returns at once; a
-        # caller that dispatches a second flush before it fetched the
-        # first's results waits here for the uploads it would overwrite
-        jax.block_until_ready(self._tier_inflight)
-        in_tail = np.ones(len(touched), bool)
-        in_tail[deep] = False
-        sels = (np.nonzero(in_tail)[0], deep)
-        forms = (dpart["shallow_uniform"], False)
-        built = d.build_tiers(staged, touched, sels, dpart["d_min"],
-                              dpart["d_max"], forms[0],
-                              self._tier_operands)
-        if built is None:
-            if len(rows) and not (0 <= int(rows.min())
-                                  and int(rows.max()) < d.capacity):
-                # corrupt staging: build_dense drops it, loudly
-                return [self._single_tier(dpart)]
-            is_deep = np.zeros(d.capacity, bool)
-            is_deep[touched[deep]] = True
-            in_deep = is_deep[rows]
-            built = []
-            for sel, mine, uniform, floors, keep in zip(
-                    sels, (~in_deep, in_deep), forms,
-                    ({}, {"u_floor": arena_mod.DEEP_TIER_MIN_ROWS,
-                          "d_floor": arena_mod.DENSE_DEPTH_CAP}),
-                    self._tier_operands):
-                # (the whole operand is zeroed and filled: what a one
-                # pass recorded of its content no longer holds)
-                keep.pop("filled", None)
-                built.append(d.build_dense(
-                    (rows[mine], vals[mine], wts[mine]), touched[sel],
-                    dpart["d_min"][sel], dpart["d_max"][sel],
-                    uniform=uniform, keep=keep, **floors))
+            sels, forms = None, (dpart["uniform"],)
+        else:
+            sels = self.digests.tier_rows(len(dpart["rows"]), deep)
+            forms = (dpart["shallow_uniform"], False)
+        built = self.digests.build_dense(
+            dpart["staged"], dpart["rows"], dpart["d_min"],
+            dpart["d_max"], uniform=forms[0], sels=sels)
         return [{"sel": sel, "deep": k == 1, "uniform": uniform,
                  "dense": dense}
                 for k, (sel, uniform, dense) in enumerate(
-                    zip(sels, forms, built))]
-
-    def _single_tier(self, dpart: dict) -> dict:
-        return {"sel": None, "deep": False, "uniform": dpart["uniform"],
-                "dense": self.digests.build_dense(
-                    dpart["staged"], dpart["rows"], dpart["d_min"],
-                    dpart["d_max"], uniform=dpart["uniform"])}
+                    zip(sels or (None,), forms, built))]
 
     def _put_tier(self, dv, dw, minmax, uniform: bool, sl: slice):
         """Device-put rows `sl` of one host-built tier."""
@@ -2466,7 +2353,9 @@ class MetricAggregator:
             seg["amortized_bytes"] = (seg.get("amortized_bytes", 0)
                                       + rpart["streamed_bytes"])
         else:
-            dv, dw, _ = m.build_dense(
+            # (no hold_dense after it: the arena makes its operands
+            # anew every flush)
+            (dv, dw, _), = m.build_dense(
                 mpart["staged"], mpart["rows"],
                 mpart["d_min"], mpart["d_max"], uniform=uniform)
             critical = dv.nbytes + dw.nbytes

@@ -48,25 +48,6 @@ from veneur_tpu.sketches import tdigest as td
 # (bounds the flush dense matrix width)
 DENSE_DEPTH_CAP = 512
 
-# staged-element count above which the dense build uses the native C++
-# single-pass fill (vn_fill_dense) instead of numpy argsort+scatter
-_NATIVE_FILL_MIN = 65536
-# padded operand bytes (the [U, D] f32 value matrix, and the weight
-# matrix when the build is not uniform) from which build_dense makes its
-# operand in ONE native call (vn_build_dense) into buffers the arena
-# keeps from flush to flush.  Below a few MiB the build is milliseconds
-# of work whichever way; above, the fresh pages of `np.zeros` (~1 us/KB
-# first touch on the chip's host, and whether glibc maps them anew
-# follows the process's allocation history) and the repeated passes over
-# the staged points (a gather, a bincount, one scan per fill thread) are
-# most of it: 77 of a meshed global's 237 ms from tick to sink at
-# [131072, 32] x 2 = 32 MiB.  16 MiB stands between that and the largest
-# single operand that builds the other way: a fleet's [2048, 256] x 2 =
-# 4 MiB.  (A skewed node's tiers — an 8 MiB tail and a 1 MiB x 2 deep
-# tier — are neither: both come from one native pass of their own into
-# buffers the aggregator keeps, build_tiers / vn_build_tiers, and only
-# that pass's fallback builds them through the code below.)
-_ONEPASS_MIN_BYTES = 16 << 20
 # The hot-key lane's compress tile: EVERY pre-reduction launch
 # (DigestArena._pre_reduce -> serving.partial_digests) has this one
 # shape, whatever a drain tick carries, so the program is known at boot
@@ -1464,12 +1445,12 @@ class DigestArena(_ArenaBase):
     past `DEEP_TIER_THRESHOLD` points (or re-staged with weights), the
     aggregator builds those apart — weighted, DENSE_DEPTH_CAP deep, a
     few hundred rows — and the long tail at its own depth in the form
-    its weights allow (`_dispatch_flush`), both from one native pass
-    over the staged points into operands the aggregator keeps
-    (`build_tiers`); `build_dense` is the single-operand build, and what
-    either tier is made with where that pass declines.  An interval with
-    no deep key, or one whose split would not halve the operand, builds
-    the one `[K_t, D]` matrix.
+    its weights allow (`_dispatch_flush`).  An interval with no deep
+    key, or one whose split would not halve the operand, builds the one
+    `[K_t, D]` matrix.  Either way `build_dense` makes the operand(s) in
+    one native pass over the staged points into buffers the arena keeps
+    (`hold_dense` is the rule for them); `build_dense_numpy` is its
+    plain reference and what builds where that pass declines.
 
     With a mesh, the dense matrix shards keys over 'shard' and depth over
     'replica'; the flush all_gathers depth slices over ICI (the
@@ -1490,9 +1471,9 @@ class DigestArena(_ArenaBase):
     # the caller (the aggregator lock: a drain tick's sync, the cut's)
     HOT_STATS = ("keys", "points_in", "points_out", "compress_launches",
                  "compress_held_ns")
-    # per flush: 1 where the one-pass kept-operand build made the
-    # operand (0: any other path, its fallback included), and the bytes
-    # of operands and row-index arrays the builds allocated anew
+    # per flush: 1 where the native pass into kept operands made every
+    # operand (0: the numpy builder), and the bytes of operands and
+    # row-index arrays the builds allocated anew
     BUILD_STATS = ("onepass", "fresh_bytes")
 
     family = "digest"
@@ -1607,13 +1588,16 @@ class DigestArena(_ArenaBase):
         # what the hot-key lane did over the open interval, for the
         # flush timeline's row (take_hot_stats, at the cut)
         self._hot_stats = dict.fromkeys(self.HOT_STATS, 0)
-        # the large single operand's buffers (build_dense above
-        # _ONEPASS_MIN_BYTES), kept from flush to flush; what the last
-        # such build's launches returned, which the next waits for
-        # before it writes them again (hold_dense; None = nobody said,
-        # so the buffers are not the arena's to rewrite); and what the
-        # builds since take_build_stats did
-        self._dense_keep: dict = {}
+        # the flush operands' host buffers (build_dense), kept from
+        # flush to flush: a dict per tier slot — the single operand or
+        # the long tail, and the deep rows — of the operands, the int16
+        # record of what the last build filled (`depths`) and, in the
+        # first, the native call's scratch; what the last build's
+        # launches returned, which the next waits for before it writes
+        # them again (hold_dense; None = nobody said, so the buffers
+        # are not the arena's to rewrite); and what the builds since
+        # take_build_stats did
+        self._dense_keep: tuple = ({}, {})
         self._dense_readers = None
         self._build_stats = dict.fromkeys(self.BUILD_STATS, 0)
         # device-resident delta mirror (flush_resident_arenas): the host
@@ -2214,23 +2198,19 @@ class DigestArena(_ArenaBase):
         staged points: a power of two to each replica's slice."""
         return max(2, self.n_replicas * _pow2(-(-depth // self.n_replicas)))
 
-    def _operand(self, keep: Optional[dict], name: str, shape, dtype,
-                 zero: bool = True):
-        """An all-zero host operand for build_dense: a fresh `np.zeros`,
-        or — `keep` given — the caller's buffer of that name, zeroed in
-        place when shape and dtype still fit.  A fresh operand of
-        megabytes is first-touch page faults inside the fill (~1 us/KB
-        on the chip's host), and whether glibc serves it from mapped
-        heap or from new pages is an accident of the process's
-        allocation history: a flush that keeps its operands pays a
-        memset, the same in every run.  zero=False: whatever the buffer
-        holds (the one-pass build writes every cell itself)."""
-        buf = None if keep is None else keep.get(name)
+    def _operand(self, keep: dict, name: str, shape, dtype, zero: bool):
+        """The buffer of that name in `keep` (a tier slot of
+        `_dense_keep`), made anew where shape or dtype no longer fit.
+        A fresh operand of megabytes is first-touch page faults inside
+        the fill (~1 us/KB on the chip's host), and whether glibc
+        serves it from mapped heap or from new pages is an accident of
+        the process's allocation history: a kept one costs the same in
+        every run.  zero: all zeros (a kept one zeroed in place); else
+        whatever the buffer holds."""
+        buf = keep.get(name)
         if buf is None or buf.shape != tuple(shape) or buf.dtype != dtype:
-            buf = (np.zeros if zero else np.empty)(shape, dtype)
+            buf = keep[name] = (np.zeros if zero else np.empty)(shape, dtype)
             self._build_stats["fresh_bytes"] += buf.nbytes
-            if keep is not None:
-                keep[name] = buf
         elif zero:
             buf.fill(0)
         return buf
@@ -2242,121 +2222,43 @@ class DigestArena(_ArenaBase):
         return out
 
     def hold_dense(self, readers) -> None:
-        """The rule for a caller that wants kept operands: after it
-        launched on what build_dense returned, it says here what the
-        launches returned (results that are ready have consumed their
-        inputs).  The next one-pass build waits for them before it
-        writes the kept buffers again — a served node's flushes are
-        serial, so that returns at once; a build nobody called this
-        after makes its own buffers."""
+        """The rule for the kept operands (safety, not a target): after
+        a caller launched on what build_dense returned, it says here
+        what the launches returned (results that are ready have consumed
+        their inputs) — and hands lend_dense the device operands it
+        keeps beyond the launch.  The next build waits for those readers
+        before it writes the kept buffers again — a served node's
+        flushes are serial, so that returns at once; a build nobody
+        called this after makes its own buffers."""
         self._dense_readers = readers
 
-    def lend_dense(self, dev_operands, keep: Optional[dict] = None) -> None:
+    def lend_dense(self, dev_operands) -> None:
         """Device arrays of the last build's operands that outlive their
         launch (a forwarding tier keeps them for its digest export).
         Where device_put aliased an aligned host buffer instead of
         copying it — the CPU backend's may — the array IS the kept
         buffer: the arena lets go of it and the next build makes its
-        own.  keep: the caller's dict where the operands were its own
-        (a tier's, build_tiers)."""
-        if keep is None:
-            keep = self._dense_keep
+        own."""
         for arr in dev_operands:
             for shard in arr.addressable_shards:
                 if shard.device.platform != "cpu":
                     continue
                 at = shard.data.unsafe_buffer_pointer()
-                for name in ("dv", "dw", "depths"):
-                    buf = keep.get(name)
-                    if (buf is not None and buf.ctypes.data <= at
-                            < buf.ctypes.data + buf.nbytes):
-                        del keep[name]
-
-    def _build_onepass(self, staged, touched: np.ndarray,
-                       d_min_t: np.ndarray, d_max_t: np.ndarray,
-                       u_pad: int, d_floor: int, uniform: bool):
-        """build_dense's large form: ONE native call maps rows to dense
-        rows, counts each row's points (so the depth is known without a
-        numpy gather or bincount), zeroes each row's tail and fills,
-        every point read once by the count and once by the fill, into
-        the buffers the arena keeps — no page is first touched in a
-        steady flush.  Same casts, same arrival order within a row: the
-        triple is bit-equal to the other builders'.  None where the
-        native engine is missing or a staged row id is out of range or
-        not in `touched`: the caller's numpy builder drops loudly."""
-        try:
-            from veneur_tpu import ingest as ingest_mod
-            ingest_mod.load_library()
-        except Exception:
-            return None
-        keep = self._dense_keep
-        if self._dense_readers is None:
-            # handed out, and nobody said who reads them
-            keep.clear()
-        else:
-            jax.block_until_ready(self._dense_readers)
-            self._dense_readers = []
-        rows, vals, wts = staged
-        rows = np.ascontiguousarray(rows, np.int64)
-        vals = np.ascontiguousarray(vals, np.float64)
-        wts = None if uniform else np.ascontiguousarray(wts, np.float64)
-        touched = np.ascontiguousarray(touched, np.int64)
-        row_map = self._operand(keep, "row_map", (self.capacity,),
-                                np.int32, zero=False)
-        cursors = self._operand(
-            keep, "cursors", (ingest_mod.BUILD_DENSE_THREADS * u_pad,),
-            np.int32, zero=False)
-
-        def attempt(d_pad: int):
-            """The call at depth d_pad (0: count only), into the kept
-            operands of that shape, made anew where they have another."""
-            dv = dw = depths = None
-            if d_pad:
-                dv = self._operand(keep, "dv", (u_pad, d_pad), np.float32,
-                                   zero=False)
-                depths = self._operand(keep, "depths", (u_pad,), np.int16,
-                                       zero=False)
-                if not uniform:
-                    dw = self._operand(keep, "dw", (u_pad, d_pad),
-                                       np.float32, zero=False)
-            status, depth = ingest_mod.build_dense(
-                rows, vals, wts, touched, row_map, cursors, dv, dw,
-                depths, u_pad, d_pad)
-            return status, self.dense_depth(max(depth, d_floor, 1)), \
-                dv, dw, depths
-
-        kept = keep.get("dv")
-        d_pad = (kept.shape[1] if kept is not None
-                 and kept.shape[0] == u_pad else 0)
-        # the kept shape first (the steady case: one call), then, where
-        # the interval's deepest row asks for another depth, that one
-        status, want, dv, dw, depths = attempt(d_pad)
-        if status <= 0 and want != d_pad:
-            status, want, dv, dw, depths = attempt(want)
-        if status != 0:
-            return None
-        self._dense_readers = None
-        self._build_stats["onepass"] = 1
-        if self.stage_dtype != np.float32 and (
-                uniform or self.compact_general):
-            dv = dv.astype(self.stage_dtype)
-            self._build_stats["fresh_bytes"] += dv.nbytes
-        if uniform:
-            return dv, depths, None
-        nd = len(touched)
-        minmax = self._operand(keep, "minmax", (2, u_pad), self.eval_dtype)
-        minmax[0, :nd] = d_min_t
-        minmax[1, :nd] = d_max_t
-        return dv, dw, minmax
+                for keep in self._dense_keep:
+                    for name in ("dv", "dw", "depths"):
+                        buf = keep.get(name)
+                        if (buf is not None and buf.ctypes.data <= at
+                                < buf.ctypes.data + buf.nbytes):
+                            del keep[name]
 
     def _tier_buffers(self, keep: dict, uniform: bool, u_pad: int,
                       d_pad: int) -> tuple:
-        """One tier's (dv, dw, depths, u_pad, d_pad) for build_tiers out
-        of the caller's dict: the kept buffers as they are where
-        `filled` says they are what `depths` records (each row's first
-        depths[r] cells filled, zeros past them) and their shapes still
-        fit; else all of them zeroed, made anew where they do not fit.
-        d_pad 0: none (the native call only counts)."""
+        """One tier's (dv, dw, depths, u_pad, d_pad) for the native
+        build out of its slot of `_dense_keep`: the kept buffers as
+        they are where their shapes still fit — they are what `depths`
+        records (each row's first depths[r] cells filled, zeros past
+        them) —, else all of them zeroed, made anew where they do not
+        fit.  d_pad 0: none (the native call only counts)."""
         if not d_pad:
             return None, None, None, u_pad, 0
         wanted = [("dv", (u_pad, d_pad), np.float32),
@@ -2366,35 +2268,90 @@ class DigestArena(_ArenaBase):
             keep.pop("dw", None)
         else:
             wanted.append(("dw", (u_pad, d_pad), np.float32))
-        stale = not keep.get("filled") or any(
-            name not in keep or keep[name].shape != shape
-            or keep[name].dtype != dtype for name, shape, dtype in wanted)
+        stale = any(name not in keep or keep[name].shape != shape
+                    or keep[name].dtype != dtype
+                    for name, shape, dtype in wanted)
         bufs = {name: self._operand(keep, name, shape, dtype, zero=stale)
                 for name, shape, dtype in wanted}
-        keep["filled"] = True
         return bufs["dv"], bufs.get("dw"), bufs["depths"], u_pad, d_pad
 
-    def build_tiers(self, staged, touched: np.ndarray, sels,
+    @staticmethod
+    def tier_rows(n_touched: int, deep: np.ndarray) -> tuple:
+        """build_dense's `sels` for a snapshot's deep tier (`deep_rows`):
+        the long tail's and the deep rows' positions among the touched
+        rows, each ascending."""
+        in_tail = np.ones(n_touched, bool)
+        in_tail[deep] = False
+        return np.nonzero(in_tail)[0], deep
+
+    def build_dense(self, staged, touched: np.ndarray,
                     d_min_t: np.ndarray, d_max_t: np.ndarray,
-                    shallow_uniform: bool, keeps):
-        """Both operands of a tiered flush in ONE native call
-        (vn_build_tiers): a row -> (tier, dense row) map, each row's
-        count from the first read of the points, the fill from the
-        second, into the buffers the caller keeps in `keeps` (the long
-        tail's dict, the deep rows') — and no cell zeroed but those the
-        last build filled past a row's new count (`depths`, the uniform
-        form's operand, is that record for either form; `filled` in a
-        dict says its buffers follow it).  sels: the tail's and the deep
-        rows' positions in `touched`, each ascending, together all of
-        them.  Returns build_dense's triple for each tier — the tail in
-        the form `shallow_uniform` allows at its own depth, the deep
-        rows weighted, DENSE_DEPTH_CAP deep, a pow2 bucket of at least
-        DEEP_TIER_MIN_ROWS — bit-equal to two build_dense(keep=) calls
-        over the tiers' own points, and the caller's only until its
-        next build.  None, and nothing built, where that cannot be
-        promised: no native engine, a staging or eval dtype other than
-        float32, a staged id out of range or not in `touched`; the
-        caller's two numpy builds then drop loudly."""
+                    u_floor: int = 0, d_floor: int = 0,
+                    uniform: bool = False, sels=None) -> list:
+        """The flush program's host operand(s): the staged COO mapped
+        onto touched-row-ordered dense matrices `[U, D]` (U = padded
+        touched count, no less than u_floor; D = padded max depth, no
+        less than d_floor), plus the stacked [2, U] min/max from the
+        SNAPSHOT scalar copies (the live arrays are already reset by
+        the time this runs).  Host work only; the caller device_puts
+        the result (outside the aggregator lock).  Returns a list of
+        one (dv, dw, minmax) triple, or — `sels` given (`tier_rows`:
+        the tail's and the deep rows' positions in `touched`) — two:
+        the long tail in the form `uniform` allows at its own depth,
+        and the deep rows weighted, DENSE_DEPTH_CAP deep, a pow2 bucket
+        of at least DEEP_TIER_MIN_ROWS.
+
+        uniform=True (legal only when every staged weight of the
+        operand is exactly 1, `staged_uniform`): the middle return is a
+        per-row int16 DEPTH VECTOR `[U]` instead of the `[U, D]` weight
+        matrix — staged points pack contiguously from column 0, so
+        `col < depth[row]` is the occupancy — and no minmax (None).
+        Halves both the host build work and the bytes crossing the
+        host->device link.
+
+        Built in ONE native call (`_build_kept` -> vn_build_tiers) into
+        operands the arena keeps from flush to flush, which are the
+        caller's only until the next build and oblige it to
+        `hold_dense`; where that call cannot be made — no native
+        engine, a staging or eval dtype other than float32
+        (digest_float64, digest_bf16_staging), a staged id out of range
+        or not in `touched` — by `build_dense_numpy` per tier, into
+        fresh operands, which drops corrupt points loudly."""
+        if sels is None:
+            specs = [(slice(None), bool(uniform), u_floor, d_floor)]
+        else:
+            specs = [(sels[0], bool(uniform), 0, 0),
+                     (sels[1], False, DEEP_TIER_MIN_ROWS, DENSE_DEPTH_CAP)]
+        built = self._build_kept(staged, touched, d_min_t, d_max_t, specs)
+        if built is not None:
+            return built
+        rows, vals, wts = self._sound_points(staged)
+        mine = [slice(None)]
+        if sels is not None:
+            is_deep = np.zeros(self.capacity, bool)
+            is_deep[touched[sels[1]]] = True
+            in_deep = is_deep[rows]
+            mine = [~in_deep, in_deep]
+        return [self.build_dense_numpy(
+            (rows[own], vals[own], wts[own]), touched[sel], d_min_t[sel],
+            d_max_t[sel], u_fl, d_fl, form)
+            for (sel, form, u_fl, d_fl), own in zip(specs, mine)]
+
+    def _build_kept(self, staged, touched: np.ndarray,
+                    d_min_t: np.ndarray, d_max_t: np.ndarray,
+                    specs: list) -> Optional[list]:
+        """build_dense's native form: ONE call (vn_build_tiers) maps
+        rows to (tier, dense row), counts each row's points from the
+        first read of them (so the depth is known without a numpy
+        gather or bincount) and fills from the second, into the
+        buffers `_dense_keep` holds for each tier slot — no page is
+        first touched in a steady flush, and no cell zeroed but those
+        the last build filled past a row's new count (the kept int16
+        `depths`, the uniform form's operand, is that record for either
+        form).  specs: each tier's (sel, uniform, u_floor, d_floor).
+        Same casts, same arrival order within a row: bit-equal to
+        build_dense_numpy over each tier's own points.  None, and no
+        cell written, where that cannot be promised."""
         if (self.eval_dtype != np.float32
                 or self.stage_dtype != np.float32):
             return None
@@ -2403,17 +2360,28 @@ class DigestArena(_ArenaBase):
             ingest_mod.load_library()
         except Exception:
             return None
+        keeps = self._dense_keep[:len(specs)]
+        if self._dense_readers is None:
+            # handed out, and nobody said who reads them
+            for keep in self._dense_keep:
+                keep.clear()
+        else:
+            jax.block_until_ready(self._dense_readers)
+            self._dense_readers = []
         rows, vals, wts = staged
+        sels, forms, u_floors, floors = zip(*specs)
         rows = np.ascontiguousarray(rows, np.int64)
         vals = np.ascontiguousarray(vals, np.float64)
-        wts = np.ascontiguousarray(wts, np.float64)
+        wts = (None if all(forms)
+               else np.ascontiguousarray(wts, np.float64))
         touched = np.ascontiguousarray(touched, np.int64)
-        deep = np.ascontiguousarray(sels[1], np.int64)
-        forms = (bool(shallow_uniform), False)
-        floors = (0, DENSE_DEPTH_CAP)
-        u_pads = tuple(
-            self.n_shards * self.dense_block_per_shard(max(len(sel), floor))
-            for sel, floor in zip(sels, (0, DEEP_TIER_MIN_ROWS)))
+        deep = (np.ascontiguousarray(sels[1], np.int64)
+                if len(specs) > 1 else np.empty(0, np.int64))
+        u_pads = [
+            self.n_shards * self.dense_block_per_shard(max(
+                len(touched) if isinstance(sel, slice) else len(sel),
+                u_floor))
+            for sel, u_floor in zip(sels, u_floors)]
         row_map = self._operand(keeps[0], "row_map", (self.capacity,),
                                 np.int32, zero=False)
         cursors = self._operand(
@@ -2422,151 +2390,84 @@ class DigestArena(_ArenaBase):
             np.int32, zero=False)
 
         def attempt(d_pads):
-            """The call at these depths (the tail's 0: count only)."""
-            if not d_pads[0]:
-                d_pads = (0, 0)
+            """The call at these depths (any of them 0: count only)."""
+            if not all(d_pads):
+                d_pads = [0] * len(specs)
             tiers = [self._tier_buffers(*tier)
                      for tier in zip(keeps, forms, u_pads, d_pads)]
             status, depths = ingest_mod.build_tiers(
                 rows, vals, wts, touched, deep, row_map, cursors, tiers)
-            want = tuple(self.dense_depth(max(depth, floor, 1))
-                         for depth, floor in zip(depths, floors))
+            want = [self.dense_depth(max(depth, floor, 1))
+                    for depth, floor in zip(depths, floors)]
             return status, want, tiers
 
         # the kept shapes first (the steady case: one call), then, where
         # a tier's deepest row asks for another depth, that one
-        d_pads = tuple(
+        d_pads = [
             keep["dv"].shape[1] if "dv" in keep
-            and keep["dv"].shape[0] == u_pad else floor
-            for keep, u_pad, floor in zip(keeps, u_pads, floors))
+            and keep["dv"].shape[0] == u_pad
+            else self.dense_depth(floor) if floor else 0
+            for keep, u_pad, floor in zip(keeps, u_pads, floors)]
         status, want, tiers = attempt(d_pads)
         if status <= 0 and want != d_pads:
             status, want, tiers = attempt(want)
         if status != 0:
             return None
+        self._dense_readers = None
         self._build_stats["onepass"] = 1
         built = []
-        for (dv, dw, depths, u_pad, _d), uniform, sel, keep in zip(
-                tiers, forms, sels, keeps):
+        for (dv, dw, depths, u_pad, _d), sel, uniform, keep in zip(
+                tiers, sels, forms, keeps):
             if uniform:
                 built.append((dv, depths, None))
                 continue
+            lo, hi = d_min_t[sel], d_max_t[sel]
             minmax = self._operand(keep, "minmax", (2, u_pad),
                                    self.eval_dtype, zero=False)
-            minmax[0, :len(sel)] = d_min_t[sel]
-            minmax[1, :len(sel)] = d_max_t[sel]
-            minmax[:, len(sel):] = 0
+            minmax[0, :len(lo)] = lo
+            minmax[1, :len(lo)] = hi
+            minmax[:, len(lo):] = 0
             built.append((dv, dw, minmax))
         return built
 
-    def build_dense(self, staged, touched: np.ndarray,
-                    d_min_t: np.ndarray, d_max_t: np.ndarray,
-                    u_floor: int = 0, d_floor: int = 0,
-                    uniform: bool = False, keep: Optional[dict] = None):
-        """Compact dense build for the flush program: map the staged COO
-        onto touched-row-ordered dense matrices `[U, D]` (U = padded
-        touched count, D = padded max depth), plus the stacked [2, U]
-        min/max from the SNAPSHOT scalar copies (the live arrays are
-        already reset by the time this runs).  Pure host numpy; the
-        caller device_puts the result (outside the aggregator lock).
+    def _sound_points(self, staged) -> tuple:
+        """The staged COO without points whose row id is outside
+        [0, capacity): a negative id would WRAP through numpy negative
+        indexing into another key's row — dropped loudly instead."""
+        rows, vals, wts = staged
+        if not len(rows) or (int(rows.min()) >= 0
+                             and int(rows.max()) < self.capacity):
+            return staged
+        bad = (rows < 0) | (rows >= self.capacity)
+        import logging
+        logging.getLogger("veneur_tpu.core.arena").error(
+            "dropping %d staged digest points with out-of-bounds "
+            "row ids (corrupt staging)", int(bad.sum()))
+        return rows[~bad], vals[~bad], wts[~bad]
 
-        uniform=True (legal only when every staged weight is exactly 1,
-        `staged_uniform`): the middle return is a per-row int32 DEPTH
-        VECTOR `[U]` instead of the `[U, D]` weight matrix — staged
-        points pack contiguously from column 0, so `col < depth[row]`
-        is the occupancy.  Halves both the host build work and the
-        bytes crossing the host->device link (the e2e flush's dominant
-        cost; VERDICT r4 items 3-4).
-
-        keep: a dict the caller owns, in which the operands' buffers
-        stay from one build to the next (_operand); the returned arrays
-        are then the caller's only until its next build with that
-        dict.  None = fresh operands — or, where the padded operands
-        reach _ONEPASS_MIN_BYTES, the arena's own kept ones, built in
-        one native pass (_build_onepass; the caller then owes
-        hold_dense).  The size is judged before any pass over the
-        points, from what is known then: the deepest row holds at least
-        the mean."""
+    def build_dense_numpy(self, staged, touched: np.ndarray,
+                          d_min_t: np.ndarray, d_max_t: np.ndarray,
+                          u_floor: int = 0, d_floor: int = 0,
+                          uniform: bool = False) -> tuple:
+        """One operand's (dv, dw, minmax) in plain numpy — a stable
+        argsort by dense row and one scatter — into fresh arrays: the
+        reference build_dense's native call is held to bit for bit, and
+        what builds for the dtypes that call would round
+        (digest_float64, bf16 staging) and after `_sound_points`
+        dropped corrupt staging."""
         rows, vals, wts = staged
         nd = len(touched)
-        per_shard = self.dense_block_per_shard(max(nd, u_floor))
-        u_pad = self.n_shards * per_shard
-        if keep is None and self.eval_dtype == np.float32:
-            d_least = self.dense_depth(
-                max(-(-len(rows) // max(nd, 1)), d_floor, 1))
-            if (u_pad * d_least * 4 * (1 if uniform else 2)
-                    >= _ONEPASS_MIN_BYTES):
-                built = self._build_onepass(staged, touched, d_min_t,
-                                            d_max_t, u_pad, d_floor,
-                                            uniform)
-                if built is not None:
-                    return built
-        if len(rows) and (int(rows.min()) < 0
-                          or int(rows.max()) >= self.capacity):
-            # corrupt staged row ids: a negative id would WRAP through
-            # numpy negative indexing (and an out-of-bounds read in the
-            # native fill) into another key's row — drop loudly instead
-            bad = (rows < 0) | (rows >= self.capacity)
-            import logging
-            logging.getLogger("veneur_tpu.core.arena").error(
-                "dropping %d staged digest points with out-of-bounds "
-                "row ids (corrupt staging)", int(bad.sum()))
-            keep_mask = ~bad
-            rows, vals, wts = rows[keep_mask], vals[keep_mask], \
-                wts[keep_mask]
+        u_pad = self.n_shards * self.dense_block_per_shard(max(nd, u_floor))
         dense_id = np.full(self.capacity, -1, np.int64)
         dense_id[touched] = np.arange(nd)
+        r = dense_id[rows]
+        self._build_stats["fresh_bytes"] += dense_id.nbytes + r.nbytes
 
-        # native single-pass fill (vn_fill_dense): per-dense-row write
-        # cursors replace numpy's argsort + gathers + fancy scatter —
-        # ~5x the host build throughput at 1M keys.  Depth comes from
-        # the bincount (cheap) so the dense shape is known up front.
-        native_fill = None
-        # f32 eval only: the native fill writes f32 buffers, which would
-        # silently round digest_float64's exact-f64 staging
-        if len(rows) >= _NATIVE_FILL_MIN and self.eval_dtype == np.float32:
-            try:
-                from veneur_tpu import ingest as ingest_mod
-                ingest_mod.load_library()
-                native_fill = ingest_mod.fill_dense
-            except Exception:
-                native_fill = None
-        rid = dense_id[rows]
-        self._build_stats["fresh_bytes"] += dense_id.nbytes + rid.nbytes
-        if native_fill is not None and len(rid) and rid.min() < 0:
-            # staged rows outside `touched` (shouldn't happen; invariant
-            # is touched >= staged) — the numpy path is the debuggable one
-            native_fill = None
-        if native_fill is not None:
-            counts = np.bincount(rid, minlength=nd)
-            depth = max(int(counts.max()) if len(rows) else 1, d_floor, 1)
-            d_pad = self.dense_depth(depth)
-            rows64 = np.ascontiguousarray(rows, np.int64)
-            vals64 = np.ascontiguousarray(vals, np.float64)
-            dv = self._operand(keep, "dv", (u_pad, d_pad), np.float32)
-            depths_vec = self._operand(keep, "depths", (u_pad,), np.int16)
-            dw = (None if uniform else self._operand(
-                keep, "dw", (u_pad, d_pad), np.float32))
-            wts64 = (None if uniform
-                     else np.ascontiguousarray(wts, np.float64))
-            dropped = native_fill(rows64, vals64, wts64, dense_id,
-                                  dv, dw, depths_vec)
-            if dropped == 0:
-                minmax = None
-                if not uniform:
-                    minmax = self._operand(keep, "minmax", (2, u_pad),
-                                           self.eval_dtype)
-                    minmax[0, :nd] = d_min_t
-                    minmax[1, :nd] = d_max_t
-                if self.stage_dtype != np.float32 and (
-                        uniform or self.compact_general):
-                    dv = dv.astype(self.stage_dtype)
-                if uniform:
-                    return dv, depths_vec, None
-                return dv, dw, minmax
-            # overflow/unmapped rows: fall through to the numpy builder
+        def fresh(shape, dtype):
+            buf = np.zeros(shape, dtype)
+            self._build_stats["fresh_bytes"] += buf.nbytes
+            return buf
 
-        r = rid
         order = np.argsort(r, kind="stable")
         r, v = r[order], vals[order]
         first = np.searchsorted(r, np.arange(nd))
@@ -2576,13 +2477,11 @@ class DigestArena(_ArenaBase):
         if uniform:
             # bf16 staging narrows the VALUE matrix only; weights (0/1,
             # implicit here) and exported centroid weights stay exact
-            dv = self._operand(keep, "dv", (u_pad, d_pad),
-                               self.stage_dtype)
+            dv = fresh((u_pad, d_pad), self.stage_dtype)
             dv[r, pos] = v
             # int16 is exact (depths <= DENSE_DEPTH_CAP < 2^15) and
             # halves the vector's bytes on the link
-            depths_vec = self._operand(keep, "depths", (u_pad,),
-                                       np.int16)
+            depths_vec = fresh((u_pad,), np.int16)
             if len(r):
                 depths_vec[:nd] = np.bincount(
                     r.astype(np.int64), minlength=nd)[:nd]
@@ -2591,15 +2490,13 @@ class DigestArena(_ArenaBase):
             return dv, depths_vec, None
         # compact_general: bf16 VALUES on the general path too (weights
         # and minmax stay eval_dtype — they feed exact accumulations)
-        dv = self._operand(keep, "dv", (u_pad, d_pad),
-                           self.stage_dtype if self.compact_general
-                           else self.eval_dtype)
+        dv = fresh((u_pad, d_pad), self.stage_dtype if self.compact_general
+                   else self.eval_dtype)
         dv[r, pos] = v
-        minmax = self._operand(keep, "minmax", (2, u_pad),
-                               self.eval_dtype)
+        minmax = fresh((2, u_pad), self.eval_dtype)
         minmax[0, :nd] = d_min_t
         minmax[1, :nd] = d_max_t
-        dw = self._operand(keep, "dw", (u_pad, d_pad), self.eval_dtype)
+        dw = fresh((u_pad, d_pad), self.eval_dtype)
         dw[r, pos] = wts[order]
         return dv, dw, minmax
 

@@ -128,20 +128,6 @@ def load_library():
         lib.vn_blast_udp.argtypes = [
             ctypes.c_char_p, ctypes.c_int, ctypes.c_longlong,
             ctypes.c_char_p, ctypes.POINTER(ctypes.c_longlong), ctypes.c_int]
-        lib.vn_fill_dense.restype = ctypes.c_longlong
-        lib.vn_fill_dense.argtypes = [
-            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-            ctypes.c_longlong, ctypes.c_void_p, ctypes.c_longlong,
-            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-            ctypes.c_longlong, ctypes.c_longlong, ctypes.c_int]
-        lib.vn_build_dense.restype = ctypes.c_longlong
-        lib.vn_build_dense.argtypes = [
-            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-            ctypes.c_longlong, ctypes.c_void_p, ctypes.c_longlong,
-            ctypes.c_longlong, ctypes.c_void_p, ctypes.c_void_p,
-            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-            ctypes.c_longlong, ctypes.c_longlong, ctypes.c_int,
-            ctypes.POINTER(ctypes.c_longlong)]
         lib.vn_build_tiers.restype = ctypes.c_longlong
         lib.vn_build_tiers.argtypes = [
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
@@ -317,98 +303,38 @@ def _ptr(a):
     return a.ctypes.data_as(ctypes.c_void_p) if a is not None else None
 
 
-def fill_dense(rows, vals, wts, dense_id, dv, dw, depths,
-               n_threads: int = 4) -> int:
-    """Native COO->dense fill (see vn_fill_dense in ingest_engine.cpp).
-    Arrays must be C-contiguous with dtypes int64/float64/float64/
-    int64/float32/float32/int16.  Row ids outside [0, len(dense_id))
-    are corrupt and count as dropped — both here (cheap vectorized
-    pre-check, so a poisoned batch never reaches native code) and in
-    the C++ fill itself (defense in depth: NumPy-style negative indices
-    would otherwise wrap into an out-of-bounds read).  Returns
-    dropped-element count (caller falls back to the numpy builder when
-    nonzero)."""
-    import numpy as np
-
-    lib = load_library()
-    assert rows.dtype == np.int64 and vals.dtype == np.float64
-    assert dv.dtype == np.float32 and dense_id.dtype == np.int64
-    capacity = len(dense_id)
-    if len(rows) and (int(rows.min()) < 0
-                      or int(rows.max()) >= capacity):
-        return int(((rows < 0) | (rows >= capacity)).sum())
-    u_pad, d_pad = dv.shape
-    return int(lib.vn_fill_dense(
-        _ptr(rows), _ptr(vals), _ptr(wts), len(rows), _ptr(dense_id),
-        capacity, _ptr(dv), _ptr(dw), _ptr(depths), u_pad, d_pad,
-        n_threads))
-
-
-# threads of one vn_build_dense call (fill_dense's default too); the
-# cursors scratch is sized by it
+# threads of one vn_build_tiers call at most (the cursors scratch is
+# sized by it), and the points that make a thread worth its spawn and
+# join (~0.1 ms each on the chip's host: a build of a handful of points
+# — a global's own timers beside its sets — runs on the calling thread;
+# every operand of 65,536 points or more gets all four)
 BUILD_DENSE_THREADS = 4
-
-
-def build_dense(rows, vals, wts, touched, row_map, cursors,
-                dv, dw, depths, u_pad: int, d_pad: int) -> tuple[int, int]:
-    """The large dense build in one native call (vn_build_dense in
-    ingest_engine.cpp): map, count, zero the tails and fill, straight
-    from the staged COO into the caller's kept operands.  rows / touched
-    int64, vals / wts float64 (wts None = uniform), row_map int32
-    [capacity] and cursors int32 [BUILD_DENSE_THREADS * u_pad] scratch,
-    dv / dw float32 [u_pad, d_pad] (dv None = count only), depths int16
-    [u_pad]; all C-contiguous.  Returns (status, deepest row's count):
-    0 filled; -1 not filled (no operands, or the deepest row does not
-    fit d_pad); > 0 that many row ids out of range or not in `touched`,
-    nothing written."""
-    import numpy as np
-
-    lib = load_library()
-    for a, dtype, size in ((rows, np.int64, len(rows)),
-                           (vals, np.float64, len(rows)),
-                           (wts, np.float64, len(rows)),
-                           (touched, np.int64, len(touched)),
-                           (row_map, np.int32, len(row_map)),
-                           (cursors, np.int32, BUILD_DENSE_THREADS * u_pad),
-                           (dv, np.float32, u_pad * d_pad),
-                           (dw, np.float32, u_pad * d_pad),
-                           (depths, np.int16, u_pad)):
-        if a is not None and not (a.dtype == dtype and a.size == size
-                                  and a.flags.c_contiguous):
-            raise ValueError("build_dense: operand of the wrong dtype, "
-                             "size or layout")
-    if dv is not None and depths is None:
-        raise ValueError("build_dense: a fill needs the depth vector")
-
-    depth = ctypes.c_longlong(0)
-    status = lib.vn_build_dense(
-        _ptr(rows), _ptr(vals), _ptr(wts), len(rows), _ptr(touched),
-        len(touched), len(row_map), _ptr(row_map), _ptr(cursors),
-        _ptr(dv), _ptr(dw), _ptr(depths), u_pad, d_pad,
-        BUILD_DENSE_THREADS, ctypes.byref(depth))
-    return int(status), int(depth.value)
+BUILD_POINTS_PER_THREAD = 16384
 
 
 def build_tiers(rows, vals, wts, touched, deep, row_map, cursors,
                 tiers) -> tuple[int, tuple[int, int]]:
-    """Both operands of a tiered flush in one native call
+    """The dense build of a digest flush in one native call
     (vn_build_tiers in ingest_engine.cpp): map, count, zero what the
     last call filled past a row's new count, and fill, straight from the
     staged COO into the caller's kept operands.  rows / touched / deep
-    int64 (deep: the deep tier's positions in touched, ascending), vals
-    / wts float64, row_map int32 [capacity] and cursors int32
+    int64 (deep: the deep tier's positions in touched, ascending; empty
+    for a single operand), vals / wts float64 (wts None: no tier is
+    weighted), row_map int32 [capacity] and cursors int32
     [(BUILD_DENSE_THREADS + 1) * (the tiers' u_pad summed)] scratch;
-    tiers: the long tail's and the deep rows' (dv, dw, depths, u_pad,
-    d_pad) — dv / dw float32 [u_pad, d_pad] (dw None = the uniform form;
-    the tail's dv None = count only), depths int16 [u_pad], the record
-    of what the last call filled, which the operands must match; all
-    C-contiguous.  Returns (status, each tier's deepest row's count):
-    0 filled; -1 nothing written (no operands, or a tier's deepest row
-    does not fit its d_pad); > 0 that many ids out of range, out of
-    order or not in `touched`, nothing written."""
+    tiers: one or two (dv, dw, depths, u_pad, d_pad), the single operand
+    or the long tail's and the deep rows' — dv / dw float32 [u_pad,
+    d_pad] (dw None = the uniform form; the first's dv None = count
+    only), depths int16 [u_pad], the record of what the last call
+    filled, which the operands must match; all C-contiguous.  Returns
+    (status, each tier's deepest row's count): 0 filled; -1 nothing
+    written (no operands, or a tier's deepest row does not fit its
+    d_pad); > 0 that many ids out of range, out of order or not in
+    `touched`, nothing written."""
     import numpy as np
 
     lib = load_library()
+    tiers = list(tiers) + [(None, None, None, 0, 0)] * (2 - len(tiers))
     u_tot = sum(t[3] for t in tiers)
     checks = [(rows, np.int64, len(rows)), (vals, np.float64, len(rows)),
               (wts, np.float64, len(rows)),
@@ -421,16 +347,17 @@ def build_tiers(rows, vals, wts, touched, deep, row_map, cursors,
         checks += [(dv, np.float32, u_pad * d_pad),
                    (dw, np.float32, u_pad * d_pad),
                    (depths, np.int16, u_pad)]
-        if fill and (dv is None or depths is None):
+        if fill and u_pad and (dv is None or depths is None):
             raise ValueError("build_tiers: a fill needs every tier's "
                              "operand and its record")
+        if wts is None and dw is not None:
+            raise ValueError("build_tiers: a weighted tier needs the "
+                             "weights")
     for a, dtype, size in checks:
         if a is not None and not (a.dtype == dtype and a.size == size
                                   and a.flags.c_contiguous):
             raise ValueError("build_tiers: operand of the wrong dtype, "
                              "size or layout")
-    if wts is None:
-        raise ValueError("build_tiers: the deep tier needs the weights")
 
     def pointers(i):
         return (ctypes.c_void_p * 2)(*(
@@ -441,11 +368,13 @@ def build_tiers(rows, vals, wts, touched, deep, row_map, cursors,
         return (ctypes.c_longlong * 2)(*(t[i] for t in tiers))
 
     depth = (ctypes.c_longlong * 2)(0, 0)
+    threads = max(1, min(BUILD_DENSE_THREADS,
+                         len(rows) // BUILD_POINTS_PER_THREAD))
     status = lib.vn_build_tiers(
         _ptr(rows), _ptr(vals), _ptr(wts), len(rows), _ptr(touched),
         len(touched), _ptr(deep), len(deep), len(row_map), _ptr(row_map),
         _ptr(cursors), pointers(0), pointers(1), pointers(2), sizes(3),
-        sizes(4), BUILD_DENSE_THREADS, depth)
+        sizes(4), threads, depth)
     return int(status), (int(depth[0]), int(depth[1]))
 
 

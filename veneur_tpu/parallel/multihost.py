@@ -102,3 +102,70 @@ def maybe_init_from_config(cfg) -> None:
             num_processes=cfg.distributed_num_processes or None,
             process_id=(cfg.distributed_process_id
                         if cfg.distributed_process_id >= 0 else None))
+
+
+# the arena families whose key dictionaries the lockstep gather compares
+# (`_ArenaBase.family` of MetricAggregator._FAMILIES)
+LOCKSTEP_FAMILIES = ("digest", "moments", "compactor", "counter", "gauge",
+                     "set", "status")
+
+
+def lockstep_agree(n_digests: int, depth: int, n_counters: int,
+                   n_sets: int, uniform: bool, fingerprints: dict) -> tuple:
+    """Lockstep agreement before a multi-controller flush: every
+    controller must run the same program on the same global shapes and
+    the same fetch sequence, whatever ITS families touched this interval
+    — one tiny DCN gather of (touched counts, staged depth) decides for
+    everyone.  The same gather carries each arena's key-dictionary
+    fingerprint (`fingerprints`: family -> (key-set checksum, key->row
+    checksum), the snapshot's lock-coherent copy): a registration-order
+    divergence between controllers would silently misalign rows (every
+    process indexes the same global arrays), so it fails loudly here
+    instead.  Returns the agreed (touched digest rows, depth, touched
+    counter rows, touched set rows, uniform)."""
+    import jax
+    import numpy as np
+    from jax.experimental import multihost_utils
+
+    names = LOCKSTEP_FAMILIES
+    cks = np.asarray(
+        [fingerprints[n][0] for n in names]
+        + [fingerprints[n][1] for n in names],
+        np.uint64).view(np.int64)
+    flags = multihost_utils.process_allgather(np.concatenate(
+        [np.asarray([n_digests, depth, n_counters, n_sets, int(uniform)],
+                    np.int64), cks]))
+    g_nd, g_depth, g_nc, g_ns = flags[:, :4].max(axis=0).tolist()
+    # the uniform kernel is a STATIC program choice — legal only when
+    # every controller's staging was uniform
+    g_uniform = bool(flags[:, 4].min())
+    nf = len(names)
+    keyset_all = flags[:, 5:5 + nf]
+    keyrow_all = flags[:, 5 + nf:5 + 2 * nf]
+    # same key SET everywhere but different key->row assignment = silent
+    # row misalignment (a registration-order divergence).  Differing key
+    # sets pass: with O(1) gathered state per family, a shared-key row
+    # conflict cannot be distinguished from benign one-sided keys, so
+    # this is a best-effort tripwire — it catches the canonical ordering
+    # bug outright, and catches an asymmetric-registration row conflict
+    # as soon as GC (or registration) makes the key sets converge (at
+    # which point the dictionaries genuinely ARE misaligned for the
+    # shared keys).  The strict contract remains: shared keys must be
+    # registered in the same order everywhere
+    diverged = [
+        name for i, name in enumerate(names)
+        if (keyset_all[:, i] == keyset_all[0, i]).all()
+        and not (keyrow_all[:, i] == keyrow_all[0, i]).all()]
+    if diverged:
+        raise RuntimeError(
+            "lockstep violation: controllers hold the same "
+            f"keys with DIFFERENT row assignments for famil"
+            f"{'ies' if len(diverged) > 1 else 'y'} "
+            f"{', '.join(diverged)} (process "
+            f"{jax.process_index()} of "
+            f"{jax.process_count()}).  All controllers must "
+            "register shared keys in the same order "
+            "(parallel/multihost.py lockstep contract); "
+            "flushing with misaligned rows would silently "
+            "merge unrelated timeseries")
+    return g_nd, g_depth, g_nc, g_ns, g_uniform
